@@ -87,6 +87,10 @@ def run_variant(name, env):
 
 
 def main():
+    print("[activation_residual_ab] CPU structure check by design: JAX_PLATFORMS=%s "
+          "(pinned by this script when unset); counts, bytes and "
+          "orderings only — no time or rate below is a device number"
+          % os.environ["JAX_PLATFORMS"], flush=True)
     variants = [
         ("base", {}),
         ("bn_bf16", {"MXNET_BN_BF16_RESIDUAL": "1"}),

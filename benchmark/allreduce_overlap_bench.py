@@ -1,8 +1,8 @@
 """Bucketed vs per-key gradient all-reduce microbench.
 
-Extends the kvstore busbw leg (tools/bandwidth.py, 52.4 GB/s on-chip
-row in VERDICT.md) with the dispatch-count story behind the gradient
-fusion layer (parallel/fusion.py): a per-key push pays one collective
+Extends the kvstore busbw leg (tools/bandwidth.py; 52.4 GB/s in
+PERF.md "Chip numbers of 2026-08-01", `bandwidth`) with the
+dispatch-count story behind the gradient fusion layer (parallel/fusion.py): a per-key push pays one collective
 dispatch per parameter, a bucketed push pays one per ~25 MB bucket
 lane, and inside a jitted step the bucketed form lets XLA overlap each
 bucket's collective with remaining backward compute.
@@ -287,9 +287,15 @@ def main():
     _pre_jax_setup(args.devices)
 
     import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from mxnet_tpu.chip import use_compile_cache
+    use_compile_cache()
     n = jax.device_count()
+    print("[allreduce_overlap_bench] CPU structure check by design: "
+          "JAX_PLATFORMS=%s (pinned by this script when unset); dispatch "
+          "counts and bytes are the result — the *_gb_s columns are XLA's "
+          "%s backend on a virtual mesh, never a device number"
+          % (os.environ["JAX_PLATFORMS"], jax.default_backend()),
+          flush=True)
     print(json.dumps({"metric": "allreduce_bench_mesh", "devices": n,
                       "backend": jax.default_backend()}))
     rows = []

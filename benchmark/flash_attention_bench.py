@@ -20,8 +20,8 @@ import numpy as np
 
 
 def main():
-    from mxnet_tpu._discover import ensure_backend
-    ensure_backend()
+    from mxnet_tpu.chip import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels.flash_attention import flash_attention

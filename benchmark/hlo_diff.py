@@ -1,7 +1,8 @@
 """Attribute the framework-vs-hand-built byte gap instruction by
 instruction.
 
-cost_compare's timed chip A/B (BENCH_TABLE cost_compare_timed) shows
+cost_compare's timed chip A/B (PERF.md "Chip numbers of 2026-08-01",
+cost_compare_timed — a claim until re-measured) shows
 the shipped framework ResNet-50 step moving ~10 GB/step more than the
 hand-built jax step at the same shapes — bytes, not flops. XLA's
 cost_analysis() only gives totals, so this script compiles BOTH steps
@@ -80,8 +81,8 @@ def summarize(tag, rows):
 
 
 def main():
-    from mxnet_tpu._discover import pin_platform_from_env
-    pin_platform_from_env()
+    from mxnet_tpu.chip import use_compile_cache
+    use_compile_cache()
     import importlib.util
     import jax
     import jax.numpy as jnp
@@ -132,8 +133,8 @@ def serving():
     MXNET_PAGED_DECODE_PALLAS flag at trace time. The diff row set is
     what the serving_megakernel bench leg's GB/step numbers roll up
     from, instruction by instruction."""
-    from mxnet_tpu._discover import pin_platform_from_env
-    pin_platform_from_env()
+    from mxnet_tpu.chip import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.observability import hlo

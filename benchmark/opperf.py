@@ -140,8 +140,8 @@ def dispatch_latency(iters=3000):
     CachedOp(add graph) -> bound executor forward. All on (4, 4)
     float32 so compute is negligible."""
     import time
-    from mxnet_tpu._discover import ensure_backend
-    ensure_backend()  # wedge guard before the first raw jnp touch
+    from mxnet_tpu.chip import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
     import mxnet_tpu as mx

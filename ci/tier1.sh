@@ -1,11 +1,9 @@
 #!/usr/bin/env bash
 # Tier-1 gate: the ROADMAP verify command + the dispatch-overhead smoke,
-# run STRICTLY SERIALLY. The build host has ONE core (PERF.md
-# operational note): any concurrent pytest/bench process starves the
-# backend-liveness probe into a false CPU fallback and multi-device
-# CPU collective rendezvous into 40 s-timeout aborts — so this script
-# never backgrounds a stage, and it FAILS LOUDLY on any stage rather
-# than degrading.
+# run STRICTLY SERIALLY: on a host with few cores a concurrent
+# pytest/bench process starves multi-device CPU collective rendezvous
+# into 40 s-timeout aborts — so this script never backgrounds a stage,
+# and it FAILS LOUDLY on any stage rather than degrading.
 #
 #   ./ci/tier1.sh            # tier-1 suite + dispatch smoke
 #   TIER1_OBS=1 ./ci/tier1.sh  # + MXNET_OBS=1 telemetry smoke lane

@@ -66,12 +66,12 @@ def main():
                              "(contrib.fold_bn deployment path)")
     args = parser.parse_args()
 
-    # the backend is part of the record: a silent CPU fallback must be
-    # visible in the captured stdout, not discovered from the timings
+    # the device is part of the record: it is printed with the rows,
+    # not discovered from the timings
     import jax
-    from mxnet_tpu._discover import ensure_backend
-    ensure_backend()
-    print("backend: %s" % jax.default_backend(), flush=True)
+    dev = jax.devices()[0]
+    print("backend: %s (%s x%d)" % (dev.platform, dev.device_kind,
+                                    len(jax.devices())), flush=True)
 
     shape = tuple(int(d) for d in args.image_shape.split(","))
     for network in args.networks.split(","):

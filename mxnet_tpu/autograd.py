@@ -207,8 +207,17 @@ def _backward_impl(outputs, head_grads=None, retain_graph=False,
                 pnode, pidx = inp._ag_node
                 add_ct(pnode, pidx, g)
                 needed.add(id(pnode))
+        # cotangents are this sweep's state: a retained graph must not
+        # carry them into its next backward
+        node.cotangents = [None] * node.num_outputs
         if not retain_graph:
-            node.cotangents = [None] * node.num_outputs
+            # free the graph's buffers now, as the reference does: the
+            # pullback closes over the saved activations (9 GB for a
+            # hybridized ResNet-50 at batch 128), and an output the user
+            # still holds (`loss`) would otherwise pin them through
+            # _ag_node into the next iteration's forward
+            node.vjp_fn = None
+            node.inputs = ()
 
     # write accumulated grads into .grad respecting grad_req
     for leaf, g in grad_acc.values():

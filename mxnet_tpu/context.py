@@ -10,7 +10,7 @@ import threading
 
 import jax
 
-from ._discover import ensure_backend
+from .base import MXNetError
 
 _thread_local = threading.local()
 
@@ -23,10 +23,9 @@ class Context:
     _default_ctx = threading.local()
 
     def __init__(self, device_type, device_id=0):
-        # NOTE: no ensure_backend() here — Contexts are constructed at
-        # import time (model_zoo ctx=cpu() default args) and must stay
-        # free of backend discovery; the guard runs at device RESOLUTION
-        # (jax_device/_accelerators) and in ndarray._resolve_ctx.
+        # Contexts are constructed at import time (model_zoo ctx=cpu()
+        # default args) and stay free of backend discovery; devices are
+        # looked up at RESOLUTION (jax_device/_accelerators).
         if isinstance(device_type, Context):
             self.device_type, self.device_id = device_type.device_type, device_type.device_id
         else:
@@ -56,18 +55,29 @@ class Context:
     # -- jax mapping ------------------------------------------------------
     @property
     def jax_device(self):
-        """The concrete jax.Device this context denotes."""
-        ensure_backend()  # wedge-proof first discovery (_discover.py)
-        if self.device_type == "cpu" or self.device_type == "cpu_pinned" \
-                or self.device_type == "cpu_shared":
+        """The concrete jax.Device this context denotes. An accelerator
+        context (tpu/gpu) resolves to the host only when the process was
+        explicitly pinned to the CPU (JAX_PLATFORMS=cpu — the tests'
+        8-virtual-device stand-in); otherwise a missing accelerator is an
+        error, and so is an index past the device count."""
+        if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = _devices_by_platform("cpu")
         else:
             devs = _accelerators()
-            if not devs:  # no accelerator present: transparently run on host
+            if not devs:
+                if not _cpu_pinned():
+                    raise MXNetError(
+                        "%r: no accelerator in this process (jax found "
+                        "platform %r); set JAX_PLATFORMS=cpu to run "
+                        "accelerator contexts on the host on purpose"
+                        % (self, jax.default_backend()))
                 devs = _devices_by_platform("cpu")
-        if not devs:
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%r: device index out of range, %d %s device(s) present"
+                % (self, len(devs),
+                   devs[0].platform if devs else self.device_type))
+        return devs[self.device_id]
 
     def empty_cache(self):
         """Release pooled memory (reference Context.empty_cache). XLA manages
@@ -96,7 +106,6 @@ def _devices_by_platform(platform):
     only THIS process's devices are addressable for eager placement, so
     cpu(0)/tpu(0) means local device 0 (reference semantics: each worker
     sees its own GPUs); the global mesh is the parallel layer's job."""
-    ensure_backend()  # wedge-proof first discovery (_discover.py)
     try:
         if jax.process_count() > 1:
             return [d for d in jax.local_devices()
@@ -106,8 +115,13 @@ def _devices_by_platform(platform):
         return []
 
 
+def _cpu_pinned():
+    """True when the process was explicitly held to the CPU platform
+    (JAX_PLATFORMS=cpu, or the same through jax.config)."""
+    return (jax.config.jax_platforms or "").strip().lower() == "cpu"
+
+
 def _accelerators():
-    ensure_backend()  # wedge-proof first discovery (_discover.py)
     if jax.process_count() > 1:
         return [d for d in jax.local_devices() if d.platform != "cpu"]
     return [d for d in jax.devices() if d.platform != "cpu"]

@@ -83,9 +83,8 @@ def needs_serial_dispatch(arrays):
     hardware path never pays this sync."""
     global _BACKEND_IS_CPU
     if _BACKEND_IS_CPU is None:
-        # the backend is fixed once jax initializes (the library pins it
-        # before first touch, _discover.py); default_backend() re-resolves
-        # config every call — too slow for the dispatch path
+        # the backend is fixed once jax initializes; default_backend()
+        # re-resolves config every call — too slow for the dispatch path
         _BACKEND_IS_CPU = jax.default_backend() == "cpu"
     if not _BACKEND_IS_CPU:
         return False
@@ -167,10 +166,7 @@ def wait_for_var(arr):
 def wait_for_all():
     """MXNDArrayWaitAll: drain host-side queues and device work."""
     _ENGINE.wait_for_all()
-    try:
-        jax.effects_barrier()
-    except Exception:  # pragma: no cover - older jax
-        pass
+    jax.effects_barrier()
 
 
 def set_bulk_size(size):

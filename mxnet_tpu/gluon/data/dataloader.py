@@ -142,9 +142,9 @@ def _batch_from_shm(name, structure, specs, convert):
 
 def _spawn_worker_entry(payload, key_queue, data_queue):
     """Spawn-mode entry: pin the CPU platform BEFORE unpickling anything
-    (unpickling NDArrays re-creates them through jax — the worker must
-    never initialize the parent's accelerator plugin, and several
-    workers grabbing one TPU chip would wedge it)."""
+    (unpickling NDArrays re-creates them through jax — and the chip
+    belongs to the parent: one process per chip, so a worker that
+    touched it would fail or hang)."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         dataset, batchify_fn = pickle.loads(payload)

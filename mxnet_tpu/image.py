@@ -178,8 +178,6 @@ def _nchw_f32(batch_np):
     device; on CPU it is a single vectorized XLA kernel."""
     import jax
     import jax.numpy as jnp
-    from ._discover import ensure_backend
-    ensure_backend()  # may be the process's first jax touch (wedge guard)
     global _nchw_jit
     if _nchw_jit is None:
         _nchw_jit = jax.jit(

@@ -25,8 +25,7 @@ NEG_INF = -1e30
 # lowerable layout (same choice as jax's reference TPU kernels). The
 # rule's "equal to the array dim" clause also admits [rows, 1] blocks
 # at 1/128th the stat HBM traffic (the dk/dv kernel re-streams lse and
-# delta once per q block) — env-overridable for the on-chip A/B
-# (benchmark/run_chip_queue.py flash_stat_lanes1 / train_lm_lanes1).
+# delta once per q block) — env-overridable for an on-chip A/B (not measured).
 STAT_LANES = int(os.environ.get("MXNET_FLASH_STAT_LANES", "128"))
 
 MIN_BLOCK = 8           # below this the grid is degenerate, not tiled

@@ -556,7 +556,8 @@ def dense_decode_with_lse(q, k_cache, v_cache, lengths):
 
     On a single v5e chip this BEATS the Pallas decode kernel at serving
     shapes (chip: 4075 tok/s dense vs 841 flash at bs8/d512/8L/4096 —
-    BENCH_TABLE decode_dense/decode_flash): decode attention reads
+    PERF.md "Chip numbers of 2026-08-01", a claim until re-measured):
+    decode attention reads
     [1, T] scores, so there is no T x T materialization for a flash
     schedule to avoid, and XLA runs the whole cache read as one fused
     batched contraction while the kernel pays per-grid-step overhead

@@ -212,7 +212,7 @@ def _paged_call(q, kpool, vpool, ks, vs, tables, pos, kb, bs, num_kb,
         def idx(b_, h_, p_, ki_, tables_ref, pos_ref):
             blk = tables_ref[b_, ki_ * kb + i]
             live = ki_ * block_k <= pos_ref[b_] + span - 1
-            return (jnp.where(live, blk, 0), 0, h_, 0)
+            return (jnp.where(live, blk, 0), 0, h_)
         return idx
 
     def _scale_index(i):
@@ -226,10 +226,18 @@ def _paged_call(q, kpool, vpool, ks, vs, tables, pos, kb, bs, num_kb,
         return (b_, h_, 0, 0)
 
     qspec = pl.BlockSpec((None, None, rows, d), _q_index)
-    kvspec = [pl.BlockSpec((None, bs, None, d), _pool_index(i))
+    # Mosaic tiles the LAST TWO block dims, so a (bs, <squeezed KV
+    # head>, d) block of the [NB, bs, KVH, D] pool does not lower.
+    # View the pool as [NB, bs, KVH*D] (a free reshape: same bytes)
+    # and take head h as lane-block h of width d — (bs, d) is then the
+    # tiled pair, bs the full second-minor dim and d lane-aligned
+    # (d % 128 == 0 on a TPU; interpret mode takes any d).
+    kflat = kpool.reshape(-1, bs, kvh * d)
+    vflat = vpool.reshape(-1, bs, kvh * d)
+    kvspec = [pl.BlockSpec((None, bs, d), _pool_index(i))
               for i in range(kb)]
     in_specs = [qspec] + kvspec + kvspec
-    inputs = [q] + [kpool] * kb + [vpool] * kb
+    inputs = [q] + [kflat] * kb + [vflat] * kb
     scratch = [
         pltpu.VMEM((rows, d), jnp.int32 if int8 else jnp.float32),
         pltpu.VMEM((rows, STAT_LANES), jnp.float32),

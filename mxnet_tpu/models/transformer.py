@@ -242,14 +242,15 @@ def _flash_min_seq():
     """Sequence-length crossover for the flash-vs-dense dispatch below.
 
     The only flash-vs-dense chip A/B so far has DENSE winning at
-    T=4096 (BENCH_TABLE `flash_attention`: fwd 16.51 ms dense vs 21.92
-    flash; fwd+bwd 37.17 vs 44.15), so a config that requests the
+    T=4096 (PERF.md "Chip numbers of 2026-08-01", `flash_attention`:
+    fwd 16.51 ms dense vs 21.92 flash; fwd+bwd 37.17 vs 44.15 — a
+    claim until re-measured), so a config that requests the
     flash kernel still routes short sequences to the dense softmax and
     engages the streamed kernel only where the [T, T] score matrix
     stops fitting the bandwidth budget. 8192 is the first unmeasured
     length above that datapoint ("dense dies past 4k" is a claim, not
-    a number — the T>=8192 sweep legs in run_chip_queue.py decide);
-    MXNET_FLASH_MIN_SEQ re-pins the crossover when they land."""
+    a number: T >= 8192 is not measured); MXNET_FLASH_MIN_SEQ re-pins
+    the crossover."""
     from .. import _fastenv
     try:
         return int(_fastenv.get("MXNET_FLASH_MIN_SEQ", "8192"))
@@ -899,8 +900,7 @@ def speculative_generate(params, draft_params, prompt, n_new, cfg,
     round — compiles to ONE device program (_spec_core), dispatched
     once: rounds advance in a lax.while_loop with the acceptance test
     on device, so tokens/s is bounded by model compute, not by
-    host-loop round trips (which dominate when the accelerator sits
-    behind a network tunnel). The round count and per-round window
+    host-loop syncs. The round count and per-round window
     width k are fixed at trace time; near the budget edge extra
     emissions are masked rather than re-shaped, and k is clamped so
     the fixed-width draft/verify writes stay inside both caches

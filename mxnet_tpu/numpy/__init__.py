@@ -123,8 +123,6 @@ def _unwrap(x):
 
 
 def array(object, dtype=None, ctx=None):
-    from .._discover import ensure_backend
-    ensure_backend()  # mx.np.array may be a process's first jax touch
     return ndarray(jnp.asarray(_unwrap(object), dtype=dtype),
                    ctx or current_context())
 

@@ -35,9 +35,9 @@ sentinel (``tools/obs_regression.py``) diffs against a committed
 baseline; ``compare_summaries()`` is the diff itself.
 
 Knobs: ``MXNET_OBS_OPS`` (default on when MXNET_OBS is on) gates both
-halves; ``MXNET_OBS_OPS_TOPK`` table depth;
-``MXNET_OBS_OPS_PEAK_FLOPS`` / ``MXNET_OBS_OPS_HBM_GBS`` set the
-roofline used for the bound/share columns.
+halves; ``MXNET_OBS_OPS_TOPK`` table depth. The roofline used for the
+bound/share columns is the chip's published peaks by ``device_kind``
+(``mxnet_tpu/chip.py``).
 """
 
 import threading
@@ -74,14 +74,17 @@ def topk():
 
 
 def peak_flops():
-    """Roofline compute peak (flop/s) for the bound/share columns;
-    default matches the v5e bf16 dense peak the LM bench uses."""
-    return float(_fastenv.get("MXNET_OBS_OPS_PEAK_FLOPS", 197e12))
+    """Roofline compute peak (flop/s) for the bound/share columns: the
+    attached chip's published bf16 peak by device_kind (mxnet_tpu/
+    chip.py; the modelled v5e in a CPU-pinned process)."""
+    from .. import chip
+    return chip.peaks().bf16_flops
 
 
 def hbm_bw():
-    """Roofline HBM bandwidth (bytes/s); default 819 GB/s (v5e)."""
-    return float(_fastenv.get("MXNET_OBS_OPS_HBM_GBS", 819)) * 1e9
+    """Roofline HBM bandwidth (bytes/s), from the same table."""
+    from .. import chip
+    return chip.peaks().hbm_bytes_per_s
 
 
 def note_scope(name):

@@ -16,8 +16,8 @@ the data (median relative error = the calibration error).
 
 Fit form per family: ``ms ~= a * flops_ms + b * bytes_ms + c`` where
 ``flops_ms = flops / peak_flops * 1e3`` and ``bytes_ms = hbm_bytes /
-hbm_bw * 1e3`` (peaks from the attribution roofline knobs
-``MXNET_OBS_OPS_PEAK_FLOPS`` / ``MXNET_OBS_OPS_HBM_GBS``). With fewer
+hbm_bw * 1e3`` (peaks from ``mxnet_tpu/chip.py``'s table, by
+``device_kind``). With fewer
 than 3 points a single achieved-fraction scale ``ms ~= alpha *
 max(flops_ms, bytes_ms)`` is fitted instead; a family with no
 archived points falls back to the global fit.

@@ -94,8 +94,6 @@ FINGERPRINT_ENVS = (
     "MXNET_FLASH_BLOCK_Q",
     "MXNET_FLASH_BLOCK_K",
     "MXNET_FLASH_STAT_LANES",
-    "MXNET_OBS_OPS_PEAK_FLOPS",
-    "MXNET_OBS_OPS_HBM_GBS",
 )
 
 _lock = threading.Lock()
@@ -243,11 +241,9 @@ def config_fingerprint(extra=None, discover=True):
 
     ``discover=False`` NEVER initializes a backend: the device doc
     comes from the newest archived record (written by the process that
-    measured it), else the unknown-device placeholder. This is for
-    orchestrators like ``benchmark/run_chip_queue.py`` whose contract
-    is that one leg subprocess at a time exclusively claims the chip —
-    a ``jax.devices()`` in the parent would hold the claim and starve
-    every later leg. The placeholder is not cached, so the doc
+    measured it), else the unknown-device placeholder. This is for a
+    parent that must stay off jax because its child holds the chip (a
+    chip belongs to one process at a time). The placeholder is not cached, so the doc
     upgrades to the real one once a leg has archived it."""
     doc = _device_doc[0]
     if doc is None:
@@ -488,8 +484,8 @@ def append_bench(leg, value=None, unit=None, metric=None, extra=None,
                  dirpath=None, run=None, fingerprint=None, config=None):
     """Archive one bench headline row (benchmark/common.py's hook).
     ``fingerprint``/``config`` let a caller that already computed the
-    fingerprint (run_chip_queue's orchestrator, which must not trigger
-    device discovery) pass it through instead of recomputing. Returns
+    fingerprint (a parent that must not trigger device discovery)
+    pass it through instead of recomputing. Returns
     the path written, or None when the store is off. Never raises — a
     bench must not fail because archiving did."""
     try:

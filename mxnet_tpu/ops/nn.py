@@ -262,7 +262,8 @@ def _pool_index_residual():
     # scatter-adds — on chip that was most of the 10 GB/step gap
     # between the shipped ResNet step (56.2 GB, 2187 img/s) and the
     # hand-built step (45.8 GB, 2461 img/s) in the same session
-    # (BENCH_TABLE cost_compare_timed). The native lax.reduce_window
+    # (PERF.md "Chip numbers of 2026-08-01", cost_compare_timed — a
+    # claim until re-measured). The native lax.reduce_window
     # path lowers to one fused window reduce + select-and-scatter and
     # carries the SAME first-max tie convention the reference uses
     # (mshadow pooling; verified: gradient of an all-equal window lands

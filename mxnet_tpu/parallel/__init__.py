@@ -51,11 +51,6 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None):
                      else os.environ.get("MXNET_TPU_PROC_ID", "0"))
     if coordinator is None and num_processes == 1:
         return False
-    # honor JAX_PLATFORMS before the backend initializes: discovery
-    # plugins can override the env var (the tests/conftest.py gotcha),
-    # and the local launcher depends on its cpu pin sticking
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     if (os.environ.get("JAX_PLATFORMS") or "").startswith("cpu"):
         # cross-process collectives on the CPU backend need the gloo
         # implementation (XLA:CPU's default rejects multiprocess

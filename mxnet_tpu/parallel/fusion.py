@@ -37,11 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.5 top-level alias
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .. import _fastenv
 from ..observability import chaos as _chaos
 from ..observability import watchdog as _wd
@@ -361,8 +356,8 @@ def _shard_update_fn(devices, n, l_pad, wdtype, gdtype, rule_name,
                  tuple(r_spec for _ in range(_N_EXTRA[rule_name]))),
                 (s_spec, s_spec) if has_mults else (r_spec, r_spec))
     out_specs = (s_spec, tuple(s_spec for _ in range(n_states)))
-    mapped = _shard_map(local, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs)
     return jax.jit(mapped, donate_argnums=(1, 2))
 
 

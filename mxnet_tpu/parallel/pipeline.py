@@ -120,9 +120,8 @@ def spmd_pipeline(layer_fn, stage_params, x, mesh, axis_name="pp",
 
     param_specs = jax.tree.map(lambda _: P(axis_name), stage_params)
     manual = set((axis_name,) + tuple(extra_manual_axes))
-    from .ring import _shard_map
-    out = _shard_map(per_stage, mesh=mesh,
-                     in_specs=(param_specs, mb_spec),
-                     out_specs=mb_spec,
-                     axis_names=manual)(stage_params, mb)
+    out = jax.shard_map(per_stage, mesh=mesh,
+                        in_specs=(param_specs, mb_spec),
+                        out_specs=mb_spec,
+                        axis_names=manual)(stage_params, mb)
     return out.reshape(x.shape)

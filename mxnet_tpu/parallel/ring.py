@@ -20,44 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.5 top-level alias
-    _shard_map_impl = jax.shard_map
-    _SMAP_NEW_API = True
-except AttributeError:                  # 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SMAP_NEW_API = False
-
-
-def _shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-               check_vma=None):
-    """shard_map across jax API generations: new jax spells partial
-    manual as ``axis_names={...}`` and the checker ``check_vma``; 0.4.x
-    spells them ``auto=<complement>`` and ``check_rep``."""
-    if _SMAP_NEW_API:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        try:
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
-        except TypeError:               # pre-check_vma new API
-            kw.pop("check_vma", None)
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
-    kw = {}
-    # 0.4.x partial-auto shard_map lowers axis_index to a PartitionId
-    # instruction SPMD partitioning rejects; since the non-manual axes
-    # never appear in these call sites' specs (data is replicated over
-    # them), running fully manual is equivalent — collectives still
-    # only reference the named axes.
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-
 from . import ring_permute
 from ..observability import chaos as _chaos
 from ..observability import watchdog as _wd
@@ -147,7 +109,7 @@ def _flash_block(q, k, v, q_offset, kv_offset, causal, carry, vma=None):
             def _v(x):
                 try:
                     return jax.lax.pcast(x, tuple(vma), to="varying")
-                except (AttributeError, TypeError, ValueError):
+                except ValueError:       # already varying
                     return x
             o, m, l = _v(o), _v(m), _v(l)
     else:
@@ -197,8 +159,8 @@ def ring_attention(q, k, v, axis_name="sp", causal=True,
         # fori_loop carry type matches its (sp-varying) outputs
         try:
             return jax.lax.pcast(x, (axis_name,), to="varying")
-        except (AttributeError, TypeError, ValueError):
-            return x  # already varying (or pcast not available)
+        except ValueError:
+            return x  # already varying
 
     # own block first (no permute), then n-1 rotate+accumulate rounds —
     # exactly n-1 collective-permutes per call
@@ -214,6 +176,19 @@ def ring_attention(q, k, v, axis_name="sp", causal=True,
     return out.astype(q.dtype)
 
 
+def _kernel_off_tpu(what):
+    """A requested Pallas kernel met a backend that is not a TPU. Under
+    the tests' explicit CPU pin the caller substitutes its jnp path
+    (same numerics); anywhere else the request cannot be honoured, and
+    a silent substitute would hide that."""
+    from ..base import MXNetError
+    from ..context import _cpu_pinned
+    if not _cpu_pinned():
+        raise MXNetError(
+            "%s: the Pallas kernel was requested but backend %r is not "
+            "a TPU" % (what, jax.default_backend()))
+
+
 def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True,
                            batch_axis=None, use_flash_kernel=False):
     """Convenience wrapper: apply ring attention to GLOBAL arrays
@@ -223,10 +198,9 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True,
     spec = P(batch_axis, axis_name, None, None)
     manual = (axis_name,) if batch_axis is None else (axis_name, batch_axis)
     kw = {}
-    if use_flash_kernel:
-        interpret = jax.default_backend() != "tpu"
-        partial_manual = bool(set(mesh.axis_names) - set(manual))
-        if interpret and partial_manual:
+    if use_flash_kernel and jax.default_backend() != "tpu":
+        _kernel_off_tpu("ring_attention_sharded(use_flash_kernel=True)")
+        if set(mesh.axis_names) - set(manual):
             # interpret-mode pallas (CPU testing) cannot run under a
             # vma-checked partially-manual shard_map (jax interpreter
             # lowers block fetches to dynamic_slice with mesh-invariant
@@ -234,17 +208,16 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True,
             # annotations and this limitation does not apply; on CPU
             # keep the numerics via the jnp blockwise path.
             use_flash_kernel = False
-        elif interpret:
+        else:
             # fully-manual mesh: disable the checker instead (outputs
             # are per-shard by construction)
             kw["check_vma"] = False
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal,
                            use_flash_kernel=use_flash_kernel)
-    smapped = _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, axis_names=set(manual), **kw)
-    # jit the mapped program: eager shard_map lacks rules for the ring
-    # loop on older jax, and compiled is what a train step wants anyway
+    smapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, axis_names=set(manual), **kw)
+    # jit the mapped program: compiled is what a train step wants
     return _watched_dispatch(
         "ring.attention", jax.jit(smapped), q, k, v,
         axis=axis_name, shape=str(tuple(q.shape)))
@@ -267,8 +240,9 @@ def sp_flash_decode(q, k_cache, v_cache, lengths, mesh, axis_name="sp",
     The per-shard compute defaults to dense_decode_with_lse (plain
     XLA): decode reads [1, T] scores, so there is nothing for a flash
     schedule to tile away, and the chip A/B measured the Pallas decode
-    kernel ~5x slower at serving shapes (BENCH_TABLE decode_dense vs
-    decode_flash). `use_pallas=True` (or MXNET_SP_DECODE_PALLAS=1)
+    kernel ~5x slower at serving shapes (PERF.md "Chip numbers of
+    2026-08-01", decode_dense vs decode_flash — a claim until
+    re-measured). `use_pallas=True` (or MXNET_SP_DECODE_PALLAS=1)
     restores the kernel path."""
     from ..kernels.flash_attention import (dense_decode_with_lse,
                                            flash_decode_with_lse)
@@ -282,9 +256,9 @@ def sp_flash_decode(q, k_cache, v_cache, lengths, mesh, axis_name="sp",
         interpret = jax.default_backend() != "tpu"
     if interpret:
         if explicit_pallas:
-            # deliberate fallback must be distinguishable from
-            # misconfiguration (ADVICE r5): the caller asked for the
-            # kernel by argument and is getting plain XLA instead
+            _kernel_off_tpu("sp_flash_decode(use_pallas=True)")
+            # under the CPU pin the deliberate fallback must still be
+            # distinguishable from misconfiguration (ADVICE r5)
             import warnings
             warnings.warn(
                 "sp_flash_decode: use_pallas=True ignored — interpret "
@@ -321,7 +295,7 @@ def sp_flash_decode(q, k_cache, v_cache, lengths, mesh, axis_name="sp",
     manual = {axis_name} if batch_axis is None else {axis_name, batch_axis}
     b = q.shape[0]
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         local, mesh=mesh, in_specs=(qspec, cspec, cspec, lspec),
         out_specs=qspec, axis_names=manual)
     return _watched_dispatch(

@@ -13,23 +13,17 @@ import threading
 
 import jax
 
-from ._discover import ensure_backend
-
 _state = threading.local()
 
 
 def _stack():
     if not hasattr(_state, "keys"):
-        # PRNGKey is often a process's FIRST jax computation (e.g. its
-        # first op is nd.random.*) — run the wedge guard before it
-        ensure_backend()
         _state.keys = [jax.random.PRNGKey(0)]
     return _state.keys
 
 
 def seed(seed_state, ctx="all"):
     """mx.random.seed (python/mxnet/random.py:38)."""
-    ensure_backend()  # may be the first jax touch (wedge guard)
     _stack()[:] = [jax.random.PRNGKey(int(seed_state))]
 
 

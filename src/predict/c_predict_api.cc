@@ -72,9 +72,9 @@ bool ensure_python() {
   std::lock_guard<std::mutex> lock(g_init_mutex);
   if (g_initialized) return true;
   if (!Py_IsInitialized()) {
-    // default the platform to CPU unless the deployer pinned one: a
-    // wedged accelerator transport must never hang a C caller (the
-    // library-side wedge guard also applies)
+    // the platform is CPU unless the deployer names one
+    // (MXNET_PREDICT_PLATFORM): a chip belongs to one process, and an
+    // embedded predictor must not claim one it was not given
     setenv("JAX_PLATFORMS", getenv("MXNET_PREDICT_PLATFORM")
                                  ? getenv("MXNET_PREDICT_PLATFORM")
                                  : "cpu",

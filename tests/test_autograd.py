@@ -243,3 +243,19 @@ def test_astype_preserves_tape():
         loss = mx.nd.sum(y.astype("float32") * 3)
     loss.backward()
     np.testing.assert_allclose(x.grad.asnumpy(), 6.0)
+
+
+def test_backward_releases_saved_buffers_unless_retained():
+    """backward() frees the graph's saved activations (the reference's
+    retain_graph=False contract): an output the caller still holds must
+    not pin the pullback's residuals into the next iteration."""
+    x = mx.nd.array([1.0, 2.0, 3.0])
+    x.attach_grad()
+    with autograd.record():
+        y = (x * x).sum()
+    node = y._ag_node[0]
+    y.backward(retain_graph=True)
+    assert node.vjp_fn is not None
+    y.backward()
+    assert node.vjp_fn is None and node.inputs == ()
+    np.testing.assert_allclose(x.grad.asnumpy(), [2.0, 4.0, 6.0])

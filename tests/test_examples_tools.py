@@ -321,7 +321,6 @@ def test_bench_fold_cast_variant_matches():
     script = (
         "import os, sys; sys.path.insert(0, %r)\n"
         "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
-        "from mxnet_tpu._discover import ensure_backend; ensure_backend()\n"
         "import numpy as np, jax.numpy as jnp\n"
         "import bench\n"
         "step, args, mom, aux = bench.build_train_step(4, 32, classes=10)\n"

@@ -91,7 +91,7 @@ def test_mesh_collectives_exact_sum():
     """shard_map psum over the 8-device CPU mesh — the all-reduce that
     backs dist_tpu_sync (exact-sum check as in dist_sync_kvstore.py:28)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     import jax.numpy as jnp
 
     mesh = parallel.make_mesh({"dp": 8})

@@ -360,7 +360,7 @@ def test_bucketed_all_reduce_in_jit():
     """The in-jit form: one psum per bucket inside shard_map, results
     equal per-array psums."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu import parallel
 
     n = jax.device_count()

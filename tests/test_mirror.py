@@ -177,7 +177,6 @@ def test_residual_compression_knobs_match_gradients():
 import os, sys
 sys.path.insert(0, %r)
 os.environ["JAX_PLATFORMS"] = "cpu"
-from mxnet_tpu._discover import ensure_backend; ensure_backend()
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd
@@ -249,7 +248,6 @@ def test_maxpool_index_residual_first_max_ties_and_grads():
     code = (
         "import sys; sys.path.insert(0, %r)\n"
         "import os; os.environ['JAX_PLATFORMS']='cpu'\n"
-        "from mxnet_tpu._discover import ensure_backend; ensure_backend()\n"
         "import numpy as np\n"
         "import mxnet_tpu as mx\n"
         "from mxnet_tpu import autograd\n"
@@ -338,7 +336,6 @@ def test_int8_conv_residual_dx_exact_dw_close():
 import os, sys
 sys.path.insert(0, %r)
 os.environ["JAX_PLATFORMS"] = "cpu"
-from mxnet_tpu._discover import ensure_backend; ensure_backend()
 import numpy as np
 import jax
 import jax.numpy as jnp

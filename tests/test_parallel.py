@@ -207,7 +207,7 @@ def test_resnet_dp_mesh_matches_single_device():
     # layer order is deterministic, so align by sorted key
     # tolerance sized to 2 steps of fp32 reduction-order drift through
     # momentum: observed max |delta| ~3e-2 on <0.0003% of elements
-    # (jax 0.4.37 CPU psum tree vs single-device sum)
+    # (CPU psum tree vs single-device sum)
     for kr, kd in zip(sorted(p_ref), sorted(p_dp)):
         np.testing.assert_allclose(p_dp[kd], p_ref[kr], rtol=5e-3,
                                    atol=4e-2, err_msg=kr)
@@ -294,8 +294,7 @@ def test_transformer_pp_matches_unsharded():
     loss_pp = float(jax.jit(
         lambda p, t: T.loss_fn(p, t, cfg, mesh))(sharded, tok))
     # tolerance: the pipeline decomposition's reduction order differs
-    # from the unsharded step (and on jax 0.4.x the stage shard_map
-    # runs fully manual — see parallel/ring.py _shard_map); observed
+    # from the unsharded step; observed
     # drift is ~1e-3 relative, a REAL divergence would be O(1)
     assert abs(loss_ref - loss_pp) < 5e-3 * abs(loss_ref), \
         (loss_ref, loss_pp)

@@ -1,0 +1,141 @@
+"""Ask the TPU's compiler, without a chip, for every Pallas kernel at
+the shapes chip_smoke.py runs (on-chip-measurement guide §2, rehearsal
+3). Interpret mode proves a kernel's arithmetic; only Mosaic says
+whether it lowers (tiling, VMEM, partitioning) — paged_attention passed
+22 interpret tests and had never lowered.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load libtpu, and every xdist worker
+imports every test file), the compiles run in the test's own process,
+and the persistent compilation cache is off around them (a compile for
+a described device is written to the cache but cannot be read back)."""
+
+import functools
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from mxnet_tpu.kernels.paged_decode import paged_attention
+from mxnet_tpu.parallel import ring
+
+# the package re-exports the function under the module's own name
+fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
+
+B, T, H, D = 2, 8192, 16, 128          # flash: where transformer.py routes
+DEC_B, DEC_T = 8, 4096                 # decode: bs 8 against a 4k cache
+NB, BS, KVH = 2048, 16, 4              # paged pool [NB, BS, KVH, D]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described chip; the kernel must be in
+    the program as a Mosaic custom call, not interpreted jnp."""
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text(), \
+        "no Mosaic kernel in the lowered program"
+    return lowered.compile()
+
+
+def _sds(one_chip, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_flash_attention_forward(one_chip):
+    q = _sds(one_chip, (B, T, H, D))
+    _compile(functools.partial(fa.flash_attention, causal=True,
+                               interpret=False), q, q, q)
+
+
+def test_flash_attention_forward_backward(one_chip):
+    q = _sds(one_chip, (B, T, H, D))
+
+    def loss(q_, k_, v_):
+        o = fa.flash_attention(q_, k_, v_, causal=True, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("kv_heads", [H, 2], ids=["mha", "gqa"])
+def test_flash_decode(one_chip, kv_heads):
+    q = _sds(one_chip, (DEC_B, H, D))
+    cache = _sds(one_chip, (DEC_B, DEC_T, kv_heads, D))
+    lengths = _sds(one_chip, (DEC_B,), jnp.int32)
+    _compile(functools.partial(fa.flash_decode, interpret=False),
+             q, cache, cache, lengths)
+
+
+def test_flash_carry_block(one_chip):
+    """The ring's per-round update at an sp=4 shard of T 8192."""
+    bh, t_shard = B * H, T // 4
+    q = _sds(one_chip, (bh, t_shard, D))
+    o = _sds(one_chip, (bh, t_shard, D), jnp.float32)
+    ml = _sds(one_chip, (bh, t_shard), jnp.float32)
+    off = _sds(one_chip, (), jnp.int32)
+    _compile(functools.partial(fa.flash_carry_block, causal=True,
+                               interpret=False),
+             q, q, q, o, ml, ml, off, off)
+
+
+def test_ring_attention_flash_under_shard_map(topo, monkeypatch):
+    """flash_carry_block as ring attention really calls it: inside a
+    vma-checked shard_map over sp=4, ppermutes between rounds. The
+    ring picks compiled-vs-interpret from jax.default_backend(), which
+    here still says cpu, so the test steers that one question."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("sp",))
+    spec = P(None, "sp", None, None)
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    fn = jax.shard_map(
+        functools.partial(ring.ring_attention, axis_name="sp",
+                          causal=True, use_flash_kernel=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names={"sp"})
+    compiled = _compile(fn, q, q, q)
+    assert "collective-permute" in compiled.as_text()
+
+
+@pytest.mark.parametrize("span", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_attention(one_chip, int8, span):
+    kv = _sds(one_chip, (NB, BS, KVH, D),
+              jnp.int8 if int8 else jnp.bfloat16)
+    pool = {"k": kv, "v": kv}
+    if int8:
+        sc = _sds(one_chip, (NB, BS, KVH), jnp.float32)
+        pool.update(ks=sc, vs=sc)
+    q = _sds(one_chip, (DEC_B, span, H, D))
+    tables = _sds(one_chip, (DEC_B, DEC_T // BS), jnp.int32)
+    pos = _sds(one_chip, (DEC_B,), jnp.int32)
+    _compile(functools.partial(paged_attention, interpret=False),
+             q, pool, tables, pos)

@@ -1409,6 +1409,10 @@ SCENARIOS = [("nan", nan_guard), ("ioerror", ioerror),
 
 
 def main():
+    print("[chaos_smoke] CPU structure check by design: JAX_PLATFORMS=%s "
+          "(pinned by this script when unset); counts, bytes and "
+          "orderings only — no time or rate below is a device number"
+          % os.environ["JAX_PLATFORMS"], flush=True)
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("args", nargs="*")
     p.add_argument("--only", help="run one scenario (%s)"

@@ -52,9 +52,8 @@ def worker_env(args, proc_id, base=None):
     if args.launcher == "local":
         # each local process simulates one device so collective code
         # paths run without hardware; OVERRIDE any inherited accelerator
-        # platform — N local processes sharing one real chip would fight
-        # over it (init_distributed re-pins this inside python, since
-        # discovery plugins can override the env var)
+        # platform — a chip belongs to one process, so N local
+        # processes must never reach for one
         env["JAX_PLATFORMS"] = "cpu"
         env.setdefault("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=1")

@@ -26,8 +26,9 @@ Three ways to get a summary in front of it:
 
 The flops/bytes columns are shape-derived estimates from the optimized
 HLO (observability/hlo.py docstring spells out the accounting model);
-``--topk`` / MXNET_OBS_OPS_TOPK controls table depth and
-MXNET_OBS_OPS_PEAK_FLOPS / MXNET_OBS_OPS_HBM_GBS set the roofline.
+``--topk`` / MXNET_OBS_OPS_TOPK controls table depth; the roofline is
+the chip's published peaks by device_kind (mxnet_tpu/chip.py — in this
+CPU-pinned tool the modelled v5e).
 """
 
 import argparse
@@ -128,6 +129,10 @@ def run_kernel_workload():
 
 
 def main(argv=None):
+    print("[obs_ops] CPU structure check by design: JAX_PLATFORMS=%s "
+          "(pinned by this script when unset); counts, bytes and "
+          "orderings only — no time or rate below is a device number"
+          % os.environ["JAX_PLATFORMS"], flush=True)
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--summary", metavar="JSON", default=None,
                    help="print the table from a saved summary instead "

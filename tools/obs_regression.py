@@ -156,6 +156,10 @@ def _fmt(rows):
 
 
 def main(argv=None):
+    print("[obs_regression] CPU structure check by design: JAX_PLATFORMS=%s "
+          "(pinned by this script when unset); counts, bytes and "
+          "orderings only — no time or rate below is a device number"
+          % os.environ["JAX_PLATFORMS"], flush=True)
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--baseline", default=None,
                    help="committed baseline JSON (ci/obs_baseline.json)")

@@ -686,6 +686,10 @@ def store_smoke():
 
 
 def main():
+    print("[obs_smoke] CPU structure check by design: JAX_PLATFORMS=%s "
+          "(pinned by this script when unset); counts, bytes and "
+          "orderings only — no time or rate below is a device number"
+          % os.environ["JAX_PLATFORMS"], flush=True)
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--nproc", type=int, default=1,
                    help="launch N gloo processes and validate the "
