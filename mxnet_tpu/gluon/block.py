@@ -354,7 +354,7 @@ class Block(object):
         # the ring); depth tracked per thread
         depth = getattr(_CALL_DEPTH, "v", 0)
         fwd_span = None
-        if depth == 0 and _obs.enabled():
+        if depth == 0 and _obs.active():
             fwd_span = _obs.span("forward", cat="step",
                                  block=self._name or
                                  type(self).__name__).start()

@@ -1630,6 +1630,17 @@ class ContinuousBatcher(object):
         recorded result is re-delivered by the next step(). Dedup hits
         count ``serving.dedup_hits``; with a journal attached the
         window survives restarts (recover() repopulates it)."""
+        # the whole admission: what lies beside prefill and patch (slot
+        # search, block accounting, bookkeeping) is this span's self time
+        sp = _obs.span("serving.admit", cat="serving")
+        with sp:
+            rid = self._admit_impl(prompt, n_new, seed, stop_token,
+                                   enqueued_ns, priority, key)
+            sp.args["rid"] = rid
+            return rid
+
+    def _admit_impl(self, prompt, n_new, seed, stop_token, enqueued_ns,
+                    priority, key):
         if n_new < 1:
             raise ValueError("n_new must be >= 1")
         if key is not None:
@@ -1639,8 +1650,8 @@ class ContinuousBatcher(object):
                 self._pending_finished[rid0] = list(toks0)
                 hit = rid0
             if hit is not None:
-                _obs.counter("serving.dedup_hits").add(1)
                 if _obs.enabled():
+                    _obs.counter("serving.dedup_hits").add(1)
                     _obs.record_instant(
                         "serving.dedup", cat="serving",
                         args={"rid": hit, "key": str(key)})
@@ -1951,8 +1962,8 @@ class ContinuousBatcher(object):
             _, _, i = min(live)
             req = self._slots[i]
             t_ns = time.perf_counter_ns()
-            _obs.counter("serving.preemptions").add(1)
             if _obs.enabled():
+                _obs.counter("serving.preemptions").add(1)
                 _obs.record_instant(
                     "serving.preempt", cat="serving",
                     args={"rid": req.rid, "lane": i,
@@ -2010,8 +2021,8 @@ class ContinuousBatcher(object):
             _, _, i = min(live)
             req = self._slots[i]
             t_ns = time.perf_counter_ns()
-            _obs.counter("serving.preemptions").add(1)
             if _obs.enabled():
+                _obs.counter("serving.preemptions").add(1)
                 _obs.record_instant(
                     "serving.preempt", cat="serving",
                     args={"rid": req.rid, "lane": i,
@@ -2224,10 +2235,18 @@ class ContinuousBatcher(object):
         With spec_k set each dispatch is a speculative draft/verify
         round (up to chunk_size * (spec_k + 1) tokens per lane per
         dispatch), pipelined the same way."""
-        if self._spec_on:
-            return self._step_spec()
-        if self.pipeline_depth > 1:
-            return self._step_pipelined()
+        # the whole round: what lies beside dispatch and sync (retire
+        # loop, coverage, lane bookkeeping) is this span's self time
+        with _obs.span("serving.step", cat="serving"):
+            if self._spec_on:
+                return self._step_spec()
+            if self.pipeline_depth > 1:
+                return self._step_pipelined()
+            return self._step_sync()
+
+    def _step_sync(self):
+        """One unpipelined scheduling step: dispatch, then block on the
+        fetch of its tokens."""
         obs_on = _obs.enabled()
         finished = {}
         if self._pending_finished:
@@ -2251,8 +2270,8 @@ class ContinuousBatcher(object):
         try:
             if self.paged:
                 self._ensure_coverage(k)
-            # the synchronous dispatch blocks through the host fetch,
-            # so one span covers dispatch + sync
+            # the synchronous dispatch blocks through the host fetch:
+            # serving.sync, inside it, is the wait for the device alone
             with _obs.span("serving.dispatch", cat="serving",
                            mode="sync", chunk=k,
                            lanes=self.active_count):
@@ -2275,8 +2294,7 @@ class ContinuousBatcher(object):
                                              args)
                     if _attr.ops_enabled():
                         self._register_dispatch("decode", fn, args)
-                    nxt, keys, state = fn(*args)
-                    toks = np.asarray(nxt).astype(np.int32)[None]
+                    toks, keys, state = fn(*args)
                 else:
                     fn = (_jitted_ragged_chunk_paged if self.paged
                           else _jitted_ragged_chunk)(
@@ -2287,7 +2305,10 @@ class ContinuousBatcher(object):
                     if _attr.ops_enabled():
                         self._register_dispatch("decode", fn, args)
                     toks, keys, state = fn(*args)
-                    toks = np.asarray(toks).astype(np.int32)   # [k, B]
+                with _obs.span("serving.sync", cat="serving",
+                               mode="sync"):
+                    toks = np.asarray(toks)
+                toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
                 if self.paged:
                     self._pool = state
                 else:
@@ -3112,8 +3133,8 @@ class ContinuousBatcher(object):
         else:
             self._prefix_cache.clear()
         new_fp = self.weight_fingerprint
-        _obs.counter("serving.weight_swaps").add(1)
         if _obs.enabled():
+            _obs.counter("serving.weight_swaps").add(1)
             _obs.record_instant(
                 "serving.swap", cat="serving",
                 args={"fingerprint": new_fp, "previous": prev_fp,

@@ -8,10 +8,17 @@ Prometheus textfile for scraping long runs. ``recompile.py`` watches
 jax.monitoring compile events and flags silent retraces with the
 argument signature that caused them.
 
-Enable with ``MXNET_OBS=1`` (or ``mx.profiler.set_state('run')``).
-With the knob unset every instrumentation site reduces to one guarded
-branch — the hot paths (kvstore dispatch, trainer step, io.next) stay
-within noise (<2%, benchmark/allreduce_overlap_bench.py).
+Two gates (``core.py``). Spans record under ``MXNET_OBS=1``,
+``mx.profiler.set_state('run')`` or any live ``jax.profiler`` trace; under
+a trace they are ``mx.<name>`` annotations on the profiler's host
+timeline, beside the device's, and feed ``core.span_totals()``. Everything
+else (counters, gauges, histograms, instants, flows, the recompile
+detector) records under ``MXNET_OBS=1`` or the profiler state only. With
+both off every instrumentation site reduces to one guarded branch — the
+hot paths (kvstore dispatch, trainer step, io.next) stay within noise
+(<2%, benchmark/allreduce_overlap_bench.py). The operator's recipe
+(``set_state('run')``, N steps, ``set_state('stop')``,
+``dumps(aggregate=True)``) is in docs/OBSERVABILITY.md.
 
 Instrumented out of the box: Trainer/Module step phases (forward /
 backward / allreduce / update), KVStore push/pull/pushpull_fused
@@ -61,8 +68,8 @@ from . import watchdog
 from .attribution import (ops_enabled, format_ops_table,
                           compare_summaries)
 from .attribution import summary as ops_summary
-from .core import (enabled, set_enabled, span, counter, gauge,
-                   record_span, record_instant, record_flow, records,
+from .core import (enabled, active, set_enabled, span, span_totals,
+                   counter, gauge, record_span, record_instant, record_flow, records,
                    counters, dropped, reset)
 from .core import histogram as get_histogram
 from .histogram import Histogram
@@ -92,8 +99,8 @@ __all__ = ["chaos", "core", "dist", "events", "export", "flight",
            "integrity", "recompile", "timeseries",
            "event", "record_incident", "note_exit",
            "watchdog", "ops_enabled", "format_ops_table",
-           "compare_summaries", "ops_summary", "enabled",
-           "set_enabled", "span", "counter", "gauge", "get_histogram",
+           "compare_summaries", "ops_summary", "enabled", "active",
+           "set_enabled", "span", "span_totals", "counter", "gauge", "get_histogram",
            "Histogram", "record_span", "record_instant", "record_flow",
            "records", "counters", "dropped", "reset",
            "start_http_server", "stop_http_server",

@@ -8,12 +8,24 @@ timing already belongs to XLA's profiler (jax.profiler / XPlane) — what
 the runtime needs to observe for itself is the HOST orchestration:
 step phases, collective dispatch, input pipeline, jit boundaries.
 
+Two gates, both derived (no knob of their own):
+
+* ``enabled()`` — ``MXNET_OBS=1`` or the profiler state machine. Gates
+  everything that writes the ring or a registry: counters, gauges,
+  histograms, instants, flows, the recompile detector.
+* ``active()`` — ``enabled()`` OR a live ``jax.profiler`` session, whoever
+  started it. Gates spans alone: while a session is live a span is also a
+  ``TraceAnnotation("mx." + name)``, so it lies on ``/host:CPU`` of the same
+  ``.xplane.pb`` as the device's ``XLA Ops``, and its duration is added to
+  the per-name totals ``span_totals()`` returns.
+
 Design constraints (ISSUE 2 tentpole):
 
 * near-zero cost when off — every instrumentation site guards on
   ``enabled()``, a module override check + one `_fastenv` dict read
-  (~0.1 us); a disabled ``span`` allocates one slotted object and does
-  nothing else. No locks, no time syscalls, no string formatting.
+  (~0.1 us); a disabled ``span`` allocates one slotted object, asks both
+  gates (the session check is ~20 ns) and does nothing else. No locks, no
+  time syscalls, no string formatting.
 * thread-safe when on — the prefetch threads (io.py), the main step
   loop and jax.monitoring callbacks all record concurrently; one lock
   guards the ring head and the counter registry, and record payloads
@@ -30,9 +42,12 @@ the env for the profiler state machine (profiler.set_state/pause).
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from .. import _fastenv
 
-__all__ = ["enabled", "set_enabled", "span", "counter", "gauge",
+__all__ = ["enabled", "active", "set_enabled", "span", "span_totals",
+           "reset_span_totals", "counter", "gauge",
            "histogram", "record_span", "record_instant", "record_flow",
            "records", "counters", "dropped", "reset", "ring_capacity",
            "Counter", "Gauge"]
@@ -51,6 +66,13 @@ _ring = [None] * 0
 _head = 0
 _total = 0
 _counters = {}
+# name -> [count, total_ns, self_ns, max_ns] of the spans that ran under a
+# profiler session; kept in memory, read by span_totals()
+_totals = {}
+_local = threading.local()
+
+# a live jax.profiler session, whoever started it (~20 ns)
+_session_live = _Annotation.is_enabled
 
 
 def enabled():
@@ -60,6 +82,12 @@ def enabled():
         return _override
     v = _fastenv.get("MXNET_OBS")
     return v is not None and v not in ("", "0", "false", "False")
+
+
+def active():
+    """Do spans record? ``enabled()``, or a profiler session is live.
+    Only spans follow this gate."""
+    return enabled() or _session_live()
 
 
 def set_enabled(value):
@@ -92,12 +120,17 @@ def _append(rec):
         _total += 1
 
 
-def record_span(name, cat, t0_ns, t1_ns, args=None):
+def record_span(name, cat, t0_ns, t1_ns, args=None, child_ns=0):
     """Record one completed span. Timestamps are perf_counter_ns values
-    (callers capture them outside the lock)."""
+    (callers capture them outside the lock). A span that enclosed others
+    (``child_ns`` > 0) carries its own share as ``args["self_us"]``."""
+    args = args or {}
+    if child_ns:
+        args = dict(args, self_us=max((t1_ns - t0_ns - child_ns) // 1000,
+                                      0))
     _append(("X", name, cat, (t0_ns - _EPOCH_NS) // 1000,
              max((t1_ns - t0_ns) // 1000, 0),
-             threading.get_ident(), args or {}))
+             threading.get_ident(), args))
 
 
 def record_instant(name, cat="event", args=None):
@@ -117,33 +150,92 @@ def record_flow(name, flow_id, phase, cat="flow", args=None):
 
 
 class span(object):
-    """``with span("allreduce", cat="step", bytes=n):`` — records one
-    "X" (complete) event when recording is on; a cheap no-op otherwise.
-    Usable as a context manager or via explicit start()/stop()."""
+    """``with span("allreduce", cat="step", bytes=n):`` — one span,
+    recorded when ``active()``; a cheap no-op otherwise. Under
+    ``enabled()`` it is an "X" (complete) event in the ring; under a live
+    profiler session it is a ``TraceAnnotation("mx.<name>")`` on the
+    profiler's clock and an entry in ``span_totals()``. A per-thread stack
+    gives it its parent, to which its duration is charged as child time.
+    Usable as a context manager or via explicit start()/stop(); stop()
+    returns the duration in ns, or None when nothing was recorded."""
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann", "_ring",
+                 "_child_ns")
 
     def __init__(self, name, cat="phase", **args):
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = None
+        self._ann = None
 
     def start(self):
-        if enabled():
+        ring, live = enabled(), _session_live()
+        if ring or live:
+            self._ring = ring
+            self._child_ns = 0
+            try:
+                _local.stack.append(self)
+            except AttributeError:
+                _local.stack = [self]
+            if live:
+                self._ann = _Annotation("mx." + self.name)
+                self._ann.__enter__()
             self._t0 = time.perf_counter_ns()
         return self
 
     def stop(self):
-        if self._t0 is not None:
-            record_span(self.name, self.cat, self._t0,
-                        time.perf_counter_ns(), self.args)
-            self._t0 = None
+        t0 = self._t0
+        if t0 is None:
+            return None
+        t1 = time.perf_counter_ns()
+        self._t0 = None
+        dur = t1 - t0
+        stack = getattr(_local, "stack", ())
+        if stack and stack[-1] is self:
+            stack.pop()
+            if stack:
+                stack[-1]._child_ns += dur
+        elif self in stack:
+            # explicit start()/stop() pairs that did not nest (a span
+            # above this one was never stopped): drop the stale ones
+            del stack[stack.index(self):]
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+            with _lock:
+                t = _totals.get(self.name)
+                if t is None:
+                    t = _totals[self.name] = [0, 0, 0, 0]
+                t[0] += 1
+                t[1] += dur
+                t[2] += dur - self._child_ns
+                if dur > t[3]:
+                    t[3] = dur
+        if self._ring:
+            record_span(self.name, self.cat, t0, t1, self.args,
+                        self._child_ns)
+        return dur
 
     __enter__ = start
 
     def __exit__(self, *exc):
         self.stop()
+
+
+def span_totals():
+    """{name: {"count", "total_ns", "self_ns", "max_ns"}} of the spans
+    that ran under a profiler session since the last reset. Self time is
+    the total less the spans opened inside it on the same thread."""
+    with _lock:
+        return {name: {"count": t[0], "total_ns": t[1], "self_ns": t[2],
+                       "max_ns": t[3]}
+                for name, t in _totals.items()}
+
+
+def reset_span_totals():
+    with _lock:
+        _totals.clear()
 
 
 class Counter(object):
@@ -259,8 +351,9 @@ def dropped():
 
 
 def reset():
-    """Clear the ring, the counter registry and the histogram registry
-    (tests, new profile sessions). The ring is rebuilt at the current
+    """Clear the ring, the counter registry, the span totals and the
+    histogram registry (tests, new profile sessions). The ring is
+    rebuilt at the current
     MXNET_OBS_RING."""
     global _ring, _head, _total
     with _lock:
@@ -268,6 +361,7 @@ def reset():
         _head = 0
         _total = 0
         _counters.clear()
+        _totals.clear()
     from . import histogram as _h
     _h.reset()
     from . import events as _ev

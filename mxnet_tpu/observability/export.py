@@ -1,5 +1,7 @@
 """Exporters over the telemetry ring: chrome://tracing JSON, an
-MXNet-style aggregate-stats percentile table, and a Prometheus textfile.
+MXNet-style aggregate-stats percentile table (with, after a profiler
+session that took an XLA trace, the device's launches and idle time laid
+against the program's ``mx.*`` spans), and a Prometheus textfile.
 
 Reference analogues: profiler.h DumpProfile() emits chrome tracing;
 AggregateStats::DumpTable() the text table. The Prometheus writer is the
@@ -8,13 +10,16 @@ scrapeable metrics, not just post-hoc traces): point a node_exporter
 textfile collector at MXNET_OBS_PROM and scrape counters per step.
 """
 
+import bisect
 import json
+import re
 
 from . import core
 from .. import _fastenv
 
 __all__ = ["chrome_trace", "dump_chrome_trace", "aggregate",
-           "aggregate_table", "prometheus_text", "write_prometheus"]
+           "aggregate_table", "idle_by_span", "read_xplane",
+           "format_idle_by_span", "prometheus_text", "write_prometheus"]
 
 
 # ------------------------------------------------------ chrome trace --
@@ -96,17 +101,23 @@ def aggregate():
     """Reduce the ring + counter registry to per-name stats.
 
     Returns {"spans": {name: stats}, "counters": {name: stats}} where
-    span stats are over durations (ms) and counter stats over the added
+    span stats are over durations (ms; ``self_ms`` leaves out the spans
+    opened inside, so enclosing phases do not count twice) and counter
+    stats over the added
     deltas (gauges: observed values); p50/p99 come from the ring samples
     (a suffix when the ring wrapped — count/total stay exact for
     counters because the registry accumulates independently).
     """
     span_samples = {}
+    span_self = {}
     counter_samples = {}
     for rec in core.records():
         ph, name, _cat, _ts, val, _tid, args = rec
         if ph == "X":
             span_samples.setdefault(name, []).append(val / 1000.0)
+            # a span that enclosed others carries its own share
+            span_self[name] = span_self.get(name, 0.0) \
+                + args.get("self_us", val) / 1000.0
         elif ph == "C":
             counter_samples.setdefault(name, []).append(
                 args.get("delta", val))
@@ -115,6 +126,7 @@ def aggregate():
         vals.sort()
         spans[name] = {
             "count": len(vals), "total_ms": sum(vals),
+            "self_ms": span_self[name],
             "min_ms": vals[0], "max_ms": vals[-1],
             "p50_ms": _percentile(vals, 0.50),
             "p99_ms": _percentile(vals, 0.99)}
@@ -158,21 +170,25 @@ def _format_timeseries():
     return lines
 
 
-def aggregate_table():
+def aggregate_table(trace_dir=None):
     """The stats as a text table (reference AggregateStats::DumpTable):
-    one section for span phases (ms), one for counters (raw values)."""
+    one section for span phases (ms), one for counters (raw values).
+    ``trace_dir`` is the directory of a finished profiler session: its
+    ``.xplane.pb`` adds the device's launches and idle time by span."""
     agg = aggregate()
     lines = ["Profile Statistics (mxnet_tpu.observability)",
-             "  Note: span times in ms; counter rows aggregate the "
-             "added deltas, Value is the running total."]
-    fmt = "%-36s %8s %12s %10s %10s %10s %10s"
+             "  Note: span times in ms (Self leaves out the spans opened "
+             "inside); counter rows aggregate the added deltas, Value is "
+             "the running total."]
+    fmt = "%-36s %8s %12s %12s %10s %10s %10s %10s"
     lines.append("")
     lines.append("Spans (phases)")
     lines.append("=" * 14)
-    lines.append(fmt % ("Name", "Count", "Total(ms)", "Min", "Max",
-                        "P50", "P99"))
+    lines.append(fmt % ("Name", "Count", "Total(ms)", "Self(ms)", "Min",
+                        "Max", "P50", "P99"))
     for name, s in agg["spans"].items():
         lines.append(fmt % (name, s["count"], "%.3f" % s["total_ms"],
+                            "%.3f" % s["self_ms"],
                             "%.3f" % s["min_ms"], "%.3f" % s["max_ms"],
                             "%.3f" % s["p50_ms"], "%.3f" % s["p99_ms"]))
     fmtc = "%-36s %8s %12s %10s %10s %10s %10s %12s"
@@ -210,12 +226,152 @@ def aggregate_table():
     lines.extend(costmodel.format_calibration_table())
     from . import goodput
     lines.extend(goodput.format_table_section())
+    if trace_dir is not None:
+        lines.extend(format_idle_by_span(trace_dir))
     if core.dropped():
         lines.append("")
         lines.append("(%d oldest records dropped from the ring; "
                      "percentiles cover the retained suffix)"
                      % core.dropped())
     return "\n".join(lines)
+
+
+# ------------------------------------- the device, by program span ---
+
+SPAN_PREFIX = "mx."
+OUTSIDE = "(outside every span)"
+_DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
+
+
+def _nest(lane_spans):
+    """One thread's spans [(start, end, name)], properly nested ->
+    (change points [(t, name, width)]: from t on the innermost open span
+    is `name`, None for none; {name: [calls, total, self]})."""
+    points, stats, stack = [], {}, []     # stack: [start, end, name, child]
+
+    def close(upto):
+        while stack and (upto is None or stack[-1][1] <= upto):
+            s, e, name, child = stack.pop()
+            st = stats.setdefault(name, [0, 0.0, 0.0])
+            st[0] += 1
+            st[1] += e - s
+            st[2] += e - s - child
+            if stack:
+                stack[-1][3] += e - s
+                points.append((e, stack[-1][2],
+                               stack[-1][1] - stack[-1][0]))
+            else:
+                points.append((e, None, None))
+
+    for s, e, name in sorted(lane_spans, key=lambda x: (x[0], -x[1])):
+        close(s)
+        stack.append([s, e, name, 0.0])
+        points.append((s, name, e - s))
+    close(None)
+    return points, stats
+
+
+def idle_by_span(spans, launches, busy):
+    """The device's launches and idle time laid against host spans, all on
+    one clock. `spans`: [(start, end, name, lane)] (lane: the host thread),
+    `launches`: [start] of device programs, `busy`: [(start, end)] in
+    which the device ran an operation. A launch belongs to the innermost
+    span open at its start, a gap between busy intervals to the innermost
+    span open at its midpoint (the narrowest when threads overlap), else
+    to OUTSIDE. Returns {name: {"calls", "total", "self", "launches",
+    "idle"}}; times in the unit given."""
+    lanes = {}
+    for s, e, name, lane in spans:
+        lanes.setdefault(lane, []).append((s, e, name))
+    rows, lookups = {}, []
+
+    def row(name):
+        return rows.setdefault(name, {"calls": 0, "total": 0.0,
+                                      "self": 0.0, "launches": 0,
+                                      "idle": 0.0})
+
+    for lane_spans in lanes.values():
+        points, stats = _nest(lane_spans)
+        lookups.append(([p[0] for p in points], points))
+        for name, (calls, total, own) in stats.items():
+            r = row(name)
+            r["calls"] += calls
+            r["total"] += total
+            r["self"] += own
+
+    def innermost(t):
+        best, width = OUTSIDE, None
+        for times, points in lookups:
+            i = bisect.bisect_right(times, t) - 1
+            if i >= 0 and points[i][1] is not None \
+                    and (width is None or points[i][2] < width):
+                best, width = points[i][1], points[i][2]
+        return best
+
+    for t in launches:
+        row(innermost(t))["launches"] += 1
+    cur_e = None
+    for s, e in sorted(busy):
+        if cur_e is not None and s > cur_e:
+            row(innermost((cur_e + s) / 2.0))["idle"] += s - cur_e
+        cur_e = e if cur_e is None else max(cur_e, e)
+    return rows
+
+
+def read_xplane(path):
+    """(spans, launches, busy, device plane name) of one `.xplane.pb`, in
+    ns on the profiler's clock: the `mx.*` annotations of `/host:CPU`, and
+    the `XLA Modules` starts and `XLA Ops` intervals of the first device
+    plane (None and empty lists when the trace has none, as on a CPU)."""
+    from jax.profiler import ProfileData
+    spans, launches, busy, device = [], [], [], None
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for lane, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        spans.append((e.start_ns,
+                                      e.start_ns + e.duration_ns,
+                                      e.name, lane))
+        elif device is None and _DEVICE_PLANE.match(plane.name):
+            device = plane.name
+            for line in plane.lines:
+                if line.name == "XLA Modules":
+                    launches = [e.start_ns for e in line.events]
+                elif line.name == "XLA Ops":
+                    busy = [(e.start_ns, e.start_ns + e.duration_ns)
+                            for e in line.events]
+    return spans, launches, busy, device
+
+
+def format_idle_by_span(trace_dir):
+    """The "Device by program span" section of the aggregate table, from
+    the newest `.xplane.pb` under `trace_dir`; [] when there is none."""
+    import glob
+    import os
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not files:
+        return []
+    spans, launches, busy, device = read_xplane(files[-1])
+    rows = idle_by_span(spans, launches, busy)
+    idle_all = sum(r["idle"] for r in rows.values())
+    lines = ["", "Device by program span (%s; %d launches, %.3f ms idle "
+             "between operations)" % (
+                 device or "no device plane in this trace",
+                 len(launches), idle_all / 1e6),
+             "=" * 22]
+    fmt = "%-36s %8s %12s %12s %10s %12s %8s"
+    lines.append(fmt % ("Name", "Calls", "Total(ms)", "Self(ms)",
+                        "Launches", "Idle(ms)", "Idle%"))
+    for name in sorted(rows, key=lambda n: (n == OUTSIDE, n)):
+        r = rows[name]
+        lines.append(fmt % (
+            name, r["calls"] or "-", "%.3f" % (r["total"] / 1e6),
+            "%.3f" % (r["self"] / 1e6), r["launches"],
+            "%.3f" % (r["idle"] / 1e6),
+            "%.1f" % (100.0 * r["idle"] / idle_all) if idle_all else "-"))
+    return lines
 
 
 # ------------------------------------------------- prometheus --------

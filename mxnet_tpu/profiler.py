@@ -16,16 +16,20 @@ module exports the reference's two ways:
   plus any user Domain/Task/Frame spans) to ``filename`` — load it at
   chrome://tracing / ui.perfetto.dev, alongside the XPlane trace dir.
 * ``dumps(aggregate=True)`` returns the aggregate-stats percentile
-  table (count/total/min/max/p50/p99 per phase and per counter), the
-  analogue of the reference's AggregateStats::DumpTable.
+  table (count/total/self/min/max/p50/p99 per phase and per counter),
+  the analogue of the reference's AggregateStats::DumpTable. After a
+  session that took an XLA trace it ends with the device's program
+  launches and idle time by ``mx.*`` span, read from that session's own
+  ``.xplane.pb``.
 
 ``set_state('run')`` force-enables telemetry recording even without
 ``MXNET_OBS=1``; pause/resume gate it. ``set_config(xla_trace=False)``
 skips the XLA trace (host-side telemetry only — cheap enough for unit
-tests and always-on dashboards)."""
+tests and always-on dashboards). The XLA trace is taken without the
+Python function tracer: the program's spans are the host timeline, and a
+hook on every Python call would slow the host-bound step it measures."""
 
 import threading
-import time
 
 import jax
 
@@ -42,7 +46,10 @@ _config = {"filename": "profile.json", "profile_all": False,
            "profile_symbolic": True, "profile_imperative": True,
            "profile_memory": True, "profile_api": True,
            "aggregate_stats": False, "xla_trace": True}
-_state = {"running": False, "dir": None, "obs_prev": None}
+# "dir": the XLA trace being taken; "last_dir": the session's, kept after
+# the trace stops for dumps(aggregate=True) to read its .xplane.pb
+_state = {"running": False, "dir": None, "last_dir": None,
+          "obs_prev": None}
 _records = []
 _lock = threading.Lock()
 
@@ -58,6 +65,20 @@ def set_config(**kwargs):
 profiler_set_config = set_config
 
 
+def _start_xla_trace():
+    trace_dir = str(_config["filename"]) + ".tracedir"
+    _state["dir"] = _state["last_dir"] = trace_dir
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
+def _stop_xla_trace():
+    if _state["dir"] is not None:
+        jax.profiler.stop_trace()
+        _state["dir"] = None
+
+
 def set_state(state="stop", profile_process="worker"):
     """'run' starts host telemetry (and a jax profiler trace unless
     xla_trace=False); 'stop' ends both — the XPlane trace lands next to
@@ -67,17 +88,15 @@ def set_state(state="stop", profile_process="worker"):
     if state == "run" and not _state["running"]:
         _state["obs_prev"] = _obs_core._override
         _obs_core.set_enabled(True)
+        _obs_core.reset_span_totals()
+        _state["last_dir"] = None
         from .observability import http as _obs_http
         _obs_http.maybe_start()    # MXNET_OBS_HTTP live scrape, if set
         if _config.get("xla_trace", True):
-            trace_dir = str(_config["filename"]) + ".tracedir"
-            _state["dir"] = trace_dir
-            jax.profiler.start_trace(trace_dir)
+            _start_xla_trace()
         _state["running"] = True
     elif state == "stop" and _state["running"]:
-        if _state["dir"] is not None:
-            jax.profiler.stop_trace()
-            _state["dir"] = None
+        _stop_xla_trace()
         _obs_core.set_enabled(_state["obs_prev"])
         _state["running"] = False
 
@@ -89,9 +108,7 @@ def pause(profile_process="worker"):
     """Keep the session open but stop recording (reference
     profiler_pause): spans/counters hit the ring again after resume()."""
     if _state["running"]:
-        if _state["dir"] is not None:
-            jax.profiler.stop_trace()
-            _state["dir"] = None
+        _stop_xla_trace()
         _obs_core.set_enabled(False)
 
 
@@ -99,9 +116,7 @@ def resume(profile_process="worker"):
     if _state["running"]:
         _obs_core.set_enabled(True)
         if _config.get("xla_trace", True) and _state["dir"] is None:
-            trace_dir = str(_config["filename"]) + ".tracedir"
-            _state["dir"] = trace_dir
-            jax.profiler.start_trace(trace_dir)
+            _start_xla_trace()
     else:
         set_state("run")
 
@@ -119,9 +134,8 @@ def dump(finished=True, profile_process="worker"):
     per-rank lanes on the barrier-aligned timebase."""
     if _state["running"] and finished:
         set_state("stop")
-    elif _state["dir"] is not None and finished:
-        jax.profiler.stop_trace()
-        _state["dir"] = None
+    elif finished:
+        _stop_xla_trace()
     from .observability import attribution as _obs_attr
     from .observability import dist as _obs_dist
     from .observability import http as _obs_http
@@ -152,12 +166,16 @@ def dump(finished=True, profile_process="worker"):
 def dumps(reset=False, aggregate=False):
     """Text dump. ``aggregate=True`` (or set_config(aggregate_stats=
     True)) returns the aggregate-stats percentile table over the
-    telemetry ring — the reference's AggregateStats table. Otherwise
-    the legacy flat listing of user profiler objects."""
+    telemetry ring — the reference's AggregateStats table, and after a
+    session that took an XLA trace the device's launches and idle time
+    by program span. Otherwise the legacy flat listing of user profiler
+    objects."""
     if aggregate or _config.get("aggregate_stats"):
-        table = _obs_export.aggregate_table()
+        table = _obs_export.aggregate_table(
+            trace_dir=None if _state["dir"] else _state["last_dir"])
         if reset:
             _obs_core.reset()
+            _state["last_dir"] = None
             with _lock:
                 del _records[:]
         return table
@@ -199,38 +217,28 @@ class Domain(object):
 
 
 class _Span(object):
-    """start()/stop() span; emits a TraceAnnotation on the host
-    timeline and a ring record for the chrome-trace/aggregate
-    exporters."""
+    """start()/stop() span over ``core.span``: on the profiler's host
+    timeline as ``mx.<name>`` and in the ring for the chrome-trace and
+    aggregate exporters while the profiler runs; always in the legacy
+    listing (without a duration when nothing recorded it)."""
 
     kind = "span"
 
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
-        self._t0 = None
-        self._ann = None
+        self._span = None
 
     def start(self):
-        self._t0 = time.perf_counter_ns()
-        self._ann = jax.profiler.TraceAnnotation(
-            "%s::%s" % (self.domain, self.name))
-        self._ann.__enter__()
+        self._span = _obs_core.span(self.name, cat=self.kind,
+                                    domain=str(self.domain)).start()
 
     def stop(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if self._t0 is not None:
-            t1 = time.perf_counter_ns()
+        if self._span is not None:
+            dur_ns = self._span.stop()
+            self._span = None
             _record(self.name, self.kind,
-                    "%.6fs" % ((t1 - self._t0) / 1e9))
-            if _obs_core.enabled():
-                # paused sessions keep the legacy listing but stay out
-                # of the trace/aggregate ring
-                _obs_core.record_span(self.name, self.kind, self._t0,
-                                      t1, {"domain": str(self.domain)})
-            self._t0 = None
+                    "-" if dur_ns is None else "%.6fs" % (dur_ns / 1e9))
 
     def __enter__(self):
         self.start()

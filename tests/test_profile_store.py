@@ -27,7 +27,17 @@ def _load_tool(name):
         "%s_for_test" % name, os.path.join(ROOT, "tools",
                                            "%s.py" % name))
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    # the tools switch telemetry on for their own process when imported
+    # (os.environ.setdefault("MXNET_OBS", "1")): here that would leave it
+    # on for every later test of this worker
+    before = os.environ.get("MXNET_OBS")
+    try:
+        spec.loader.exec_module(mod)
+    finally:
+        if before is None:
+            os.environ.pop("MXNET_OBS", None)
+        else:
+            os.environ["MXNET_OBS"] = before
     return mod
 
 

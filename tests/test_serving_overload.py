@@ -99,7 +99,16 @@ def test_block_allocator_check_invariants():
 # ---- preemption with bit-exact resume (tentpole 2) ----
 
 
-def test_preempt_resume_bit_exact_greedy():
+@pytest.fixture
+def telemetry():
+    """The serving.* counters count only with telemetry on."""
+    obs.set_enabled(True)
+    yield
+    obs.set_enabled(None)
+    obs.reset()
+
+
+def test_preempt_resume_bit_exact_greedy(telemetry):
     """A higher-priority admission short on blocks preempts the
     lower-priority lane mid-stream; the victim's synced prefix is
     captured, its blocks fund the admission, and its resumed stream is
@@ -301,6 +310,7 @@ def test_router_infeasible_deadline_expires_by_eta():
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     obs.set_enabled(True)
+    obs.reset()                 # the medians below are the seeded ones
     try:
         # seed the estimator: median TTFT 100ms, ITL 100ms -> any job
         # behind another costs >= 700ms end to end
@@ -322,7 +332,7 @@ def test_router_infeasible_deadline_expires_by_eta():
                                   _solo(params, _P0, 6, cfg))
 
 
-def test_router_absorbs_preempted_and_resumes():
+def test_router_absorbs_preempted_and_resumes(telemetry):
     """Fleet-level preemption round trip: the replica preempts for the
     high-priority admission, the router requeues the victim as a
     continuation, and both streams complete bit-exactly."""
