@@ -16,6 +16,14 @@ restores the per-key path. MXNET_KVSTORE_SHARD_UPDATE=1 additionally
 moves the optimizer into the store as a reduce-scatter -> sharded
 update -> all-gather per bucket (PAPERS.md cross-replica sharding),
 which cuts per-replica optimizer state by (N-1)/N.
+
+Update path: with the update outside the store (the default), _update
+hands the Updater EVERY fresh parameter in one call, and the Updater
+(optimizer.py) runs one multi-tensor program a step for all it can fuse:
+built-in SGD (+momentum), NAG and Adam on dense gradients, multi_precision
+masters included. Row-sparse gradients, the other rules, Optimizer
+subclasses that override the update and parameters on unlike device sets
+are updated one at a time inside the same call (docs/GRAD_FUSION.md).
 """
 
 import time as _time
@@ -297,6 +305,8 @@ class Trainer(object):
             self._update_impl(ignore_stale_grad)
 
     def _update_impl(self, ignore_stale_grad=False):
+        on_kvstore = self._update_on_kvstore and self._kvstore is not None
+        indices, grads, weights = [], [], []
         for i, param in self._trainable():
             if param._data is None:
                 if not ignore_stale_grad:
@@ -316,11 +326,19 @@ class Trainer(object):
                     "intentionally only using a subset, call step with "
                     "ignore_stale_grad=True to suppress this warning"
                     % (param.name, str(param.list_ctx()[0])))
-            if self._update_on_kvstore and self._kvstore is not None:
+            if on_kvstore:
                 self._kvstore.pull(i, param.data(), priority=-i)
+                param._data._fresh_grad = False
             else:
-                self._updaters[0](i, param.grad(), param.data())
-            param._data._fresh_grad = False
+                indices.append(i)
+                grads.append(param.grad())
+                weights.append(param.data())
+        if indices:
+            # every parameter in ONE call, as the reference's _update
+            # does: the Updater fuses what it can into one program
+            self._updaters[0](indices, grads, weights)
+            for weight in weights:
+                weight._fresh_grad = False
 
     # ------------------------------------------------------------ states --
     def save_states(self, fname):

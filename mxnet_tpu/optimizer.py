@@ -10,9 +10,13 @@ XLA fuses the elementwise chain into one HBM pass). Hyper-parameters that
 change per step (lr, wd, rescale) are passed as traced scalars so a
 changing schedule never recompiles. States live as jax.Arrays inside
 NDArrays, matching `create_state`/`update` semantics that kvstore's
-server-side Updater also consumes.
+server-side Updater also consumes. Given every parameter of a step in one
+call (gluon.Trainer), the Updater runs the rules that have a pure kernel
+(SGD, NAG, Adam) as ONE multi-tensor program: the analogue of the
+reference's `multi_mp_sgd_mom_update` aggregation.
 """
 
+import functools
 import math
 import pickle
 
@@ -23,6 +27,7 @@ import jax.numpy as jnp
 from . import ndarray as nd
 from .ndarray import NDArray
 from .base import MXNetError
+from .observability import core as _obs
 
 __all__ = ["Optimizer", "SGD", "NAG", "Signum", "FTML", "DCASGD", "LBSGD",
            "SGLD", "Adam", "AdaGrad", "AdaDelta", "RMSProp", "Ftrl",
@@ -86,20 +91,25 @@ def _align_update_devices(weight, grad, state):
 
 
 def _align_state_tree(state, sharding):
-    if state is None:
-        return
-    if isinstance(state, (tuple, list)):
-        for s in state:
-            _align_state_tree(s, sharding)
-        return
-    data = getattr(state, "_data", None)
-    if data is not None and getattr(data, "sharding", None) is not None \
-            and data.sharding.device_set != sharding.device_set:
-        state._data = jax.device_put(data, sharding)
+    for leaf in _state_leaves(state):
+        data = getattr(leaf, "_data", None)
+        if data is not None and getattr(data, "sharding", None) is not None \
+                and data.sharding.device_set != sharding.device_set:
+            leaf._data = jax.device_put(data, sharding)
 
 
 def _flt(x):
     return jnp.asarray(x, dtype=jnp.float32)
+
+
+def _state_leaves(state):
+    """The NDArrays of one optimizer state (None, one, or tuples of
+    them, as ``(master, (mean, var))``), flat and in order."""
+    if state is None:
+        return ()
+    if isinstance(state, (tuple, list)):
+        return tuple(leaf for s in state for leaf in _state_leaves(s))
+    return (state,)
 
 
 class Optimizer(object):
@@ -120,7 +130,7 @@ class Optimizer(object):
             begin_num_update=begin_num_update,
             num_update=begin_num_update, _index_update_count={},
             clip_gradient=clip_gradient,
-            multi_precision=multi_precision, aggregate_num=0,
+            multi_precision=multi_precision,
             idx2name=dict(param_idx2name or {}),
             sym_info=(sym.attr_dict(), sym.list_arguments())
             if sym is not None else (),
@@ -169,6 +179,32 @@ class Optimizer(object):
 
     def _apply_rule(self, t, lr, wd, weight, grad, state):
         raise NotImplementedError()
+
+    # A rule that is a pure function of its arrays states it ONCE, as
+    # ``_kernel(w, g, states, lr, wd, hyper) -> (w, states)`` over jax
+    # arrays (a staticmethod): ``_apply_kernel`` runs it for one
+    # parameter, the Updater's fused program for all of them.
+    _kernel = None
+
+    def _kernel_hyper(self):
+        """The rule's own scalars (momentum, betas), traced operands."""
+        return ()
+
+    def _kernel_lr(self, t, lr):
+        """The learning rate the kernel gets at a parameter's step ``t``
+        (Adam folds its bias correction in here, on the host)."""
+        return lr
+
+    def _apply_kernel(self, t, lr, wd, weight, grad, state):
+        """``_apply_rule`` of a rule that has a ``_kernel``."""
+        leaves = _state_leaves(state)
+        weight._data, new = self._kernel(
+            weight._data, self._preprocess_grad(grad),
+            tuple(s._data for s in leaves),
+            _flt(self._kernel_lr(t, lr)), _flt(wd),
+            tuple(_flt(h) for h in self._kernel_hyper()))
+        for leaf, data in zip(leaves, new):
+            leaf._data = data
 
     def update_multi_precision(self, index, weight, grad, state):
         grad = _align_update_devices(weight, grad, state)
@@ -311,6 +347,17 @@ def _adam_update(w, g, m, v, lr, wd, beta1, beta2, eps):
     return w - lr * m / (jnp.sqrt(v) + eps), m, v
 
 
+def _momentum_kernel(mom_update):
+    """``Optimizer._kernel`` of a rule that is plain SGD without a
+    momentum buffer and ``mom_update`` with one (``hyper`` = (momentum,))."""
+    def kernel(w, g, states, lr, wd, hyper):
+        if not states:
+            return _sgd_update(w, g, lr, wd), ()
+        w, mom = mom_update(w, g, states[0], lr, wd, hyper[0])
+        return w, (mom,)
+    return staticmethod(kernel)
+
+
 @register
 class SGD(Optimizer):
     """SGD with momentum and optional multi-precision (optimizer.py:479;
@@ -337,13 +384,12 @@ class SGD(Optimizer):
             else:
                 weight._data = w.at[rows].add(-lr * (g + wd * w[rows]))
             return
-        g = self._preprocess_grad(grad)
-        if state is not None:
-            weight._data, state._data = _sgd_mom_update(
-                weight._data, g, state._data, _flt(lr), _flt(wd),
-                _flt(self.momentum))
-        else:
-            weight._data = _sgd_update(weight._data, g, _flt(lr), _flt(wd))
+        self._apply_kernel(t, lr, wd, weight, grad, state)
+
+    _kernel = _momentum_kernel(_sgd_mom_update)
+
+    def _kernel_hyper(self):
+        return (self.momentum,)
 
 
 @register
@@ -357,14 +403,10 @@ class NAG(Optimizer):
     def create_state(self, index, weight):
         return self._zeros_like(weight) if self.momentum else None
 
-    def _apply_rule(self, t, lr, wd, weight, grad, state):
-        g = self._preprocess_grad(grad)
-        if state is not None:
-            weight._data, state._data = _nag_mom_update(
-                weight._data, g, state._data, _flt(lr), _flt(wd),
-                _flt(self.momentum))
-        else:
-            weight._data = _sgd_update(weight._data, g, _flt(lr), _flt(wd))
+    _apply_rule = Optimizer._apply_kernel
+
+    _kernel = _momentum_kernel(_nag_mom_update)
+    _kernel_hyper = SGD._kernel_hyper
 
 
 @register
@@ -506,15 +548,20 @@ class Adam(Optimizer):
         return (self._zeros_like(weight),
                 self._zeros_like(weight))
 
-    def _apply_rule(self, t, lr, wd, weight, grad, state):
+    _apply_rule = Optimizer._apply_kernel
+
+    @staticmethod
+    def _kernel(w, g, states, lr, wd, hyper):
+        w, mean, var = _adam_update(w, g, *states, lr, wd, *hyper)
+        return w, (mean, var)
+
+    def _kernel_hyper(self):
+        return (self.beta1, self.beta2, self.epsilon)
+
+    def _kernel_lr(self, t, lr):
         coef1 = 1. - self.beta1 ** t
         coef2 = 1. - self.beta2 ** t
-        lr *= math.sqrt(coef2) / coef1
-        g = self._preprocess_grad(grad)
-        mean, var = state
-        weight._data, mean._data, var._data = _adam_update(
-            weight._data, g, mean._data, var._data, _flt(lr), _flt(wd),
-            _flt(self.beta1), _flt(self.beta2), _flt(self.epsilon))
+        return lr * (math.sqrt(coef2) / coef1)
 
 
 @register
@@ -700,28 +747,167 @@ class Test(Optimizer):
 _OPT_REGISTRY["ccsgd"] = SGD
 
 
+# ------------------------------------------------- multi-tensor update ---
+# What a list call of the Updater runs for every parameter it can fuse: ONE
+# program a step instead of a cast, a rescale, three scalar puts and a
+# kernel a parameter.
+
+_FUSED_RULES = (SGD, NAG, Adam)
+# what a subclass may not override and still be fused: the fused program
+# stands in for all of these
+_RULE_METHODS = ("update", "update_multi_precision", "_apply_rule",
+                 "_kernel", "_kernel_hyper", "_kernel_lr",
+                 "_preprocess_grad", "_update_count", "_get_lr", "_get_lrs",
+                 "_get_wd", "_get_wds", "create_state_multi_precision")
+
+
+def _fused_kernel(optimizer):
+    """The pure kernel of a built-in SGD / NAG / Adam, or None for any
+    other rule and for a subclass that overrides part of the update (its
+    override would silently not run; same rule as FlatOptimizer.supports)."""
+    kind = type(optimizer)
+    base = next((b for b in _FUSED_RULES if isinstance(optimizer, b)), None)
+    if base is None or any(getattr(kind, m) is not getattr(base, m)
+                           for m in _RULE_METHODS):
+        return None
+    return base._kernel
+
+
+def _shared_devices(weight, grad, state):
+    """The one device set a weight, its dense gradient and its state all
+    sit on, or None: a sparse gradient takes its rule's lazy path and
+    unlike placements are ``_align_update_devices``' work, both a
+    parameter at a time."""
+    if grad._stype != "default":
+        return None
+    try:
+        devices = weight._data.sharding.device_set
+        if grad._data.sharding.device_set != devices:
+            return None
+        for leaf in _state_leaves(state):
+            if leaf._data.sharding.device_set != devices:
+                return None
+    except AttributeError:
+        return None
+    return frozenset(devices)
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_program(kernel, has_clip):
+    """The jitted multi-tensor update of one rule. Per parameter, in the
+    order ``update_multi_precision`` + ``_preprocess_grad`` + the kernel
+    apply them one by one: gradient to the master's dtype, rescale, clip,
+    the rule, the new master cast back to the weight's dtype. Shapes,
+    dtypes and the set of parameters key jit's own cache; every
+    hyperparameter is a traced operand, so a schedule, a new
+    ``rescale_grad`` or a loss scale never compiles again. Donated are the
+    buffers the Updater alone owns (masters and states): weights and
+    gradients are also held by the CachedOp and the tape."""
+
+    def run(weights, grads, masters, states, lrs, wds, rescale, clip,
+            hyper):
+        out_w, out_m, out_s = [], [], []
+        for k, (w, g, master, state) in enumerate(
+                zip(weights, grads, masters, states)):
+            if master is not None:
+                g = g.astype(master.dtype)
+            g = g * rescale.astype(g.dtype)
+            if has_clip:
+                bound = clip.astype(g.dtype)
+                g = jnp.clip(g, -bound, bound)
+            new, state = kernel(w if master is None else master, g, state,
+                                lrs[k], wds[k], hyper)
+            out_m.append(None if master is None else new)
+            out_w.append(new.astype(w.dtype))
+            out_s.append(state)
+        return out_w, out_m, out_s
+
+    return jax.jit(run, donate_argnums=(2, 3))
+
+
 class Updater(object):
     """Applies an optimizer to (index, grad, weight) triples — the object
     the reference ships to kvstore servers (optimizer.py get_updater /
-    kvstore_dist_server.h ApplyUpdates)."""
+    kvstore_dist_server.h ApplyUpdates).
+
+    Called with one triple (Module, KVStore) it runs the optimizer's own
+    per-parameter update. Called with lists (``gluon.Trainer``, once a
+    step) it aggregates as the reference's ``aggregate_num`` kernels do:
+    every parameter whose update is a built-in pure rule on a dense
+    gradient goes into one program a device set, the rest a parameter at a
+    time. ``states[i]`` has the same structure either way."""
 
     def __init__(self, optimizer):
         self.optimizer = optimizer
         self.states = {}
         self.states_synced = {}
-        self.aggregate_updates = optimizer.aggregate_num > 0
 
     def __call__(self, index, grad, weight):
-        batched = isinstance(index, (list, tuple))
-        triples = zip(index, grad, weight) if batched \
-            else ((index, grad, weight),)
-        for i, g, w in triples:
-            if i not in self.states:
-                self.states[i] = \
-                    self.optimizer.create_state_multi_precision(i, w)
-                self.states_synced[i] = True
-            self.optimizer.update_multi_precision(i, w, g,
-                                                  self.states[i])
+        opt = self.optimizer
+        if not isinstance(index, (list, tuple)):
+            opt.update_multi_precision(index, weight, grad,
+                                       self._state(index, weight))
+            return
+        kernel = _fused_kernel(opt)
+        groups, rest = {}, []
+        for triple in zip(index, grad, weight):
+            i, g, w = triple
+            state = self._state(i, w)
+            devices = kernel and _shared_devices(w, g, state)
+            if devices:
+                groups.setdefault(devices, []).append(triple)
+            else:
+                rest.append(triple)
+        for triples in groups.values():
+            self._update_fused(kernel, triples)
+        for i, g, w in rest:
+            opt.update_multi_precision(i, w, g, self.states[i])
+        if _obs.enabled():
+            _obs.counter("optimizer.fused_params").add(
+                len(index) - len(rest))
+            _obs.counter("optimizer.fallback_params").add(len(rest))
+
+    def _state(self, index, weight):
+        if index not in self.states:
+            self.states[index] = \
+                self.optimizer.create_state_multi_precision(index, weight)
+            self.states_synced[index] = True
+        return self.states[index]
+
+    def _update_fused(self, kernel, triples):
+        opt = self.optimizer
+        indices = [i for i, _, _ in triples]
+        opt._update_count(indices)
+        steps = opt._index_update_count
+        lrs = [opt._kernel_lr(steps[i], lr)
+               for i, lr in zip(indices, opt._get_lrs(indices))]
+        masters, leaves = [], []
+        for i, _, w in triples:
+            state = self.states[i]
+            master = None
+            if opt.multi_precision and w.dtype == jnp.bfloat16:
+                master, state = state
+            masters.append(master)
+            leaves.append(_state_leaves(state))
+        clip = opt.clip_gradient
+        with _obs.span("optimizer.fused", cat="step", params=len(triples)):
+            new_w, new_m, new_s = _fused_program(kernel, clip is not None)(
+                [w._data for _, _, w in triples],
+                [g._data for _, g, _ in triples],
+                [m if m is None else m._data for m in masters],
+                [tuple(s._data for s in ls) for ls in leaves],
+                np.asarray(lrs, np.float32),
+                np.asarray(opt._get_wds(indices), np.float32),
+                np.float32(opt.rescale_grad),
+                np.float32(0.0 if clip is None else clip),
+                tuple(np.float32(h) for h in opt._kernel_hyper()))
+        for (_, _, w), master, ls, w_data, m_data, s_data in zip(
+                triples, masters, leaves, new_w, new_m, new_s):
+            w._data = w_data
+            if master is not None:
+                master._data = m_data
+            for leaf, data in zip(ls, s_data):
+                leaf._data = data
 
     def get_states(self, dump_optimizer=False):
         payload = (self.states, self.optimizer) if dump_optimizer \

@@ -398,3 +398,173 @@ def test_split_and_load():
     assert len(parts) == 3 and parts[0].shape == (2, 2)
     loaded = gluon.utils.split_and_load(data, [mx.cpu()])
     assert len(loaded) == 1
+
+
+# ------------------------------------- the trainer's multi-tensor update ---
+# Trainer hands the Updater every parameter in one call; what can be fused
+# runs as one program, counted by `optimizer.fused_params` /
+# `optimizer.fallback_params` and spanned by `optimizer.fused`.
+
+@pytest.fixture
+def telemetry(monkeypatch):
+    from mxnet_tpu.observability import core
+    monkeypatch.delenv("MXNET_OBS", raising=False)
+    core.reset()
+    core.set_enabled(True)
+    yield core
+    core.set_enabled(None)
+    core.reset()
+
+
+def _mlp(optimizer="sgd", hyper=None, kvstore=None):
+    """A small hybridized block with six parameters, a trainer and a
+    function that runs forward + backward (on the first `heads` heads)."""
+    np.random.seed(3)
+    mx.random.seed(3)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(6, activation="relu", in_units=4),
+            nn.Dense(5, activation="relu", in_units=6),
+            nn.Dense(3, in_units=5))
+    net.initialize(mx.init.Xavier())
+    net.hybridize()
+    trainer = gluon.Trainer(
+        net.collect_params(), optimizer,
+        hyper if hyper is not None else {"learning_rate": 0.1,
+                                         "momentum": 0.9},
+        kvstore=kvstore)
+    x = mx.nd.array(np.random.randn(8, 4).astype(np.float32))
+
+    def backward():
+        with autograd.record():
+            loss = (net(x) ** 2).mean()
+        loss.backward()
+
+    return net, trainer, backward
+
+
+def _opt_counts(core):
+    c = core.counters()
+    return tuple(int(c[k].total) if k in c else None
+                 for k in ("optimizer.fused_params",
+                           "optimizer.fallback_params"))
+
+
+def _weights(net):
+    return [p.data().asnumpy().copy()
+            for p in net.collect_params().values()]
+
+
+def test_trainer_update_is_one_fused_program(telemetry):
+    net, trainer, backward = _mlp(kvstore="device")
+    backward()
+    trainer.step(8)
+    assert _opt_counts(telemetry) == (6, 0)
+    spans = [r for r in telemetry.records() if r[0] == "X"]
+    fused = [r for r in spans if r[1] == "optimizer.fused"]
+    update = [r for r in spans if r[1] == "update"]
+    assert len(fused) == 1 and len(update) == 1
+    (_, _, _, f0, fdur, ftid, fargs), (_, _, _, u0, udur, utid, _) = \
+        fused[0], update[0]
+    assert ftid == utid and u0 <= f0 and f0 + fdur <= u0 + udur
+    assert fargs["params"] == 6
+
+
+def test_trainer_update_counts_nothing_when_telemetry_is_off(monkeypatch):
+    from mxnet_tpu.observability import core
+    monkeypatch.delenv("MXNET_OBS", raising=False)
+    core.set_enabled(None)
+    core.reset()
+    _, trainer, backward = _mlp()
+    backward()
+    trainer.step(8)
+    assert core.counters() == {} and core.records() == []
+
+
+def test_trainer_update_row_sparse_gradient_falls_back(telemetry):
+    from mxnet_tpu import sparse
+    net, trainer, backward = _mlp()
+    ref_net, ref_trainer, ref_backward = _mlp()
+    first = list(net.collect_params().values())[0]
+    ref_first = list(ref_net.collect_params().values())[0]
+    for _ in range(2):
+        backward()
+        ref_backward()
+        dense = first.grad().asnumpy().copy()
+        dense[1::2] = 0.0       # rows 0, 2, 4 carry the gradient
+        first._grad = sparse.row_sparse_array(dense)
+        ref_first.grad()._data = mx.nd.array(dense)._data
+        trainer.step(8)
+        ref_trainer.step(8)
+    assert _opt_counts(telemetry) == (10 + 12, 2)
+    # lazy update: rows the sparse gradient does not name keep their
+    # momentum's pull out of the weight; rows it names agree with dense
+    got, want = first.data().asnumpy(), ref_first.data().asnumpy()
+    np.testing.assert_allclose(got[0::2], want[0::2], rtol=1e-6)
+    for a, b in zip(_weights(net)[1:], _weights(ref_net)[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-6)
+
+
+def test_trainer_update_overridden_optimizer_falls_back(telemetry):
+    seen = []
+
+    class Mine(mx.optimizer.SGD):
+        def update(self, index, weight, grad, state):
+            seen.append(index)
+            super().update(index, weight, grad, state)
+
+    hyper = dict(learning_rate=0.1, momentum=0.9)
+    net, trainer, backward = _mlp(Mine(**hyper), {})
+    ref_net, ref_trainer, ref_backward = _mlp("sgd", hyper)
+    for _ in range(2):
+        backward()
+        trainer.step(8)
+        ref_backward()
+        ref_trainer.step(8)
+    assert seen == list(range(6)) * 2
+    assert _opt_counts(telemetry) == (12, 12)   # ref fused, Mine not
+    for a, b in zip(_weights(net), _weights(ref_net)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+def test_trainer_update_skips_stale_parameter(telemetry):
+    net, trainer, _ = _mlp()
+    extra = nn.Dense(2, in_units=3)
+    extra.initialize()
+    params = net.collect_params()
+    params.update(extra.collect_params())
+    trainer = gluon.Trainer(params, "sgd", {"learning_rate": 0.1},
+                            kvstore=None)
+    x = mx.nd.array(np.random.randn(8, 4).astype(np.float32))
+    before = _weights(net), _weights(extra)
+    with autograd.record():
+        loss = (net(x) ** 2).mean()      # `extra` takes no part
+    loss.backward()
+    with pytest.raises(UserWarning):
+        trainer.step(8)
+    # the refused step updated nothing and consumed no gradient
+    for a, b in zip(_weights(net), before[0]):
+        np.testing.assert_array_equal(a, b)
+    trainer.step(8, ignore_stale_grad=True)
+    assert _opt_counts(telemetry) == (6, 0)
+    for a, b in zip(_weights(extra), before[1]):
+        np.testing.assert_array_equal(a, b)
+    assert all((a != b).any() for a, b in zip(_weights(net), before[0]))
+
+
+def test_trainer_update_never_recompiles_for_a_hyperparameter():
+    from mxnet_tpu import optimizer as opt
+    from mxnet_tpu.lr_scheduler import FactorScheduler
+    _, trainer, backward = _mlp(hyper={
+        "learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4,
+        "lr_scheduler": FactorScheduler(step=2, factor=0.7, base_lr=0.1)})
+    program = opt._fused_program(opt.SGD._kernel, False)
+    sizes = []
+    for step in range(10):
+        backward()
+        trainer.step(1 + step)                # a new rescale_grad
+        trainer._optimizer.wd *= 1.5
+        trainer._optimizer.momentum *= 0.99
+        sizes.append(program._cache_size())
+    # the scheduler moved the rate too
+    assert trainer.learning_rate < 0.1 * 0.7 ** 3
+    assert sizes[-1] == sizes[0], sizes

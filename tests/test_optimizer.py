@@ -125,3 +125,130 @@ def test_multi_precision():
     assert str(w.dtype) == "bfloat16"
     master = state[0]
     assert str(master.dtype) == "float32"
+
+
+# ------------------------------------------- multi-tensor (fused) update ---
+# A list call of the Updater runs every fusable parameter in one program;
+# one call a parameter is the reference it has to agree with.
+
+_FUSED_SHAPES = [(3,), (1,), (4, 5), (7, 1), (2, 3, 4), (16,), (5, 5),
+                 (1, 9), (2, 2, 2, 2), (11,), (6, 3), (8, 8)]
+_FUSED_RULES = {
+    "sgd": ("sgd", {}),
+    "sgd_momentum": ("sgd", {"momentum": 0.9}),
+    "nag": ("nag", {"momentum": 0.9}),
+    "adam": ("adam", {}),
+}
+
+
+class _Mults(object):
+    def __init__(self, lr_mult, wd_mult):
+        self.lr_mult, self.wd_mult = lr_mult, wd_mult
+
+
+def _fused_case(rule, bf16, knobs):
+    """(updater, weights, gradient maker) of one case, seeded."""
+    from mxnet_tpu.lr_scheduler import FactorScheduler
+    name, hyper = _FUSED_RULES[rule]
+    hyper = dict(hyper, learning_rate=0.05, multi_precision=bf16,
+                 rescale_grad=0.5)
+    if knobs:
+        hyper.update(
+            clip_gradient=0.3, wd=0.01,
+            lr_scheduler=FactorScheduler(step=2, factor=0.5, base_lr=0.05),
+            param_dict={i: _Mults(1.0 + 0.25 * (i % 3), 0.5 * (i % 2))
+                        for i in range(0, len(_FUSED_SHAPES), 2)})
+    rng = np.random.RandomState(7)
+    weights = [mx.nd.array(rng.randn(*s).astype(np.float32))
+               for s in _FUSED_SHAPES]
+    if bf16:
+        weights = [w.astype("bfloat16") for w in weights]
+
+    def grads(step):
+        r = np.random.RandomState(100 + step)
+        out = [mx.nd.array(r.randn(*s).astype(np.float32))
+               for s in _FUSED_SHAPES]
+        return [g.astype("bfloat16") for g in out] if bf16 else out
+
+    return opt.get_updater(opt.create(name, **hyper)), weights, grads
+
+
+def _structure(state):
+    if isinstance(state, tuple):
+        return tuple(_structure(s) for s in state)
+    return type(state)
+
+
+def _assert_same_update(fused, one, w_fused, w_one, bf16):
+    for a, b in zip(w_fused, w_one):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        if bf16:    # the stored weights: exactly
+            np.testing.assert_array_equal(
+                a.asnumpy().astype(np.float32),
+                b.asnumpy().astype(np.float32))
+        else:
+            np.testing.assert_allclose(a.asnumpy(), b.asnumpy(),
+                                       rtol=1e-6, atol=1e-7)
+    assert set(fused.states) == set(one.states)
+    for i in fused.states:
+        assert _structure(fused.states[i]) == _structure(one.states[i])
+        for a, b in zip(opt._state_leaves(fused.states[i]),
+                        opt._state_leaves(one.states[i])):
+            assert a.dtype == b.dtype == np.float32
+            np.testing.assert_allclose(a.asnumpy(), b.asnumpy(),
+                                       rtol=1e-6, atol=1e-7)
+    assert fused.optimizer._index_update_count \
+        == one.optimizer._index_update_count
+    assert fused.optimizer.num_update == one.optimizer.num_update
+
+
+@pytest.mark.parametrize("knobs", [False, True], ids=["plain", "knobs"])
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bf16_mp"])
+@pytest.mark.parametrize("rule", sorted(_FUSED_RULES))
+def test_fused_update_matches_per_parameter(rule, bf16, knobs):
+    fused, w_fused, grads = _fused_case(rule, bf16, knobs)
+    one, w_one, _ = _fused_case(rule, bf16, knobs)
+    indices = list(range(len(_FUSED_SHAPES)))
+    assert opt._fused_kernel(fused.optimizer) is not None
+
+    def not_fused(*args):
+        raise AssertionError("the list call fell back to one parameter")
+
+    fused.optimizer.update_multi_precision = not_fused
+
+    def step(t, fused, one):
+        fused(indices, grads(t), w_fused)
+        for i, g, w in zip(indices, grads(t), w_one):
+            one(i, g, w)
+
+    for t in range(3):
+        step(t, fused, one)
+        _assert_same_update(fused, one, w_fused, w_one, bf16)
+    if bf16:
+        assert all(isinstance(s, tuple) and str(s[0].dtype) == "float32"
+                   for s in fused.states.values())
+    # the states of one path load into the other, and the next step agrees
+    swapped_fused = opt.get_updater(fused.optimizer)
+    swapped_fused.set_states(one.get_states())
+    swapped_one = opt.get_updater(one.optimizer)
+    swapped_one.set_states(fused.get_states())
+    step(3, swapped_fused, swapped_one)
+    _assert_same_update(swapped_fused, swapped_one, w_fused, w_one, bf16)
+    assert fused.optimizer.num_update == 4
+
+
+def test_fused_update_leaves_single_triple_alone():
+    """One triple (Module, KVStore) runs the optimizer's own update."""
+    calls = []
+
+    class Spy(opt.SGD):
+        def update_multi_precision(self, index, weight, grad, state):
+            calls.append(index)
+            super().update_multi_precision(index, weight, grad, state)
+
+    upd = opt.get_updater(Spy(learning_rate=0.1, momentum=0.9))
+    w = mx.nd.array(np.ones((3,), dtype=np.float32))
+    g = mx.nd.array(np.full((3,), 0.5, dtype=np.float32))
+    upd(4, g, w)
+    assert calls == [4]
+    np.testing.assert_allclose(w.asnumpy(), np.full(3, 0.95), rtol=1e-6)
