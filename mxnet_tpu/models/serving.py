@@ -15,11 +15,20 @@ Design notes (all static-shape, XLA-friendly):
   is data, not shape.
 * Admission prefills the prompt at a power-of-two BUCKET width (one
   compiled prefill per bucket, not per prompt length) with the logits
-  row for the true last token selected out. Pad garbage in the cache
-  beyond the prompt is harmless: attention masks to `<= pos`, and
+  row for the true last token selected out. Pad garbage in the K/V
+  rows beyond the prompt is harmless: attention masks to `<= pos`, and
   positions beyond the prompt are overwritten by decode writes before
   they ever become attendable — the same self-healing argument the
   speculative decoder relies on.
+* HYBRID models (cfg.layer_kinds with "mamba" layers) give a lane two
+  kinds of state: K/V rows for the attention layers and a fixed-size
+  recurrent state (conv window + float32 SSM state) for the others.
+  That state has no self-healing: a padded position folded into it
+  stays. It is exact by construction instead — the bucketed prefill
+  stops it at the last real token (prefill_chunk's logits_row), a
+  cached prefix carries the state at its end, a continuation prefills
+  its history again. Mechanisms that cannot carry it (paged blocks,
+  speculative rollback, int8 KV) are refused at construction.
 * Idle slots keep lanes busy writing at position 0 of retired rows;
   the next admission's prefill overwrites them. Throughput is
   proportional to active lanes, latency to the slowest active row —
@@ -245,7 +254,9 @@ def _jitted_slot_write(cfg):
     beyond the bucket, which is load-bearing for slot reuse — any
     future narrowing to bucket width must add an explicit tail-clear
     or retired requests' cache lines become attendable again once the
-    new request decodes past its own prompt."""
+    new request decodes past its own prompt. A hybrid model's
+    recurrent state leaves ride the same tree.map (batch first): the
+    row's state replaces the previous occupant's whole."""
     return tf._serving_jit("slot_write", cfg, lambda fz: jax.jit(
         lambda full, row, i: jax.tree.map(
             lambda f, r: jax.lax.dynamic_update_slice_in_dim(
@@ -1032,6 +1043,21 @@ class ContinuousBatcher(object):
             paged = (_fastenv.get("MXNET_KV_PAGED") or "") \
                 not in ("", "0", "false", "False")
         self.paged = bool(paged)
+        if tf._recurrent(cfg):
+            for on, what in (
+                    (self.paged, "paged=True (or MXNET_KV_PAGED): a "
+                     "block holds K/V positions, and a lane's recurrent "
+                     "state has no block to live in or be shared "
+                     "through"),
+                    (self._spec_on, "spec_k (or MXNET_SPEC_K): a "
+                     "rejected draft is already folded into the "
+                     "recurrent state and cannot be rolled back"),
+                    (cfg.kv_cache_int8, "kv_cache_int8: the int8 cache "
+                     "layout has no place for the float32 state")):
+                if on:
+                    raise ValueError(
+                        "a model with state-space layers cannot be "
+                        "served with %s" % what)
         if self.paged:
             if block_size is None:
                 block_size = int(_fastenv.get("MXNET_KV_BLOCK_SIZE",
@@ -1077,6 +1103,17 @@ class ContinuousBatcher(object):
         self._tok = np.zeros((self.max_batch,), np.int32)
         self._keys = np.zeros((self.max_batch, 2), np.uint32)
         self._slots = [None] * self.max_batch   # Request or None
+        # what a live lane holds, for the serving.state_bytes /
+        # serving.kv_bytes gauges: bytes of recurrent state a lane
+        # (whatever its length) and bytes of K/V a position
+        row = jax.eval_shape(lambda: tf.init_cache(cfg, 1))
+
+        def nbytes(layers):
+            return sum(x.size * x.dtype.itemsize
+                       for layer in layers for x in layer.values())
+        self._lane_state_bytes = nbytes(l for l in row if "ssm" in l)
+        self._kv_pos_bytes = nbytes(
+            l for l in row if "ssm" not in l) // cfg.max_len
         if self._device_carry:
             # device-resident lane carry (the host-side mirrors above
             # go unused): tok/pos/keys live on device between
@@ -1289,6 +1326,9 @@ class ContinuousBatcher(object):
         snap = {
             "serving.lane_occupancy": active,
             "serving.lane_utilization": active / float(self.max_batch),
+            "serving.state_bytes": active * self._lane_state_bytes,
+            "serving.kv_bytes": self._kv_pos_bytes * sum(
+                len(r.tokens) for r in self._slots if r is not None),
             "serving.slo_attainment": _slo.attainment(),
             "serving.weight_fingerprint": self.weight_fingerprint,
             "serving.weight_version": int(self.weight_fingerprint, 16),
@@ -1734,9 +1774,12 @@ class ContinuousBatcher(object):
                 padded[0, : t_p - p_len] = prompt[p_len:]
                 # one compiled prefill per bucket width (prefill_chunk
                 # already specializes per chunk shape); fills positions
-                # [p_len, p_len+width) — rows beyond t_p are pad
+                # [p_len, p_len+width) — K/V rows beyond t_p are pad
                 # garbage that decode overwrites before attention can
-                # reach them
+                # reach them. A recurrent state could not be healed
+                # so: prefill_chunk stops it at the logits row, t_p - 1,
+                # and it continues from the row cache's own (zeros, or
+                # a cached prefix's state at p_len)
                 logits, row_cache = \
                     tf._jitted_prefill_chunk_row(self.cfg)(
                         self.params, row_cache, jnp.asarray(padded),
@@ -3316,6 +3359,9 @@ class ContinuousBatcher(object):
         ctx = sum(len(r.tokens) for r in self._slots if r is not None)
         _obs.gauge("serving.kv_utilization").set(
             ctx / float(self.max_batch * self.cfg.max_len))
+        _obs.gauge("serving.state_bytes").set(
+            active * self._lane_state_bytes)
+        _obs.gauge("serving.kv_bytes").set(ctx * self._kv_pos_bytes)
         if self.paged:
             usable = self.num_blocks - 1
             free = self._alloc.free_blocks

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from . import ssm
 from ..parallel.ring import ring_attention, ring_attention_sharded
 from ..parallel.pipeline import stack_stage_params, spmd_pipeline
 
@@ -48,7 +49,14 @@ class TransformerConfig:
     # GROUP of query heads
     n_kv_heads: int = None
     n_layers: int = 2
+    # each layer's mixer, a tuple of n_layers names: "attention" (the K/V
+    # cache kind) or "mamba" (models/ssm.py: fixed-size recurrent state
+    # instead of K/V rows). None = every layer attention
+    layer_kinds: tuple = None
     d_ff: int = 128
+    # the feed-forward's form: "gelu" = w2 gelu(w1 x); "gated_silu" =
+    # w2 (silu(w1 x) * w3 x), dense only
+    ffn: str = "gelu"
     n_experts: int = 0          # 0 = dense FFN; >0 = MoE every layer
     max_len: int = 128
     dtype: object = jnp.float32
@@ -65,6 +73,17 @@ class TransformerConfig:
     # simply unused when rope=True
     rope: bool = False
     rope_base: float = 10000.0
+    # "none" = no positional encoding anywhere (hybrid models whose
+    # state-space layers carry the order): no `pos` table, no rotation.
+    # Left None, `rope` says which of the other two it is
+    positions: str = None
+    # Mamba mixer sizes: states a channel, conv taps, channels as a
+    # multiple of d_model, rank of the step-size projection (None =
+    # ceil(d_model / 16), the family's rule)
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = None
     use_ring_attention: bool = True
     # attention through the Pallas flash kernel (kernels/
     # flash_attention.py): single-device dense path AND the per-shard
@@ -100,6 +119,47 @@ def _kvh(cfg):
     return kvh
 
 
+def _layer_kinds(cfg):
+    """The mixer of every layer, checked."""
+    kinds = cfg.layer_kinds or ("attention",) * cfg.n_layers
+    if len(kinds) != cfg.n_layers \
+            or set(kinds) - {"attention", "mamba"}:
+        raise ValueError(
+            "layer_kinds must name %d layers, each 'attention' or "
+            "'mamba'; got %r" % (cfg.n_layers, kinds))
+    return tuple(kinds)
+
+
+def _recurrent(cfg):
+    return "mamba" in _layer_kinds(cfg)
+
+
+def _refuse_recurrent(cfg, mechanism):
+    """Recurrent state is exact by construction or wrong: a mechanism
+    that cannot carry it says so instead of serving other tokens."""
+    if _recurrent(cfg):
+        raise ValueError(
+            "%s cannot carry a state-space layer's recurrent state "
+            "(layer_kinds has 'mamba' layers); serve this model through "
+            "the dense cache" % mechanism)
+
+
+def _learned_pos(cfg):
+    """Whether a learned position table is added to the embeddings."""
+    if cfg.positions not in (None, "learned", "rope", "none") \
+            or (cfg.positions is not None
+                and cfg.rope != (cfg.positions == "rope")):
+        raise ValueError(
+            "positions=%r with rope=%r: positions is 'learned', 'rope' "
+            "(with rope=True) or 'none'" % (cfg.positions, cfg.rope))
+    return not cfg.rope and cfg.positions != "none"
+
+
+def _mamba_state(cfg, batch):
+    return ssm.init_state(cfg.ssm_expand * cfg.d_model, cfg.ssm_state,
+                          cfg.ssm_conv, batch, cfg.dtype)
+
+
 def _rope(x, positions, base):
     """Rotary position encoding on [..., T, H, Dh] (or [..., H, Dh]
     with scalar/[B] positions at decode): rotate feature pairs
@@ -132,24 +192,32 @@ def _repeat_kv(x, g):
 def param_specs(cfg):
     """PartitionSpec per parameter — Megatron-style TP, experts on ep."""
     tp, ep = cfg.tp_axis, cfg.ep_axis
-    layer = {
-        "ln1": P(None), "ln2": P(None),
+    attention = {
         "wq": P(None, tp, None), "wk": P(None, tp, None),
         "wv": P(None, tp, None), "wo": P(tp, None, None),
     }
+    # a Mamba mixer is replicated: no sharded path runs one yet
+    mamba = {k: P(*(None,) * n) for k, n in (
+        ("in_proj", 2), ("conv_w", 2), ("conv_b", 1), ("x_proj", 2),
+        ("dt_norm", 1), ("b_norm", 1), ("c_norm", 1), ("dt_proj", 2),
+        ("dt_bias", 1), ("A_log", 2), ("D", 1), ("out_proj", 2))}
+    ffn = {"ln1": P(None), "ln2": P(None)}
     if cfg.n_experts:
-        layer.update({
+        ffn.update({
             "gate": P(None, None),
             "w1": P(ep, None, tp), "w2": P(ep, tp, None),
         })
     else:
-        layer.update({"w1": P(None, tp), "w2": P(tp, None)})
+        ffn.update({"w1": P(None, tp), "w2": P(tp, None)})
+        if cfg.ffn == "gated_silu":
+            ffn["w3"] = P(None, tp)
     out = {
         "embed": P(None, None),
         "ln_f": P(None),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": [dict(ffn, **(mamba if kind == "mamba" else attention))
+                   for kind in _layer_kinds(cfg)],
     }
-    if not cfg.rope:
+    if _learned_pos(cfg):
         out["pos"] = P(None, None)
     return out
 
@@ -163,15 +231,39 @@ def init_params(cfg, seed=0):
         scale = 1.0 / np.sqrt(shape[0] if len(shape) == 2 else cfg.d_model)
         return jnp.asarray(rng.randn(*shape) * scale, dt)
 
-    def layer():
+    def mamba():
+        # the family's initialisation: A = -(1..N) on every channel, a
+        # bias that puts softplus(dt) log-uniform in [1e-3, 1e-1], D = 1
+        e, n = cfg.ssm_expand * cfg.d_model, cfg.ssm_state
+        r = cfg.ssm_dt_rank or -(-cfg.d_model // 16)
+        dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), e))
+        return {
+            "in_proj": dense(cfg.d_model, 2 * e),
+            "conv_w": dense(cfg.ssm_conv, e),
+            "conv_b": jnp.zeros((e,), dt),
+            "x_proj": dense(e, r + 2 * n),
+            "dt_norm": jnp.ones((r,), dt),
+            "b_norm": jnp.ones((n,), dt),
+            "c_norm": jnp.ones((n,), dt),
+            "dt_proj": dense(r, e),
+            "dt_bias": jnp.asarray(dt0 + np.log(-np.expm1(-dt0)), dt),
+            "A_log": jnp.asarray(np.log(np.tile(
+                np.arange(1.0, n + 1)[:, None], (1, e))), dt),
+            "D": jnp.ones((e,), dt),
+            "out_proj": dense(e, cfg.d_model),
+        }
+
+    def layer(kind):
         p = {
             "ln1": jnp.ones(_norm_shape(cfg), dt),
             "ln2": jnp.ones(_norm_shape(cfg), dt),
+        }
+        p.update(mamba() if kind == "mamba" else {
             "wq": dense(cfg.d_model, cfg.n_heads, hd),
             "wk": dense(cfg.d_model, _kvh(cfg), hd),
             "wv": dense(cfg.d_model, _kvh(cfg), hd),
             "wo": dense(cfg.n_heads, hd, cfg.d_model),
-        }
+        })
         if cfg.n_experts:
             p["gate"] = dense(cfg.d_model, cfg.n_experts)
             p["w1"] = jnp.asarray(
@@ -183,15 +275,17 @@ def init_params(cfg, seed=0):
         else:
             p["w1"] = dense(cfg.d_model, cfg.d_ff)
             p["w2"] = dense(cfg.d_ff, cfg.d_model)
+            if cfg.ffn == "gated_silu":
+                p["w3"] = dense(cfg.d_model, cfg.d_ff)
         return p
 
     out = {
         "embed": jnp.asarray(rng.randn(cfg.vocab_size, cfg.d_model) * 0.02,
                              dt),
         "ln_f": jnp.ones(_norm_shape(cfg), dt),
-        "layers": [layer() for _ in range(cfg.n_layers)],
+        "layers": [layer(kind) for kind in _layer_kinds(cfg)],
     }
-    if not cfg.rope:
+    if _learned_pos(cfg):
         # rope models carry no learned position table — at long-context
         # scale it would be dead HBM (+ momentum + checkpoint bloat)
         out["pos"] = jnp.asarray(
@@ -204,6 +298,7 @@ def shard_params(params, cfg, mesh):
     (quantize_weights_int8) shard too: the int8 payload takes the
     weight's spec, its scale/dt sidecars replicate (scales are shared
     along the leading axis, which no spec here partitions alone)."""
+    _refuse_recurrent(cfg, "mesh-sharded parameters (shard_params)")
     specs = param_specs(cfg)
     if cfg.tp_axis and cfg.tp_axis in mesh.shape:
         tp_size = mesh.shape[cfg.tp_axis]
@@ -331,6 +426,10 @@ def _attention(x, p, cfg, mesh, manual_sp=False):
 
 
 def _ffn(x, p, cfg):
+    if cfg.ffn not in ("gelu", "gated_silu") \
+            or (cfg.n_experts and cfg.ffn != "gelu"):
+        raise ValueError("ffn=%r: 'gelu', or 'gated_silu' with "
+                         "n_experts=0" % (cfg.ffn,))
     if cfg.n_experts:
         # dense top-all dispatch: every token weighted over every expert.
         # XLA shards the E dim over ep (and d_ff over tp) so each device
@@ -340,7 +439,11 @@ def _ffn(x, p, cfg):
         h = jax.nn.gelu(jnp.einsum("btd,edf->betf", x, p["w1"]))
         y = jnp.einsum("betf,efd->betd", h, p["w2"])
         return jnp.einsum("betd,bte->btd", y, gates)
-    h = jax.nn.gelu(jnp.einsum("btd,df->btf", x, p["w1"]))
+    h = jnp.einsum("btd,df->btf", x, p["w1"])
+    if cfg.ffn == "gated_silu":
+        h = jax.nn.silu(h) * jnp.einsum("btd,df->btf", x, p["w3"])
+    else:
+        h = jax.nn.gelu(h)
     return jnp.einsum("btf,fd->btd", h, p["w2"])
 
 
@@ -353,10 +456,12 @@ def _pp_size(cfg, mesh):
 def forward(params, tokens, cfg, mesh=None):
     """tokens [B, T] int32 -> logits [B, T, vocab]."""
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         x = x + params["pos"][: tokens.shape[1]]
     act = P(cfg.dp_axis, cfg.sp_axis, None)
     if mesh is not None:
+        _refuse_recurrent(cfg, "the mesh-sharded forward (ring "
+                          "attention, pipeline stages, tp)")
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, act))
     n_stages = _pp_size(cfg, mesh)
     if n_stages > 1:
@@ -380,8 +485,13 @@ def forward(params, tokens, cfg, mesh=None):
             microbatch_spec=P(None, None, cfg.sp_axis, None) if ring
             else P())
     else:
-        def layer_body(p, xl):
-            xl = xl + _attention(_rms_norm(xl, p["ln1"]), p, cfg, mesh)
+        def layer_body(p, xl, kind):
+            h = _rms_norm(xl, p["ln1"])
+            if kind == "mamba":     # from a zero state
+                xl = xl + ssm.mixer_seq(
+                    h, p, _mamba_state(cfg, xl.shape[0]))[0]
+            else:
+                xl = xl + _attention(h, p, cfg, mesh)
             xl = xl + _ffn(_rms_norm(xl, p["ln2"]), p, cfg)
             if mesh is not None:
                 xl = jax.lax.with_sharding_constraint(
@@ -391,9 +501,9 @@ def forward(params, tokens, cfg, mesh=None):
         if cfg.remat_layers:
             # save only layer boundaries; backward recomputes each
             # layer's internals (attention scores, ffn hidden) on the fly
-            layer_body = jax.checkpoint(layer_body)
-        for p in params["layers"]:
-            x = layer_body(p, x)
+            layer_body = jax.checkpoint(layer_body, static_argnums=(2,))
+        for kind, p in zip(_layer_kinds(cfg), params["layers"]):
+            x = layer_body(p, x, kind)
     x = _rms_norm(x, params["ln_f"])
     return jnp.einsum("btd,vd->btv", x, params["embed"])
 
@@ -423,19 +533,26 @@ def init_cache(cfg, batch):
     position, head) fp32 scales ("ks"/"vs") — ~half the HBM of a bf16
     cache (the fp32 scale planes add 4/head_dim of the code bytes:
     ~3% at head_dim 128, but 25% at head_dim 16 — small-head configs
-    keep less than the headline half)."""
+    keep less than the headline half).
+
+    A Mamba layer (cfg.layer_kinds) holds no rows but a fixed-size
+    state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}: batch
+    first like the rows, so whatever moves a lane's rows (the batcher's
+    lane write, beam search's re-gather) moves its state the same way."""
     hd = cfg.d_model // cfg.n_heads
     shape = (batch, cfg.max_len, _kvh(cfg), hd)
     if cfg.kv_cache_int8:
+        _refuse_recurrent(cfg, "kv_cache_int8")
         sshape = shape[:3]
         return [{"k": jnp.zeros(shape, jnp.int8),
                  "ks": jnp.zeros(sshape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.int8),
                  "vs": jnp.zeros(sshape, jnp.float32)}
                 for _ in range(cfg.n_layers)]
-    return [{"k": jnp.zeros(shape, cfg.dtype),
+    return [_mamba_state(cfg, batch) if kind == "mamba" else
+            {"k": jnp.zeros(shape, cfg.dtype),
              "v": jnp.zeros(shape, cfg.dtype)}
-            for _ in range(cfg.n_layers)]
+            for kind in _layer_kinds(cfg)]
 
 
 def _kv_quant(x):
@@ -526,7 +643,15 @@ def quantize_weights_int8(params):
     prefill) XLA fuses the dequantizing convert into each weight's
     consuming matmul, so no full-precision copy is materialized; an
     EAGER decode_step call on a q8 tree dequantizes the whole tree per
-    call — serve through the jitted entry points. Idempotent."""
+    call — serve through the jitted entry points. Idempotent.
+    A tree with Mamba layers is refused: A_log, the step-size bias and
+    the inner norms set a recurrence's decay, and no int8 rule for them
+    has been checked against a reference."""
+    if any("A_log" in layer for layer in params.get("layers", ())):
+        raise ValueError(
+            "quantize_weights_int8 cannot carry a state-space layer's "
+            "parameters (the tree has Mamba layers)")
+
     def q(leaf):
         if _is_q8(leaf):
             return leaf
@@ -570,6 +695,7 @@ def shard_cache(cache, cfg, mesh):
     replicated — each device holds its heads' full cache and the
     attention needs no cross-device traffic; only wo's output
     contraction all-reduces over tp (GSPMD inserts it)."""
+    _refuse_recurrent(cfg, "a mesh-sharded cache (shard_cache)")
     return jax.tree.map(
         lambda x: jax.device_put(
             x, NamedSharding(mesh, _cache_pspec(cfg, x))), cache)
@@ -644,11 +770,19 @@ def prefill(params, cache, tokens, cfg):
     params = _maybe_dequantize(params)
     b, t_p = tokens.shape
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         x = x + params["pos"][:t_p]
     new_cache = []
-    for p, layer_cache in zip(params["layers"], cache):
+    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
+                                    cache):
         h = _rms_norm(x, p["ln1"])
+        if kind == "mamba":
+            # position 0: whatever state the cache held is dropped
+            y, state = ssm.mixer_seq(h, p, _mamba_state(cfg, b))
+            new_cache.append(state)
+            x = x + y
+            x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
+            continue
         q, k, v = _qkv(h, p)
         if cfg.rope:
             # keys are cached ROTATED: their rotation depends only on
@@ -750,7 +884,14 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
     chunks, and speculative decoding verifies k draft tokens in one
     pass. Row i of the chunk attends cache positions <= start+i, so
     stale cache entries beyond the verified stream are never read (and
-    are overwritten when re-processed)."""
+    are overwritten when re-processed).
+
+    A Mamba layer has no such healing. Its state continues from the
+    cache's (a zeroed cache at start 0, a cached prefix's state at a
+    prefix hit, the previous chunk's otherwise) and, with `logits_row`,
+    stops after that row: the rows behind it are the bucket's padding
+    and leave no trace (ssm.mixer_seq's valid_len). Without
+    `logits_row` every row of the chunk is real."""
     params = _maybe_dequantize(params)
     b, c = tokens.shape
     try:
@@ -765,12 +906,20 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
     x = params["embed"][tokens]
     if cfg.rope:
         chunk_pos = start + jnp.arange(c)
-    else:
+    elif _learned_pos(cfg):
         x = x + jax.lax.dynamic_slice_in_dim(params["pos"], start, c, 0)
     new_cache = []
     g = cfg.n_heads // _kvh(cfg)
-    for p, layer_cache in zip(params["layers"], cache):
+    valid_len = None if logits_row is None else logits_row + 1
+    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
+                                    cache):
         h = _rms_norm(x, p["ln1"])
+        if kind == "mamba":
+            y, state = ssm.mixer_seq(h, p, layer_cache, valid_len)
+            new_cache.append(state)
+            x = x + y
+            x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
+            continue
         q, k, v = _qkv(h, p)
         if cfg.rope:
             q = _rope(q, chunk_pos, cfg.rope_base)
@@ -911,6 +1060,9 @@ def speculative_generate(params, draft_params, prompt, n_new, cfg,
     Both configs must share vocab_size."""
     if prompt.shape[0] != 1:
         raise ValueError("speculative decoding serves batch=1")
+    for c in (cfg, draft_cfg):
+        _refuse_recurrent(c, "speculative decoding (a rejected draft "
+                          "cannot be rolled back)")
     if cfg.vocab_size != draft_cfg.vocab_size:
         raise ValueError("draft and target must share the vocab")
     t_prompt = int(prompt.shape[1])
@@ -956,7 +1108,7 @@ def decode_step(params, cache, tokens, pos, cfg):
     params = _maybe_dequantize(params)
     ragged = jnp.ndim(pos) == 1        # trace-time branch: [B] vs scalar
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         if ragged:
             x = x + jnp.take(params["pos"], pos, axis=0)
         else:
@@ -964,8 +1116,15 @@ def decode_step(params, cache, tokens, pos, cfg):
                 params["pos"], pos, 0, keepdims=False)
     b = x.shape[0]
     new_cache = []
-    for p, layer_cache in zip(params["layers"], cache):
+    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
+                                    cache):
         h = _rms_norm(x, p["ln1"])
+        if kind == "mamba":
+            y, state = ssm.mixer_step(h, p, layer_cache)
+            new_cache.append(state)
+            x = x + y
+            x = x + _ffn(_rms_norm(x, p["ln2"])[:, None], p, cfg)[:, 0]
+            continue
         q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
         k_new = jnp.einsum("bd,dhk->bhk", h, p["wk"])
         v_new = jnp.einsum("bd,dhk->bhk", h, p["wv"])
@@ -1013,6 +1172,7 @@ def init_paged_cache(cfg, num_blocks, block_size):
     kv_cache_int8 the per-(position, head) fp32 scale planes split the
     same way ([num_blocks, block_size, KVH]), so a block carries its
     own scales and int8-KV composes per block."""
+    _refuse_recurrent(cfg, "the paged KV pool (init_paged_cache)")
     if num_blocks < 2:
         raise ValueError("need >= 2 blocks (block 0 is the null block)")
     hd = cfg.d_model // cfg.n_heads
@@ -1110,9 +1270,10 @@ def decode_step_paged(params, pool, tables, tokens, pos, cfg):
     contraction reads each once per group), int8-KV (codes + per-block
     scales gathered together, the one shared _int8_cache_attention
     does the rest), quantized weight trees."""
+    _refuse_recurrent(cfg, "paged decode (decode_step_paged)")
     params = _maybe_dequantize(params)
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         x = x + jnp.take(params["pos"], pos, axis=0)
     new_pool = []
     for p, layer_pool in zip(params["layers"], pool):
@@ -1192,11 +1353,13 @@ def verify_chunk(params, cache, tokens, pos, cfg):
     any row can attend it. Windows that run past max_len (a parked
     lane, a near-budget lane coasting) DROP their writes instead of
     clamping. Returns (logits [B, C, vocab], cache)."""
+    _refuse_recurrent(cfg, "speculative verification (verify_chunk: a "
+                      "rejected draft cannot be rolled back)")
     params = _maybe_dequantize(params)
     b, c = tokens.shape
     positions = pos[:, None] + jnp.arange(c)[None, :]        # [B, C]
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         # take() clamps OOB rows — their logits are garbage, but their
         # writes drop and their emissions are never credited
         x = x + jnp.take(params["pos"], positions, axis=0)
@@ -1275,11 +1438,13 @@ def verify_chunk_paged(params, pool, tables, tokens, pos, cfg):
     read-only here; allocation (including the speculative over-reserve
     and release-on-reject) is the host scheduler's job.
     Returns (logits [B, C, vocab], pool)."""
+    _refuse_recurrent(cfg, "paged speculative verification "
+                      "(verify_chunk_paged)")
     params = _maybe_dequantize(params)
     b, c = tokens.shape
     positions = pos[:, None] + jnp.arange(c)[None, :]        # [B, C]
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if _learned_pos(cfg):
         x = x + jnp.take(params["pos"], positions, axis=0)
     new_pool = []
     g = cfg.n_heads // _kvh(cfg)
