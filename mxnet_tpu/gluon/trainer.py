@@ -6,16 +6,22 @@ Parameters; gradient aggregation across data-parallel devices goes through
 the KVStore layer, which on this build is XLA collectives over the active
 device mesh.
 
-Comm path: by default gradients travel BUCKETED (parallel/fusion.py) —
-keys pack into ~25 MB buckets in reverse-registration order (the last
-layers' grads, ready first in backward, reduce first — the reference's
-priority push, trainer.py:356 priority=-idx) and each bucket is one
-fused collective dispatch; XLA's async dispatch overlaps a bucket's
-all-reduce with the packing of the next. MXNET_KVSTORE_FUSION=0
-restores the per-key path. MXNET_KVSTORE_SHARD_UPDATE=1 additionally
-moves the optimizer into the store as a reduce-scatter -> sharded
-update -> all-gather per bucket (PAPERS.md cross-replica sharding),
-which cuts per-replica optimizer state by (N-1)/N.
+Store: resolved by the reference's rule (model.py _create_kvstore, "no
+need to use kv for single device and single machine"): a string spec with
+no "dist" in it on one gradient copy a parameter is NO store, and the
+step's allreduce phase launches nothing (docs/GRAD_FUSION.md).
+
+Comm path: with a store, gradients travel BUCKETED by default
+(parallel/fusion.py) — keys pack into ~25 MB buckets in
+reverse-registration order (the last layers' grads, ready first in
+backward, reduce first — the reference's priority push, trainer.py:356
+priority=-idx) and each bucket is one fused collective dispatch; XLA's
+async dispatch overlaps a bucket's all-reduce with the packing of the
+next. MXNET_KVSTORE_FUSION=0 restores the per-key path.
+MXNET_KVSTORE_SHARD_UPDATE=1 additionally moves the optimizer into the
+store as a reduce-scatter -> sharded update -> all-gather per bucket
+(PAPERS.md cross-replica sharding), which cuts per-replica optimizer
+state by (N-1)/N.
 
 Update path: with the update outside the store (the default), _update
 hands the Updater EVERY fresh parameter in one call, and the Updater
@@ -31,6 +37,7 @@ import time as _time
 from .. import optimizer as opt
 from .. import kvstore as kvs
 from ..base import MXNetError
+from ..model import _create_kvstore
 from ..observability import chaos as _chaos
 from ..observability import core as _obs
 from ..observability import dist as _obs_dist
@@ -53,7 +60,16 @@ class Trainer(object):
     params : ParameterDict or list of Parameter
     optimizer : str or Optimizer
     optimizer_params : dict
-    kvstore : str or KVStore, default 'device'
+    kvstore : str, KVStore or None, default 'device'
+        A string with no 'dist' in it ('device', 'local') means no store
+        when every parameter holds one gradient copy, as every Gluon
+        parameter does: with nobody else contributing the all-reduce is
+        the identity, so `_kvstore` stays None, the update is local and
+        the step's `allreduce` span is empty. Such a string still builds
+        its store when `compression_params` or `update_on_kvstore=True`
+        ask for what only a store does. A 'dist*' type
+        ('dist_tpu_sync' over the device mesh: data parallelism) and a
+        KVStore instance are always used as given; None is no store.
     compression_params : dict, optional (gradient compression config)
     update_on_kvstore : bool, optional
     """
@@ -98,10 +114,21 @@ class Trainer(object):
         self._updaters = [opt.get_updater(self._optimizer)]
 
     def _resolve_store(self):
+        """The reference's rule (model.py:69 _create_kvstore, which its
+        Trainer._init_kvstore calls): a string spec with no ``dist`` in
+        it, on parameters that hold one gradient copy each, needs no
+        store — a reduction over one contributor is the identity. A
+        KVStore instance and ``None`` are taken as given, and a string
+        whose caller also asked for what only a store does (gradient
+        compression, the update on the store) still builds one."""
         spec = self._kvstore_type
-        if spec is None or isinstance(spec, kvs.KVStore):
-            return spec
-        return kvs.create(spec)
+        if isinstance(spec, str) and (self._compression_params
+                                      or self._update_on_kvstore):
+            return kvs.create(spec)
+        copies = max((len(p.list_grad()) for _, p in self._trainable()
+                      if p._data is not None), default=1)
+        return _create_kvstore(
+            spec, copies, {p.name: p for p in self._params})[0]
 
     def _init_kvstore(self):
         kv = self._kvstore = self._resolve_store()
@@ -267,10 +294,15 @@ class Trainer(object):
         return items
 
     def _allreduce_grads(self):
-        if self._kvstore is None:
-            return
         with _obs.span("allreduce", cat="step",
                        fused=fusion.fusion_enabled()):
+            if self._kvstore is None:
+                # nobody else contributes: the phase opens, so a step's
+                # spans read the same with and without a store, and
+                # launches nothing
+                if _obs.enabled():
+                    _obs.counter("trainer.allreduce_noop").add()
+                return
             self._allreduce_grads_impl()
 
     def _allreduce_grads_impl(self):

@@ -13,6 +13,7 @@ import numpy as np
 from . import ndarray as nd
 from . import symbol as sym
 from . import io as mx_io
+from . import kvstore as kvs
 from . import metric as mx_metric
 from . import optimizer as opt
 from .base import MXNetError
@@ -22,6 +23,34 @@ __all__ = ["BatchEndParam", "save_checkpoint", "load_checkpoint",
 
 BatchEndParam = namedtuple("BatchEndParams",
                            ["epoch", "nbatch", "eval_metric", "locals"])
+
+
+def _create_kvstore(kvstore, num_device, arg_params):
+    """model.py:69 — the store a trainer needs, and whether the update
+    would run on it. "No need to use kv for single device and single
+    machine": a string spec with no ``dist`` in it and one device gives
+    ``(None, False)``. Module.init_optimizer and gluon.Trainer both
+    resolve their ``kvstore`` argument here."""
+    update_on_kvstore = True
+    if kvstore is None:
+        kv = None
+    elif isinstance(kvstore, kvs.KVStore):
+        kv = kvstore
+    elif isinstance(kvstore, str):
+        if num_device == 1 and "dist" not in kvstore:
+            kv = None
+        else:
+            kv = kvs.create(kvstore)
+            if kvstore == "local":
+                max_size = max(np.prod(param.shape)
+                               for param in arg_params.values())
+                if max_size > 1024 * 1024 * 16:
+                    update_on_kvstore = False
+    else:
+        raise TypeError("kvstore must be KVStore, str or None")
+    if kv is None:
+        update_on_kvstore = False
+    return (kv, update_on_kvstore)
 
 
 def save_checkpoint(prefix, epoch, symbol, arg_params, aux_params,

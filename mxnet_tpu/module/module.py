@@ -13,12 +13,9 @@ host-side (executor_group.py:282 in the reference).
 import logging
 import warnings
 
-import numpy as np
-
 from .. import context as ctx_mod
 from .. import ndarray as nd
 from .. import optimizer as opt
-from .. import kvstore as kvs
 from ..base import MXNetError
 from ..initializer import Uniform, InitDesc
 from ..observability import chaos as _chaos
@@ -27,7 +24,7 @@ from ..observability import dist as _obs_dist
 from ..observability import goodput as _obs_goodput
 from ..observability import integrity as _integrity
 from ..observability import recompile as _obs_recompile
-from ..model import save_checkpoint, load_checkpoint
+from ..model import _create_kvstore, save_checkpoint, load_checkpoint
 from .base_module import BaseModule, _check_input_names
 
 
@@ -559,27 +556,3 @@ class Module(BaseModule):
                     self._kvstore.row_sparse_pull(
                         i, out=self._exec.arg_dict[name],
                         row_ids=row_ids[name])
-
-
-def _create_kvstore(kvstore, num_device, arg_params):
-    """model.py:69 _create_kvstore semantics."""
-    update_on_kvstore = True
-    if kvstore is None:
-        kv = None
-    elif isinstance(kvstore, kvs.KVStore):
-        kv = kvstore
-    elif isinstance(kvstore, str):
-        if num_device == 1 and "dist" not in kvstore:
-            kv = None
-        else:
-            kv = kvs.create(kvstore)
-            if kvstore == "local":
-                max_size = max(np.prod(param.shape)
-                               for param in arg_params.values())
-                if max_size > 1024 * 1024 * 16:
-                    update_on_kvstore = False
-    else:
-        raise TypeError("kvstore must be KVStore, str or None")
-    if kv is None:
-        update_on_kvstore = False
-    return (kv, update_on_kvstore)

@@ -109,7 +109,10 @@ def test_chaos_error_is_oserror():
 
 # ------------------------------------------------------- the step guard --
 
-def _tiny_gluon(kvstore="device"):
+def _tiny_gluon():
+    """A net and its step through a store INSTANCE (the string spec
+    resolves to no store on one worker), so the guard is held on the path
+    where the allreduce and the update may live inside the store."""
     from mxnet_tpu import gluon
     from mxnet_tpu.gluon import nn
     net = nn.HybridSequential()
@@ -118,7 +121,8 @@ def _tiny_gluon(kvstore="device"):
         net.add(nn.Dense(2))
     net.initialize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1}, kvstore=kvstore)
+                            {"learning_rate": 0.1},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.L2Loss()
     x = mx.nd.random.uniform(shape=(4, 6))
     y = mx.nd.random.uniform(shape=(4, 2))

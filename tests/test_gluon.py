@@ -416,9 +416,11 @@ def telemetry(monkeypatch):
     core.reset()
 
 
-def _mlp(optimizer="sgd", hyper=None, kvstore=None):
+def _mlp(optimizer="sgd", hyper=None, kvstore=None, dtype="float32",
+         **trainer_args):
     """A small hybridized block with six parameters, a trainer and a
-    function that runs forward + backward (on the first `heads` heads)."""
+    function that runs forward + backward (on the first `heads` heads).
+    `kvstore="default"` leaves the argument to the Trainer's default."""
     np.random.seed(3)
     mx.random.seed(3)
     net = nn.HybridSequential()
@@ -426,13 +428,16 @@ def _mlp(optimizer="sgd", hyper=None, kvstore=None):
             nn.Dense(5, activation="relu", in_units=6),
             nn.Dense(3, in_units=5))
     net.initialize(mx.init.Xavier())
+    net.cast(dtype)
     net.hybridize()
+    if kvstore != "default":
+        trainer_args["kvstore"] = kvstore
     trainer = gluon.Trainer(
         net.collect_params(), optimizer,
         hyper if hyper is not None else {"learning_rate": 0.1,
                                          "momentum": 0.9},
-        kvstore=kvstore)
-    x = mx.nd.array(np.random.randn(8, 4).astype(np.float32))
+        **trainer_args)
+    x = mx.nd.array(np.random.randn(8, 4).astype(np.float32), dtype=dtype)
 
     def backward():
         with autograd.record():
@@ -455,7 +460,7 @@ def _weights(net):
 
 
 def test_trainer_update_is_one_fused_program(telemetry):
-    net, trainer, backward = _mlp(kvstore="device")
+    net, trainer, backward = _mlp(kvstore=mx.kvstore.create("device"))
     backward()
     trainer.step(8)
     assert _opt_counts(telemetry) == (6, 0)
@@ -568,3 +573,174 @@ def test_trainer_update_never_recompiles_for_a_hyperparameter():
     # the scheduler moved the rate too
     assert trainer.learning_rate < 0.1 * 0.7 ** 3
     assert sizes[-1] == sizes[0], sizes
+
+
+# ------------------------------------------------ the trainer's store ---
+# The reference's rule (model.py _create_kvstore): a string spec with no
+# "dist" in it, on one gradient copy a parameter, is no store at all. The
+# allreduce phase still opens every step and counts that it had nothing to
+# do; whoever asks for what only a store does keeps one.
+
+def _spans(core, name):
+    return [r for r in core.records() if r[0] == "X" and r[1] == name]
+
+
+@pytest.mark.parametrize("spec", ["default", "device", "local"])
+def test_trainer_string_spec_on_one_worker_builds_no_store(telemetry, spec):
+    _, trainer, backward = _mlp(kvstore=spec)
+    for _ in range(3):
+        backward()
+        trainer.step(8)
+    assert trainer._kvstore is None
+    assert trainer._update_on_kvstore is False
+    assert not [n for n in telemetry.counters() if n.startswith("kvstore.")]
+    assert not [r for r in telemetry.records()
+                if r[1].startswith("kvstore.")]
+    assert len(_spans(telemetry, "allreduce")) == 3
+    assert telemetry.counters()["trainer.allreduce_noop"].total == 3
+    assert _opt_counts(telemetry) == (18, 0)
+
+
+def test_trainer_default_spec_step_boundaries_take_no_store(
+        telemetry, monkeypatch, tmp_path):
+    """Everything in step() after the allreduce is handed the store; the
+    default spec now hands each of them None."""
+    from mxnet_tpu.observability import integrity
+    monkeypatch.setenv("MXNET_INTEGRITY", "1")
+    monkeypatch.setenv("MXNET_INTEGRITY_EVERY", "1")
+    monkeypatch.setenv("MXNET_OBS_SKEW_EVERY", "1")
+    integrity._reset_for_tests()
+    net, trainer, backward = _mlp(kvstore="default")
+    try:
+        for _ in range(2):
+            backward()
+            trainer.step(8)
+        assert integrity._state["steps"] == 2
+        assert integrity.stats["detected"] == 0
+    finally:
+        integrity._reset_for_tests()
+    assert trainer._kvstore is None
+    fname = str(tmp_path / "trainer.states")
+    trainer.save_states(fname)
+    saved = _bits(_state_leaves(trainer))
+    backward()
+    trainer.step(8)
+    assert _bits(_state_leaves(trainer)) != saved
+    trainer.load_states(fname)
+    assert _bits(_state_leaves(trainer)) == saved
+
+
+@pytest.mark.parametrize("case,args", [
+    ("instance", {}),
+    ("dist", {"kvstore": "dist_tpu_sync"}),
+    ("compression", {"kvstore": "device",
+                     "compression_params": {"type": "2bit",
+                                            "threshold": 0.5}}),
+    ("update_on_kvstore", {"kvstore": "device",
+                           "update_on_kvstore": True}),
+])
+def test_trainer_keeps_the_store_only_a_store_can_serve(telemetry, case,
+                                                         args):
+    if case == "instance":
+        args = {"kvstore": mx.kvstore.create("device")}
+    net, trainer, backward = _mlp(**args)
+    before = _weights(net)
+    for _ in range(3):
+        backward()
+        trainer.step(8)
+    kv = trainer._kvstore
+    assert isinstance(kv, mx.kvstore.KVStore)
+    assert kv.dispatch_stats["buckets"] > 0
+    assert kv.dispatch_stats["keys"] == 18
+    assert kv.gradient_compression.active == (case == "compression")
+    assert trainer._update_on_kvstore == (case == "update_on_kvstore")
+    assert telemetry.counters()["kvstore.buckets"].total \
+        == kv.dispatch_stats["buckets"]
+    assert "trainer.allreduce_noop" not in telemetry.counters()
+    assert len(_spans(telemetry, "allreduce")) == 3
+    assert all((a != b).any() for a, b in zip(_weights(net), before))
+
+
+def _bits(arrays):
+    return [np.asarray(a).tobytes() for a in arrays]
+
+
+def _state_leaves(trainer):
+    """Every optimizer state array (momenta; with multi_precision the
+    float32 masters beside them), in parameter order."""
+    states = trainer._updaters[0].states
+    return [np.asarray(a._data) for i in sorted(states)
+            for a in (states[i] if isinstance(states[i], tuple)
+                      else (states[i],))]
+
+
+@pytest.mark.parametrize("dtype,hyper", [
+    ("float32", {"learning_rate": 0.1, "momentum": 0.9}),
+    ("float16", {"learning_rate": 0.1, "momentum": 0.9,
+                 "multi_precision": True}),
+])
+def test_trainer_store_or_none_gives_the_same_bits(dtype, hyper):
+    runs = []
+    for spec in (None, "device", mx.kvstore.create("device")):
+        net, trainer, backward = _mlp(hyper=dict(hyper), kvstore=spec,
+                                      dtype=dtype)
+        for _ in range(3):
+            backward()
+            trainer.step(8)
+        assert (trainer._kvstore is None) == (not isinstance(
+            spec, mx.kvstore.KVStore))
+        leaves = _state_leaves(trainer)
+        # momenta for six parameters, and their masters in float16
+        assert len(leaves) == (12 if dtype == "float16" else 6)
+        runs.append((_bits(_weights(net)), _bits(leaves)))
+    assert runs[0] == runs[1] == runs[2]
+
+
+def test_trainer_states_saved_beside_a_store_load_under_the_default(
+        tmp_path):
+    """What a run before the rule wrote (a store for the gradients, the
+    update and its states in the Updater) is what the default spec reads."""
+    fname = str(tmp_path / "trainer.states")
+    net, trainer, backward = _mlp(kvstore=mx.kvstore.create("device"))
+    for _ in range(2):
+        backward()
+        trainer.step(8)
+    assert trainer._kvstore is not None and not trainer._update_on_kvstore
+    trainer.save_states(fname)
+    new_net, new_trainer, new_backward = _mlp(kvstore="default")
+    for mine, theirs in zip(new_net.collect_params().values(),
+                            net.collect_params().values()):
+        mine.set_data(theirs.data())
+    new_trainer.load_states(fname)
+    assert new_trainer._kvstore is None
+    assert _bits(_state_leaves(new_trainer)) == _bits(_state_leaves(trainer))
+    for step, back in ((trainer, backward), (new_trainer, new_backward)):
+        back()
+        step.step(8)
+    assert _bits(_weights(new_net)) == _bits(_weights(net))
+    assert _bits(_state_leaves(new_trainer)) == _bits(_state_leaves(trainer))
+
+
+@pytest.mark.parametrize("spec", [None, "device", "local", "nccl",
+                                  "dist_tpu_sync", "instance"])
+def test_module_and_trainer_resolve_a_spec_alike(spec):
+    if spec == "instance":
+        spec = mx.kvstore.create("local")
+    _, trainer, backward = _mlp(kvstore=spec)
+    backward()
+    trainer.step(8)
+    out = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(mx.sym.Variable("data"), name="fc",
+                              num_hidden=2), name="softmax")
+    mod = mx.mod.Module(out, data_names=["data"],
+                        label_names=["softmax_label"])
+    mod.bind(data_shapes=[("data", (4, 6))],
+             label_shapes=[("softmax_label", (4,))])
+    mod.init_params()
+    mod.init_optimizer(kvstore=spec)
+    if isinstance(spec, mx.kvstore.KVStore):
+        assert trainer._kvstore is spec and mod._kvstore is spec
+    else:
+        assert type(trainer._kvstore) is type(mod._kvstore)
+        assert (trainer._kvstore is None) == (
+            spec is None or "dist" not in spec)

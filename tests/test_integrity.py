@@ -275,8 +275,10 @@ def _tiny_train(steps=2, lr=0.05):
         net.add(nn.Dense(8, activation="relu"))
         net.add(nn.Dense(4))
     net.initialize()
+    # a store INSTANCE: the string spec resolves to no store on one worker
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": lr}, kvstore="device")
+                            {"learning_rate": lr},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.L2Loss()
     rng = np.random.RandomState(0)
     x = mx.nd.array(rng.uniform(size=(8, 10)).astype(np.float32))

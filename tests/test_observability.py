@@ -220,7 +220,8 @@ def test_trainer_step_trace_and_aggregate(obs_on, tmp_path):
         net.add(nn.Dense(4))
     net.initialize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1})
+                            {"learning_rate": 0.1},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.L2Loss()
     x = mx.nd.random.uniform(shape=(8, 10))
     y = mx.nd.random.uniform(shape=(8, 4))

@@ -171,7 +171,7 @@ def test_resnet_dp_mesh_matches_single_device():
         net.hybridize()
         trainer = gluon.Trainer(net.collect_params(), "sgd",
                                 {"learning_rate": 0.05, "momentum": 0.9},
-                                kvstore="device")
+                                kvstore=mx.kvstore.create("device"))
         loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
         rs = np.random.RandomState(0)
         X = rs.rand(8, 3, 32, 32).astype(np.float32)

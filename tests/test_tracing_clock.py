@@ -76,7 +76,8 @@ def _gluon_step():
     net.initialize(mx.init.Xavier())
     net.hybridize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1}, kvstore="device")
+                            {"learning_rate": 0.1},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     x = mx.nd.array(np.random.RandomState(0).randn(4, 5))
     y = mx.nd.array(np.array([0, 1, 2, 1]))

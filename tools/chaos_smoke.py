@@ -124,7 +124,8 @@ def nan_guard():
         net.add(nn.Dense(2))
     net.initialize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1}, kvstore="device")
+                            {"learning_rate": 0.1},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.L2Loss()
     x = mx.nd.random.uniform(shape=(4, 6))
     y = mx.nd.random.uniform(shape=(4, 2))
@@ -726,7 +727,8 @@ def integrity_train_worker(ckdir, steps):
         net.add(nn.Dense(4))
     net.initialize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.05}, kvstore="device")
+                            {"learning_rate": 0.05},
+                            kvstore=mx.kvstore.create("device"))
     loss_fn = gluon.loss.L2Loss()
     rng = np.random.RandomState(0)
     x = mx.nd.array(rng.uniform(size=(8, 10)).astype(np.float32))

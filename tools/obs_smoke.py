@@ -75,7 +75,9 @@ def _train_steps(kvstore, steps=2):
 
 
 def single_process():
-    mx = _train_steps(kvstore="device")
+    import mxnet_tpu as mx
+    # a store instance: the "device" string is no store on one worker
+    _train_steps(kvstore=mx.kvstore.create("device"))
     fname = os.path.join(tempfile.mkdtemp(prefix="obs_smoke_"),
                          "trace.json")
     mx.profiler.set_config(filename=fname, xla_trace=False)
