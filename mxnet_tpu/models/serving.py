@@ -112,33 +112,64 @@ def _bucket(n, lo=8):
     return b
 
 
-def _jitted_ragged_step(cfg, greedy, temperature, top_k, top_p):
-    """One compiled program: ragged decode + per-row token choice.
+def _pick_next(logits, keys, greedy, temperature, top_k, top_p):
+    """Every lane's next token from its logits row: argmax, or a sample.
 
     Sampling mirrors generate()'s key chain PER ROW (split the row's
     key, sample with the sub-key), so a request's sampled stream is
     identical to its solo generate(seed=...) run — slot placement and
-    pool mix cannot perturb it."""
+    pool mix cannot perturb it. Returns (tokens [B], the advanced
+    keys)."""
+    if greedy:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), keys
+    split = jax.vmap(jax.random.split)(keys)   # [B, 2, 2]
+    keys, subs = split[:, 0], split[:, 1]
+    nxt = jax.vmap(
+        lambda l, k: tf._sample_logits(
+            l[None], k, temperature, top_k, top_p)[0]
+    )(logits, subs)
+    return nxt, keys
+
+
+# The decode programs take (params, cache, tables, tok, pos, keys) for
+# either kind of cache: `tables` is None for the dense rows (an empty
+# pytree: no argument reaches the device) and the per-lane block tables
+# for the paged pool, and tf._decode_step_on picks the step at trace
+# time. The cache / pool is donated; tables are donated only by the
+# pipelined chunk (which carries them device-resident) — the sync
+# programs read them. `paged` is part of each _serving_jit key, so the
+# two kinds never share a wrapper.
+
+def _jitted_ragged_step(cfg, greedy, temperature, top_k, top_p, paged):
+    """One compiled program: ragged decode + per-row token choice."""
     def build(fz):
-        def step(params, cache, tok, pos, keys):
-            logits, cache = tf.decode_step(params, cache, tok, pos, fz)
-            if greedy:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, keys, cache
-            split = jax.vmap(jax.random.split)(keys)   # [B, 2, 2]
-            keys, subs = split[:, 0], split[:, 1]
-            nxt = jax.vmap(
-                lambda l, k: tf._sample_logits(
-                    l[None], k, temperature, top_k, top_p)[0]
-            )(logits, subs)
+        def step(params, cache, tables, tok, pos, keys):
+            logits, cache = tf._decode_step_on(params, cache, tables,
+                                               tok, pos, fz)
+            nxt, keys = _pick_next(logits, keys, greedy, temperature,
+                                   top_k, top_p)
             return nxt, keys, cache
         return jax.jit(step, donate_argnums=tf._serving_donate(1))
     return tf._serving_jit(
-        ("decode_ragged", greedy, float(temperature), top_k, top_p),
-        cfg, build)
+        ("decode_ragged", paged, greedy, float(temperature), top_k,
+         top_p), cfg, build)
 
 
-def _jitted_ragged_chunk(cfg, greedy, temperature, top_k, top_p, k):
+def _scan_steps(fz, controls, k, params, cache, tables, tok, pos, keys):
+    """`k` ragged decode steps as one lax.scan; returns the rolling
+    carry (cache, last token, advanced positions, key chain) and the
+    [k, B] emissions."""
+    def body(carry, _):
+        cache, tok, pos, keys = carry
+        logits, cache = tf._decode_step_on(params, cache, tables, tok,
+                                           pos, fz)
+        nxt, keys = _pick_next(logits, keys, *controls)
+        return (cache, nxt, pos + 1, keys), nxt
+    return jax.lax.scan(body, (cache, tok, pos, keys), None, length=k)
+
+
+def _jitted_ragged_chunk(cfg, greedy, temperature, top_k, top_p, k,
+                         paged):
     """`k` ragged decode steps as ONE compiled program (lax.scan) —
     multi-step scheduling. Each scheduling round costs a dispatch plus
     a result sync; where that host cost exceeds a decode step,
@@ -147,35 +178,25 @@ def _jitted_ragged_chunk(cfg, greedy, temperature, top_k, top_p, k):
     [k, B] token block afterwards, discarding any tail a request
     emitted past its stop token or budget (bounded waste, the
     standard continuous-batching trade for chunked scheduling)."""
+    controls = (greedy, temperature, top_k, top_p)
+
     def build(fz):
-        def chunk(params, cache, tok, pos, keys):
-            def body(carry, _):
-                cache, tok, pos, keys = carry
-                logits, cache = tf.decode_step(params, cache, tok,
-                                               pos, fz)
-                if greedy:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    split = jax.vmap(jax.random.split)(keys)
-                    keys, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(
-                        lambda l, kk: tf._sample_logits(
-                            l[None], kk, temperature, top_k, top_p)[0]
-                    )(logits, subs)
-                return (cache, nxt, pos + 1, keys), nxt
-            (cache, _, _, keys), toks = jax.lax.scan(
-                body, (cache, tok, pos, keys), None, length=k)
+        def chunk(params, cache, tables, tok, pos, keys):
+            (cache, _, _, keys), toks = _scan_steps(
+                fz, controls, k, params, cache, tables, tok, pos, keys)
             return toks, keys, cache           # toks [k, B]
         return jax.jit(chunk, donate_argnums=tf._serving_donate(1))
     return tf._serving_jit(
-        ("decode_ragged_chunk", greedy, float(temperature), top_k,
-         top_p, k), cfg, build)
+        ("decode_ragged_chunk", paged, greedy, float(temperature),
+         top_k, top_p, k), cfg, build)
 
 
-def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k):
+def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k,
+                           paged):
     """`k` ragged decode steps that return the WHOLE rolling carry
-    (cache, last token, advanced positions, key chain) alongside the
-    [k, B] emissions — the dispatch unit of the PIPELINED batcher.
+    (cache, tables, last token, advanced positions, key chain)
+    alongside the [k, B] emissions — the dispatch unit of the
+    PIPELINED batcher.
 
     The sync-mode chunk (_jitted_ragged_chunk) hands its carry back to
     the host, which re-uploads it next step; here the carry never
@@ -183,31 +204,21 @@ def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k):
     k's output buffers BEFORE anyone syncs chunk k's tokens. The
     emissions are the only output the host ever fetches. The carry is
     donated on accelerators (tok/pos/keys included — they are dead the
-    moment the next chunk is built from them)."""
+    moment the next chunk is built from them); tables pass through
+    unchanged (allocation patches apply between dispatches,
+    host-side)."""
+    controls = (greedy, temperature, top_k, top_p)
+
     def build(fz):
-        def chunk(params, cache, tok, pos, keys):
-            def body(carry, _):
-                cache, tok, pos, keys = carry
-                logits, cache = tf.decode_step(params, cache, tok,
-                                               pos, fz)
-                if greedy:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    split = jax.vmap(jax.random.split)(keys)
-                    keys, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(
-                        lambda l, kk: tf._sample_logits(
-                            l[None], kk, temperature, top_k, top_p)[0]
-                    )(logits, subs)
-                return (cache, nxt, pos + 1, keys), nxt
-            (cache, tok, pos, keys), toks = jax.lax.scan(
-                body, (cache, tok, pos, keys), None, length=k)
-            return toks, cache, tok, pos, keys   # toks [k, B]
+        def chunk(params, cache, tables, tok, pos, keys):
+            (cache, tok, pos, keys), toks = _scan_steps(
+                fz, controls, k, params, cache, tables, tok, pos, keys)
+            return toks, cache, tables, tok, pos, keys   # toks [k, B]
         return jax.jit(chunk,
-                       donate_argnums=tf._serving_donate(1, 2, 3, 4))
+                       donate_argnums=tf._serving_donate(1, 2, 3, 4, 5))
     return tf._serving_jit(
-        ("decode_pipeline", greedy, float(temperature), top_k, top_p,
-         k), cfg, build)
+        ("decode_pipeline", paged, greedy, float(temperature), top_k,
+         top_p, k), cfg, build)
 
 
 def _jitted_lane_patch(cfg):
@@ -265,91 +276,8 @@ def _jitted_slot_write(cfg):
 
 
 # ---- paged-cache compiled programs -------------------------------------
-# Ragged decode through the per-layer block pool + per-lane block tables
-# (tf.decode_step_paged): same scheduling shapes as the dense programs
-# with the cache argument split into (pool, tables). The pool is donated
-# like the dense cache; tables are donated only by the pipelined chunk
-# (which carries them device-resident) — the sync programs read them.
-
-def _jitted_ragged_step_paged(cfg, greedy, temperature, top_k, top_p):
-    def build(fz):
-        def step(params, pool, tables, tok, pos, keys):
-            logits, pool = tf.decode_step_paged(params, pool, tables,
-                                                tok, pos, fz)
-            if greedy:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, keys, pool
-            split = jax.vmap(jax.random.split)(keys)
-            keys, subs = split[:, 0], split[:, 1]
-            nxt = jax.vmap(
-                lambda l, k: tf._sample_logits(
-                    l[None], k, temperature, top_k, top_p)[0]
-            )(logits, subs)
-            return nxt, keys, pool
-        return jax.jit(step, donate_argnums=tf._serving_donate(1))
-    return tf._serving_jit(
-        ("decode_ragged_paged", greedy, float(temperature), top_k,
-         top_p), cfg, build)
-
-
-def _jitted_ragged_chunk_paged(cfg, greedy, temperature, top_k, top_p,
-                               k):
-    def build(fz):
-        def chunk(params, pool, tables, tok, pos, keys):
-            def body(carry, _):
-                pool, tok, pos, keys = carry
-                logits, pool = tf.decode_step_paged(
-                    params, pool, tables, tok, pos, fz)
-                if greedy:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    split = jax.vmap(jax.random.split)(keys)
-                    keys, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(
-                        lambda l, kk: tf._sample_logits(
-                            l[None], kk, temperature, top_k, top_p)[0]
-                    )(logits, subs)
-                return (pool, nxt, pos + 1, keys), nxt
-            (pool, _, _, keys), toks = jax.lax.scan(
-                body, (pool, tok, pos, keys), None, length=k)
-            return toks, keys, pool            # toks [k, B]
-        return jax.jit(chunk, donate_argnums=tf._serving_donate(1))
-    return tf._serving_jit(
-        ("decode_ragged_chunk_paged", greedy, float(temperature),
-         top_k, top_p, k), cfg, build)
-
-
-def _jitted_pipeline_chunk_paged(cfg, greedy, temperature, top_k,
-                                 top_p, k):
-    """Paged twin of _jitted_pipeline_chunk: the rolling carry is
-    (pool, tables, tok, pos, keys), all device-resident and donated —
-    tables pass through unchanged (allocation patches apply between
-    dispatches, host-side)."""
-    def build(fz):
-        def chunk(params, pool, tables, tok, pos, keys):
-            def body(carry, _):
-                pool, tok, pos, keys = carry
-                logits, pool = tf.decode_step_paged(
-                    params, pool, tables, tok, pos, fz)
-                if greedy:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    split = jax.vmap(jax.random.split)(keys)
-                    keys, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(
-                        lambda l, kk: tf._sample_logits(
-                            l[None], kk, temperature, top_k, top_p)[0]
-                    )(logits, subs)
-                return (pool, nxt, pos + 1, keys), nxt
-            (pool, tok, pos, keys), toks = jax.lax.scan(
-                body, (pool, tok, pos, keys), None, length=k)
-            return toks, pool, tables, tok, pos, keys
-        return jax.jit(chunk,
-                       donate_argnums=tf._serving_donate(1, 2, 3, 4, 5))
-    return tf._serving_jit(
-        ("decode_pipeline_paged", greedy, float(temperature), top_k,
-         top_p, k), cfg, build)
-
+# What moves blocks between a row cache and the per-layer pool, and the
+# block-table patches. The decode programs above serve the pool too.
 
 def _jitted_block_write(cfg, n):
     """Scatter `n` consecutive blocks of a [1, max_len] row cache
@@ -493,15 +421,19 @@ def _jitted_spec_chunk(cfg, dcfg, k, ng, rounds, paged, use_model):
             safe = jnp.where(keep, hpos, fz.max_len + kk)
             return hist.at[rows, safe].set(target, mode="drop")
 
-        if not use_model and not paged:
-            def chunk(params, cache, hist, tok, pos, keff):
+        # each provider's rounds are written once over (cache, tables),
+        # `tables` None for the dense rows (tf._verify_chunk_on /
+        # _decode_step_on pick the step); the dense programs keep their
+        # own signature, without the tables
+        if not use_model:
+            def ngram_rounds(params, cache, tables, hist, tok, pos, keff):
                 def body(carry, _):
                     cache, hist, tok, pos = carry
                     drafts = _ngram_propose(hist, tok, pos, keff, k, ng)
                     window = jnp.concatenate(
                         [tok[:, None], jnp.maximum(drafts, 0)], axis=1)
-                    logits, cache = tf.verify_chunk(
-                        params, cache, window, pos, fz)
+                    logits, cache = tf._verify_chunk_on(
+                        params, cache, tables, window, pos, fz)
                     target = jnp.argmax(logits, axis=-1) \
                         .astype(jnp.int32)
                     emit, tok = accept(drafts, target)
@@ -512,35 +444,25 @@ def _jitted_spec_chunk(cfg, dcfg, k, ng, rounds, paged, use_model):
                     jax.lax.scan(body, (cache, hist, tok, pos), None,
                                  length=rounds)
                 return targets, emits, cache, hist, tok, pos
-            donate = tf._serving_donate(1, 2, 3, 4)
-        elif not use_model:
-            def chunk(params, pool, tables, hist, tok, pos, keff):
-                def body(carry, _):
-                    pool, hist, tok, pos = carry
-                    drafts = _ngram_propose(hist, tok, pos, keff, k, ng)
-                    window = jnp.concatenate(
-                        [tok[:, None], jnp.maximum(drafts, 0)], axis=1)
-                    logits, pool = tf.verify_chunk_paged(
-                        params, pool, tables, window, pos, fz)
-                    target = jnp.argmax(logits, axis=-1) \
-                        .astype(jnp.int32)
-                    emit, tok = accept(drafts, target)
-                    hist = hist_update(hist, target, emit, pos)
-                    return (pool, hist, tok, pos + emit), \
-                        (target, emit)
-                (pool, hist, tok, pos), (targets, emits) = \
-                    jax.lax.scan(body, (pool, hist, tok, pos), None,
-                                 length=rounds)
-                return targets, emits, pool, hist, tok, pos
-            donate = tf._serving_donate(1, 3, 4, 5)
-        elif not paged:
-            def chunk(params, dparams, cache, dcache, tok, pos, keff):
+            if paged:
+                chunk = ngram_rounds
+                donate = tf._serving_donate(1, 3, 4, 5)
+            else:
+                def chunk(params, cache, hist, tok, pos, keff):
+                    return ngram_rounds(params, cache, None, hist, tok,
+                                        pos, keff)
+                donate = tf._serving_donate(1, 2, 3, 4)
+        else:
+            # with a draft model the draft pool SHARES the target's
+            # tables
+            def model_rounds(params, dparams, cache, dcache, tables, tok,
+                             pos, keff):
                 def body(carry, _):
                     cache, dcache, tok, pos = carry
                     def dstep(c, i):
                         dc, t = c
-                        dl, dc = tf.decode_step(dparams, dc, t,
-                                                pos + i, dcfg)
+                        dl, dc = tf._decode_step_on(
+                            dparams, dc, tables, t, pos + i, dcfg)
                         nxt = jnp.argmax(dl, axis=-1) \
                             .astype(jnp.int32)
                         return (dc, nxt), nxt
@@ -551,8 +473,8 @@ def _jitted_spec_chunk(cfg, dcfg, k, ng, rounds, paged, use_model):
                         seq.T, -1)
                     window = jnp.concatenate(
                         [tok[:, None], jnp.maximum(drafts, 0)], axis=1)
-                    logits, cache = tf.verify_chunk(
-                        params, cache, window, pos, fz)
+                    logits, cache = tf._verify_chunk_on(
+                        params, cache, tables, window, pos, fz)
                     target = jnp.argmax(logits, axis=-1) \
                         .astype(jnp.int32)
                     emit, tok = accept(drafts, target)
@@ -562,38 +484,14 @@ def _jitted_spec_chunk(cfg, dcfg, k, ng, rounds, paged, use_model):
                     jax.lax.scan(body, (cache, dcache, tok, pos), None,
                                  length=rounds)
                 return targets, emits, cache, dcache, tok, pos
-            donate = tf._serving_donate(2, 3, 4, 5)
-        else:
-            def chunk(params, dparams, pool, dpool, tables, tok, pos,
-                      keff):
-                def body(carry, _):
-                    pool, dpool, tok, pos = carry
-                    def dstep(c, i):
-                        dc, t = c
-                        dl, dc = tf.decode_step_paged(
-                            dparams, dc, tables, t, pos + i, dcfg)
-                        nxt = jnp.argmax(dl, axis=-1) \
-                            .astype(jnp.int32)
-                        return (dc, nxt), nxt
-                    (dpool, _), seq = jax.lax.scan(
-                        dstep, (dpool, tok), jnp.arange(k))
-                    drafts = jnp.where(
-                        jnp.arange(k)[None] < keff[:, None],
-                        seq.T, -1)
-                    window = jnp.concatenate(
-                        [tok[:, None], jnp.maximum(drafts, 0)], axis=1)
-                    logits, pool = tf.verify_chunk_paged(
-                        params, pool, tables, window, pos, fz)
-                    target = jnp.argmax(logits, axis=-1) \
-                        .astype(jnp.int32)
-                    emit, tok = accept(drafts, target)
-                    return (pool, dpool, tok, pos + emit), \
-                        (target, emit)
-                (pool, dpool, tok, pos), (targets, emits) = \
-                    jax.lax.scan(body, (pool, dpool, tok, pos), None,
-                                 length=rounds)
-                return targets, emits, pool, dpool, tok, pos
-            donate = tf._serving_donate(2, 3, 5, 6)
+            if paged:
+                chunk = model_rounds
+                donate = tf._serving_donate(2, 3, 5, 6)
+            else:
+                def chunk(params, dparams, cache, dcache, tok, pos, keff):
+                    return model_rounds(params, dparams, cache, dcache,
+                                        None, tok, pos, keff)
+                donate = tf._serving_donate(2, 3, 4, 5)
         return jax.jit(chunk, donate_argnums=donate)
 
     key = ("spec_chunk", k, ng, rounds, paged, use_model,
@@ -1134,12 +1032,8 @@ class ContinuousBatcher(object):
                     self.spec_ngram, self.chunk_size, self.paged,
                     self._spec_provider == "model")
             else:
-                self._pipe_fn = (
-                    _jitted_pipeline_chunk_paged(cfg, *self._controls,
-                                                 self.chunk_size)
-                    if self.paged else
-                    _jitted_pipeline_chunk(cfg, *self._controls,
-                                           self.chunk_size))
+                self._pipe_fn = _jitted_pipeline_chunk(
+                    cfg, *self._controls, self.chunk_size, self.paged)
             self._patch_fn = _jitted_lane_patch(cfg)
         if self._spec_on:
             # per-lane adaptive k: effective draft length (masked
@@ -2244,6 +2138,13 @@ class ContinuousBatcher(object):
                                 args={"rung": rung})
             _events.event("brownout", frm=prev, to=rung)
 
+    def _kv_args(self):
+        """The (cache, tables) pair every decode program takes: the
+        block pool behind its tables, or the dense rows and None."""
+        if self.paged:
+            return self._pool, self._tables
+        return self._cache, None
+
     def _register_dispatch(self, kind, fn, args):
         """Attribution over the serving jit boundary: register this
         dispatch executable (once per signature) so its named scopes —
@@ -2320,34 +2221,20 @@ class ContinuousBatcher(object):
                            lanes=self.active_count):
                 if _chaos.enabled():
                     _chaos.fire(self._chaos_site, mode="sync")
-                args = (self.params,)
-                if self.paged:
-                    args += (self._pool, self._tables)
-                else:
-                    args += (self._cache,)
-                args += (jnp.asarray(self._tok),
-                         jnp.asarray(self._pos),
-                         jnp.asarray(self._keys))
+                args = (self.params,) + self._kv_args() + (
+                    jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    jnp.asarray(self._keys))
                 if k == 1:
-                    fn = (_jitted_ragged_step_paged if self.paged
-                          else _jitted_ragged_step)(
-                        self.cfg, *self._controls)
-                    if _membudget.enabled():
-                        _membudget.preflight(self._chaos_site, fn,
-                                             args)
-                    if _attr.ops_enabled():
-                        self._register_dispatch("decode", fn, args)
-                    toks, keys, state = fn(*args)
+                    fn = _jitted_ragged_step(
+                        self.cfg, *self._controls, self.paged)
                 else:
-                    fn = (_jitted_ragged_chunk_paged if self.paged
-                          else _jitted_ragged_chunk)(
-                        self.cfg, *self._controls, k)
-                    if _membudget.enabled():
-                        _membudget.preflight(self._chaos_site, fn,
-                                             args)
-                    if _attr.ops_enabled():
-                        self._register_dispatch("decode", fn, args)
-                    toks, keys, state = fn(*args)
+                    fn = _jitted_ragged_chunk(
+                        self.cfg, *self._controls, k, self.paged)
+                if _membudget.enabled():
+                    _membudget.preflight(self._chaos_site, fn, args)
+                if _attr.ops_enabled():
+                    self._register_dispatch("decode", fn, args)
+                toks, keys, state = fn(*args)
                 with _obs.span("serving.sync", cat="serving",
                                mode="sync"):
                     toks = np.asarray(toks)
@@ -2477,26 +2364,19 @@ class ContinuousBatcher(object):
             if _chaos.enabled():
                 _chaos.fire(self._chaos_site, mode="pipelined",
                             depth=len(self._inflight) + 1)
+            args = (self.params,) + self._kv_args() + (
+                self._dev_tok, self._dev_pos, self._dev_keys)
+            if _membudget.enabled():
+                _membudget.preflight(self._chaos_site, self._pipe_fn,
+                                     args)
+            if self.paged and _attr.ops_enabled():
+                self._register_dispatch("pipeline", self._pipe_fn,
+                                        args)
+            toks, state, tables, tok, pos, keys = self._pipe_fn(*args)
             if self.paged:
-                args = (self.params, self._pool, self._tables,
-                        self._dev_tok, self._dev_pos, self._dev_keys)
-                if _membudget.enabled():
-                    _membudget.preflight(self._chaos_site,
-                                         self._pipe_fn, args)
-                if _attr.ops_enabled():
-                    self._register_dispatch("pipeline", self._pipe_fn,
-                                            args)
-                toks, pool, tables, tok, pos, keys = \
-                    self._pipe_fn(*args)
-                self._pool, self._tables = pool, tables
+                self._pool, self._tables = state, tables
             else:
-                args = (self.params, self._cache, self._dev_tok,
-                        self._dev_pos, self._dev_keys)
-                if _membudget.enabled():
-                    _membudget.preflight(self._chaos_site,
-                                         self._pipe_fn, args)
-                toks, cache, tok, pos, keys = self._pipe_fn(*args)
-                self._cache = cache
+                self._cache = state
         self._dispatch_failures = 0
         self.dispatch_count += 1
         if self.paged:

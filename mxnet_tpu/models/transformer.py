@@ -453,6 +453,50 @@ def _pp_size(cfg, mesh):
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(cfg.pp_axis, 1)
 
 
+def _layer(x, p, kind, cfg, mix, state=None):
+    """One transformer block, the residual frame every entry point
+    runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
+    [B, d] for decode's one row. `mix(kind, h, p, state)` is the
+    entry point's mixer (_mixer) and returns (y, the layer's new
+    state); returns (x, that state)."""
+    y, state = mix(kind, _rms_norm(x, p["ln1"]), p, state)
+    x = x + y
+    h = _rms_norm(x, p["ln2"])
+    if h.ndim == 2:
+        return x + _ffn(h[:, None], p, cfg)[:, 0], state
+    return x + _ffn(h, p, cfg), state
+
+
+def _mixer(cfg, attend, valid_len=None, from_zero=False):
+    """A layer's mix by its KIND, the one place a kind is decided.
+    "attention" is `attend(h, p, state)`, the entry point's form of it.
+    "mamba" keeps a recurrent state {"conv", "ssm"} where an attention
+    layer keeps K/V: the step form for decode's one row [B, d], the
+    sequence form for [B, C, d], which with `valid_len` stops after
+    that many rows (ssm.mixer_seq) and with `from_zero` starts from a
+    zero state whatever it was handed (training, and a prefill at
+    position 0)."""
+    def mix(kind, h, p, state):
+        if kind == "mamba":
+            if h.ndim == 2:
+                return ssm.mixer_step(h, p, state)
+            if from_zero:
+                state = _mamba_state(cfg, h.shape[0])
+            return ssm.mixer_seq(h, p, state, valid_len)
+        return attend(h, p, state)
+    return mix
+
+
+def _run_layers(x, params, state, cfg, mix):
+    """x through every layer, each with its own state; returns (x, the
+    new states in layer order)."""
+    new_state = []
+    for kind, p, layer in zip(_layer_kinds(cfg), params["layers"], state):
+        x, layer = _layer(x, p, kind, cfg, mix, layer)
+        new_state.append(layer)
+    return x, new_state
+
+
 def forward(params, tokens, cfg, mesh=None):
     """tokens [B, T] int32 -> logits [B, T, vocab]."""
     x = params["embed"][tokens]
@@ -464,16 +508,16 @@ def forward(params, tokens, cfg, mesh=None):
                           "attention, pipeline stages, tp)")
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, act))
     n_stages = _pp_size(cfg, mesh)
+    # ring attention runs manually over sp inside a pipeline stage
+    ring = n_stages > 1 and bool(cfg.use_ring_attention and cfg.sp_axis)
+    # self-attention over the fresh K/V: training keeps no state
+    mix = _mixer(cfg, lambda h, p, _: (
+        _attention(h, p, cfg, mesh, manual_sp=ring), None), from_zero=True)
     if n_stages > 1:
         # pipeline the homogeneous layer stack over pp: stage-major
-        # stacked weights, ppermute microbatch schedule; ring attention
-        # runs manually over sp inside each stage, tp/ep stay auto
-        ring = bool(cfg.use_ring_attention and cfg.sp_axis)
-
+        # stacked weights, ppermute microbatch schedule; tp/ep stay auto
         def layer_fn(p, xm):
-            xm = xm + _attention(_rms_norm(xm, p["ln1"]), p, cfg, mesh,
-                                 manual_sp=ring)
-            return xm + _ffn(_rms_norm(xm, p["ln2"]), p, cfg)
+            return _layer(xm, p, "attention", cfg, mix)[0]
 
         if cfg.remat_layers:
             layer_fn = jax.checkpoint(layer_fn)
@@ -486,13 +530,7 @@ def forward(params, tokens, cfg, mesh=None):
             else P())
     else:
         def layer_body(p, xl, kind):
-            h = _rms_norm(xl, p["ln1"])
-            if kind == "mamba":     # from a zero state
-                xl = xl + ssm.mixer_seq(
-                    h, p, _mamba_state(cfg, xl.shape[0]))[0]
-            else:
-                xl = xl + _attention(h, p, cfg, mesh)
-            xl = xl + _ffn(_rms_norm(xl, p["ln2"]), p, cfg)
+            xl = _layer(xl, p, kind, cfg, mix)[0]
             if mesh is not None:
                 xl = jax.lax.with_sharding_constraint(
                     xl, NamedSharding(mesh, act))
@@ -539,20 +577,26 @@ def init_cache(cfg, batch):
     state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}: batch
     first like the rows, so whatever moves a lane's rows (the batcher's
     lane write, beam search's re-gather) moves its state the same way."""
-    hd = cfg.d_model // cfg.n_heads
-    shape = (batch, cfg.max_len, _kvh(cfg), hd)
     if cfg.kv_cache_int8:
         _refuse_recurrent(cfg, "kv_cache_int8")
-        sshape = shape[:3]
-        return [{"k": jnp.zeros(shape, jnp.int8),
-                 "ks": jnp.zeros(sshape, jnp.float32),
-                 "v": jnp.zeros(shape, jnp.int8),
-                 "vs": jnp.zeros(sshape, jnp.float32)}
-                for _ in range(cfg.n_layers)]
     return [_mamba_state(cfg, batch) if kind == "mamba" else
-            {"k": jnp.zeros(shape, cfg.dtype),
-             "v": jnp.zeros(shape, cfg.dtype)}
+            _kv_leaves(cfg, batch, cfg.max_len)
             for kind in _layer_kinds(cfg)]
+
+
+def _kv_leaves(cfg, n, t):
+    """An attention layer's zeroed K/V leaves [n, t, KVH, D]: rows of a
+    dense cache (n lanes, t = max_len) or blocks of a paged pool (n
+    blocks of t positions). Under kv_cache_int8, int8 codes plus the
+    fp32 scale planes "ks"/"vs" [n, t, KVH]."""
+    shape = (n, t, _kvh(cfg), cfg.d_model // cfg.n_heads)
+    if cfg.kv_cache_int8:
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "ks": jnp.zeros(shape[:3], jnp.float32),
+                "v": jnp.zeros(shape, jnp.int8),
+                "vs": jnp.zeros(shape[:3], jnp.float32)}
+    return {"k": jnp.zeros(shape, cfg.dtype),
+            "v": jnp.zeros(shape, cfg.dtype)}
 
 
 def _kv_quant(x):
@@ -568,19 +612,63 @@ def _kv_dequant(q8, scale, dtype):
     return (q8.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def _cache_write_rows(layer_cache, k, v, start, cfg):
-    """Write fresh k/v [B, C, KVH, D] into cache positions
-    [start, start+C) — quantizing on the way in under kv_cache_int8."""
-    def upd(name, arr):
-        return jax.lax.dynamic_update_slice_in_dim(
-            layer_cache[name], arr.astype(layer_cache[name].dtype),
-            start, axis=1)
+def _kv_store(layer, k, v, cfg, put):
+    """The one store of fresh k/v into a layer's leaves, quantizing on
+    the way in under kv_cache_int8 (codes, and their scales into
+    "ks"/"vs"). `put(leaf, arr)` is the state kind's primitive: it
+    returns `leaf` with `arr` written where that kind puts it."""
+    fresh = {"k": k, "v": v}
     if cfg.kv_cache_int8:
         kq, ks = _kv_quant(k)
         vq, vs = _kv_quant(v)
-        return {"k": upd("k", kq), "ks": upd("ks", ks),
-                "v": upd("v", vq), "vs": upd("vs", vs)}
-    return {"k": upd("k", k), "v": upd("v", v)}
+        fresh = {"k": kq, "ks": ks, "v": vq, "vs": vs}
+    return {name: put(layer[name], arr.astype(layer[name].dtype))
+            for name, arr in fresh.items()}
+
+
+# What an attention layer keeps between calls is one of two KINDS of
+# state, chosen by what the caller holds (never by a flag): dense rows,
+# or paged blocks behind block tables (further down, with the pool).
+# Each kind is a (store, read) pair for one call's positions `where`
+# and its contraction: store(layer, k, v) writes the fresh k/v there,
+# and read(q, layer, k, v) hands `contract(q, view)` the stored layer
+# as [B, T, KVH, D], position-ordered, so every kind feeds the SAME
+# contractions (_decode_attention, _cached_attention). The third kind,
+# a Mamba layer's recurrent state, is _mixer's.
+
+def _dense_rows(cfg, where, contract):
+    """Dense rows: leaves {"k", "v"[, "ks", "vs"]} [B, Tmax, KVH, ...],
+    a lane a row. `where` is a scalar start (every lane's fresh
+    [B, C, KVH, D] lands on [start, start+C)), [B] (row i's one
+    [KVH, D] at where[i]) or [B, C] (row i's window at where[i, :])."""
+    def store(layer, k, v):
+        if jnp.ndim(where) == 0:
+            if k.ndim == 3:     # decode's one row is a C = 1 window
+                k, v = k[:, None], v[:, None]
+
+            def put(leaf, arr):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    leaf, arr, where, axis=1)
+        elif where.ndim == 1:
+            rows = jnp.arange(k.shape[0])
+
+            def put(leaf, arr):
+                return leaf.at[rows, where].set(arr)
+        else:
+            # out-of-bounds positions (a lane's window running past
+            # max_len) are DROPPED by the scatter rather than clamped,
+            # so a deep window can never corrupt an earlier,
+            # still-attendable cache row
+            rows = jnp.arange(k.shape[0])[:, None]
+
+            def put(leaf, arr):
+                return leaf.at[rows, where].set(arr, mode="drop")
+        return _kv_store(layer, k, v, cfg, put)
+
+    def read(q, layer, k, v):
+        return contract(q, layer)
+
+    return store, read
 
 
 def _int8_cache_attention(qg, layer_cache, mask, out_dtype):
@@ -616,20 +704,6 @@ def _cache_pspec(cfg, x):
     sequence replicated — truncated to the leaf's rank, because int8
     scale planes are [B, T, KVH] while code planes are rank 4."""
     return P(*P(cfg.dp_axis, None, cfg.tp_axis, None)[: x.ndim])
-
-
-def _cache_write_ragged(layer_cache, k_new, v_new, pos, cfg):
-    """Per-row scatter: row i writes its k/v [B, KVH, D] at pos[i]."""
-    rows = jnp.arange(k_new.shape[0])
-    def st(name, arr):
-        return layer_cache[name].at[rows, pos].set(
-            arr.astype(layer_cache[name].dtype))
-    if cfg.kv_cache_int8:
-        kq, ks = _kv_quant(k_new)
-        vq, vs = _kv_quant(v_new)
-        return {"k": st("k", kq), "ks": st("ks", ks),
-                "v": st("v", vq), "vs": st("vs", vs)}
-    return {"k": st("k", k_new), "v": st("v", v_new)}
 
 
 def quantize_weights_int8(params):
@@ -751,6 +825,63 @@ def _decode_attention_int8(q, layer_cache, pos, cfg):
     return o.reshape(b, h, d)
 
 
+def _cached_attention(q, view, positions, cfg, out_dtype):
+    """The one chunk contraction against cached K/V: q [B, C, H, D]
+    against a layer's view [B, T, KVH, D], chunk row i attending
+    positions t <= positions[i] ([C], one window for the whole batch)
+    or t <= positions[b, i] ([B, C], a window a lane), so stale entries
+    beyond the verified stream are never read. Grouped: the KVH-head
+    cache is read once per GROUP of query heads (like _decode_attention,
+    no materialized repeat on the hot path). Chunked prefill and both
+    verifiers read through THIS function, which is what keeps
+    pool == solo and verify == decode bit-identical."""
+    b, c, _, dh = q.shape
+    kvh = _kvh(cfg)
+    qg = q.reshape(b, c, kvh, cfg.n_heads // kvh, dh)
+    t_pos = jnp.arange(view["k"].shape[1])
+    if positions.ndim == 1:
+        mask = (t_pos[None, :] <= positions[:, None])[None]      # [1,C,T]
+    else:
+        mask = t_pos[None, None, :] <= positions[:, :, None]     # [B,C,T]
+    if cfg.kv_cache_int8:
+        return _int8_cache_attention(qg, view, mask, out_dtype) \
+            .reshape(b, c, cfg.n_heads, dh)
+    ck, cv = view["k"], view["v"]
+    s = jnp.einsum("bckgd,btkd->bckgt", qg, ck,
+                   preferred_element_type=jnp.float32) / np.sqrt(dh)
+    s = jnp.where(mask[:, :, None, None, :], s, -1e30)
+    a = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bckgt,btkd->bckgd", a.astype(cv.dtype), cv,
+                      preferred_element_type=jnp.float32
+                      ).astype(out_dtype).reshape(b, c, cfg.n_heads, dh)
+
+
+def _cache_attend(cfg, where, store, read):
+    """_mixer's `attend` for the entry points that keep K/V: project,
+    rotate by the positions `where`, `store(layer, k, v)` the fresh
+    k/v, then `read(q, layer, k, v)`: attention over the stored layer
+    (or, for a prefill at position 0, over the fresh k/v themselves).
+    h is [B, C, d], or decode's one row [B, d]."""
+    def attend(h, p, layer):
+        if h.ndim == 2:
+            q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
+            k = jnp.einsum("bd,dhk->bhk", h, p["wk"])
+            v = jnp.einsum("bd,dhk->bhk", h, p["wv"])
+        else:
+            q, k, v = _qkv(h, p)
+        if cfg.rope:
+            # keys are cached ROTATED: their rotation depends only on
+            # their own position, so decode never re-rotates the cache
+            q = _rope(q, where, cfg.rope_base)
+            k = _rope(k, where, cfg.rope_base)
+        layer = store(layer, k, v)
+        o = read(q, layer, k, v)
+        if h.ndim == 2:
+            return jnp.einsum("bhk,hkd->bd", o, p["wo"]), layer
+        return jnp.einsum("bchk,hkd->bcd", o, p["wo"]), layer
+    return attend
+
+
 def prefill(params, cache, tokens, cfg):
     """Process the whole prompt in ONE forward pass, filling the KV
     cache for positions [0, Tp) — the serving-side complement of the
@@ -768,34 +899,23 @@ def prefill(params, cache, tokens, cfg):
                              logits_row=jnp.int32(tokens.shape[1] - 1),
                              attend_limit=int(tokens.shape[1]))
     params = _maybe_dequantize(params)
-    b, t_p = tokens.shape
+    t_p = tokens.shape[1]
     x = params["embed"][tokens]
     if _learned_pos(cfg):
         x = x + params["pos"][:t_p]
-    new_cache = []
-    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
-                                    cache):
-        h = _rms_norm(x, p["ln1"])
-        if kind == "mamba":
-            # position 0: whatever state the cache held is dropped
-            y, state = ssm.mixer_seq(h, p, _mamba_state(cfg, b))
-            new_cache.append(state)
-            x = x + y
-            x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
-            continue
-        q, k, v = _qkv(h, p)
-        if cfg.rope:
-            # keys are cached ROTATED: their rotation depends only on
-            # their own position, so decode never re-rotates the cache
-            positions = jnp.arange(t_p)
-            q = _rope(q, positions, cfg.rope_base)
-            k = _rope(k, positions, cfg.rope_base)
-        new_cache.append(_cache_write_rows(layer_cache, k, v, 0, cfg))
-        g = cfg.n_heads // _kvh(cfg)
-        o = _causal_attention(q, _repeat_kv(k, g), _repeat_kv(v, g),
-                              cfg, x.dtype)
-        x = x + jnp.einsum("bthk,hkd->btd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
+    g = cfg.n_heads // _kvh(cfg)
+    store, _ = _dense_rows(cfg, 0, None)
+
+    def read(q, layer, k, v):
+        # self-attention over the fresh K/V: at position 0 the rows
+        # just stored are all there is to read
+        return _causal_attention(q, _repeat_kv(k, g), _repeat_kv(v, g),
+                                 cfg, q.dtype)
+
+    # position 0: whatever recurrent state the cache held is dropped
+    mix = _mixer(cfg, _cache_attend(cfg, jnp.arange(t_p), store, read),
+                 from_zero=True)
+    x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x[:, -1], params["ln_f"])
     return jnp.einsum("bd,vd->bv", x, params["embed"]), new_cache
 
@@ -893,7 +1013,7 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
     and leave no trace (ssm.mixer_seq's valid_len). Without
     `logits_row` every row of the chunk is real."""
     params = _maybe_dequantize(params)
-    b, c = tokens.shape
+    c = tokens.shape[1]
     try:
         concrete_end = int(start) + c      # eager path only; traced
     except Exception:                      # starts check inside jit is
@@ -904,54 +1024,20 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
             "would clamp and corrupt earlier cache positions)"
             % (concrete_end - c, concrete_end, cfg.max_len))
     x = params["embed"][tokens]
-    if cfg.rope:
-        chunk_pos = start + jnp.arange(c)
-    elif _learned_pos(cfg):
+    if _learned_pos(cfg):
         x = x + jax.lax.dynamic_slice_in_dim(params["pos"], start, c, 0)
-    new_cache = []
-    g = cfg.n_heads // _kvh(cfg)
-    valid_len = None if logits_row is None else logits_row + 1
-    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
-                                    cache):
-        h = _rms_norm(x, p["ln1"])
-        if kind == "mamba":
-            y, state = ssm.mixer_seq(h, p, layer_cache, valid_len)
-            new_cache.append(state)
-            x = x + y
-            x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
-            continue
-        q, k, v = _qkv(h, p)
-        if cfg.rope:
-            q = _rope(q, chunk_pos, cfg.rope_base)
-            k = _rope(k, chunk_pos, cfg.rope_base)
-        nlayer = _cache_write_rows(layer_cache, k, v, start, cfg)
-        new_cache.append(nlayer)
-        # chunk row i sees cache positions <= start+i; grouped
-        # contraction reads the KVH-head cache once per GROUP (like
-        # _decode_attention — no materialized repeat on the hot path)
-        dh = q.shape[-1]
-        qg = q.reshape(b, c, _kvh(cfg), g, dh)
-        att = nlayer if attend_limit is None else \
-            {name: arr[:, :attend_limit] for name, arr in nlayer.items()}
-        t_pos = jnp.arange(att["k"].shape[1])
-        mask = (t_pos[None, :]
-                <= (start + jnp.arange(c))[:, None])[None]   # [1,C,T]
-        if cfg.kv_cache_int8:
-            o = _int8_cache_attention(qg, att, mask, x.dtype) \
-                .reshape(b, c, cfg.n_heads, dh)
-        else:
-            ck, cv = att["k"], att["v"]
-            s = jnp.einsum("bckgd,btkd->bckgt", qg, ck,
-                           preferred_element_type=jnp.float32
-                           ) / np.sqrt(dh)
-            s = jnp.where(mask[:, :, None, None, :], s, -1e30)
-            a = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bckgt,btkd->bckgd", a.astype(cv.dtype), cv,
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype).reshape(b, c,
-                                                     cfg.n_heads, dh)
-        x = x + jnp.einsum("bchk,hkd->bcd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
+    positions = start + jnp.arange(c)      # chunk row i sits at start+i
+
+    def contract(q, view):
+        if attend_limit is not None:
+            view = {name: arr[:, :attend_limit]
+                    for name, arr in view.items()}
+        return _cached_attention(q, view, positions, cfg, q.dtype)
+
+    mix = _mixer(cfg, _cache_attend(
+        cfg, positions, *_dense_rows(cfg, start, contract)),
+        valid_len=None if logits_row is None else logits_row + 1)
+    x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x, params["ln_f"])
     if logits_row is not None:
         xr = jax.lax.dynamic_index_in_dim(x, logits_row, 1,
@@ -1105,45 +1191,27 @@ def decode_step(params, cache, tokens, pos, cfg):
     program decodes every position. Accepts quantize_weights_int8
     trees: the dequantizing converts fuse into each weight's matmul.
     """
+    return _decode(params, cache, None, tokens, pos, cfg)
+
+
+def _decode(params, state, tables, tokens, pos, cfg):
+    """decode_step on either kind of K/V state: dense rows (`tables`
+    None; pos a scalar or [B]) or a block pool behind `tables` (pos
+    [B]). Both read through _decode_attention, the T_q = 1 row form."""
     params = _maybe_dequantize(params)
-    ragged = jnp.ndim(pos) == 1        # trace-time branch: [B] vs scalar
     x = params["embed"][tokens]
     if _learned_pos(cfg):
-        if ragged:
+        if jnp.ndim(pos) == 1:     # trace-time branch: [B] vs scalar
             x = x + jnp.take(params["pos"], pos, axis=0)
         else:
             x = x + jax.lax.dynamic_index_in_dim(
                 params["pos"], pos, 0, keepdims=False)
-    b = x.shape[0]
-    new_cache = []
-    for kind, p, layer_cache in zip(_layer_kinds(cfg), params["layers"],
-                                    cache):
-        h = _rms_norm(x, p["ln1"])
-        if kind == "mamba":
-            y, state = ssm.mixer_step(h, p, layer_cache)
-            new_cache.append(state)
-            x = x + y
-            x = x + _ffn(_rms_norm(x, p["ln2"])[:, None], p, cfg)[:, 0]
-            continue
-        q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
-        k_new = jnp.einsum("bd,dhk->bhk", h, p["wk"])
-        v_new = jnp.einsum("bd,dhk->bhk", h, p["wv"])
-        if cfg.rope:
-            q = _rope(q, pos, cfg.rope_base)
-            k_new = _rope(k_new, pos, cfg.rope_base)
-        if ragged:
-            # per-row scatter: row i writes its K/V at its own pos[i]
-            nlayer = _cache_write_ragged(layer_cache, k_new, v_new,
-                                         pos, cfg)
-        else:
-            nlayer = _cache_write_rows(layer_cache, k_new[:, None],
-                                       v_new[:, None], pos, cfg)
-        new_cache.append(nlayer)
-        o = _decode_attention(q, nlayer, pos, cfg)
-        x = x + jnp.einsum("bhk,hkd->bd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"])[:, None], p, cfg)[:, 0]
+    mix = _mixer(cfg, _cache_attend(cfg, pos, *_kv_state(
+        cfg, tables, pos,
+        lambda q, view: _decode_attention(q, view, pos, cfg))))
+    x, new_state = _run_layers(x, params, state, cfg, mix)
     x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bd,vd->bv", x, params["embed"]), new_cache
+    return jnp.einsum("bd,vd->bv", x, params["embed"]), new_state
 
 
 # ------------------------------------------------------- paged decode ---
@@ -1175,17 +1243,7 @@ def init_paged_cache(cfg, num_blocks, block_size):
     _refuse_recurrent(cfg, "the paged KV pool (init_paged_cache)")
     if num_blocks < 2:
         raise ValueError("need >= 2 blocks (block 0 is the null block)")
-    hd = cfg.d_model // cfg.n_heads
-    shape = (num_blocks, block_size, _kvh(cfg), hd)
-    if cfg.kv_cache_int8:
-        sshape = shape[:3]
-        return [{"k": jnp.zeros(shape, jnp.int8),
-                 "ks": jnp.zeros(sshape, jnp.float32),
-                 "v": jnp.zeros(shape, jnp.int8),
-                 "vs": jnp.zeros(sshape, jnp.float32)}
-                for _ in range(cfg.n_layers)]
-    return [{"k": jnp.zeros(shape, cfg.dtype),
-             "v": jnp.zeros(shape, cfg.dtype)}
+    return [_kv_leaves(cfg, num_blocks, block_size)
             for _ in range(cfg.n_layers)]
 
 
@@ -1234,28 +1292,71 @@ def _paged_gather(layer_pool, tables):
     return {name: g(leaf) for name, leaf in layer_pool.items()}
 
 
-def _paged_write_ragged(layer_pool, k_new, v_new, tables, pos, cfg):
-    """Per-row scatter through the table: row i writes its k/v
-    [B, KVH, D] into block tables[i, pos[i]//bs] at offset pos[i]%bs —
-    quantizing on the way in under kv_cache_int8, like the dense
-    ragged write. A position past the table (a retired lane coasting
-    to its chunk boundary) clamps to the last entry, which the
-    allocator guarantees is never a shared block; an unallocated entry
-    is the null block. Either way the garbage is unreadable."""
-    bs = layer_pool["k"].shape[1]
-    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    off = pos % bs
+def _paged_blocks(cfg, tables, where, contract):
+    """Paged blocks: the dense rows' leaves as pools
+    [num_blocks, block_size, KVH, ...], reached through `tables`
+    [B, max_len // bs]. `where` is [B] (row i's one k/v at where[i]) or
+    [B, C] (row i's window at where[i, :]); a position goes to block
+    tables[i, position // bs] at offset position % bs."""
+    def store(layer, k, v):
+        bs = layer["k"].shape[1]
+        if where.ndim == 1:
+            # a position past the table (a retired lane coasting to its
+            # chunk boundary) clamps to the last entry, which the
+            # allocator guarantees is never a shared block; an
+            # unallocated entry is the null block. Either way the
+            # garbage is unreadable
+            blk = jnp.take_along_axis(tables, (where // bs)[:, None],
+                                      axis=1)[:, 0]
+        else:
+            # positions past the TABLE (beyond max_len) are routed to
+            # the null block: clamping to the last entry is not safe
+            # for a window, because a near-budget lane's window can
+            # overrun while the lane is still live and its last block
+            # still attendable. Unallocated entries are the null block
+            # as usual
+            nb = tables.shape[1]
+            blk_idx = where // bs                                # [B, C]
+            blk = jnp.take_along_axis(
+                tables, jnp.clip(blk_idx, 0, nb - 1), axis=1)
+            blk = jnp.where(blk_idx < nb, blk, 0)
+        off = where % bs
 
-    def st(name, arr):
-        return layer_pool[name].at[blk, off].set(
-            arr.astype(layer_pool[name].dtype))
+        def put(leaf, arr):
+            return leaf.at[blk, off].set(arr)
 
-    if cfg.kv_cache_int8:
-        kq, ks = _kv_quant(k_new)
-        vq, vs = _kv_quant(v_new)
-        return {"k": st("k", kq), "ks": st("ks", ks),
-                "v": st("v", vq), "vs": st("vs", vs)}
-    return {"k": st("k", k_new), "v": st("v", v_new)}
+        return _kv_store(layer, k, v, cfg, put)
+
+    def read(q, layer, k, v):
+        if not _paged_pallas_requested():
+            # the gathered view carries bit-identical values at every
+            # unmasked position: paged == dense == solo stays exact
+            return contract(q, _paged_gather(layer, tables))
+        # batched-lane megakernel: reads the pool THROUGH the tables
+        # (no dense gather copy), skips dead blocks per lane; a ragged
+        # [B, k+1] spec-verify window is just the k>1 case of the
+        # decode grid. The batcher's membudget preflight already covers
+        # this jit boundary (it preflights every dispatch fn), and the
+        # scope makes its bytes attributable via hlo/attribution.
+        from ..kernels import paged_attention
+        from ..observability import attribution as _obs_attr
+        row = q.ndim == 3          # decode's one row is a span of 1
+        scope = "paged_decode_kernel" if row else "paged_verify_kernel"
+        _obs_attr.note_scope(scope)
+        with jax.named_scope(scope):
+            o = paged_attention(q[:, None] if row else q, layer, tables,
+                                where if row else where[:, 0])
+        return o[:, 0] if row else o
+
+    return store, read
+
+
+def _kv_state(cfg, tables, where, contract):
+    """The (store, read) pair of the K/V state the caller holds: dense
+    rows, or with `tables` the block pool behind them."""
+    if tables is None:
+        return _dense_rows(cfg, where, contract)
+    return _paged_blocks(cfg, tables, where, contract)
 
 
 def decode_step_paged(params, pool, tables, tokens, pos, cfg):
@@ -1271,70 +1372,23 @@ def decode_step_paged(params, pool, tables, tokens, pos, cfg):
     scales gathered together, the one shared _int8_cache_attention
     does the rest), quantized weight trees."""
     _refuse_recurrent(cfg, "paged decode (decode_step_paged)")
-    params = _maybe_dequantize(params)
-    x = params["embed"][tokens]
-    if _learned_pos(cfg):
-        x = x + jnp.take(params["pos"], pos, axis=0)
-    new_pool = []
-    for p, layer_pool in zip(params["layers"], pool):
-        h = _rms_norm(x, p["ln1"])
-        q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
-        k_new = jnp.einsum("bd,dhk->bhk", h, p["wk"])
-        v_new = jnp.einsum("bd,dhk->bhk", h, p["wv"])
-        if cfg.rope:
-            q = _rope(q, pos, cfg.rope_base)
-            k_new = _rope(k_new, pos, cfg.rope_base)
-        nlayer = _paged_write_ragged(layer_pool, k_new, v_new, tables,
-                                     pos, cfg)
-        new_pool.append(nlayer)
-        if _paged_pallas_requested():
-            # batched-lane megakernel: reads the pool THROUGH the
-            # tables (no dense gather copy), skips dead blocks per
-            # lane. The batcher's membudget preflight already covers
-            # this jit boundary (it preflights every dispatch fn), and
-            # the scope makes its bytes attributable via hlo/attribution.
-            from ..kernels import paged_attention
-            from ..observability import attribution as _obs_attr
-            _obs_attr.note_scope("paged_decode_kernel")
-            with jax.named_scope("paged_decode_kernel"):
-                o = paged_attention(q[:, None], nlayer, tables,
-                                    pos)[:, 0]
-        else:
-            o = _decode_attention(q, _paged_gather(nlayer, tables),
-                                  pos, cfg)
-        x = x + jnp.einsum("bhk,hkd->bd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"])[:, None], p, cfg)[:, 0]
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bd,vd->bv", x, params["embed"]), new_pool
+    return _decode(params, pool, tables, tokens, pos, cfg)
+
+
+def _decode_step_on(params, state, tables, tokens, pos, cfg):
+    """decode_step on whichever K/V state a scheduler holds, through
+    that kind's own door: `tables` None is the dense cache."""
+    if tables is None:
+        return decode_step(params, state, tokens, pos, cfg)
+    return decode_step_paged(params, state, tables, tokens, pos, cfg)
 
 
 # ------------------------------------------------------ batched verify ---
 # The ragged-chunk forward that batched speculative decoding needs: C
 # tokens per lane, each lane's window anchored at its OWN position. Both
-# variants share the attention contractions with prefill_chunk (dense /
-# _int8_cache_attention), which is what keeps batched verify bit-exact
+# variants share the attention contraction with prefill_chunk
+# (_cached_attention), which is what keeps batched verify bit-exact
 # with the stepped decode it replaces.
-
-def _cache_write_ragged_chunk(layer_cache, k_new, v_new, positions, cfg):
-    """Per-row WINDOW scatter: row b writes its C fresh k/v
-    [B, C, KVH, D] at its own positions[b, :] — the C>1 generalization
-    of _cache_write_ragged. Out-of-bounds positions (a lane's window
-    running past max_len) are DROPPED by the scatter rather than
-    clamped, so a deep window can never corrupt an earlier,
-    still-attendable cache row."""
-    rows = jnp.arange(k_new.shape[0])[:, None]
-
-    def st(name, arr):
-        return layer_cache[name].at[rows, positions].set(
-            arr.astype(layer_cache[name].dtype), mode="drop")
-
-    if cfg.kv_cache_int8:
-        kq, ks = _kv_quant(k_new)
-        vq, vs = _kv_quant(v_new)
-        return {"k": st("k", kq), "ks": st("ks", ks),
-                "v": st("v", vq), "vs": st("vs", vs)}
-    return {"k": st("k", k_new), "v": st("v", v_new)}
-
 
 def verify_chunk(params, cache, tokens, pos, cfg):
     """Process a RAGGED chunk: C tokens PER LANE, lane b's window
@@ -1355,142 +1409,50 @@ def verify_chunk(params, cache, tokens, pos, cfg):
     clamping. Returns (logits [B, C, vocab], cache)."""
     _refuse_recurrent(cfg, "speculative verification (verify_chunk: a "
                       "rejected draft cannot be rolled back)")
+    return _verify(params, cache, None, tokens, pos, cfg)
+
+
+def _verify(params, state, tables, tokens, pos, cfg):
+    """verify_chunk on either kind of K/V state: dense rows (`tables`
+    None) or a block pool behind `tables`."""
     params = _maybe_dequantize(params)
-    b, c = tokens.shape
+    c = tokens.shape[1]
     positions = pos[:, None] + jnp.arange(c)[None, :]        # [B, C]
     x = params["embed"][tokens]
     if _learned_pos(cfg):
         # take() clamps OOB rows — their logits are garbage, but their
         # writes drop and their emissions are never credited
         x = x + jnp.take(params["pos"], positions, axis=0)
-    new_cache = []
-    g = cfg.n_heads // _kvh(cfg)
-    for p, layer_cache in zip(params["layers"], cache):
-        h = _rms_norm(x, p["ln1"])
-        q, k, v = _qkv(h, p)
-        if cfg.rope:
-            q = _rope(q, positions, cfg.rope_base)
-            k = _rope(k, positions, cfg.rope_base)
-        nlayer = _cache_write_ragged_chunk(layer_cache, k, v,
-                                           positions, cfg)
-        new_cache.append(nlayer)
-        dh = q.shape[-1]
-        qg = q.reshape(b, c, _kvh(cfg), g, dh)
-        t_pos = jnp.arange(nlayer["k"].shape[1])
-        mask = t_pos[None, None, :] <= positions[:, :, None]  # [B,C,T]
-        if cfg.kv_cache_int8:
-            o = _int8_cache_attention(qg, nlayer, mask, x.dtype) \
-                .reshape(b, c, cfg.n_heads, dh)
-        else:
-            ck, cv = nlayer["k"], nlayer["v"]
-            s = jnp.einsum("bckgd,btkd->bckgt", qg, ck,
-                           preferred_element_type=jnp.float32
-                           ) / np.sqrt(dh)
-            s = jnp.where(mask[:, :, None, None, :], s, -1e30)
-            a = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bckgt,btkd->bckgd", a.astype(cv.dtype), cv,
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype).reshape(b, c,
-                                                     cfg.n_heads, dh)
-        x = x + jnp.einsum("bchk,hkd->bcd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
+    mix = _mixer(cfg, _cache_attend(cfg, positions, *_kv_state(
+        cfg, tables, positions,
+        lambda q, view: _cached_attention(q, view, positions, cfg,
+                                          q.dtype))))
+    x, new_state = _run_layers(x, params, state, cfg, mix)
     x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bcd,vd->bcv", x, params["embed"]), new_cache
-
-
-def _paged_write_ragged_chunk(layer_pool, k_new, v_new, tables,
-                              positions, cfg):
-    """Window scatter through the block tables: row b writes its C
-    fresh k/v at positions[b, :], each position routed to block
-    tables[b, position//bs] at offset position%bs. Positions past the
-    TABLE (beyond max_len) are routed to the null block — unlike the
-    single-position _paged_write_ragged, clamping to the last entry is
-    not safe here, because a near-budget lane's window can overrun
-    while the lane is still live and its last block still attendable.
-    Unallocated entries are the null block as usual."""
-    bs = layer_pool["k"].shape[1]
-    nb = tables.shape[1]
-    blk_idx = positions // bs                                # [B, C]
-    blk = jnp.take_along_axis(tables, jnp.clip(blk_idx, 0, nb - 1),
-                              axis=1)
-    blk = jnp.where(blk_idx < nb, blk, 0)
-    off = positions % bs
-
-    def st(name, arr):
-        return layer_pool[name].at[blk, off].set(
-            arr.astype(layer_pool[name].dtype))
-
-    if cfg.kv_cache_int8:
-        kq, ks = _kv_quant(k_new)
-        vq, vs = _kv_quant(v_new)
-        return {"k": st("k", kq), "ks": st("ks", ks),
-                "v": st("v", vq), "vs": st("vs", vs)}
-    return {"k": st("k", k_new), "v": st("v", v_new)}
+    return jnp.einsum("bcd,vd->bcv", x, params["embed"]), new_state
 
 
 def verify_chunk_paged(params, pool, tables, tokens, pos, cfg):
     """verify_chunk through the block tables: same ragged-window
-    semantics, writes scattered into the pool
-    (_paged_write_ragged_chunk), reads through the gathered dense view
-    (_paged_gather) into the SAME attention contraction as the dense
-    verify — bit-identical values at every unmasked position, so
-    paged == dense == solo stays exact under speculation. Tables are
-    read-only here; allocation (including the speculative over-reserve
-    and release-on-reject) is the host scheduler's job.
+    semantics, writes scattered into the pool (_paged_blocks), reads
+    through the gathered dense view (_paged_gather) into the SAME
+    attention contraction as the dense verify — bit-identical values
+    at every unmasked position, so paged == dense == solo stays exact
+    under speculation. Tables are read-only here; allocation
+    (including the speculative over-reserve and release-on-reject) is
+    the host scheduler's job.
     Returns (logits [B, C, vocab], pool)."""
     _refuse_recurrent(cfg, "paged speculative verification "
                       "(verify_chunk_paged)")
-    params = _maybe_dequantize(params)
-    b, c = tokens.shape
-    positions = pos[:, None] + jnp.arange(c)[None, :]        # [B, C]
-    x = params["embed"][tokens]
-    if _learned_pos(cfg):
-        x = x + jnp.take(params["pos"], positions, axis=0)
-    new_pool = []
-    g = cfg.n_heads // _kvh(cfg)
-    for p, layer_pool in zip(params["layers"], pool):
-        h = _rms_norm(x, p["ln1"])
-        q, k, v = _qkv(h, p)
-        if cfg.rope:
-            q = _rope(q, positions, cfg.rope_base)
-            k = _rope(k, positions, cfg.rope_base)
-        nlayer = _paged_write_ragged_chunk(layer_pool, k, v, tables,
-                                           positions, cfg)
-        new_pool.append(nlayer)
-        dh = q.shape[-1]
-        if _paged_pallas_requested():
-            # same megakernel, span=C: the ragged [B, k+1] spec-verify
-            # window is just the k>1 case of the decode grid
-            from ..kernels import paged_attention
-            from ..observability import attribution as _obs_attr
-            _obs_attr.note_scope("paged_verify_kernel")
-            with jax.named_scope("paged_verify_kernel"):
-                o = paged_attention(q, nlayer, tables, pos)
-            x = x + jnp.einsum("bchk,hkd->bcd", o, p["wo"])
-            x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
-            continue
-        qg = q.reshape(b, c, _kvh(cfg), g, dh)
-        att = _paged_gather(nlayer, tables)
-        t_pos = jnp.arange(att["k"].shape[1])
-        mask = t_pos[None, None, :] <= positions[:, :, None]  # [B,C,T]
-        if cfg.kv_cache_int8:
-            o = _int8_cache_attention(qg, att, mask, x.dtype) \
-                .reshape(b, c, cfg.n_heads, dh)
-        else:
-            ck, cv = att["k"], att["v"]
-            s = jnp.einsum("bckgd,btkd->bckgt", qg, ck,
-                           preferred_element_type=jnp.float32
-                           ) / np.sqrt(dh)
-            s = jnp.where(mask[:, :, None, None, :], s, -1e30)
-            a = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bckgt,btkd->bckgd", a.astype(cv.dtype), cv,
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype).reshape(b, c,
-                                                     cfg.n_heads, dh)
-        x = x + jnp.einsum("bchk,hkd->bcd", o, p["wo"])
-        x = x + _ffn(_rms_norm(x, p["ln2"]), p, cfg)
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bcd,vd->bcv", x, params["embed"]), new_pool
+    return _verify(params, pool, tables, tokens, pos, cfg)
+
+
+def _verify_chunk_on(params, state, tables, tokens, pos, cfg):
+    """verify_chunk on whichever K/V state a scheduler holds, through
+    that kind's own door: `tables` None is the dense cache."""
+    if tables is None:
+        return verify_chunk(params, state, tokens, pos, cfg)
+    return verify_chunk_paged(params, state, tables, tokens, pos, cfg)
 
 
 def make_decode_step(cfg):

@@ -179,6 +179,88 @@ def test_prefill_delegates_to_chunk_exactly_int8():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _filled_cache(cfg, batch, seed):
+    """A dense cache with something at every position (int8: codes and
+    positive scales), as a stream that ran for a while leaves it."""
+    rng = np.random.RandomState(seed)
+
+    def fill(x):
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.randint(-127, 128, x.shape), jnp.int8)
+        return jnp.asarray(np.abs(rng.randn(*x.shape)) * 0.1 + 0.01,
+                           x.dtype)
+    return jax.tree.map(fill, tf.init_cache(cfg, batch))
+
+
+def _same(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_verify_window_of_one_is_the_ragged_decode_step(int8):
+    """verify_chunk with C = 1 and decode_step at ragged positions are
+    the same layer body over the same store and the same masked read.
+    Through the int8 cache both contract in _int8_cache_attention and
+    agree bit for bit; a float cache is read by two forms of one
+    contraction (_cached_attention's chunk form, _decode_attention's
+    row form), which XLA may sum in another order: a few ulps."""
+    cfg = _cfg(int8, n_kv_heads=2)
+    params = tf.init_params(cfg, seed=31)
+    cache = _filled_cache(cfg, 3, 1)
+    tok = jnp.asarray([5, 17, 90], jnp.int32)
+    pos = jnp.asarray([4, 20, 0], jnp.int32)
+    ld, cd = jax.jit(lambda p, c, t, q: tf.decode_step(p, c, t, q, cfg))(
+        params, cache, tok, pos)
+    lv, cv = jax.jit(lambda p, c, t, q: tf.verify_chunk(p, c, t, q, cfg))(
+        params, cache, tok[:, None], pos)
+    if int8:
+        _same(lv[:, 0], ld)
+        _same(cv, cd)
+        return
+    np.testing.assert_allclose(np.asarray(lv[:, 0]), np.asarray(ld),
+                               rtol=0, atol=2e-6)
+    for x, y in zip(jax.tree.leaves(cv), jax.tree.leaves(cd)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("door", ["decode", "verify"])
+def test_paged_doors_equal_their_dense_forms_on_a_gathered_pool(door,
+                                                                int8):
+    """A pool whose gathered view IS the dense cache: the paged step
+    and the paged verifier give the dense ones' logits bit for bit, and
+    the pool they leave gathers to the cache the dense ones leave,
+    whether the cache holds floats or int8 codes and scales."""
+    cfg = _cfg(int8, n_kv_heads=2, rope=True)
+    params = tf.init_params(cfg, seed=37)
+    b, bs = 3, 8
+    nb = cfg.max_len // bs
+    cache = _filled_cache(cfg, b, 2)
+    tables = jnp.asarray(1 + np.arange(b * nb).reshape(b, nb), jnp.int32)
+
+    def blocks(leaf):       # lane i's rows become blocks 1+i*nb ...
+        body = leaf.reshape((b * nb, bs) + leaf.shape[2:])
+        return jnp.concatenate([jnp.zeros_like(body[:1]), body])
+    pool = jax.tree.map(blocks, cache)
+    _same([tf._paged_gather(layer, tables) for layer in pool], cache)
+    pos = jnp.asarray([4, 20, 0], jnp.int32)
+    if door == "decode":
+        tok = jnp.asarray([5, 17, 90], jnp.int32)
+        dense, paged = tf.decode_step, tf.decode_step_paged
+    else:
+        tok = jnp.asarray(
+            np.random.RandomState(5).randint(1, 97, (b, 4)), jnp.int32)
+        dense, paged = tf.verify_chunk, tf.verify_chunk_paged
+    ld, cd = jax.jit(lambda p, c, t, q: dense(p, c, t, q, cfg))(
+        params, cache, tok, pos)
+    lp, pp = jax.jit(lambda p, c, tb, t, q: paged(p, c, tb, t, q, cfg))(
+        params, pool, tables, tok, pos)
+    _same(lp, ld)
+    _same([tf._paged_gather(layer, tables) for layer in pp], cd)
+
+
 def test_beam_search_int8_on_mesh():
     """Beam search's traced cache sharding handles the rank-3 scale
     planes (rank-sliced spec, like shard_cache)."""

@@ -12,8 +12,10 @@ returned at refcount zero) are asserted directly.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from mxnet_tpu.models import serving
 from mxnet_tpu.models import transformer as tf
 from mxnet_tpu.models.serving import BlockAllocator, ContinuousBatcher
 from mxnet_tpu.observability import chaos
@@ -279,6 +281,60 @@ def test_paged_int8_kv_matches_dense_int8():
                 / np.max(np.abs(np.asarray(lfp))))
     assert rel < 0.02, "int8-paged logits drifted %.3f%% from fp" \
         % (100 * rel)
+
+
+@pytest.mark.parametrize("controls", [
+    (True, 1.0, None, None), (False, 0.8, 5, 0.9)],
+    ids=["greedy", "sampled"])
+@pytest.mark.parametrize("build,extra,cache_at,tables_at", [
+    (serving._jitted_ragged_step, (), 2, None),
+    (serving._jitted_ragged_chunk, (3,), 2, None),
+    (serving._jitted_pipeline_chunk, (3,), 1, 2)],
+    ids=["step", "chunk", "pipeline"])
+def test_one_decode_program_serves_both_caches(build, extra, cache_at,
+                                               tables_at, controls):
+    """Each decode program is written once over (cache, tables): built
+    dense (tables None) and paged it is two entries of _serving_jit,
+    never one wrapper, and on a pool that gathers to the dense cache
+    the two emit the same tokens, advance the same keys and leave the
+    same K/V."""
+    cfg = _cfg()
+    params = tf.init_params(cfg, seed=3)
+    dense = build(cfg, *controls, *extra, False)
+    paged = build(cfg, *controls, *extra, True)
+    assert dense is not paged
+    assert build(cfg, *controls, *extra, False) is dense
+    assert build(cfg, *controls, *extra, True) is paged
+    b, bs = 3, 8
+    nb = cfg.max_len // bs
+    rng = np.random.RandomState(4)
+    cache = jax.tree.map(
+        lambda x: jnp.asarray(rng.randn(*x.shape) * 0.1, x.dtype),
+        tf.init_cache(cfg, b))
+    tables = jnp.asarray(1 + np.arange(b * nb).reshape(b, nb), jnp.int32)
+    pool = jax.tree.map(
+        lambda x: jnp.concatenate([
+            jnp.zeros((1, bs) + x.shape[2:], x.dtype),
+            x.reshape((b * nb, bs) + x.shape[2:])]), cache)
+    tok = jnp.asarray([5, 17, 90], jnp.int32)
+    pos = jnp.asarray([4, 20, 0], jnp.int32)
+    keys = jnp.asarray(rng.randint(0, 2 ** 31, (b, 2)), jnp.uint32)
+    got_d = dense(params, cache, None, tok, pos, keys)
+    got_p = paged(params, pool, tables, tok, pos, keys)
+    assert len(got_d) == len(got_p)
+    for i, (d, p) in enumerate(zip(got_d, got_p)):
+        if i == tables_at:      # passed through: None, or the tables
+            assert d is None
+            np.testing.assert_array_equal(np.asarray(p),
+                                          np.asarray(tables))
+        elif i == cache_at:
+            for layer_d, layer_p in zip(d, p):
+                view = tf._paged_gather(layer_p, tables)
+                for name in layer_d:
+                    np.testing.assert_array_equal(
+                        np.asarray(layer_d[name]), np.asarray(view[name]))
+        else:                   # emissions, keys, the rolling carry
+            np.testing.assert_array_equal(np.asarray(d), np.asarray(p))
 
 
 def test_paged_capacity_2x_dense_at_equal_hbm():

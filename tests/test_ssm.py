@@ -169,6 +169,54 @@ def test_forward_prefill_and_decode_agree_through_the_cache():
         _close(logits, full[:, t])
 
 
+def _rows(layer, t, cfg):
+    """A layer's state as float32, its K/V rows cut to the first t
+    positions (an int8 cache dequantized: a code may flip on a rounding
+    edge, its value may not move)."""
+    if "ssm" in layer:
+        return dict(layer)
+    if cfg.kv_cache_int8:
+        return {n: tf._kv_dequant(layer[n][:, :t], layer[n + "s"][:, :t],
+                                  jnp.float32) for n in ("k", "v")}
+    return {n: layer[n][:, :t] for n in ("k", "v")}
+
+
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(layer_kinds=None, positions=None),
+    dict(layer_kinds=None, positions="rope", rope=True, n_kv_heads=2),
+    dict(layer_kinds=None, positions=None, kv_cache_int8=True)],
+    ids=["hybrid", "attention", "attention-rope-gqa", "attention-int8"])
+def test_chunk_prefill_and_stepped_decode_leave_one_state(kw):
+    """One layer body behind three doors: prefill_chunk over the whole
+    prompt, prefill, and decode_step token by token leave the same
+    state in every layer, of either kind, and the same last logits."""
+    cfg = _cfg(**kw)
+    params = tf.init_params(cfg, 5)
+    t = 11
+    toks = jnp.asarray(np.random.RandomState(2).randint(1, 97, (2, t)),
+                       jnp.int32)
+    want, c_want = _prefill(params, toks, cfg)
+    got, c_chunk = _chunk(params, tf.init_cache(cfg, 2), toks, 0, t - 1,
+                          cfg)
+    decode, c_step = _decode_fn(cfg), tf.init_cache(cfg, 2)
+    for i in range(t):
+        pos = jnp.full((2,), i, jnp.int32) if i % 2 else jnp.int32(i)
+        last, c_step = decode(params, c_step, toks[:, i], pos)
+    # the quantizer's noise where the cache is int8: the contraction
+    # reads codes, and the three doors round on different sides
+    tol = 0.15 if cfg.kv_cache_int8 else 2e-5
+    _close(got, want, tol)
+    _close(last, want, tol)
+    for l_want, l_chunk, l_step in zip(c_want, c_chunk, c_step):
+        rows = _rows(l_want, t, cfg)
+        for name in rows:
+            scale = float(np.abs(np.asarray(rows[name], np.float32)).max())
+            ltol = 2 * scale / 127 if cfg.kv_cache_int8 else 2e-5
+            _close(_rows(l_chunk, t, cfg)[name], rows[name], ltol)
+            _close(_rows(l_step, t, cfg)[name], rows[name], ltol)
+
+
 def test_generate_and_beam_one_agree_on_a_hybrid_model():
     cfg = _cfg()
     params = tf.init_params(cfg, 4)
