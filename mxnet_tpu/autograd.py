@@ -12,6 +12,7 @@ cotangents; exactness comes from XLA's AD rules rather than 345 hand-written
 gradient registrations.
 """
 
+import functools
 import threading
 
 import jax
@@ -35,7 +36,11 @@ def _st():
 
 class TapeNode:
     """One recorded op: pullback + input/output bookkeeping
-    (the analogue of nnvm::Node + AGInfo, include/mxnet/imperative.h:42-79)."""
+    (the analogue of nnvm::Node + AGInfo, include/mxnet/imperative.h:42-79).
+
+    `vjp_fn` is a bare pullback (`jax.vjp` outside any jit, a custom
+    Function's backward): it wants an array for every output, so the sweep
+    builds the missing cotangents itself, each a program of its own."""
 
     __slots__ = ("vjp_fn", "inputs", "num_outputs", "cotangents", "out_shapes",
                  "out_dtypes", "op_name")
@@ -49,6 +54,39 @@ class TapeNode:
         self.out_shapes = out_shapes
         self.out_dtypes = out_dtypes
         self.op_name = op_name
+
+
+class ProgramNode(TapeNode):
+    """A node whose pullback came out of a jitted forward (a CachedOp's,
+    `ndarray._recorded_vjp`'s compiled ops) and runs as ONE program:
+    `vjp_fn` takes the cotangents as they are, `None` for an output
+    nothing flowed back to, and fills those inside the program
+    (`apply_vjp`)."""
+
+    __slots__ = ()
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _apply_vjp(avals, vjp, cts):
+    # `avals` ((shape, dtype) an output) is read only where a cotangent
+    # is missing; a node with one output is never swept without it
+    if isinstance(cts, tuple):
+        cts = tuple(jnp.zeros(*a) if c is None else c
+                    for a, c in zip(avals, cts))
+    return vjp(cts)
+
+
+def apply_vjp(vjp, out_shapes, out_dtypes):
+    """The `vjp_fn` of a ProgramNode: `vjp`, a pullback returned by a
+    jitted function, applied INSIDE jit. Such a closure is a pytree of
+    residuals whose structure repeats call after call, so one compiled
+    program a signature serves; called bare it would interpret the
+    backward jaxpr operation by operation (no XLA fusion, and on the CPU
+    mesh the flock of in-flight collective launches deadlocks,
+    engine.py). A pullback from a bare `jax.vjp` is a new function every
+    call and must NOT come here: every backward would recompile."""
+    return functools.partial(_apply_vjp, tuple(zip(out_shapes, out_dtypes)),
+                             vjp)
 
 
 # ------------------------------------------------------------- scopes --
@@ -161,6 +199,8 @@ def _backward_impl(outputs, head_grads=None, retain_graph=False,
     tape = _tape()
     # seed cotangents
     grad_acc = {}  # id(leaf NDArray) -> (leaf, jnp grad)
+    # tape nodes pulled back, and cotangents built outside a program
+    pulled = seeds = 0
 
     def add_ct(node, idx, ct):
         cur = node.cotangents[idx]
@@ -172,7 +212,10 @@ def _backward_impl(outputs, head_grads=None, retain_graph=False,
         if head_grads is not None and head_grads[i] is not None:
             hg = head_grads[i]._data
         else:
-            hg = jnp.ones(o.shape, dtype=o.dtype)
+            # the default head gradient is a host constant handed over,
+            # not a program (`jnp.ones` is two)
+            hg = jnp.asarray(_np.ones(o.shape, dtype=o.dtype))
+            seeds += 1
         if o._ag_leaf and o._ag_node is None:
             _acc_leaf(o, hg, grad_acc)
             continue
@@ -188,14 +231,16 @@ def _backward_impl(outputs, head_grads=None, retain_graph=False,
             # reverse order visits consumers first and marks producers below.
             if all(c is None for c in node.cotangents):
                 continue
-        cts = []
-        for k in range(node.num_outputs):
-            c = node.cotangents[k]
-            if c is None:
-                c = jnp.zeros(node.out_shapes[k], dtype=node.out_dtypes[k])
-            cts.append(c)
-        ct_arg = tuple(cts) if node.num_outputs > 1 else cts[0]
-        in_grads = node.vjp_fn(ct_arg)
+        cts = node.cotangents
+        if not isinstance(node, ProgramNode):
+            for k, c in enumerate(cts):
+                if c is None:
+                    cts[k] = jnp.zeros(node.out_shapes[k],
+                                       dtype=node.out_dtypes[k])
+                    seeds += 1
+        pulled += 1
+        in_grads = node.vjp_fn(tuple(cts) if node.num_outputs > 1
+                               else cts[0])
         engine.sync_if_needed([g for g in in_grads
                                if hasattr(g, "block_until_ready")])
         for inp, g in zip(node.inputs, in_grads):
@@ -229,6 +274,9 @@ def _backward_impl(outputs, head_grads=None, retain_graph=False,
 
     if not retain_graph:
         tape.clear()
+    if _obs.enabled():
+        _obs.counter("autograd.pullbacks").add(pulled)
+        _obs.counter("autograd.eager_seeds").add(seeds)
 
 
 def _acc_leaf(leaf, g, grad_acc):

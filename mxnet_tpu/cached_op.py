@@ -11,17 +11,20 @@ executor.build_graph_fn. XLA subsumes static_alloc/static_shape (buffer
 assignment), op bulking (fusion) and the backward-graph pass (jax.vjp).
 Autograd integration records a single tape node whose pullback is the
 compiled transpose of the whole computation — the reference's
-CachedOp::Backward analogue.
+CachedOp::Backward analogue. Auxiliary states (BatchNorm running
+statistics) leave the compiled forward as `has_aux` outputs, here and in
+Executor (executor.fwd_res_fn): their updates are computed and written
+back, never differentiated, so a backward is one program however many
+the block has.
 """
 
 import jax
-import jax.numpy as jnp
 
 from . import autograd
 from . import engine as _engine
 from . import random as _random
 from .base import MXNetError
-from .executor import apply_mirror, build_graph_fn, mirror_enabled
+from .executor import build_graph_fn, fwd_res_fn, mirror_enabled
 from .observability import attribution as _obs_attr
 from .observability import core as _obs
 from .observability import membudget as _membudget
@@ -30,12 +33,6 @@ from .observability import recompile as _obs_recompile
 # fixed key fed to RNG-free graphs (never consumed; avoids a per-call
 # host-side split)
 _ZERO_KEY = None
-
-
-@jax.jit
-def _apply_vjp(vjp, ct):
-    (grads,) = vjp(ct)
-    return grads
 
 
 def _zero_key():
@@ -106,26 +103,13 @@ class CachedOp:
         graph_fn = build_graph_fn(self._sym, is_train=is_train)
 
         if diff_names:
-            def pure(diff_list, rest, aux, rng_key):
-                full = dict(rest)
-                full.update(zip(diff_names, diff_list))
-                outs, aux_up = graph_fn(full, aux, rng_key)
-                return tuple(outs), aux_up
-            # hybridize(backward_do_mirror=True) / MXNET_BACKWARD_DO_MIRROR:
-            # remat the traced graph so backward recomputes activations
+            # compile forward + residuals ONCE per signature (a per-call
+            # jax.vjp would re-trace the whole graph); under
+            # hybridize(backward_do_mirror=True) /
+            # MXNET_BACKWARD_DO_MIRROR backward recomputes activations
             # under the mirror policy instead of storing them
-            pure = apply_mirror(pure, mirror_enabled(self._flags))
-
-            def fwd_res(diff_list, rest, aux, rng_key):
-                # compile forward + residuals ONCE per signature; the
-                # vjp closure is a jax.tree_util.Partial and crosses the
-                # jit boundary (executor.fwd_res_fn does the same) — a
-                # per-call jax.vjp would re-trace the whole graph
-                outs_aux, vjp = jax.vjp(
-                    lambda d: pure(d, rest, aux, rng_key), diff_list,
-                    has_aux=False)
-                return outs_aux, vjp
-            fn = jax.jit(fwd_res)
+            fn = jax.jit(fwd_res_fn(graph_fn, diff_names,
+                                    mirror_enabled(self._flags)))
         else:
             def pure(args, aux, rng_key):
                 outs, aux_up = graph_fn(args, aux, rng_key)
@@ -181,46 +165,43 @@ class CachedOp:
 
             diff_nds = [by_name[n] for n in diff_names]
 
+            out_shapes = [tuple(o.shape) for o in outs]
+            out_dtypes = [o.dtype for o in outs]
+            pull = autograd.apply_vjp(vjp_fn, out_shapes, out_dtypes)
+
             def tape_vjp(cts):
-                cts_t = tuple(cts) if isinstance(cts, (tuple, list)) \
-                    else (cts,)
-                # cotangent structure matches pure's (outs, aux_up); aux
-                # updates get zero cotangents. Apply the vjp closure
-                # INSIDE jit (it is a Partial — a pytree of residuals):
-                # calling it bare would interpret the backward jaxpr
-                # op-by-op eagerly — no XLA fusion, and on the CPU mesh
-                # the resulting flock of in-flight collective launches
-                # deadlocks (engine.py). Executor.bwd_fn does the same.
-                aux_ct = jax.tree.map(jnp.zeros_like, aux_up)
+                # the pullback takes the outputs' cotangents ONLY (the
+                # auxiliary states left the forward as has_aux outputs),
+                # in the structure of the forward's `outs`
+                cts_t = cts if isinstance(cts, tuple) else (cts,)
                 origin = "CachedOp[%s].step" % self._obs_name()
                 if sig is not None and _obs_attr.ops_enabled() \
                         and _obs_attr.needs_program(origin, sig):
                     # per-operator attribution: register a combined
                     # fwd+vjp analysis program. The runtime executes
-                    # fn and _apply_vjp as two programs, but replaying
+                    # fn and the pullback as two programs, but replaying
                     # the stored vjp closure in a separate jit drops
                     # the op_name name-stack metadata — re-deriving the
                     # vjp inside ONE traced program keeps every
                     # backward instruction attributed to its block.
                     def _step(diff, rest, aux_a, key, ct):
                         _o, v = fn(diff, rest, aux_a, key)
-                        return _o, _apply_vjp(v, ct)
+                        return _o, autograd.apply_vjp(
+                            v, out_shapes, out_dtypes)(ct)
                     _obs_attr.register_program(
                         origin, sig, jax.jit(_step),
-                        (diff_list, args, aux, rng_key,
-                         (cts_t, aux_ct)))
+                        (diff_list, args, aux, rng_key, cts_t))
                 if _membudget.enabled():
                     _membudget.preflight(origin, signature=sig)
                 try:
-                    grads = _apply_vjp(vjp_fn, (cts_t, aux_ct))
+                    (grads,) = pull(cts_t)
                 except Exception as exc:
                     _membudget.note_oom(origin, exc)
                     raise
                 return grads
 
-            node = autograd.TapeNode(
-                tape_vjp, diff_nds, len(outs),
-                [tuple(o.shape) for o in outs], [o.dtype for o in outs],
+            node = autograd.ProgramNode(
+                tape_vjp, diff_nds, len(outs), out_shapes, out_dtypes,
                 op_name="CachedOp")
             autograd._record_node(node)
             results = []

@@ -88,6 +88,31 @@ def apply_mirror(fn, enabled):
     return jax.checkpoint(fn, policy=_mirror_policy())
 
 
+def fwd_res_fn(graph_fn, diff_names, mirror):
+    """fn(diff_list, rest, aux, key) -> ((outs, aux_up), vjp) over a
+    build_graph_fn plan: forward + pullback residuals with respect to
+    `diff_names`, for Executor and CachedOp alike. The returned vjp
+    closure is a pytree of residual arrays, so it crosses the jit
+    boundary intact and backward replays ONLY the transposed computation
+    (the reference executor also keeps fwd/bwd as two engine segments,
+    graph_executor.cc RunOps). Auxiliary states (BatchNorm running
+    statistics) leave as `has_aux` outputs: nothing differentiates them,
+    and as differentiated outputs the pullback would demand a zero
+    cotangent for each and transpose their updates against it. With
+    `mirror` the whole graph is rematerialized under the mirror policy,
+    shrinking the residual set."""
+    def fwd_res(diff_list, rest, aux, key):
+        def f(diff):
+            full = dict(rest)
+            full.update(zip(diff_names, diff))
+            outs, aux_up = graph_fn(full, aux, key)
+            return tuple(outs), aux_up
+        outs, vjp, aux_up = jax.vjp(apply_mirror(f, mirror),
+                                    list(diff_list), has_aux=True)
+        return (outs, aux_up), vjp
+    return fwd_res
+
+
 def node_eval_fn(node, for_inference=False):
     """Pure fn(*input_arrays) for one graph node (used by eval_shape)."""
     op = ops.get(node.op)
@@ -304,25 +329,7 @@ class Executor:
             outs, _ = fwd_infer(arg_arrays, aux_arrays, key)
             return outs
 
-        do_mirror = mirror_enabled()
-
-        def fwd_res_fn(diff_arrays, rest_arrays, aux_arrays, key):
-            """Forward + pullback residuals. The returned vjp closure is a
-            jax.tree_util.Partial (a pytree of residual arrays), so it
-            crosses the jit boundary intact: backward() replays ONLY the
-            transposed computation — custom head gradients cost no second
-            forward (the reference executor also keeps fwd/bwd as two
-            engine segments, graph_executor.cc RunOps). With
-            MXNET_BACKWARD_DO_MIRROR the whole graph is rematerialized
-            under the mirror policy, shrinking the residual set."""
-            def f(diff):
-                full = dict(rest_arrays)
-                full.update(dict(zip(diff_names, diff)))
-                outs, aux_up = fwd_train(full, aux_arrays, key)
-                return outs, aux_up
-            f = apply_mirror(f, do_mirror)
-            outs, vjp, aux_up = jax.vjp(f, list(diff_arrays), has_aux=True)
-            return outs, aux_up, vjp
+        fwd_res = fwd_res_fn(fwd_train, diff_names, mirror_enabled())
 
         def bwd_fn(vjp, heads):
             (grads,) = vjp(heads)
@@ -334,10 +341,10 @@ class Executor:
             # (group2ctx) graphs run op-by-op so each segment can live on
             # its own device with transfers at group boundaries
             infer_fn = jax.jit(infer_fn)
-            fwd_res_fn = jax.jit(fwd_res_fn)
+            fwd_res = jax.jit(fwd_res)
             bwd_fn = jax.jit(bwd_fn)
         self._infer_fn = infer_fn
-        self._fwd_res_fn = fwd_res_fn
+        self._fwd_res_fn = fwd_res
         self._bwd_fn = bwd_fn
         self._obs_sig = None
 
@@ -388,8 +395,8 @@ class Executor:
                     self._fwd_res_fn, (diff, rest, aux_arrays, key),
                     signature=sig)
             try:
-                outs, aux_up, vjp = self._fwd_res_fn(diff, rest,
-                                                     aux_arrays, key)
+                (outs, aux_up), vjp = self._fwd_res_fn(diff, rest,
+                                                       aux_arrays, key)
             except Exception as exc:
                 _membudget.note_oom(
                     "Executor[%s].fwd" % self._symbol.list_outputs()[0],

@@ -521,8 +521,9 @@ def _freeze_attr(v):
 
 
 def _recorded_vjp(op, kw, arr_names, has_varargs, datas):
-    """(outs, vjp_fn) with per-signature compilation; falls back to an
-    uncached jax.vjp when an attr is unhashable (host callbacks etc.)."""
+    """(outs, vjp_fn, compiled) with per-signature compilation; falls
+    back to an uncached jax.vjp (`compiled` False: a new function every
+    call) when an attr is unhashable (host callbacks etc.)."""
     rng = kw.get("rng_key", None)
     static = {k: v for k, v in kw.items() if k != "rng_key"}
 
@@ -534,6 +535,13 @@ def _recorded_vjp(op, kw, arr_names, has_varargs, datas):
             c.update(zip(arr_names, xs))
             return op.fn(**c)
         return f
+
+    def _direct():
+        call = dict(static)
+        if rng is not None:
+            call["rng_key"] = rng
+        outs, vjp_fn = jax.vjp(build_f(call), *datas)
+        return outs, vjp_fn, False
 
     try:
         from .ops.nn import residual_knobs
@@ -547,16 +555,7 @@ def _recorded_vjp(op, kw, arr_names, has_varargs, datas):
                residual_knobs())
         hash(key)
     except TypeError:
-        call = dict(static)
-        if rng is not None:
-            call["rng_key"] = rng
-        return jax.vjp(build_f(call), *datas)
-
-    def _direct():
-        call = dict(static)
-        if rng is not None:
-            call["rng_key"] = rng
-        return jax.vjp(build_f(call), *datas)
+        return _direct()
 
     jfn = _RECORD_VJP_CACHE.get(key, _MISSING)
     if jfn is None:          # known jit-incompatible (dynamic shapes)
@@ -572,7 +571,7 @@ def _recorded_vjp(op, kw, arr_names, has_varargs, datas):
 
         jfn = jax.jit(fwd_res)
         try:
-            out = jfn(tuple(datas), rng)
+            outs, vjp_fn = jfn(tuple(datas), rng)
         except jax.errors.JAXTypeError:
             # ops with data-dependent output shapes (boolean_mask ->
             # jnp.nonzero) cannot trace under jit; remember and run the
@@ -580,8 +579,9 @@ def _recorded_vjp(op, kw, arr_names, has_varargs, datas):
             _RECORD_VJP_CACHE[key] = None
             return _direct()
         _RECORD_VJP_CACHE[key] = jfn
-        return out
-    return jfn(tuple(datas), rng)
+        return outs, vjp_fn, True
+    outs, vjp_fn = jfn(tuple(datas), rng)
+    return outs, vjp_fn, True
 
 
 _INT32_MAX = 2**31 - 1
@@ -645,15 +645,18 @@ def imperative_invoke(op_name, nd_inputs, attrs, out=None, input_names=None):
 
     with _large_tensor_ctx(datas):
         if record:
-            outs, vjp_fn = _recorded_vjp(op, kw, arr_names, has_varargs,
-                                         datas)
+            outs, vjp_fn, compiled = _recorded_vjp(
+                op, kw, arr_names, has_varargs, datas)
             multi = isinstance(outs, (tuple, list))
             outs_list = list(outs) if multi else [outs]
-            node = autograd.TapeNode(
-                vjp_fn, list(nd_inputs), len(outs_list),
-                [tuple(o.shape) for o in outs_list],
-                [o.dtype for o in outs_list],
-                op_name=op_name)
+            out_shapes = [tuple(o.shape) for o in outs_list]
+            out_dtypes = [o.dtype for o in outs_list]
+            kind = autograd.TapeNode
+            if compiled:
+                kind = autograd.ProgramNode
+                vjp_fn = autograd.apply_vjp(vjp_fn, out_shapes, out_dtypes)
+            node = kind(vjp_fn, list(nd_inputs), len(outs_list),
+                        out_shapes, out_dtypes, op_name=op_name)
             autograd._record_node(node)
             results = []
             for k, o in enumerate(outs_list):

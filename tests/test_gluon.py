@@ -5,8 +5,10 @@ LoC): parameter lifecycle, deferred init, hybridize consistency, trainer
 steps, losses vs hand-computed numpy references.
 """
 
+import glob
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -744,3 +746,299 @@ def test_module_and_trainer_resolve_a_spec_alike(spec):
         assert type(trainer._kvstore) is type(mod._kvstore)
         assert (trainer._kvstore is None) == (
             spec is None or "dist" not in spec)
+
+
+# ------------------------------------- one program a tape node (ISSUE 31) ---
+# Auxiliary states (BatchNorm running statistics) leave a compiled block as
+# has_aux outputs, not as differentiated ones, so its backward is ONE program
+# however many batch norms it has; and the sweep applies every pullback that
+# came out of a jitted forward inside one program, seeds filled there.
+
+def _bn_net(n_bn, hybrid=True, **flags):
+    np.random.seed(5)
+    mx.random.seed(5)
+    net = nn.HybridSequential()
+    for i in range(n_bn):
+        net.add(nn.Conv2D(4, 3, padding=1, use_bias=False,
+                          in_channels=4 if i else 3),
+                nn.BatchNorm(in_channels=4), nn.Activation("relu"))
+    net.add(nn.GlobalAvgPool2D(), nn.Dense(3, in_units=4))
+    net.initialize(mx.init.Xavier())
+    if hybrid:
+        net.hybridize(**flags)
+    return net
+
+
+def _bn_batch():
+    return (mx.nd.array(np.random.RandomState(0).randn(4, 3, 8, 8)),
+            mx.nd.array(np.array([0, 1, 2, 1])))
+
+
+def _bn_loss(net, heads=None):
+    """Record forward + loss; the loss (a scalar, or the per-sample vector
+    when the caller brings its own head gradient)."""
+    x, y = _bn_batch()
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    with autograd.record():
+        loss = loss_fn(net(x), y)
+        return loss if heads else loss.mean()
+
+
+def _grads(net):
+    return [p.grad().asnumpy() for p in net.collect_params().values()
+            if p.grad_req != "null"]
+
+
+def _stats(net):
+    return [p.data().asnumpy() for n, p in net.collect_params().items()
+            if "running" in n]
+
+
+def _jitted_calls(tmp_path, fn):
+    """Names of the jitted calls `fn()` makes, from a `jax.profiler` host
+    trace (independent of the program's own counters). jax writes each call
+    as two `PjitFunction(<name>)` events."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test.backward"):
+            fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    spans, calls = [], []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == "test.backward":
+                    spans.append((e.start_ns, e.start_ns + e.duration_ns))
+                elif e.name.startswith("PjitFunction("):
+                    calls.append((e.name[len("PjitFunction("):-1],
+                                  e.start_ns))
+    assert len(spans) == 1
+    names = sorted(n for n, t in calls if spans[0][0] <= t <= spans[0][1])
+    assert len(names) % 2 == 0
+    return names[::2]
+
+
+@pytest.mark.parametrize("heads", [False, True],
+                         ids=["default-head", "given-head"])
+def test_hybridized_backward_does_not_grow_with_its_batch_norms(
+        tmp_path, heads):
+    calls = {}
+    autograd._tape().clear()
+    for n_bn in (1, 8):
+        net = _bn_net(n_bn)
+        head = mx.nd.ones((4,)) if heads else None
+        for _ in range(2):
+            _bn_loss(net, heads).backward(head)
+        loss = _bn_loss(net, heads)
+        nodes = len(autograd._tape())
+        calls[n_bn] = _jitted_calls(tmp_path / str(n_bn),
+                                    lambda: loss.backward(head))
+    assert calls[1] == calls[8]
+    # one program a tape node and none between them: the block and the
+    # loss's own few operations (the mean too, where taken)
+    assert nodes == (5 if heads else 6)
+    assert calls[8] == ["_apply_vjp"] * nodes
+
+
+@pytest.mark.parametrize("n_bn", [1, 3])
+def test_hybridized_gradients_equal_the_eager_tapes(n_bn):
+    hybrid, eager = _bn_net(n_bn), _bn_net(n_bn, hybrid=False)
+    _bn_loss(hybrid).backward()
+    _bn_loss(eager).backward()
+    for a, b in zip(_grads(hybrid), _grads(eager)):
+        assert np.abs(a).sum() > 0
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    for a, b in zip(_stats(hybrid), _stats(eager)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+# what the parent (bb24960) wrote into the four statistics of _bn_net(2)
+# after one recorded forward, on this seed
+_PARENT_STATS = [
+    [0.0030160362366586924, -0.0075761908665299416,
+     -0.006369703449308872, 0.019041553139686584],
+    [0.9934601783752441, 0.9843229651451111,
+     0.9920638203620911, 0.9936774373054504],
+    [-0.06526137888431549, -0.016486667096614838,
+     0.015546808950603008, -0.06275202333927155],
+    [0.939578652381897, 0.9296086430549622,
+     0.9289587736129761, 0.9496206045150757]]
+
+
+def test_running_statistics_are_written_back_as_before():
+    """The statistics' updates are still computed and written back: to the
+    bit what the forward gives when they are differentiated outputs (the
+    parent's form, built here from the same plan), and the parent's own
+    recorded numbers."""
+    from mxnet_tpu.executor import build_graph_fn
+    net = _bn_net(2)
+    x, _ = _bn_batch()
+    with autograd.pause():
+        net(x)                               # builds the CachedOp
+    op = net._cached_op
+    before = {n: p.data()._data for n, p in net.collect_params().items()}
+    before["data"] = x._data
+    inputs = {n: before.get(n, before.get(net.prefix + n))
+              for n in op._input_names}
+    assert all(v is not None for v in inputs.values())
+    _bn_loss(net).backward()
+    after = _stats(net)
+    for got, want in zip(after, _PARENT_STATS):
+        np.testing.assert_allclose(got, want, rtol=2e-6)
+
+    graph_fn = build_graph_fn(op.symbol, is_train=True)
+    args = {n: inputs[n] for n in op._arg_names}
+    aux = {n: inputs[n] for n in op._aux_names}
+    diff = tuple(n for n in op._arg_names if n != "data")
+
+    def fwd_res(diff_list, rest, aux, rng_key):
+        def pure(d):
+            full = dict(rest)
+            full.update(zip(diff, d))
+            outs, aux_up = graph_fn(full, aux, rng_key)
+            return tuple(outs), aux_up
+        return jax.vjp(pure, diff_list)
+    (_, aux_up), _ = jax.jit(fwd_res)(
+        [args[n] for n in diff], args, aux, jax.random.PRNGKey(0))
+    assert len(aux_up) == 4
+    for name, got in zip(op._aux_names, after):
+        np.testing.assert_array_equal(got, np.asarray(aux_up[name]))
+
+
+def test_grad_req_add_accumulates_over_two_hybridized_backwards():
+    net, once = _bn_net(2), _bn_net(2)
+    _bn_loss(once).backward()
+    for p in net.collect_params().values():
+        if p.grad_req != "null":
+            p.grad_req = "add"
+    for p in net.collect_params().values():
+        p.zero_grad()
+    # the second forward centres on the statistics the first one moved:
+    # the same gradient, rounded otherwise
+    for _ in range(2):
+        _bn_loss(net).backward()
+    for a, b in zip(_grads(net), _grads(once)):
+        np.testing.assert_allclose(a, 2 * b, rtol=1e-5, atol=1e-7)
+
+
+def test_retained_hybridized_graph_sweeps_twice_alike():
+    net = _bn_net(2)
+    loss = _bn_loss(net)
+    loss.backward(retain_graph=True)
+    first = _grads(net)
+    loss.backward()
+    for a, b in zip(first, _grads(net)):
+        np.testing.assert_array_equal(a, b)
+    assert autograd._tape() == []
+
+
+def test_backward_do_mirror_gives_the_same_gradients():
+    plain, mirror = _bn_net(2), _bn_net(2, backward_do_mirror=True)
+    _bn_loss(plain).backward()
+    _bn_loss(mirror).backward()
+    for a, b in zip(_grads(plain), _grads(mirror)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    for a, b in zip(_stats(plain), _stats(mirror)):
+        np.testing.assert_allclose(a, b, rtol=1e-6)
+
+
+class _TwoHeads(gluon.HybridBlock):
+    def __init__(self):
+        super().__init__()
+        with self.name_scope():
+            self.fc = nn.Dense(3, in_units=4)
+            self.bn = nn.BatchNorm(in_channels=3)
+
+    def hybrid_forward(self, F, x):
+        h = self.bn(self.fc(x))
+        return h * 2, F.tanh(h)
+
+
+@pytest.mark.parametrize("used", [0, 1])
+def test_an_unused_output_of_a_hybridized_block_still_differentiates(used):
+    """A multi-output block with one output unused: its cotangent is None
+    and is filled inside the backward program."""
+    def run(hybrid):
+        np.random.seed(7)
+        mx.random.seed(7)
+        net = _TwoHeads()
+        net.initialize(mx.init.Xavier())
+        if hybrid:
+            net.hybridize()
+        rs = np.random.RandomState(1)
+        x, w = mx.nd.array(rs.randn(5, 4)), mx.nd.array(rs.randn(5, 3))
+        with autograd.record():
+            loss = (net(x)[used] * w).sum()
+        loss.backward()
+        return _grads(net)
+    for a, b in zip(run(True), run(False)):
+        assert np.abs(a).sum() > 0
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_autograd_grad_through_a_cached_op_returns_what_backward_writes():
+    # two nets: a training batch norm centres on the running mean, so a
+    # second forward of one net rounds otherwise
+    net, twin = _bn_net(2), _bn_net(2)
+    _bn_loss(twin).backward()
+    written = _grads(twin)
+    params = [p.data() for p in net.collect_params().values()
+              if p.grad_req != "null"]
+    returned = autograd.grad(_bn_loss(net), params)
+    for a, b in zip(written, returned):
+        np.testing.assert_array_equal(a, b.asnumpy())
+
+
+class _Split(autograd.Function):
+    """Two outputs of a bare pullback; the test uses one."""
+
+    def forward(self, x):
+        return x * 2, x * 3
+
+    def backward(self, da, db):
+        return da * 2 + db * 3
+
+
+@pytest.mark.parametrize("case,nodes,seeds", [
+    ("hybrid-given-head", 5, 0),
+    ("hybrid-default-head", 6, 1),
+    ("eager-given-head", None, 0),
+    ("function-unused-output", 3, 2),
+])
+def test_the_sweep_counts_its_pullbacks_and_eager_seeds(telemetry, case,
+                                                        nodes, seeds):
+    autograd._tape().clear()
+    if case == "function-unused-output":
+        x = mx.nd.array(np.arange(3.0))
+        x.attach_grad()
+        with autograd.record():
+            loss = (_Split()(x)[0] * 1.0).sum()
+        tape, head = len(autograd._tape()), None
+        assert tape == nodes
+    else:
+        given = case.endswith("given-head")
+        loss = _bn_loss(_bn_net(2, hybrid=case.startswith("hybrid")), given)
+        tape = len(autograd._tape())
+        head = mx.nd.ones((4,)) if given else None
+        assert nodes is None or tape == nodes
+    assert "autograd.pullbacks" not in telemetry.counters()
+    loss.backward(head)
+    counters = telemetry.counters()
+    assert counters["autograd.pullbacks"].total == tape
+    assert counters["autograd.eager_seeds"].total == seeds
+
+
+def test_the_sweeps_counters_do_not_exist_with_telemetry_off(monkeypatch):
+    from mxnet_tpu.observability import core
+    monkeypatch.delenv("MXNET_OBS", raising=False)
+    core.set_enabled(None)
+    core.reset()
+    _bn_loss(_bn_net(1)).backward()
+    assert not [n for n in core.counters() if n.startswith("autograd.")]
