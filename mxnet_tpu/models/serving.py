@@ -20,15 +20,22 @@ Design notes (all static-shape, XLA-friendly):
   positions beyond the prompt are overwritten by decode writes before
   they ever become attendable — the same self-healing argument the
   speculative decoder relies on.
-* HYBRID models (cfg.layer_kinds with "mamba" layers) give a lane two
-  kinds of state: K/V rows for the attention layers and a fixed-size
-  recurrent state (conv window + float32 SSM state) for the others.
+* HYBRID models (cfg.layer_kinds with "mamba" or "kda" layers) give a
+  lane two kinds of state: rows for the attention layers (K/V heads,
+  or an "mla" layer's one latent a position) and a fixed-size
+  recurrent state for the others (conv window + a float32 SSM state,
+  or a KDA layer's float32 matrix a head).
   That state has no self-healing: a padded position folded into it
   stays. It is exact by construction instead — the bucketed prefill
   stops it at the last real token (prefill_chunk's logits_row), a
   cached prefix carries the state at its end, a continuation prefills
-  its history again. Mechanisms that cannot carry it (paged blocks,
-  speculative rollback, int8 KV) are refused at construction.
+  its history again. Mechanisms that cannot carry it or latent rows
+  (paged blocks, speculative rollback, int8 KV) are refused at
+  construction.
+* ROUTED EXPERTS (cfg.n_experts): the unpipelined decode programs
+  return, beside their tokens, the round's routing counts
+  (tf.MOE_STATS), which step() adds to the counters moe.<name> while
+  spans record.
 * Idle slots keep lanes busy writing at position 0 of retired rows;
   the next admission's prefill overwrites them. Throughput is
   proportional to active lanes, latency to the slowest active row —
@@ -140,15 +147,30 @@ def _pick_next(logits, keys, greedy, temperature, top_k, top_p):
 # programs read them. `paged` is part of each _serving_jit key, so the
 # two kinds never share a wrapper.
 
+def _decode_and_pick(fz, controls, params, cache, tables, tok, pos, keys):
+    """One ragged decode step and every lane's next token. Returns
+    (tokens, keys, cache, routing): the round's routing counts
+    (tf.moe_stats) for a model with routed experts, else None."""
+    loads = [] if fz.n_experts else None
+    logits, cache = tf._decode_step_on(params, cache, tables, tok, pos,
+                                       fz, loads)
+    nxt, keys = _pick_next(logits, keys, *controls)
+    return nxt, keys, cache, \
+        tf.moe_stats(loads, tok.shape[0], fz) if loads else None
+
+
 def _jitted_ragged_step(cfg, greedy, temperature, top_k, top_p, paged):
-    """One compiled program: ragged decode + per-row token choice."""
+    """One compiled program: ragged decode + per-row token choice (and
+    a model with routed experts' routing counts as a fourth output)."""
+    controls = (greedy, temperature, top_k, top_p)
+
     def build(fz):
         def step(params, cache, tables, tok, pos, keys):
-            logits, cache = tf._decode_step_on(params, cache, tables,
-                                               tok, pos, fz)
-            nxt, keys = _pick_next(logits, keys, greedy, temperature,
-                                   top_k, top_p)
-            return nxt, keys, cache
+            nxt, keys, cache, routing = _decode_and_pick(
+                fz, controls, params, cache, tables, tok, pos, keys)
+            if routing is None:
+                return nxt, keys, cache
+            return nxt, keys, cache, routing
         return jax.jit(step, donate_argnums=tf._serving_donate(1))
     return tf._serving_jit(
         ("decode_ragged", paged, greedy, float(temperature), top_k,
@@ -158,13 +180,13 @@ def _jitted_ragged_step(cfg, greedy, temperature, top_k, top_p, paged):
 def _scan_steps(fz, controls, k, params, cache, tables, tok, pos, keys):
     """`k` ragged decode steps as one lax.scan; returns the rolling
     carry (cache, last token, advanced positions, key chain) and the
-    [k, B] emissions."""
+    [k, B] emissions with each step's routing counts ([k, ...], or
+    None: _decode_and_pick)."""
     def body(carry, _):
         cache, tok, pos, keys = carry
-        logits, cache = tf._decode_step_on(params, cache, tables, tok,
-                                           pos, fz)
-        nxt, keys = _pick_next(logits, keys, *controls)
-        return (cache, nxt, pos + 1, keys), nxt
+        nxt, keys, cache, routing = _decode_and_pick(
+            fz, controls, params, cache, tables, tok, pos, keys)
+        return (cache, nxt, pos + 1, keys), (nxt, routing)
     return jax.lax.scan(body, (cache, tok, pos, keys), None, length=k)
 
 
@@ -182,9 +204,11 @@ def _jitted_ragged_chunk(cfg, greedy, temperature, top_k, top_p, k,
 
     def build(fz):
         def chunk(params, cache, tables, tok, pos, keys):
-            (cache, _, _, keys), toks = _scan_steps(
+            (cache, _, _, keys), (toks, routing) = _scan_steps(
                 fz, controls, k, params, cache, tables, tok, pos, keys)
-            return toks, keys, cache           # toks [k, B]
+            if routing is None:
+                return toks, keys, cache       # toks [k, B]
+            return toks, keys, cache, jnp.sum(routing, axis=0)
         return jax.jit(chunk, donate_argnums=tf._serving_donate(1))
     return tf._serving_jit(
         ("decode_ragged_chunk", paged, greedy, float(temperature),
@@ -211,7 +235,7 @@ def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k,
 
     def build(fz):
         def chunk(params, cache, tables, tok, pos, keys):
-            (cache, tok, pos, keys), toks = _scan_steps(
+            (cache, tok, pos, keys), (toks, _) = _scan_steps(
                 fz, controls, k, params, cache, tables, tok, pos, keys)
             return toks, cache, tables, tok, pos, keys   # toks [k, B]
         return jax.jit(chunk,
@@ -941,21 +965,16 @@ class ContinuousBatcher(object):
             paged = (_fastenv.get("MXNET_KV_PAGED") or "") \
                 not in ("", "0", "false", "False")
         self.paged = bool(paged)
-        if tf._recurrent(cfg):
-            for on, what in (
-                    (self.paged, "paged=True (or MXNET_KV_PAGED): a "
-                     "block holds K/V positions, and a lane's recurrent "
-                     "state has no block to live in or be shared "
-                     "through"),
-                    (self._spec_on, "spec_k (or MXNET_SPEC_K): a "
-                     "rejected draft is already folded into the "
-                     "recurrent state and cannot be rolled back"),
-                    (cfg.kv_cache_int8, "kv_cache_int8: the int8 cache "
-                     "layout has no place for the float32 state")):
-                if on:
-                    raise ValueError(
-                        "a model with state-space layers cannot be "
-                        "served with %s" % what)
+        # only dense lanes carry a recurrent state or latent rows
+        # (tf._DENSE_ONLY): a block holds K/V positions of heads and a
+        # state has none to live in or be shared through; a rejected
+        # draft is already folded into a state and cannot be rolled
+        # back; the int8 layout has no place for either
+        for on, what in ((self.paged, "paged=True (or MXNET_KV_PAGED)"),
+                         (self._spec_on, "spec_k (or MXNET_SPEC_K)"),
+                         (cfg.kv_cache_int8, "kv_cache_int8")):
+            if on:
+                tf._refuse_dense_only(cfg, "a batcher with " + what)
         if self.paged:
             if block_size is None:
                 block_size = int(_fastenv.get("MXNET_KV_BLOCK_SIZE",
@@ -1003,15 +1022,19 @@ class ContinuousBatcher(object):
         self._slots = [None] * self.max_batch   # Request or None
         # what a live lane holds, for the serving.state_bytes /
         # serving.kv_bytes gauges: bytes of recurrent state a lane
-        # (whatever its length) and bytes of K/V a position
-        row = jax.eval_shape(lambda: tf.init_cache(cfg, 1))
+        # (whatever its length: a Mamba layer's, a KDA layer's matrices)
+        # and bytes of rows a position (K/V heads, or a latent)
+        row = list(zip(tf._layer_kinds(cfg),
+                       jax.eval_shape(lambda: tf.init_cache(cfg, 1))))
 
         def nbytes(layers):
             return sum(x.size * x.dtype.itemsize
                        for layer in layers for x in layer.values())
-        self._lane_state_bytes = nbytes(l for l in row if "ssm" in l)
+        self._lane_state_bytes = nbytes(
+            l for kind, l in row if kind in tf._RECURRENT)
         self._kv_pos_bytes = nbytes(
-            l for l in row if "ssm" not in l) // cfg.max_len
+            l for kind, l in row if kind not in tf._RECURRENT) \
+            // cfg.max_len
         if self._device_carry:
             # device-resident lane carry (the host-side mirrors above
             # go unused): tok/pos/keys live on device between
@@ -2234,10 +2257,15 @@ class ContinuousBatcher(object):
                     _membudget.preflight(self._chaos_site, fn, args)
                 if _attr.ops_enabled():
                     self._register_dispatch("decode", fn, args)
-                toks, keys, state = fn(*args)
+                toks, keys, state, *routing = fn(*args)
                 with _obs.span("serving.sync", cat="serving",
                                mode="sync"):
                     toks = np.asarray(toks)
+                if routing and _obs.active():
+                    # a model with routed experts: the round's counts
+                    for name, n in zip(tf.MOE_STATS,
+                                       np.asarray(routing[0])):
+                        _obs.counter("moe." + name).add(int(n))
                 toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
                 if self.paged:
                     self._pool = state

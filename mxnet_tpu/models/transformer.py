@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import ssm
+from . import kda, ssm
 from ..parallel.ring import ring_attention, ring_attention_sharded
 from ..parallel.pipeline import stack_stage_params, spmd_pipeline
 
@@ -50,14 +50,37 @@ class TransformerConfig:
     n_kv_heads: int = None
     n_layers: int = 2
     # each layer's mixer, a tuple of n_layers names: "attention" (the K/V
-    # cache kind) or "mamba" (models/ssm.py: fixed-size recurrent state
-    # instead of K/V rows). None = every layer attention
+    # cache kind), "mamba" (models/ssm.py: fixed-size recurrent state
+    # instead of K/V rows), "kda" (models/kda.py: a matrix-valued state a
+    # head) or "mla" (latent attention: one latent row a position instead
+    # of K/V heads). None = every layer attention
     layer_kinds: tuple = None
     d_ff: int = 128
-    # the feed-forward's form: "gelu" = w2 gelu(w1 x); "gated_silu" =
-    # w2 (silu(w1 x) * w3 x), dense only
+    # the feed-forward's form, dense or a routed expert's: "gelu" =
+    # w2 gelu(w1 x); "gated_silu" = w2 (silu(w1 x) * w3 x)
     ffn: str = "gelu"
-    n_experts: int = 0          # 0 = dense FFN; >0 = MoE every layer
+    # routed experts: 0 = a dense FFN in every layer; E > 0 = the layers
+    # from `first_dense_layers` on route each token over E experts of
+    # width `d_expert` (None = d_ff) and add `n_shared_experts` every
+    # token passes through. `experts_per_token` k (None = E, every
+    # expert) are chosen by `expert_scoring`: "softmax" = the softmax
+    # over all E as the weights; "sigmoid" = sigmoid scores, the k
+    # chosen (by score + the layer's "gate_bias") renormalised to sum to
+    # `expert_scale`. `experts_held` = (first, count): the contiguous
+    # range of the E this program holds weights for (None = all); it
+    # routes over all E and computes its own experts' part of the result
+    n_experts: int = 0
+    experts_per_token: int = None
+    expert_scoring: str = "softmax"
+    expert_scale: float = 1.0
+    experts_held: tuple = None
+    d_expert: int = None
+    n_shared_experts: int = 0
+    first_dense_layers: int = 0
+    # False = an output head of its own ("head" [vocab, d]) instead of
+    # the embedding's transpose
+    tied_head: bool = True
+    norm_eps: float = 1e-6      # every RMSNorm's epsilon
     max_len: int = 128
     dtype: object = jnp.float32
     # mesh axis names (set to None to disable an axis)
@@ -84,6 +107,19 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = None
+    # KDA mixer sizes: heads (None = n_heads), the size of a head's keys
+    # and values and of the gates' inner projection (None = d_model /
+    # n_heads), conv taps
+    kda_heads: int = None
+    kda_head_dim: int = None
+    kda_conv: int = 4
+    # latent attention ("mla") sizes: the rank of the K/V latent, a
+    # head's key part up-projected from it, its key part shared by all
+    # heads (cached beside the latent, never rotated), a head's values
+    mla_rank: int = None
+    mla_nope_dim: int = None
+    mla_rope_dim: int = None
+    mla_v_dim: int = None
     use_ring_attention: bool = True
     # attention through the Pallas flash kernel (kernels/
     # flash_attention.py): single-device dense path AND the per-shard
@@ -119,29 +155,47 @@ def _kvh(cfg):
     return kvh
 
 
+# what a layer of each kind other than "attention" keeps between calls.
+# Only dense lanes carry any of them: paged blocks, speculation, int8
+# and a mesh refuse these kinds by name (_refuse_dense_only)
+_DENSE_ONLY = {"mamba": "a state-space layer's recurrent state",
+               "kda": "a linear-attention layer's matrix state",
+               "mla": "a latent-attention layer's latent rows"}
+# the kinds whose state is a recurrence: fixed-size, and not healed by
+# position as K/V or latent rows are
+_RECURRENT = ("mamba", "kda")
+
+
 def _layer_kinds(cfg):
     """The mixer of every layer, checked."""
     kinds = cfg.layer_kinds or ("attention",) * cfg.n_layers
     if len(kinds) != cfg.n_layers \
-            or set(kinds) - {"attention", "mamba"}:
+            or set(kinds) - {"attention"} - set(_DENSE_ONLY):
         raise ValueError(
-            "layer_kinds must name %d layers, each 'attention' or "
-            "'mamba'; got %r" % (cfg.n_layers, kinds))
+            "layer_kinds must name %d layers, each 'attention', 'mamba', "
+            "'kda' or 'mla'; got %r" % (cfg.n_layers, kinds))
     return tuple(kinds)
 
 
 def _recurrent(cfg):
-    return "mamba" in _layer_kinds(cfg)
+    return any(k in _RECURRENT for k in _layer_kinds(cfg))
 
 
-def _refuse_recurrent(cfg, mechanism):
-    """Recurrent state is exact by construction or wrong: a mechanism
-    that cannot carry it says so instead of serving other tokens."""
-    if _recurrent(cfg):
+def _dense_only(cfg):
+    """The kinds of this model that only dense lanes can carry."""
+    return [k for k in _DENSE_ONLY if k in _layer_kinds(cfg)]
+
+
+def _refuse_dense_only(cfg, mechanism):
+    """A recurrent state is exact by construction or wrong, and a latent
+    row is not K/V heads: a mechanism that cannot carry a kind says so
+    instead of serving other tokens."""
+    kinds = _dense_only(cfg)
+    if kinds:
         raise ValueError(
-            "%s cannot carry a state-space layer's recurrent state "
-            "(layer_kinds has 'mamba' layers); serve this model through "
-            "the dense cache" % mechanism)
+            "%s cannot carry %s (layer_kinds has %r layers); serve this "
+            "model through the dense cache"
+            % (mechanism, _DENSE_ONLY[kinds[0]], kinds[0]))
 
 
 def _learned_pos(cfg):
@@ -158,6 +212,53 @@ def _learned_pos(cfg):
 def _mamba_state(cfg, batch):
     return ssm.init_state(cfg.ssm_expand * cfg.d_model, cfg.ssm_state,
                           cfg.ssm_conv, batch, cfg.dtype)
+
+
+def _kda_sizes(cfg):
+    """(heads, head size) of a KDA mixer."""
+    return (cfg.kda_heads or cfg.n_heads,
+            cfg.kda_head_dim or cfg.d_model // cfg.n_heads)
+
+
+def _kda_state(cfg, batch):
+    return kda.init_state(*_kda_sizes(cfg), cfg.kda_conv, batch, cfg.dtype)
+
+
+def _mla_sizes(cfg):
+    """(latent rank, a head's up-projected key part, its shared key
+    part, a head's values), checked."""
+    sizes = (cfg.mla_rank, cfg.mla_nope_dim, cfg.mla_rope_dim,
+             cfg.mla_v_dim)
+    if None in sizes:
+        raise ValueError(
+            "an 'mla' layer needs mla_rank, mla_nope_dim, mla_rope_dim "
+            "and mla_v_dim; got %r" % (sizes,))
+    return sizes
+
+
+def _experts(cfg):
+    """(E routed, k a token, first expert held, experts held, their
+    width), checked; None for a model without routed experts."""
+    e = cfg.n_experts
+    if not e:
+        return None
+    k = cfg.experts_per_token or e
+    first, held = cfg.experts_held or (0, e)
+    if cfg.expert_scoring not in ("softmax", "sigmoid") \
+            or not 1 <= k <= e or first < 0 or held < 1 \
+            or first + held > e:
+        raise ValueError(
+            "n_experts=%d with experts_per_token=%r, experts_held=%r, "
+            "expert_scoring=%r: k is in 1..E, the range held lies in "
+            "0..E, scoring is 'softmax' or 'sigmoid'"
+            % (e, cfg.experts_per_token, cfg.experts_held,
+               cfg.expert_scoring))
+    return e, k, first, held, cfg.d_expert or cfg.d_ff
+
+
+def _has_experts(cfg, i):
+    """Whether layer i routes over experts or has the dense FFN."""
+    return bool(cfg.n_experts) and i >= cfg.first_dense_layers
 
 
 def _rope(x, positions, base):
@@ -201,22 +302,38 @@ def param_specs(cfg):
         ("in_proj", 2), ("conv_w", 2), ("conv_b", 1), ("x_proj", 2),
         ("dt_norm", 1), ("b_norm", 1), ("c_norm", 1), ("dt_proj", 2),
         ("dt_bias", 1), ("A_log", 2), ("D", 1), ("out_proj", 2))}
-    ffn = {"ln1": P(None), "ln2": P(None)}
-    if cfg.n_experts:
-        ffn.update({
-            "gate": P(None, None),
-            "w1": P(ep, None, tp), "w2": P(ep, tp, None),
-        })
-    else:
-        ffn.update({"w1": P(None, tp), "w2": P(tp, None)})
-        if cfg.ffn == "gated_silu":
-            ffn["w3"] = P(None, tp)
+    # so are a KDA and a latent-attention mixer
+    kda_mixer = {k: P(*(None,) * n) for k, n in (
+        ("wqkv", 2), ("conv_w", 2), ("f_a", 2), ("f_b", 2), ("dt_bias", 1),
+        ("A_log", 1), ("b_proj", 2), ("g_a", 2), ("g_b", 2), ("o_norm", 1),
+        ("out_proj", 2))}
+    mla = {k: P(*(None,) * n) for k, n in (
+        ("wq", 3), ("wkva", 2), ("kv_norm", 1), ("wkvb", 3), ("wo", 3))}
+    mixers = {"attention": attention, "mamba": mamba, "kda": kda_mixer,
+              "mla": mla}
+    gated = cfg.ffn == "gated_silu"
+    dense = {"w1": P(None, tp), "w2": P(tp, None)}
+    if gated:
+        dense["w3"] = P(None, tp)
+    # routed experts over ep, their width over tp; a shared expert is a
+    # dense FFN every token passes through
+    experts = {"gate": P(None, None),
+               "w1": P(ep, None, tp), "w2": P(ep, tp, None)}
+    if gated:
+        experts["w3"] = P(ep, None, tp)
+    if cfg.expert_scoring == "sigmoid":
+        experts["gate_bias"] = P(None)
+    if cfg.n_shared_experts:
+        experts.update({"ws" + k[1:]: v for k, v in dense.items()})
     out = {
         "embed": P(None, None),
         "ln_f": P(None),
-        "layers": [dict(ffn, **(mamba if kind == "mamba" else attention))
-                   for kind in _layer_kinds(cfg)],
+        "layers": [dict(experts if _has_experts(cfg, i) else dense,
+                        ln1=P(None), ln2=P(None), **mixers[kind])
+                   for i, kind in enumerate(_layer_kinds(cfg))],
     }
+    if not cfg.tied_head:
+        out["head"] = P(None, None)
     if _learned_pos(cfg):
         out["pos"] = P(None, None)
     return out
@@ -253,29 +370,84 @@ def init_params(cfg, seed=0):
             "out_proj": dense(e, cfg.d_model),
         }
 
-    def layer(kind):
-        p = {
-            "ln1": jnp.ones(_norm_shape(cfg), dt),
-            "ln2": jnp.ones(_norm_shape(cfg), dt),
+    def kda_mixer():
+        # the family's initialisation: A = -U(1, 16) a head, a bias that
+        # puts softplus(dt) log-uniform in [1e-3, 1e-1]; the decay's two
+        # leaves stay float32
+        h, dk = _kda_sizes(cfg)
+        dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), h * dk))
+        return {
+            "wqkv": dense(cfg.d_model, 3 * h * dk),
+            "conv_w": dense(cfg.kda_conv, 3 * h * dk),
+            "f_a": dense(cfg.d_model, dk), "f_b": dense(dk, h * dk),
+            "dt_bias": jnp.asarray(dt0 + np.log(-np.expm1(-dt0)),
+                                   jnp.float32),
+            "A_log": jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)),
+                                 jnp.float32),
+            "b_proj": dense(cfg.d_model, h),
+            "g_a": dense(cfg.d_model, dk), "g_b": dense(dk, h * dk),
+            "o_norm": jnp.ones((dk,), dt),
+            "out_proj": dense(h * dk, cfg.d_model),
         }
-        p.update(mamba() if kind == "mamba" else {
+
+    def mla():
+        r, n, e, v = _mla_sizes(cfg)
+        return {
+            "wq": dense(cfg.d_model, cfg.n_heads, n + e),
+            "wkva": dense(cfg.d_model, r + e),
+            "kv_norm": jnp.ones((r,), dt),
+            "wkvb": jnp.asarray(
+                rng.randn(r, cfg.n_heads, n + v) / np.sqrt(r), dt),
+            "wo": dense(cfg.n_heads, v, cfg.d_model),
+        }
+
+    def attention():
+        return {
             "wq": dense(cfg.d_model, cfg.n_heads, hd),
             "wk": dense(cfg.d_model, _kvh(cfg), hd),
             "wv": dense(cfg.d_model, _kvh(cfg), hd),
             "wo": dense(cfg.n_heads, hd, cfg.d_model),
-        })
-        if cfg.n_experts:
-            p["gate"] = dense(cfg.d_model, cfg.n_experts)
-            p["w1"] = jnp.asarray(
-                rng.randn(cfg.n_experts, cfg.d_model, cfg.d_ff) /
-                np.sqrt(cfg.d_model), dt)
-            p["w2"] = jnp.asarray(
-                rng.randn(cfg.n_experts, cfg.d_ff, cfg.d_model) /
-                np.sqrt(cfg.d_ff), dt)
+        }
+
+    mixers = {"attention": attention, "mamba": mamba, "kda": kda_mixer,
+              "mla": mla}
+    gated = cfg.ffn == "gated_silu"
+
+    def experts():
+        _, _, _, held, f = _experts(cfg)
+
+        def stack(fan_in, fan_out):
+            return jnp.asarray(rng.randn(held, fan_in, fan_out)
+                               / np.sqrt(fan_in), dt)
+
+        p = {"gate": dense(cfg.d_model, cfg.n_experts),
+             "w1": stack(cfg.d_model, f), "w2": stack(f, cfg.d_model)}
+        if gated:
+            p["w3"] = stack(cfg.d_model, f)
+        if cfg.expert_scoring == "sigmoid":
+            # the score-correction bias only orders the experts
+            p["gate_bias"] = jnp.asarray(
+                rng.randn(cfg.n_experts) * 0.02, jnp.float32)
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            p["ws1"] = dense(cfg.d_model, fs)
+            p["ws2"] = dense(fs, cfg.d_model)
+            if gated:
+                p["ws3"] = dense(cfg.d_model, fs)
+        return p
+
+    def layer(i, kind):
+        p = {
+            "ln1": jnp.ones(_norm_shape(cfg), dt),
+            "ln2": jnp.ones(_norm_shape(cfg), dt),
+        }
+        p.update(mixers[kind]())
+        if _has_experts(cfg, i):
+            p.update(experts())
         else:
             p["w1"] = dense(cfg.d_model, cfg.d_ff)
             p["w2"] = dense(cfg.d_ff, cfg.d_model)
-            if cfg.ffn == "gated_silu":
+            if gated:
                 p["w3"] = dense(cfg.d_model, cfg.d_ff)
         return p
 
@@ -283,8 +455,12 @@ def init_params(cfg, seed=0):
         "embed": jnp.asarray(rng.randn(cfg.vocab_size, cfg.d_model) * 0.02,
                              dt),
         "ln_f": jnp.ones(_norm_shape(cfg), dt),
-        "layers": [layer(kind) for kind in _layer_kinds(cfg)],
+        "layers": [layer(i, kind)
+                   for i, kind in enumerate(_layer_kinds(cfg))],
     }
+    if not cfg.tied_head:
+        out["head"] = jnp.asarray(
+            rng.randn(cfg.vocab_size, cfg.d_model) * 0.02, dt)
     if _learned_pos(cfg):
         # rope models carry no learned position table — at long-context
         # scale it would be dead HBM (+ momentum + checkpoint bloat)
@@ -298,7 +474,7 @@ def shard_params(params, cfg, mesh):
     (quantize_weights_int8) shard too: the int8 payload takes the
     weight's spec, its scale/dt sidecars replicate (scales are shared
     along the leading axis, which no spec here partitions alone)."""
-    _refuse_recurrent(cfg, "mesh-sharded parameters (shard_params)")
+    _refuse_dense_only(cfg, "mesh-sharded parameters (shard_params)")
     specs = param_specs(cfg)
     if cfg.tp_axis and cfg.tp_axis in mesh.shape:
         tp_size = mesh.shape[cfg.tp_axis]
@@ -321,9 +497,15 @@ def shard_params(params, cfg, mesh):
                         is_leaf=lambda x: isinstance(x, P) or _is_q8(x))
 
 
-def _rms_norm(x, g):
+def _rms_norm(x, g, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * g
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g
+
+
+def _head(params, cfg):
+    """The output projection [vocab, d]: the embedding's transpose, or
+    the model's own "head"."""
+    return params["embed" if cfg.tied_head else "head"]
 
 
 def _qkv(x, p):
@@ -425,26 +607,95 @@ def _attention(x, p, cfg, mesh, manual_sp=False):
     return jnp.einsum("bthk,hkd->btd", o, p["wo"])
 
 
-def _ffn(x, p, cfg):
-    if cfg.ffn not in ("gelu", "gated_silu") \
-            or (cfg.n_experts and cfg.ffn != "gelu"):
-        raise ValueError("ffn=%r: 'gelu', or 'gated_silu' with "
-                         "n_experts=0" % (cfg.ffn,))
-    if cfg.n_experts:
-        # dense top-all dispatch: every token weighted over every expert.
-        # XLA shards the E dim over ep (and d_ff over tp) so each device
-        # computes only its experts' slices; the combine is a psum over ep.
-        gates = jax.nn.softmax(
-            jnp.einsum("btd,de->bte", x, p["gate"]), axis=-1)
-        h = jax.nn.gelu(jnp.einsum("btd,edf->betf", x, p["w1"]))
-        y = jnp.einsum("betf,efd->betd", h, p["w2"])
-        return jnp.einsum("betd,bte->btd", y, gates)
-    h = jnp.einsum("btd,df->btf", x, p["w1"])
+def _mlp(x, w1, w2, w3, cfg):
+    """The dense feed-forward on x [B, T, d], in cfg.ffn's form."""
+    h = jnp.einsum("btd,df->btf", x, w1)
     if cfg.ffn == "gated_silu":
-        h = jax.nn.silu(h) * jnp.einsum("btd,df->btf", x, p["w3"])
+        h = jax.nn.silu(h) * jnp.einsum("btd,df->btf", x, w3)
     else:
         h = jax.nn.gelu(h)
-    return jnp.einsum("btf,fd->btd", h, p["w2"])
+    return jnp.einsum("btf,fd->btd", h, w2)
+
+
+def _ffn(x, p, cfg, loads=None):
+    """A layer's feed-forward on x [B, T, d]: the dense form, or, for a
+    layer with a router ("gate"), its routed experts (_expert_ffn)."""
+    if cfg.ffn not in ("gelu", "gated_silu"):
+        raise ValueError("ffn=%r: 'gelu' or 'gated_silu'" % (cfg.ffn,))
+    if "gate" in p:
+        return _expert_ffn(x, p, cfg, loads)
+    return _mlp(x, p["w1"], p["w2"], p.get("w3"), cfg)
+
+
+# what a decode round counts of its routing, summed over the expert
+# layers: token-expert picks routed, those on experts held here, held
+# experts with at least one token, the busiest held expert's tokens, the
+# experts held, the expert layers. The serving programs return them in
+# this order (moe_stats) and the batcher adds each to the counter
+# moe.<name>
+MOE_STATS = ("picks", "picks_here", "experts_touched", "load_max",
+             "experts_held", "layers")
+
+
+def moe_stats(loads, tokens, cfg):
+    """int32 [len(MOE_STATS)] from the expert layers' per-expert loads
+    (what _expert_ffn appended to `loads`) for `tokens` routed tokens."""
+    load = jnp.stack(loads)                              # [layers, held]
+    return jnp.stack([
+        jnp.int32(tokens * _experts(cfg)[1] * load.shape[0]), jnp.sum(load),
+        jnp.sum(load > 0), jnp.sum(jnp.max(load, axis=1)),
+        jnp.int32(load.size), jnp.int32(load.shape[0])]).astype(jnp.int32)
+
+
+def _expert_ffn(x, p, cfg, loads):
+    """Routed experts on x [B, T, d]: every token is scored over all E
+    experts and picks k of them; the picks that fall on the experts held
+    here are sorted by expert and run as ONE grouped matmul a weight
+    (jax.lax.ragged_dot: each expert sees only its own tokens, no
+    capacity, no dropped token); the picks on experts held elsewhere
+    add nothing here, though they keep their share of the renormalised
+    weights. A shared expert is a dense FFN added for every token. With
+    `loads` a list, the per-expert token counts [held] are appended."""
+    _, k, first, held, _ = _experts(cfg)
+    b, t, d = x.shape
+    rows = x.reshape(b * t, d)
+    with jax.named_scope("mx.moe.route"):
+        logits = jnp.einsum("nd,de->ne", rows, p["gate"],
+                            preferred_element_type=jnp.float32)
+        if cfg.expert_scoring == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+            _, top = jax.lax.top_k(
+                score + p["gate_bias"].astype(jnp.float32), k)
+            w = jnp.take_along_axis(score, top, axis=-1)
+            w = cfg.expert_scale * w / jnp.sum(w, axis=-1, keepdims=True)
+        else:
+            w, top = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        # a pick's group: its expert's place among those held, or one
+        # past them ("elsewhere", sorted last and in no group)
+        here = (top >= first) & (top < first + held)
+        group = jnp.where(here, top - first, held).reshape(-1)
+        order = jnp.argsort(group)
+        sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32),
+                        axis=0)
+        if loads is not None:
+            loads.append(sizes)
+    with jax.named_scope("mx.moe.experts"):
+        picked = rows[order // k]                        # [N * k, d]
+        h = jax.lax.ragged_dot(picked, p["w1"], sizes)
+        if cfg.ffn == "gated_silu":
+            h = jax.nn.silu(h) * jax.lax.ragged_dot(picked, p["w3"], sizes)
+        else:
+            h = jax.nn.gelu(h)
+        y = jax.lax.ragged_dot(h, p["w2"], sizes)
+        # back in pick order; rows in no group hold nothing to read
+        y = jnp.where(here.reshape(-1, 1), y[jnp.argsort(order)], 0)
+        y = jnp.einsum("nkd,nk->nd", y.reshape(b * t, k, d),
+                       jnp.where(here, w, 0.0)).astype(x.dtype)
+    y = y.reshape(b, t, d)
+    if "ws1" in p:
+        with jax.named_scope("mx.moe.shared"):
+            y = y + _mlp(x, p["ws1"], p["ws2"], p.get("ws3"), cfg)
+    return y
 
 
 def _pp_size(cfg, mesh):
@@ -453,29 +704,31 @@ def _pp_size(cfg, mesh):
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(cfg.pp_axis, 1)
 
 
-def _layer(x, p, kind, cfg, mix, state=None):
+def _layer(x, p, kind, cfg, mix, state=None, loads=None):
     """One transformer block, the residual frame every entry point
     runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
     [B, d] for decode's one row. `mix(kind, h, p, state)` is the
     entry point's mixer (_mixer) and returns (y, the layer's new
-    state); returns (x, that state)."""
-    y, state = mix(kind, _rms_norm(x, p["ln1"]), p, state)
+    state); returns (x, that state). `loads`: see _expert_ffn."""
+    y, state = mix(kind, _rms_norm(x, p["ln1"], cfg.norm_eps), p, state)
     x = x + y
-    h = _rms_norm(x, p["ln2"])
+    h = _rms_norm(x, p["ln2"], cfg.norm_eps)
     if h.ndim == 2:
-        return x + _ffn(h[:, None], p, cfg)[:, 0], state
-    return x + _ffn(h, p, cfg), state
+        return x + _ffn(h[:, None], p, cfg, loads)[:, 0], state
+    return x + _ffn(h, p, cfg, loads), state
 
 
-def _mixer(cfg, attend, valid_len=None, from_zero=False):
+def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False):
     """A layer's mix by its KIND, the one place a kind is decided.
-    "attention" is `attend(h, p, state)`, the entry point's form of it.
-    "mamba" keeps a recurrent state {"conv", "ssm"} where an attention
-    layer keeps K/V: the step form for decode's one row [B, d], the
-    sequence form for [B, C, d], which with `valid_len` stops after
-    that many rows (ssm.mixer_seq) and with `from_zero` starts from a
-    zero state whatever it was handed (training, and a prefill at
-    position 0)."""
+    "attention" is `attend(h, p, state)`, the entry point's form of it,
+    and "mla" is `latent(h, p, state)`, its form of latent attention
+    (_latent_attend; an entry point that has none refuses the kind).
+    "mamba" and "kda" keep a recurrent state ({"conv", "ssm"} /
+    {"conv", "kda"}) where an attention layer keeps K/V: the step form
+    for decode's one row [B, d], the sequence form for [B, C, d], which
+    with `valid_len` stops after that many rows (ssm / kda .mixer_seq)
+    and with `from_zero` starts from a zero state whatever it was handed
+    (training, and a prefill at position 0)."""
     def mix(kind, h, p, state):
         if kind == "mamba":
             if h.ndim == 2:
@@ -483,16 +736,24 @@ def _mixer(cfg, attend, valid_len=None, from_zero=False):
             if from_zero:
                 state = _mamba_state(cfg, h.shape[0])
             return ssm.mixer_seq(h, p, state, valid_len)
+        if kind == "kda":
+            if h.ndim == 2:
+                return kda.mixer_step(h, p, state, cfg.norm_eps)
+            if from_zero:
+                state = _kda_state(cfg, h.shape[0])
+            return kda.mixer_seq(h, p, state, valid_len, cfg.norm_eps)
+        if kind == "mla":
+            return latent(h, p, state)
         return attend(h, p, state)
     return mix
 
 
-def _run_layers(x, params, state, cfg, mix):
+def _run_layers(x, params, state, cfg, mix, loads=None):
     """x through every layer, each with its own state; returns (x, the
     new states in layer order)."""
     new_state = []
     for kind, p, layer in zip(_layer_kinds(cfg), params["layers"], state):
-        x, layer = _layer(x, p, kind, cfg, mix, layer)
+        x, layer = _layer(x, p, kind, cfg, mix, layer, loads)
         new_state.append(layer)
     return x, new_state
 
@@ -504,7 +765,7 @@ def forward(params, tokens, cfg, mesh=None):
         x = x + params["pos"][: tokens.shape[1]]
     act = P(cfg.dp_axis, cfg.sp_axis, None)
     if mesh is not None:
-        _refuse_recurrent(cfg, "the mesh-sharded forward (ring "
+        _refuse_dense_only(cfg, "the mesh-sharded forward (ring "
                           "attention, pipeline stages, tp)")
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, act))
     n_stages = _pp_size(cfg, mesh)
@@ -512,7 +773,9 @@ def forward(params, tokens, cfg, mesh=None):
     ring = n_stages > 1 and bool(cfg.use_ring_attention and cfg.sp_axis)
     # self-attention over the fresh K/V: training keeps no state
     mix = _mixer(cfg, lambda h, p, _: (
-        _attention(h, p, cfg, mesh, manual_sp=ring), None), from_zero=True)
+        _attention(h, p, cfg, mesh, manual_sp=ring), None),
+        _latent_attend(cfg, lambda layer, **rows: None,
+                       _latent_self_attention(cfg)), from_zero=True)
     if n_stages > 1:
         # pipeline the homogeneous layer stack over pp: stage-major
         # stacked weights, ppermute microbatch schedule; tp/ep stay auto
@@ -542,8 +805,8 @@ def forward(params, tokens, cfg, mesh=None):
             layer_body = jax.checkpoint(layer_body, static_argnums=(2,))
         for kind, p in zip(_layer_kinds(cfg), params["layers"]):
             x = layer_body(p, x, kind)
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("btd,vd->btv", x, params["embed"])
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return jnp.einsum("btd,vd->btv", x, _head(params, cfg))
 
 
 def loss_fn(params, tokens, cfg, mesh=None):
@@ -574,21 +837,33 @@ def init_cache(cfg, batch):
     keep less than the headline half).
 
     A Mamba layer (cfg.layer_kinds) holds no rows but a fixed-size
-    state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}: batch
-    first like the rows, so whatever moves a lane's rows (the batcher's
-    lane write, beam search's re-gather) moves its state the same way."""
+    state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}, and a KDA
+    layer {"conv": [B, K-1, 3*H*Dk], "kda": [B, H, Dk, Dv] float32}, a
+    matrix a head: batch first like the rows, so whatever moves a lane's
+    rows (the batcher's lane write, beam search's re-gather) moves its
+    state the same way. A latent-attention layer holds rows of ONE
+    latent a position, {"c": [B, max_len, R], "kr": [B, max_len, E]}:
+    the normed K/V latent and the key part all heads share (two leaves
+    because the chip tiles a leaf's last axis by 128: R + E = 576 in one
+    leaf costs two copies of the whole cache a decode round)."""
     if cfg.kv_cache_int8:
-        _refuse_recurrent(cfg, "kv_cache_int8")
-    return [_mamba_state(cfg, batch) if kind == "mamba" else
-            _kv_leaves(cfg, batch, cfg.max_len)
+        _refuse_dense_only(cfg, "kv_cache_int8")
+    states = {"mamba": _mamba_state, "kda": _kda_state}
+    return [states[kind](cfg, batch) if kind in states else
+            _kv_leaves(cfg, batch, cfg.max_len, kind)
             for kind in _layer_kinds(cfg)]
 
 
-def _kv_leaves(cfg, n, t):
+def _kv_leaves(cfg, n, t, kind="attention"):
     """An attention layer's zeroed K/V leaves [n, t, KVH, D]: rows of a
     dense cache (n lanes, t = max_len) or blocks of a paged pool (n
     blocks of t positions). Under kv_cache_int8, int8 codes plus the
-    fp32 scale planes "ks"/"vs" [n, t, KVH]."""
+    fp32 scale planes "ks"/"vs" [n, t, KVH]. An "mla" layer's leaves
+    are its latent rows, "c" [n, t, R] and "kr" [n, t, E]."""
+    if kind == "mla":
+        r, _, e, _ = _mla_sizes(cfg)
+        return {"c": jnp.zeros((n, t, r), cfg.dtype),
+                "kr": jnp.zeros((n, t, e), cfg.dtype)}
     shape = (n, t, _kvh(cfg), cfg.d_model // cfg.n_heads)
     if cfg.kv_cache_int8:
         return {"k": jnp.zeros(shape, jnp.int8),
@@ -612,15 +887,15 @@ def _kv_dequant(q8, scale, dtype):
     return (q8.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def _kv_store(layer, k, v, cfg, put):
-    """The one store of fresh k/v into a layer's leaves, quantizing on
-    the way in under kv_cache_int8 (codes, and their scales into
-    "ks"/"vs"). `put(leaf, arr)` is the state kind's primitive: it
-    returns `leaf` with `arr` written where that kind puts it."""
-    fresh = {"k": k, "v": v}
+def _kv_store(layer, fresh, cfg, put):
+    """The one store of a call's fresh rows (`fresh`: {"k", "v"}, or a
+    latent layer's {"c", "kr"}) into a layer's leaves, quantizing K/V on the
+    way in under kv_cache_int8 (codes, and their scales into "ks"/"vs").
+    `put(leaf, arr)` is the state kind's primitive: it returns `leaf`
+    with `arr` written where that kind puts it."""
     if cfg.kv_cache_int8:
-        kq, ks = _kv_quant(k)
-        vq, vs = _kv_quant(v)
+        kq, ks = _kv_quant(fresh["k"])
+        vq, vs = _kv_quant(fresh["v"])
         fresh = {"k": kq, "ks": ks, "v": vq, "vs": vs}
     return {name: put(layer[name], arr.astype(layer[name].dtype))
             for name, arr in fresh.items()}
@@ -630,27 +905,29 @@ def _kv_store(layer, k, v, cfg, put):
 # state, chosen by what the caller holds (never by a flag): dense rows,
 # or paged blocks behind block tables (further down, with the pool).
 # Each kind is a (store, read) pair for one call's positions `where`
-# and its contraction: store(layer, k, v) writes the fresh k/v there,
-# and read(q, layer, k, v) hands `contract(q, view)` the stored layer
-# as [B, T, KVH, D], position-ordered, so every kind feeds the SAME
-# contractions (_decode_attention, _cached_attention). The third kind,
-# a Mamba layer's recurrent state, is _mixer's.
+# and its contraction: store(layer, k=k, v=v) writes the fresh k/v
+# there, and read(q, layer, k, v) hands `contract(q, view)` the stored
+# layer as [B, T, KVH, D], position-ordered, so every kind feeds the
+# SAME contractions (_decode_attention, _cached_attention). A latent
+# layer's rows are dense rows too, store(layer, c=c, kr=kr), read by its
+# own two contractions (_latent_attend). The recurrent kinds' state is
+# _mixer's.
 
 def _dense_rows(cfg, where, contract):
-    """Dense rows: leaves {"k", "v"[, "ks", "vs"]} [B, Tmax, KVH, ...],
-    a lane a row. `where` is a scalar start (every lane's fresh
-    [B, C, KVH, D] lands on [start, start+C)), [B] (row i's one
-    [KVH, D] at where[i]) or [B, C] (row i's window at where[i, :])."""
-    def store(layer, k, v):
+    """Dense rows: leaves {"k", "v"[, "ks", "vs"]} [B, Tmax, KVH, ...]
+    or a latent layer's {"c", "kr"} [B, Tmax, R | E], a lane a row. `where`
+    is a scalar start (every lane's fresh [B, C, ...] lands on
+    [start, start+C)), [B] (row i's one entry at where[i]) or [B, C]
+    (row i's window at where[i, :])."""
+    def store(layer, **fresh):
         if jnp.ndim(where) == 0:
-            if k.ndim == 3:     # decode's one row is a C = 1 window
-                k, v = k[:, None], v[:, None]
-
             def put(leaf, arr):
+                if arr.ndim < leaf.ndim:    # decode's one row is a
+                    arr = arr[:, None]      # C = 1 window
                 return jax.lax.dynamic_update_slice_in_dim(
                     leaf, arr, where, axis=1)
         elif where.ndim == 1:
-            rows = jnp.arange(k.shape[0])
+            rows = jnp.arange(where.shape[0])
 
             def put(leaf, arr):
                 return leaf.at[rows, where].set(arr)
@@ -659,11 +936,11 @@ def _dense_rows(cfg, where, contract):
             # max_len) are DROPPED by the scatter rather than clamped,
             # so a deep window can never corrupt an earlier,
             # still-attendable cache row
-            rows = jnp.arange(k.shape[0])[:, None]
+            rows = jnp.arange(where.shape[0])[:, None]
 
             def put(leaf, arr):
                 return leaf.at[rows, where].set(arr, mode="drop")
-        return _kv_store(layer, k, v, cfg, put)
+        return _kv_store(layer, fresh, cfg, put)
 
     def read(q, layer, k, v):
         return contract(q, layer)
@@ -718,13 +995,19 @@ def quantize_weights_int8(params):
     consuming matmul, so no full-precision copy is materialized; an
     EAGER decode_step call on a q8 tree dequantizes the whole tree per
     call — serve through the jitted entry points. Idempotent.
-    A tree with Mamba layers is refused: A_log, the step-size bias and
-    the inner norms set a recurrence's decay, and no int8 rule for them
-    has been checked against a reference."""
-    if any("A_log" in layer for layer in params.get("layers", ())):
-        raise ValueError(
-            "quantize_weights_int8 cannot carry a state-space layer's "
-            "parameters (the tree has Mamba layers)")
+    A tree with Mamba or KDA layers is refused: A_log, the step-size
+    bias and the inner norms set a recurrence's decay, and no int8 rule
+    for them has been checked against a reference; so is one with
+    latent-attention layers, whose up-projection decode absorbs into the
+    query."""
+    for leaf, kind, what in (
+            ("D", "mamba", "a state-space layer's"),
+            ("b_proj", "kda", "a linear-attention layer's"),
+            ("wkva", "mla", "a latent-attention layer's")):
+        if any(leaf in layer for layer in params.get("layers", ())):
+            raise ValueError(
+                "quantize_weights_int8 cannot carry %s parameters (the "
+                "tree has %r layers)" % (what, kind))
 
     def q(leaf):
         if _is_q8(leaf):
@@ -769,7 +1052,7 @@ def shard_cache(cache, cfg, mesh):
     replicated — each device holds its heads' full cache and the
     attention needs no cross-device traffic; only wo's output
     contraction all-reduces over tp (GSPMD inserts it)."""
-    _refuse_recurrent(cfg, "a mesh-sharded cache (shard_cache)")
+    _refuse_dense_only(cfg, "a mesh-sharded cache (shard_cache)")
     return jax.tree.map(
         lambda x: jax.device_put(
             x, NamedSharding(mesh, _cache_pspec(cfg, x))), cache)
@@ -874,12 +1157,152 @@ def _cache_attend(cfg, where, store, read):
             # their own position, so decode never re-rotates the cache
             q = _rope(q, where, cfg.rope_base)
             k = _rope(k, where, cfg.rope_base)
-        layer = store(layer, k, v)
+        layer = store(layer, k=k, v=v)
         o = read(q, layer, k, v)
         if h.ndim == 2:
             return jnp.einsum("bhk,hkd->bd", o, p["wo"]), layer
         return jnp.einsum("bchk,hkd->bcd", o, p["wo"]), layer
     return attend
+
+
+# Latent attention ("mla"): q = W_q x [H, N + E]; [c, k_r] = W_kva x;
+# the layer's row is {"c": rms(c) [R], "kr": k_r [E]}, all a position
+# keeps; keys and values are up-projected from it, k = [W_kvb_k c, k_r
+# shared by all heads], v = W_kvb_v c; scores q k^T / sqrt(N + E), no
+# rotation on either side. A chunk contracts through the up-projected
+# heads (_latent_chunk_attention); decode's one row absorbs W_kvb into
+# the query and the output and contracts over the rows directly
+# (_latent_decode_attention): the same sums in another order.
+
+# queries a block of _latent_chunk_attention: a whole bucket's scores
+# against max_len rows ([C, H, T] float32) would not fit beside the model;
+# and stored rows a block: a block of queries contracts only with the
+# blocks of rows that hold a position it may see
+MLA_QUERY_BLOCK = 256
+MLA_KEY_BLOCK = 512
+
+
+def _latent_attend(cfg, store, contract):
+    """_mixer's `latent` for the entry points: project, `store(layer,
+    c=.., kr=..)` the fresh rows, then `contract(q, layer, rows, p)`:
+    the entry point's contraction over the stored rows (or, for training
+    and a prefill at position 0, over the fresh `rows` themselves). h is
+    [B, C, d], or decode's one row [B, d]."""
+    def attend(h, p, layer):
+        r = _mla_sizes(cfg)[0]
+        q = jnp.einsum("...d,dhk->...hk", h, p["wq"])
+        ckr = jnp.einsum("...d,df->...f", h, p["wkva"])
+        rows = {"c": _rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps),
+                "kr": ckr[..., r:]}
+        layer = store(layer, **rows)
+        o = contract(q, layer, rows, p)
+        return jnp.einsum("...hk,hkd->...d", o, p["wo"]), layer
+    return attend
+
+
+def _latent_up(rows, p, cfg):
+    """rows {"c": [B, T, R], "kr": [B, T, E]} -> the heads' keys
+    [B, T, H, N + E] and values [B, T, H, V]."""
+    n = _mla_sizes(cfg)[1]
+    with jax.named_scope("mx.mla.up"):
+        kv = jnp.einsum("btr,rhk->bthk", rows["c"], p["wkvb"])
+        shared = jnp.broadcast_to(
+            rows["kr"][:, :, None, :],
+            kv.shape[:3] + rows["kr"].shape[-1:])
+        return jnp.concatenate([kv[..., :n], shared], axis=-1), kv[..., n:]
+
+
+def _latent_self_attention(cfg):
+    """Causal self-attention over a call's own rows (training, and a
+    prefill at position 0), as _latent_attend's contraction."""
+    def contract(q, layer, rows, p):
+        k, v = _latent_up(rows, p, cfg)
+        return _causal_attention(q, k, v, cfg, q.dtype)
+    return contract
+
+
+def _latent_chunk_attention(q, rows, positions, p, cfg):
+    """q [B, C, H, N + E] against the stored rows ([B, T, ...]), chunk
+    row i attending t <= positions[i] ([C]) or positions[b, i] ([B, C]):
+    the rows are up-projected once and the queries contract through the
+    heads, MLA_QUERY_BLOCK at a time, each block over the rows up to the
+    last position it sees, MLA_KEY_BLOCK at a time with a running
+    maximum and sum (the softmax's sums in blocks). Returns
+    [B, C, H, V]."""
+    k, v = _latent_up(rows, p, cfg)
+    width = min(MLA_KEY_BLOCK, k.shape[1])
+    k, v = (jnp.pad(x, ((0, 0), (0, -x.shape[1] % width), (0, 0), (0, 0)))
+            for x in (k, v))
+    c = q.shape[1]
+    if positions.ndim == 1:
+        positions = positions[None]
+
+    def block(qb, pb):
+        def part(j, carry):
+            top, total, acc = carry
+            kj, vj = (jax.lax.dynamic_slice_in_dim(x, j * width, width, 1)
+                      for x in (k, v))
+            s = jnp.einsum("bqhd,bthd->bhqt", qb, kj,
+                           preferred_element_type=jnp.float32) \
+                / np.sqrt(q.shape[-1])
+            seen = j * width + jnp.arange(width) <= pb[..., None]
+            s = jnp.where(seen[:, None], s, -1e30)
+            new_top = jnp.maximum(top, jnp.max(s, axis=-1))
+            w = jnp.exp(s - new_top[..., None])
+            keep = jnp.exp(top - new_top)
+            return (new_top, keep * total + jnp.sum(w, axis=-1),
+                    keep[..., None] * acc + jnp.einsum(
+                        "bhqt,bthv->bhqv", w.astype(v.dtype), vj,
+                        preferred_element_type=jnp.float32))
+
+        # every row sees position 0, so the first block already gives
+        # each row a real maximum; a block wholly behind a row adds 0
+        lead = (q.shape[0], q.shape[2], qb.shape[1])          # [B, H, Q]
+        _, total, acc = jax.lax.fori_loop(
+            0, jnp.minimum(jnp.max(pb) // width + 1, k.shape[1] // width),
+            part, (jnp.full(lead, -1e30, jnp.float32),
+                   jnp.zeros(lead, jnp.float32),
+                   jnp.zeros(lead + v.shape[-1:], jnp.float32)))
+        return jnp.moveaxis(acc / total[..., None], 1, 2).astype(q.dtype)
+
+    size = MLA_QUERY_BLOCK
+    if c <= size:
+        return block(q, positions)
+    # whole blocks; the padding rows attend nothing that is read
+    pad = -c % size
+    qs = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    ps = jnp.pad(positions, ((0, 0), (0, pad)))
+    out = jax.lax.map(
+        lambda xs: block(*xs),
+        (jnp.moveaxis(qs.reshape(q.shape[0], -1, size, *q.shape[2:]), 1, 0),
+         jnp.moveaxis(ps.reshape(ps.shape[0], -1, size), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(
+        (q.shape[0], -1) + out.shape[3:])[:, :c]
+
+
+def _latent_decode_attention(q, rows, pos, p, cfg):
+    """Decode's one row, absorbed: q [B, H, N + E] against the stored
+    rows ([B, T, ...]), attending t <= pos (scalar or [B]). W_kvb's key
+    half moves into the query ([H, R]) and its value half behind the
+    weighted sum, so the contraction runs over the latents as they lie
+    in the cache and no head's key or value is ever formed. Returns
+    [B, H, V]."""
+    n = _mla_sizes(cfg)[1]
+    c, kr = rows["c"], rows["kr"]
+    with jax.named_scope("mx.mla.absorbed"):
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :n], p["wkvb"][..., :n])
+        s = (jnp.einsum("bhr,btr->bht", q_lat, c,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhe,bte->bht", q[..., n:], kr,
+                          preferred_element_type=jnp.float32)) \
+            / np.sqrt(q.shape[-1])
+        seen = jnp.arange(c.shape[1])[None, :] \
+            <= jnp.atleast_1d(pos)[:, None]
+        a = jax.nn.softmax(jnp.where(seen[:, None, :], s, -1e30), axis=-1)
+        o = jnp.einsum("bht,btr->bhr", a.astype(c.dtype), c,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("bhr,rhv->bhv", o.astype(q.dtype),
+                          p["wkvb"][..., n:])
 
 
 def prefill(params, cache, tokens, cfg):
@@ -914,10 +1337,11 @@ def prefill(params, cache, tokens, cfg):
 
     # position 0: whatever recurrent state the cache held is dropped
     mix = _mixer(cfg, _cache_attend(cfg, jnp.arange(t_p), store, read),
+                 _latent_attend(cfg, store, _latent_self_attention(cfg)),
                  from_zero=True)
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
-    x = _rms_norm(x[:, -1], params["ln_f"])
-    return jnp.einsum("bd,vd->bv", x, params["embed"]), new_cache
+    x = _rms_norm(x[:, -1], params["ln_f"], cfg.norm_eps)
+    return jnp.einsum("bd,vd->bv", x, _head(params, cfg)), new_cache
 
 
 # jitted prefill per config VALUE: generate() is the latency-sensitive
@@ -1034,16 +1458,25 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
                     for name, arr in view.items()}
         return _cached_attention(q, view, positions, cfg, q.dtype)
 
-    mix = _mixer(cfg, _cache_attend(
-        cfg, positions, *_dense_rows(cfg, start, contract)),
-        valid_len=None if logits_row is None else logits_row + 1)
+    def latent(q, layer, rows, p):
+        # the bucket's padding behind logits_row sees what that row sees
+        # and no further: its blocks of queries stop there too
+        return _latent_chunk_attention(
+            q, {name: arr[:, :attend_limit] for name, arr in layer.items()},
+            positions if logits_row is None
+            else jnp.minimum(positions, start + logits_row), p, cfg)
+
+    store, read = _dense_rows(cfg, start, contract)
+    mix = _mixer(cfg, _cache_attend(cfg, positions, store, read),
+                 _latent_attend(cfg, store, latent),
+                 valid_len=None if logits_row is None else logits_row + 1)
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
-    x = _rms_norm(x, params["ln_f"])
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     if logits_row is not None:
         xr = jax.lax.dynamic_index_in_dim(x, logits_row, 1,
                                           keepdims=False)
-        return jnp.einsum("bd,vd->bv", xr, params["embed"]), new_cache
-    return jnp.einsum("bcd,vd->bcv", x, params["embed"]), new_cache
+        return jnp.einsum("bd,vd->bv", xr, _head(params, cfg)), new_cache
+    return jnp.einsum("bcd,vd->bcv", x, _head(params, cfg)), new_cache
 
 
 def _spec_core(params, draft_params, prompt, cfg, dcfg, k, n_new):
@@ -1147,7 +1580,7 @@ def speculative_generate(params, draft_params, prompt, n_new, cfg,
     if prompt.shape[0] != 1:
         raise ValueError("speculative decoding serves batch=1")
     for c in (cfg, draft_cfg):
-        _refuse_recurrent(c, "speculative decoding (a rejected draft "
+        _refuse_dense_only(c, "speculative decoding (a rejected draft "
                           "cannot be rolled back)")
     if cfg.vocab_size != draft_cfg.vocab_size:
         raise ValueError("draft and target must share the vocab")
@@ -1194,10 +1627,12 @@ def decode_step(params, cache, tokens, pos, cfg):
     return _decode(params, cache, None, tokens, pos, cfg)
 
 
-def _decode(params, state, tables, tokens, pos, cfg):
+def _decode(params, state, tables, tokens, pos, cfg, loads=None):
     """decode_step on either kind of K/V state: dense rows (`tables`
     None; pos a scalar or [B]) or a block pool behind `tables` (pos
-    [B]). Both read through _decode_attention, the T_q = 1 row form."""
+    [B]). Both read through _decode_attention, the T_q = 1 row form;
+    a latent layer's dense rows through _latent_decode_attention.
+    `loads`: see _expert_ffn."""
     params = _maybe_dequantize(params)
     x = params["embed"][tokens]
     if _learned_pos(cfg):
@@ -1206,12 +1641,15 @@ def _decode(params, state, tables, tokens, pos, cfg):
         else:
             x = x + jax.lax.dynamic_index_in_dim(
                 params["pos"], pos, 0, keepdims=False)
-    mix = _mixer(cfg, _cache_attend(cfg, pos, *_kv_state(
+    store, read = _kv_state(
         cfg, tables, pos,
-        lambda q, view: _decode_attention(q, view, pos, cfg))))
-    x, new_state = _run_layers(x, params, state, cfg, mix)
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bd,vd->bv", x, params["embed"]), new_state
+        lambda q, view: _decode_attention(q, view, pos, cfg))
+    mix = _mixer(cfg, _cache_attend(cfg, pos, store, read), _latent_attend(
+        cfg, store, lambda q, layer, rows, p: _latent_decode_attention(
+            q, layer, pos, p, cfg)))
+    x, new_state = _run_layers(x, params, state, cfg, mix, loads)
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return jnp.einsum("bd,vd->bv", x, _head(params, cfg)), new_state
 
 
 # ------------------------------------------------------- paged decode ---
@@ -1240,7 +1678,7 @@ def init_paged_cache(cfg, num_blocks, block_size):
     kv_cache_int8 the per-(position, head) fp32 scale planes split the
     same way ([num_blocks, block_size, KVH]), so a block carries its
     own scales and int8-KV composes per block."""
-    _refuse_recurrent(cfg, "the paged KV pool (init_paged_cache)")
+    _refuse_dense_only(cfg, "the paged KV pool (init_paged_cache)")
     if num_blocks < 2:
         raise ValueError("need >= 2 blocks (block 0 is the null block)")
     return [_kv_leaves(cfg, num_blocks, block_size)
@@ -1298,7 +1736,7 @@ def _paged_blocks(cfg, tables, where, contract):
     [B, max_len // bs]. `where` is [B] (row i's one k/v at where[i]) or
     [B, C] (row i's window at where[i, :]); a position goes to block
     tables[i, position // bs] at offset position % bs."""
-    def store(layer, k, v):
+    def store(layer, **fresh):
         bs = layer["k"].shape[1]
         if where.ndim == 1:
             # a position past the table (a retired lane coasting to its
@@ -1325,7 +1763,7 @@ def _paged_blocks(cfg, tables, where, contract):
         def put(leaf, arr):
             return leaf.at[blk, off].set(arr)
 
-        return _kv_store(layer, k, v, cfg, put)
+        return _kv_store(layer, fresh, cfg, put)
 
     def read(q, layer, k, v):
         if not _paged_pallas_requested():
@@ -1371,16 +1809,17 @@ def decode_step_paged(params, pool, tables, tokens, pos, cfg):
     contraction reads each once per group), int8-KV (codes + per-block
     scales gathered together, the one shared _int8_cache_attention
     does the rest), quantized weight trees."""
-    _refuse_recurrent(cfg, "paged decode (decode_step_paged)")
+    _refuse_dense_only(cfg, "paged decode (decode_step_paged)")
     return _decode(params, pool, tables, tokens, pos, cfg)
 
 
-def _decode_step_on(params, state, tables, tokens, pos, cfg):
+def _decode_step_on(params, state, tables, tokens, pos, cfg, loads=None):
     """decode_step on whichever K/V state a scheduler holds, through
-    that kind's own door: `tables` None is the dense cache."""
-    if tables is None:
-        return decode_step(params, state, tokens, pos, cfg)
-    return decode_step_paged(params, state, tables, tokens, pos, cfg)
+    that kind's own door: `tables` None is the dense cache. `loads`:
+    see _expert_ffn."""
+    if tables is not None:
+        _refuse_dense_only(cfg, "paged decode (decode_step_paged)")
+    return _decode(params, state, tables, tokens, pos, cfg, loads)
 
 
 # ------------------------------------------------------ batched verify ---
@@ -1407,7 +1846,7 @@ def verify_chunk(params, cache, tokens, pos, cfg):
     any row can attend it. Windows that run past max_len (a parked
     lane, a near-budget lane coasting) DROP their writes instead of
     clamping. Returns (logits [B, C, vocab], cache)."""
-    _refuse_recurrent(cfg, "speculative verification (verify_chunk: a "
+    _refuse_dense_only(cfg, "speculative verification (verify_chunk: a "
                       "rejected draft cannot be rolled back)")
     return _verify(params, cache, None, tokens, pos, cfg)
 
@@ -1428,8 +1867,8 @@ def _verify(params, state, tables, tokens, pos, cfg):
         lambda q, view: _cached_attention(q, view, positions, cfg,
                                           q.dtype))))
     x, new_state = _run_layers(x, params, state, cfg, mix)
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum("bcd,vd->bcv", x, params["embed"]), new_state
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return jnp.einsum("bcd,vd->bcv", x, _head(params, cfg)), new_state
 
 
 def verify_chunk_paged(params, pool, tables, tokens, pos, cfg):
@@ -1442,7 +1881,7 @@ def verify_chunk_paged(params, pool, tables, tokens, pos, cfg):
     (including the speculative over-reserve and release-on-reject) is
     the host scheduler's job.
     Returns (logits [B, C, vocab], pool)."""
-    _refuse_recurrent(cfg, "paged speculative verification "
+    _refuse_dense_only(cfg, "paged speculative verification "
                       "(verify_chunk_paged)")
     return _verify(params, pool, tables, tokens, pos, cfg)
 

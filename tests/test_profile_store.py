@@ -195,6 +195,10 @@ def test_record_run_spans(store, monkeypatch):
     monkeypatch.setenv("MXNET_OBS", "1")
     core.set_enabled(True)
     core.reset()
+    # programs an earlier test of this process registered for attribution
+    # are scopes too: the one span recorded here is to be the only record
+    from mxnet_tpu.observability import attribution
+    attribution.reset()
     try:
         t0 = time.perf_counter_ns()
         core.record_span("phase.step", "phase", t0, t0 + 4_000_000)
