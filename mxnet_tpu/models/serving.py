@@ -32,19 +32,20 @@ Design notes (all static-shape, XLA-friendly):
   its history again. Mechanisms that cannot carry it or latent rows
   (paged blocks, speculative rollback, int8 KV) are refused at
   construction.
-* ROUTED EXPERTS (cfg.n_experts): the unpipelined decode programs
-  return, beside their tokens, the round's routing counts
+* ROUTED EXPERTS (cfg.n_experts): the decode programs, pipelined or
+  not, return beside their tokens the dispatch's routing counts
   (tf.MOE_STATS), which step() adds to the counters moe.<name> while
-  spans record.
+  spans record (the speculative programs count nothing).
 * Idle slots keep lanes busy writing at position 0 of retired rows;
   the next admission's prefill overwrites them. Throughput is
   proportional to active lanes, latency to the slowest active row —
   exactly the continuous-batching trade.
-* Chunk PIPELINING (pipeline_depth >= 2): the decode carry — cache,
-  per-lane tokens/positions, sample keys — stays device-resident, so
-  chunk k+1 dispatches against chunk k's output buffers before anyone
-  syncs chunk k's emissions, and the per-chunk host sync amortizes
-  over `depth` chunks. Admission/eviction are jitted lane
+* Chunk PIPELINING (pipeline_depth >= 2; the default is 2): the
+  decode carry — cache, per-lane tokens/positions, sample keys — stays
+  device-resident, so chunk k+1 dispatches against chunk k's output
+  buffers before anyone syncs chunk k's emissions: the host's share of
+  a round (fetch, retire loop, bookkeeping) runs while the device works
+  on the next one. Admission/eviction are jitted lane
   patches sequenced after the in-flight chunks; emissions are credited
   by dispatch-time lane identity, which is what keeps every stream
   bit-identical to the synchronous pool and to solo generate().
@@ -230,14 +231,18 @@ def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k,
     donated on accelerators (tok/pos/keys included — they are dead the
     moment the next chunk is built from them); tables pass through
     unchanged (allocation patches apply between dispatches,
-    host-side)."""
+    host-side). A model with routed experts returns the chunk's summed
+    routing counts as one more output, as the sync-mode chunk does."""
     controls = (greedy, temperature, top_k, top_p)
 
     def build(fz):
         def chunk(params, cache, tables, tok, pos, keys):
-            (cache, tok, pos, keys), (toks, _) = _scan_steps(
+            (cache, tok, pos, keys), (toks, routing) = _scan_steps(
                 fz, controls, k, params, cache, tables, tok, pos, keys)
-            return toks, cache, tables, tok, pos, keys   # toks [k, B]
+            out = toks, cache, tables, tok, pos, keys    # toks [k, B]
+            if routing is None:
+                return out
+            return out + (jnp.sum(routing, axis=0),)
         return jax.jit(chunk,
                        donate_argnums=tf._serving_donate(1, 2, 3, 4, 5))
     return tf._serving_jit(
@@ -812,19 +817,24 @@ class ContinuousBatcher(object):
     cached prefix prefill only the suffix. LRU-bounded
     (prefix_cache_slots row caches on device).
 
-    `pipeline_depth=d` (d >= 2) turns on CHUNK PIPELINING: up to d
-    chunk dispatches ride in flight against the device-resident carry
+    `pipeline_depth=d` (default 2) is CHUNK PIPELINING: up to d chunk
+    dispatches ride in flight against the device-resident carry
     (cache, lane tokens/positions, sample keys), and each step() syncs
-    only the OLDEST chunk's emissions — so the per-step host round
-    trip amortizes over d chunks instead of gating every one.
-    Admissions and evictions become tiny jitted lane patches applied
-    to the carry between dispatches (bounded staleness: a request
-    admitted while chunks are in flight enters at the NEXT dispatch
-    boundary; chunks already in flight keep advancing its lane's
-    previous occupant, whose emissions are discarded by request
-    identity at sync). Token streams are bit-identical to
-    pipeline_depth=1 and to solo generate() (tested). depth=1 is the
-    synchronous batcher, unchanged.
+    only the OLDEST chunk's emissions — so the next round is on the
+    device before the last one's tokens are read, and the host's share
+    of a round no longer leaves the device idle (on the chip 3-4 ms
+    of every round at depth 1; depth 3 served no more tokens/s than 2
+    and a worse tail: docs/SERVING.md). Admissions and evictions become tiny jitted lane
+    patches applied to the carry between dispatches (bounded
+    staleness: a token is returned one step() after the one that
+    dispatched its chunk; a request admitted while chunks are in
+    flight enters at the NEXT dispatch boundary; chunks already in
+    flight keep advancing its lane's previous occupant, whose
+    emissions are discarded by request identity at sync). Token
+    streams are bit-identical to pipeline_depth=1 and to solo
+    generate() (tested). `pipeline_depth=1` is the synchronous
+    batcher, for a caller who needs a round's tokens in the step()
+    that computed them.
 
     `paged=True` (default: MXNET_KV_PAGED) virtualizes the cache into
     fixed-size blocks (`block_size`, default MXNET_KV_BLOCK_SIZE=16):
@@ -868,7 +878,7 @@ class ContinuousBatcher(object):
 
     def __init__(self, params, cfg, max_batch=8, greedy=None,
                  temperature=1.0, top_k=None, top_p=None,
-                 chunk_size=1, prefix_cache_slots=4, pipeline_depth=1,
+                 chunk_size=1, prefix_cache_slots=4, pipeline_depth=2,
                  paged=None, block_size=None, num_blocks=None,
                  name=None, spec_k=None, spec_ngram=None,
                  spec_accept_floor=None, draft_params=None,
@@ -1044,8 +1054,9 @@ class ContinuousBatcher(object):
             self._dev_pos = jnp.zeros((self.max_batch,), jnp.int32)
             self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
             # in-flight dispatches, oldest first: (emissions [k, B],
-            # per-lane rid snapshot at dispatch time) — speculative
-            # records carry (targets, emits, rids, keff) instead
+            # per-lane rid snapshot at dispatch time, the chunk's
+            # routing counts or None) — speculative records carry
+            # (targets, emits, rids, keff) instead
             self._inflight = deque()
             # resolved once — a pipelined dispatch must not pay the
             # _serving_jit registry lookup per chunk
@@ -2194,10 +2205,10 @@ class ContinuousBatcher(object):
         remaining in-chunk tokens are discarded and its slot frees at
         the chunk boundary.
 
-        With pipeline_depth > 1 each step() keeps up to depth chunk
-        dispatches in flight and syncs only the oldest one — same
-        return contract, tokens arrive one dispatch later (bounded
-        staleness; see the class docstring).
+        With pipeline_depth > 1 (the default is 2) each step() keeps up
+        to depth chunk dispatches in flight and syncs only the oldest
+        one — same return contract, tokens arrive one dispatch later
+        (bounded staleness; see the class docstring).
 
         With spec_k set each dispatch is a speculative draft/verify
         round (up to chunk_size * (spec_k + 1) tokens per lane per
@@ -2262,10 +2273,7 @@ class ContinuousBatcher(object):
                                mode="sync"):
                     toks = np.asarray(toks)
                 if routing and _obs.active():
-                    # a model with routed experts: the round's counts
-                    for name, n in zip(tf.MOE_STATS,
-                                       np.asarray(routing[0])):
-                        _obs.counter("moe." + name).add(int(n))
+                    self._count_routing(routing[0])
                 toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
                 if self.paged:
                     self._pool = state
@@ -2276,7 +2284,7 @@ class ContinuousBatcher(object):
             self._end_round()
             return finished
         self._dispatch_failures = 0
-        self.dispatch_count += 1
+        self._count_dispatch(ahead=False)
         t_sync = time.perf_counter_ns() if obs_on else None
         # np.array (copy): asarray would give a READ-ONLY view of the
         # device buffer and the next admit()'s in-place key write fails
@@ -2313,6 +2321,25 @@ class ContinuousBatcher(object):
             self._publish_occupancy()
         self._end_round()
         return finished
+
+    def _count_dispatch(self, ahead):
+        """One more target-model dispatch. While spans record, also the
+        counters serving.dispatches and serving.dispatch_ahead: the
+        dispatches issued while an older one was still unsynced, i.e.
+        with the device already fed — every pipelined dispatch but the
+        first after a drained window, none at depth 1."""
+        self.dispatch_count += 1
+        if _obs.active():
+            _obs.counter("serving.dispatches").add(1)
+            if ahead:
+                _obs.counter("serving.dispatch_ahead").add(1)
+
+    @staticmethod
+    def _count_routing(routing):
+        """A dispatch's routing counts (a model with routed experts)
+        into the counters moe.<name>."""
+        for name, n in zip(tf.MOE_STATS, np.asarray(routing)):
+            _obs.counter("moe." + name).add(int(n))
 
     def _end_round(self):
         """Per-scheduling-round epilogue shared by every step path:
@@ -2400,13 +2427,14 @@ class ContinuousBatcher(object):
             if self.paged and _attr.ops_enabled():
                 self._register_dispatch("pipeline", self._pipe_fn,
                                         args)
-            toks, state, tables, tok, pos, keys = self._pipe_fn(*args)
+            toks, state, tables, tok, pos, keys, *routing = \
+                self._pipe_fn(*args)
             if self.paged:
                 self._pool, self._tables = state, tables
             else:
                 self._cache = state
         self._dispatch_failures = 0
-        self.dispatch_count += 1
+        self._count_dispatch(ahead=bool(self._inflight))
         if self.paged:
             # every lane's device position advances k per chunk —
             # mirror it so the NEXT dispatch's coverage is exact
@@ -2414,7 +2442,8 @@ class ContinuousBatcher(object):
         self._dev_tok, self._dev_pos, self._dev_keys = tok, pos, keys
         self._inflight.append(
             (toks, [r.rid if r is not None else None
-                    for r in self._slots]))
+                    for r in self._slots],
+             routing[0] if routing else None))
         if _obs.enabled():
             _obs.gauge("serving.inflight_depth").set(
                 len(self._inflight))
@@ -2426,10 +2455,17 @@ class ContinuousBatcher(object):
         DISPATCHED (and still do): evicted or re-admitted lanes are
         discarded, a request ending mid-chunk keeps only its prefix.
         This is the only host-blocking point of the pipelined loop."""
-        toks_dev, lanes = self._inflight.popleft()
+        toks_dev, lanes, routing = self._inflight.popleft()
+        counting = routing is not None and _obs.active()
+        if counting:
+            # a model with routed experts: the chunk's counts ride the
+            # tokens' fetch, no round trip of their own
+            routing.copy_to_host_async()
         with _obs.span("serving.sync", cat="serving",
                        behind=len(self._inflight)):
             toks = np.asarray(toks_dev).astype(np.int32)     # [k, B]
+        if counting:
+            self._count_routing(routing)
         obs_on = _obs.enabled()
         t_sync = time.perf_counter_ns() if obs_on else None
         finished = {}
@@ -2566,7 +2602,7 @@ class ContinuousBatcher(object):
                     self._spec_fn(*args)
                 self._cache, self._dcache = cache, dcache
         self._dispatch_failures = 0
-        self.dispatch_count += 1
+        self._count_dispatch(ahead=bool(self._inflight))
         if self.paged:
             # worst-case position mirror so the NEXT dispatch's
             # coverage is sufficient whatever this one accepts; the

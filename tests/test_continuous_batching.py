@@ -29,6 +29,12 @@ def _prompts(rng, n, vocab=211):
             for _ in range(n)]
 
 
+# the batcher as a user gets it (two rounds in flight) and the
+# synchronous loop, which stays an argument: both keep every case
+both_loops = pytest.mark.parametrize(
+    "loop", [{}, {"pipeline_depth": 1}], ids=["default", "depth1"])
+
+
 def test_ragged_decode_matches_scalar():
     """decode_step with an all-equal pos vector == scalar pos."""
     cfg = _cfg()
@@ -82,7 +88,8 @@ def test_bucket():
     assert [_bucket(n) for n in (1, 8, 9, 16, 17)] == [8, 8, 16, 16, 32]
 
 
-def test_batcher_matches_generate():
+@both_loops
+def test_batcher_matches_generate(loop):
     """Mixed-length requests served through the shared pool emit
     exactly generate()'s greedy tokens for each request alone."""
     cfg = _cfg()
@@ -90,7 +97,7 @@ def test_batcher_matches_generate():
     rng = np.random.RandomState(1)
     jobs = [(p, int(rng.randint(1, 10)))
             for p in _prompts(rng, 6)]
-    srv = ContinuousBatcher(params, cfg, max_batch=3)
+    srv = ContinuousBatcher(params, cfg, max_batch=3, **loop)
     results, order = srv.run(jobs)
     assert len(results) == len(jobs) and len(order) == len(jobs)
     # admission is FIFO, so rid i corresponds to jobs[i]
@@ -103,13 +110,14 @@ def test_batcher_matches_generate():
                     % (rid, len(prompt), n_new))
 
 
-def test_batcher_slot_reuse_no_contamination():
+@both_loops
+def test_batcher_slot_reuse_no_contamination(loop):
     """A slot retired and re-admitted must not leak the previous
     occupant's cache: serve two waves through ONE slot."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=5)
     rng = np.random.RandomState(2)
-    srv = ContinuousBatcher(params, cfg, max_batch=1)
+    srv = ContinuousBatcher(params, cfg, max_batch=1, **loop)
     for prompt in _prompts(rng, 3):
         rid = srv.admit(prompt, 6)
         assert rid is not None
@@ -123,14 +131,15 @@ def test_batcher_slot_reuse_no_contamination():
                                       np.asarray(want[0]))
 
 
-def test_batcher_mid_stream_admission():
+@both_loops
+def test_batcher_mid_stream_admission(loop):
     """Admitting while another request is mid-decode leaves the running
     request's stream untouched."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=7)
     rng = np.random.RandomState(3)
     p1, p2 = _prompts(rng, 2)
-    srv = ContinuousBatcher(params, cfg, max_batch=2)
+    srv = ContinuousBatcher(params, cfg, max_batch=2, **loop)
     r1 = srv.admit(p1, 8)
     done = {}
     done.update(srv.step())
@@ -159,7 +168,8 @@ def test_batcher_int8_weights():
                                   np.asarray(want[0]))
 
 
-def test_batcher_sampling_matches_generate():
+@both_loops
+def test_batcher_sampling_matches_generate(loop):
     """Pool-level temperature/top-k sampling with per-request seeds:
     each request's stream equals its solo generate(seed=...) run —
     slot placement and pool mix must not perturb the key chain."""
@@ -169,7 +179,7 @@ def test_batcher_sampling_matches_generate():
     jobs = [(p, int(rng.randint(2, 8)), 100 + i)
             for i, p in enumerate(_prompts(rng, 5))]
     srv = ContinuousBatcher(params, cfg, max_batch=2,
-                            temperature=0.8, top_k=20)
+                            temperature=0.8, top_k=20, **loop)
     results, order = srv.run(jobs)
     for rid, (prompt, n_new, seed) in zip(order, jobs):
         want = tf.generate(params, jnp.asarray([prompt], jnp.int32),
@@ -223,7 +233,8 @@ def test_admit_validation():
         srv.admit(list(range(1, 60)), 30)    # exceeds max_len
 
 
-def test_cancel_mid_decode_frees_slot_without_perturbing_others():
+@both_loops
+def test_cancel_mid_decode_frees_slot_without_perturbing_others(loop):
     """Evict one request mid-decode: its slot frees for the next
     admission and the surviving lane's stream stays exactly
     generate()'s."""
@@ -231,7 +242,7 @@ def test_cancel_mid_decode_frees_slot_without_perturbing_others():
     params = tf.init_params(cfg, seed=21)
     rng = np.random.RandomState(7)
     p1, p2, p3 = _prompts(rng, 3)
-    srv = ContinuousBatcher(params, cfg, max_batch=2)
+    srv = ContinuousBatcher(params, cfg, max_batch=2, **loop)
     r1 = srv.admit(p1, 12)
     r2 = srv.admit(p2, 12)
     assert not srv.has_capacity
@@ -295,7 +306,8 @@ def test_decode_to_max_len_boundary():
                                       np.asarray(want[0]))
 
 
-def test_churn_fuzz_admit_cancel_step():
+@both_loops
+def test_churn_fuzz_admit_cancel_step(loop):
     """Randomized churn: interleaved admits, cancels, and steps over a
     seeded schedule. Every COMPLETED stream must equal its solo
     generate() run; every canceled stream must be a prefix of its solo
@@ -303,7 +315,7 @@ def test_churn_fuzz_admit_cancel_step():
     cfg = _cfg(max_len=48)
     params = tf.init_params(cfg, seed=27)
     rng = np.random.RandomState(10)
-    srv = ContinuousBatcher(params, cfg, max_batch=3)
+    srv = ContinuousBatcher(params, cfg, max_batch=3, **loop)
     spec = {}              # rid -> (prompt, n_new)
     done, canceled = {}, {}
     pending = [(list(rng.randint(1, 211, rng.randint(3, 20))),
@@ -366,7 +378,8 @@ def test_stream_yields_run_streams_incrementally():
         np.testing.assert_array_equal(got[rid], want[rid][len(prompt):])
 
 
-def test_stop_token_ends_request_early():
+@both_loops
+def test_stop_token_ends_request_early(loop):
     """A request whose stream hits its stop token finishes early (stop
     token included), freeing the slot; its output equals the solo
     generate() prefix through the stop token."""
@@ -382,7 +395,7 @@ def test_stop_token_ends_request_early():
         stop = int(generated[2])             # pick an earlier unique one
     cut = next(i for i, t in enumerate(generated) if int(t) == stop)
 
-    srv = ContinuousBatcher(params, cfg, max_batch=1)
+    srv = ContinuousBatcher(params, cfg, max_batch=1, **loop)
     results, order = srv.run([(prompt, 10, 0, stop)])
     out = results[order[0]]
     np.testing.assert_array_equal(out, solo[:len(prompt) + cut + 1])
@@ -417,8 +430,9 @@ def test_stream_emits_terminal_event_for_cancel():
     assert srv.active_count == 0
 
 
+@both_loops
 @pytest.mark.parametrize("chunk", [2, 4, 7])
-def test_chunked_pool_matches_generate(chunk):
+def test_chunked_pool_matches_generate(chunk, loop):
     """Multi-step scheduling (chunk_size=k) emits exactly the same
     per-request greedy streams as chunk_size=1 and as solo generate(),
     including requests whose budget or stop token lands mid-chunk."""
@@ -426,7 +440,8 @@ def test_chunked_pool_matches_generate(chunk):
     params = tf.init_params(cfg, seed=3)
     rng = np.random.RandomState(7)
     jobs = [(p, int(rng.randint(1, 12))) for p in _prompts(rng, 6)]
-    srv = ContinuousBatcher(params, cfg, max_batch=3, chunk_size=chunk)
+    srv = ContinuousBatcher(params, cfg, max_batch=3, chunk_size=chunk,
+                            **loop)
     results, order = srv.run(jobs)
     assert len(results) == len(jobs)
     for rid, (prompt, n_new) in zip(order, jobs):
@@ -480,13 +495,15 @@ def test_chunked_stop_token_and_stream_events():
     assert results[order[0]][len(prompt):] == want
 
 
-def test_chunked_churn_matches_oracle():
+@both_loops
+def test_chunked_churn_matches_oracle(loop):
     """Randomized admit/cancel/step churn on a chunked pool: every
     completed request still equals its solo generate() prefix."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=13)
     rng = np.random.RandomState(23)
-    srv = ContinuousBatcher(params, cfg, max_batch=3, chunk_size=3)
+    srv = ContinuousBatcher(params, cfg, max_batch=3, chunk_size=3,
+                            **loop)
     jobs = {}
     done = {}
     rid_job = {}
@@ -514,28 +531,31 @@ def test_chunked_churn_matches_oracle():
                                       np.asarray(want[0]))
 
 
-@pytest.mark.parametrize("depth,chunk", [(2, 1), (3, 1), (2, 3)])
-def test_pipelined_matches_sync_and_generate(depth, chunk):
-    """Chunk pipelining (pipeline_depth>1) emits BIT-IDENTICAL greedy
-    streams to the synchronous pool and to solo generate(), across
-    depths and chunk sizes — the depth>1 vs depth=1 identity
-    contract."""
+@pytest.mark.parametrize("kw", [
+    dict(), dict(pipeline_depth=2), dict(pipeline_depth=3),
+    dict(pipeline_depth=2, chunk_size=3)],
+    ids=["default", "depth2", "depth3", "depth2-chunk3"])
+def test_pipelined_matches_sync_and_generate(kw):
+    """Chunk pipelining (pipeline_depth>1; a default-constructed
+    batcher runs it at depth 2) emits BIT-IDENTICAL greedy streams to
+    the synchronous pool and to solo generate(), across depths and
+    chunk sizes — the depth>1 vs depth=1 identity contract."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     rng = np.random.RandomState(1)
     jobs = [(p, int(rng.randint(1, 10))) for p in _prompts(rng, 6)]
     sync, order_s = ContinuousBatcher(
-        params, cfg, max_batch=3, chunk_size=chunk).run(jobs)
-    pipe, order_p = ContinuousBatcher(
-        params, cfg, max_batch=3, chunk_size=chunk,
-        pipeline_depth=depth).run(jobs)
+        params, cfg, max_batch=3, **dict(kw, pipeline_depth=1)).run(jobs)
+    srv = ContinuousBatcher(params, cfg, max_batch=3, **kw)
+    assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
+    assert srv._device_carry
+    pipe, order_p = srv.run(jobs)
     assert len(pipe) == len(jobs)
     for rs, rp, (prompt, n_new) in zip(order_s, order_p, jobs):
         want = tf.generate(params, jnp.asarray([prompt], jnp.int32),
                            n_new, cfg)
         np.testing.assert_array_equal(
-            np.asarray(pipe[rp]), np.asarray(want[0]),
-            err_msg="depth %d chunk %d" % (depth, chunk))
+            np.asarray(pipe[rp]), np.asarray(want[0]), err_msg=str(kw))
         assert sync[rs] == pipe[rp]
 
 
@@ -576,7 +596,7 @@ def test_pipelined_admission_staleness():
     r2 = srv.admit(p2, 5)               # admitted MID-FLIGHT
     # the staleness rule, observable: no chunk already in flight may
     # carry the new request's lane identity
-    assert all(r2 not in lanes for _, lanes in srv._inflight)
+    assert all(r2 not in rec[1] for rec in srv._inflight)
     while r1 not in done or r2 not in done:
         done.update(srv.step())
     for rid, prompt, n in ((r1, p1, 10), (r2, p2, 5)):
@@ -731,7 +751,37 @@ def test_pipelined_obs_spans_and_zero_when_off():
         ContinuousBatcher(params, cfg, pipeline_depth=0)
 
 
-def test_prefix_cache_streams_equal_no_prefix():
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_dispatch_counters_say_how_many_ran_ahead(depth):
+    """While spans record, serving.dispatches counts every chunk
+    dispatch and serving.dispatch_ahead those issued while an older
+    chunk was still unsynced: every one but the first after a drained
+    window when pipelined, none in the synchronous loop."""
+    from mxnet_tpu.observability import core as obs
+    cfg = _cfg()
+    params = tf.init_params(cfg, seed=3)
+    obs.reset()
+    obs.set_enabled(True)
+    try:
+        srv = ContinuousBatcher(params, cfg, max_batch=2,
+                                pipeline_depth=depth)
+        srv.run([([4, 7, 2], 6)])
+        srv.run([([9, 1], 4)])          # the window drained in between
+        ahead = obs.counter("serving.dispatch_ahead").value
+        assert obs.counter("serving.dispatches").value \
+            == srv.dispatch_count
+        if depth == 1:
+            # one dispatch a decode round, and none runs ahead
+            assert (srv.dispatch_count, ahead) == (5 + 3, 0)
+        else:
+            assert ahead == srv.dispatch_count - 2
+    finally:
+        obs.set_enabled(None)
+        obs.reset()
+
+
+@both_loops
+def test_prefix_cache_streams_equal_no_prefix(loop):
     """Shared-prefix admission (suffix-only prefill) emits the same
     streams as the pool without prefix caching and as solo
     generate() — greedy, mixed prefix/non-prefix prompts, slot
@@ -741,7 +791,7 @@ def test_prefix_cache_streams_equal_no_prefix():
     system = [7, 3, 9, 1, 4]                     # the shared preamble
     jobs = [(system + [11, 22], 8), ([5, 6], 6),
             (system + [33], 9), (system, 5)]     # incl. exact match
-    srv = ContinuousBatcher(params, cfg, max_batch=2)
+    srv = ContinuousBatcher(params, cfg, max_batch=2, **loop)
     assert srv.cache_prefix(system) == len(system)
     results, order = srv.run(jobs)
     for rid, (p, n) in zip(order, jobs):

@@ -222,23 +222,28 @@ def test_the_stored_rows_are_attended_in_blocks_up_to_the_last_seen(
 # ------------------------------------------------------------ serving ---
 
 def _alone(params, cfg, prompt, n_new):
-    srv = ContinuousBatcher(params, cfg, max_batch=1)
+    srv = ContinuousBatcher(params, cfg, max_batch=1, pipeline_depth=1)
     got, order = srv.run([(prompt, n_new)])
     return list(got[order[0]])
 
 
-@pytest.mark.parametrize("kw", [{}, {"chunk_size": 4}, {"pipeline_depth": 2}],
-                         ids=["defaults", "chunk4", "depth2"])
+@pytest.mark.parametrize("kw", [
+    {}, {"chunk_size": 4}, {"pipeline_depth": 2}, {"pipeline_depth": 1},
+    {"chunk_size": 4, "pipeline_depth": 1}],
+    ids=["defaults", "chunk4", "depth2", "depth1", "chunk4-depth1"])
 def test_three_staggered_requests_on_two_lanes_equal_each_served_alone(
         sides, kw):
     """The third request waits for a lane and overwrites its previous
     occupant's latent rows, conv windows and matrix states whole; every
-    stream equals the request served alone, and solo generate()."""
+    stream equals the request served alone by the synchronous loop, and
+    solo generate(). The defaults keep two rounds in flight."""
     params, cfg, _ = sides
     rng = np.random.RandomState(9)
     jobs = [(list(rng.randint(1, 256, n)), m)
             for n, m in ((5, 9), (13, 4), (9, 7))]
     srv = ContinuousBatcher(params, cfg, max_batch=2, **kw)
+    assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
+    assert srv._device_carry == (srv.pipeline_depth > 1)
     got, order = srv.run(jobs)
     assert len(got) == 3
     for (prompt, n_new), rid in zip(jobs, order):
@@ -298,9 +303,34 @@ def test_a_decode_round_counts_its_routing(sides, telemetry):
     assert obs.counter("moe.picks").value == c["picks"]
 
 
-def test_a_chunked_round_sums_its_steps_counts(sides, telemetry):
+def test_two_rounds_in_flight_count_the_routing_one_does(sides, telemetry):
+    """The pipelined chunk returns its routing counts as the unpipelined
+    programs do, the in-flight record carries them and they are added
+    when the chunk's tokens are fetched: over the same requests (a full
+    pool, equal budgets, so no lane parks early) every moe.* counter
+    reads what the synchronous loop adds. The chunk still in flight when
+    the last stream ends is dropped unfetched, and uncounted."""
     params, cfg, _ = sides
-    srv = ContinuousBatcher(params, cfg, max_batch=2, chunk_size=4)
+    jobs = [([5, 6, 7, 8, 9], 7), ([1, 2, 3], 7)]
+    read = {}
+    for depth in (1, 2):
+        obs.reset()
+        srv = ContinuousBatcher(params, cfg, max_batch=2,
+                                pipeline_depth=depth)
+        got, order = srv.run(jobs)
+        read[depth] = ({name: obs.counter("moe." + name).value
+                        for name in tf.MOE_STATS},
+                       [list(got[r]) for r in order])
+    assert read[1] == read[2]
+    assert read[1][0]["picks"] == 6 * (2 * 4 * 3)      # six decode rounds
+    assert read[1][0]["experts_touched"] > 0
+
+
+@pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
+                         ids=["default", "depth1"])
+def test_a_chunked_round_sums_its_steps_counts(sides, telemetry, loop):
+    params, cfg, _ = sides
+    srv = ContinuousBatcher(params, cfg, max_batch=2, chunk_size=4, **loop)
     srv.admit([5, 6, 7, 8, 9], 9)
     srv.step()
     assert obs.counter("moe.picks").value == 4 * (2 * 4 * 3)

@@ -37,15 +37,19 @@ def _solo(params, prompt, n, cfg, **kw):
                                   n, cfg, **kw)[0])
 
 
+@pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
+                         ids=["default", "depth1"])
 @pytest.mark.parametrize("paged", [False, True])
-def test_router_streams_bit_exact(paged):
+def test_router_streams_bit_exact(paged, loop):
     """Jobs spread over 2 replicas all emit exactly their solo greedy
-    streams, and the fleet balances (both replicas served work)."""
+    streams, and the fleet balances (both replicas served work):
+    replicas as built by default (two rounds in flight) and replicas
+    on the synchronous loop."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     rng = np.random.RandomState(1)
     jobs = _jobs(rng, 8)
-    kw = dict(paged=True, block_size=8) if paged else {}
+    kw = dict(loop, paged=True, block_size=8) if paged else dict(loop)
     r = ReplicaRouter.build(params, cfg, n_replicas=2, max_batch=2,
                             **kw)
     results, order = r.run(jobs)

@@ -131,7 +131,12 @@ def test_journal_gc_never_truncates_live_segments(tmp_path):
     j.close()
 
 
-def test_journal_off_path_identity(setup, tmp_path):
+both_loops = pytest.mark.parametrize(
+    "loop", [{}, {"pipeline_depth": 1}], ids=["default", "depth1"])
+
+
+@both_loops
+def test_journal_off_path_identity(setup, tmp_path, loop):
     """With the journal attached, every stream's tokens AND the
     dispatch count are bit-identical to a journal-less run."""
     cfg, params = setup
@@ -139,7 +144,7 @@ def test_journal_off_path_identity(setup, tmp_path):
 
     def run(journal):
         srv = ContinuousBatcher(params, cfg, max_batch=2,
-                                journal=journal)
+                                journal=journal, **loop)
         res, order = srv.run(list(jobs))
         return [res[r] for r in order], srv.dispatch_count
 
@@ -154,20 +159,21 @@ def test_journal_off_path_identity(setup, tmp_path):
 # --------------------------------------------------------- recovery --
 
 
+@both_loops
 @pytest.mark.parametrize("greedy", [True, False])
-def test_recover_bit_exact(setup, tmp_path, greedy):
+def test_recover_bit_exact(setup, tmp_path, greedy, loop):
     """Drop the batcher mid-flight (simulated crash: the journal is
     all that survives); a fresh batcher's recover() + stepping yields
     exactly the uninterrupted run's streams — greedy and sampled."""
     cfg, params = setup
     jobs = [([1, 2, 3], 6, 0), ([4, 5], 6, 1), ([7, 8, 9], 6, 2)]
     ref_srv = ContinuousBatcher(params, cfg, max_batch=4,
-                                greedy=greedy, journal=False)
+                                greedy=greedy, journal=False, **loop)
     ref, order = ref_srv.run(list(jobs))
     ref = [ref[r] for r in order]
 
     srv = ContinuousBatcher(params, cfg, max_batch=4, greedy=greedy,
-                            journal=str(tmp_path))
+                            journal=str(tmp_path), **loop)
     for p, n, s in jobs:
         srv.admit(p, n, seed=s)
     srv.step()
@@ -175,7 +181,7 @@ def test_recover_bit_exact(setup, tmp_path, greedy):
     del srv
 
     srv2 = ContinuousBatcher(params, cfg, max_batch=4, greedy=greedy,
-                             journal=str(tmp_path))
+                             journal=str(tmp_path), **loop)
     resumed, done, skipped = srv2.recover()
     assert skipped == []
     assert resumed                     # genuinely mid-flight
@@ -323,14 +329,16 @@ def test_idempotency_window_survives_recovery(setup, tmp_path):
 # --------------------------------------------------------- hot-swap --
 
 
-def test_swap_weights_verified(setup, tmp_path):
+@both_loops
+def test_swap_weights_verified(setup, tmp_path, loop):
     """A manifest-verified swap lands mid-stream without dropping the
     request, and the post-swap fingerprint matches the manifest."""
     cfg, params = setup
     p1 = tf.init_params(cfg, seed=1)
     ckdir = str(tmp_path / "ck")
     ck.save_checkpoint(ckdir, cfg, p1, step=1)
-    srv = ContinuousBatcher(params, cfg, max_batch=2, journal=False)
+    srv = ContinuousBatcher(params, cfg, max_batch=2, journal=False,
+                            **loop)
     rid = srv.admit([1, 2, 3], 8)
     srv.step()
     info = srv.swap_weights(p1, manifest=ckdir)

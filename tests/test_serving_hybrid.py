@@ -39,14 +39,19 @@ def _solo(params, prompt, n_new):
 
 @pytest.mark.parametrize("kw", [
     {}, {"chunk_size": 4}, {"pipeline_depth": 2},
-    {"chunk_size": 2, "pipeline_depth": 2}],
-    ids=["defaults", "chunk4", "depth2", "chunk2-depth2"])
+    {"chunk_size": 2, "pipeline_depth": 2}, {"pipeline_depth": 1},
+    {"chunk_size": 4, "pipeline_depth": 1}],
+    ids=["defaults", "chunk4", "depth2", "chunk2-depth2", "depth1",
+         "chunk4-depth1"])
 def test_streams_equal_solo_generate_with_lane_reuse(params, kw):
     """Seven requests through two lanes: every lane is reused, each
     admission writes a whole row of both kinds of state over the
-    previous occupant's."""
+    previous occupant's. The defaults keep two rounds in flight; the
+    synchronous loop (depth 1) serves the same streams."""
     jobs = _jobs(np.random.RandomState(3), 7)
     srv = ContinuousBatcher(params, CFG, max_batch=2, **kw)
+    assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
+    assert srv._device_carry == (srv.pipeline_depth > 1)
     got, order = srv.run(jobs)
     assert len(got) == len(order) == len(jobs)
     for (prompt, n_new), rid in zip(jobs, order):

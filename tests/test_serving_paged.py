@@ -41,7 +41,8 @@ def _solo(params, prompt, n, cfg, **kw):
 
 @pytest.mark.parametrize("kw", [
     dict(), dict(chunk_size=3), dict(pipeline_depth=2),
-    dict(pipeline_depth=2, chunk_size=3)])
+    dict(pipeline_depth=2, chunk_size=3), dict(pipeline_depth=1),
+    dict(pipeline_depth=1, chunk_size=3)])
 def test_paged_streams_bit_exact(kw):
     """Greedy streams through the paged pool == solo generate(), in
     sync, chunked, and pipelined scheduling — and the pool drains back
@@ -230,7 +231,7 @@ def test_paged_pipelined_staleness_eviction_and_prefix():
     done.update(srv.step())             # window fills to depth 3
     assert len(srv._inflight) > 0
     r2 = srv.admit(p2, 8)               # admitted MID-FLIGHT
-    assert all(r2 not in lanes for _, lanes in srv._inflight)
+    assert all(r2 not in rec[1] for rec in srv._inflight)
     done.update(srv.step())
     partial = srv.cancel(r1)            # evicted MID-FLIGHT
     assert partial is not None

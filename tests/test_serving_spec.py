@@ -381,13 +381,15 @@ def test_spec_validation():
 def test_spec_off_path_silence(paged):
     """spec_k unset => ZERO behavior change: identical streams AND an
     identical dispatch count to the pre-speculation batcher (the
-    counter is the invariant the A/B bench divides by)."""
+    counter is the invariant the A/B bench divides by). One dispatch a
+    step is the synchronous loop's promise, so depth 1 is named."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     jobs = list(zip(_PROMPTS, _N_NEW))
 
     def drive(**kw):
-        srv = ContinuousBatcher(params, cfg, max_batch=2, **kw)
+        srv = ContinuousBatcher(params, cfg, max_batch=2,
+                                pipeline_depth=1, **kw)
         out, order = _run_pool(srv, jobs)
         return srv, out, order
 
@@ -399,7 +401,8 @@ def test_spec_off_path_silence(paged):
     # the paged/dense non-spec batchers run the same one-dispatch-per-
     # step schedule — the counter itself must not care about paging
     srv2 = ContinuousBatcher(params, cfg, max_batch=2, paged=paged,
-                             block_size=8 if paged else None)
+                             block_size=8 if paged else None,
+                             pipeline_depth=1)
     out2, order2 = _run_pool(srv2, jobs)
     assert srv2.dispatch_count == base.dispatch_count
     for rid, (p, n) in zip(order2, jobs):
