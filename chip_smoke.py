@@ -80,49 +80,18 @@ BF16_TOL = 3e-2
 NEAR_TIE_ULPS = 4
 
 
-class CompileClock(object):
-    """Seconds JAX spent compiling, from its own monitoring events.
-    jax times the backend step around compile-or-load-from-cache, so
-    the seconds spent reading and loading cached executables are taken
-    out of compile_s and shown as cache_load_s."""
-
-    BACKEND = "/jax/core/compile/backend_compile_duration"
-    LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
-    FRONT = ("/jax/core/compile/jaxpr_trace_duration",
-             "/jax/core/compile/jaxpr_to_mlir_module_duration")
-
-    def __init__(self):
-        import jax
-        self.backend = self.front = self.load = 0.0
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._secs)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _secs(self, event, secs, **_):
-        if event == self.BACKEND:
-            self.backend += secs
-        elif event == self.LOAD:
-            self.load += secs
-        elif event in self.FRONT:
-            self.front += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.backend, self.front, self.hits, self.misses,
-                self.load)
-
-    def since(self, snap):
-        load = self.load - snap[4]
-        return {"compile_s": round(self.backend - snap[0] - load, 2),
-                "cache_load_s": round(load, 2),
-                "trace_lower_s": round(self.front - snap[1], 2),
-                "cache_hits": self.hits - snap[2],
-                "cache_misses": self.misses - snap[3]}
+def _compile_ledger(since):
+    """What the program's compile ledger holds since `since` (ns on the
+    telemetry epoch): seconds compiling, loading cached executables
+    (jax times compile-or-load together, so they are taken out of
+    compile_s), tracing and lowering, and the cache's hits and misses."""
+    from mxnet_tpu.observability import recompile
+    led = recompile.summary(since=since)
+    return {"compile_s": round(led["compile_s"], 2),
+            "cache_load_s": round(led["cache_load_s"], 2),
+            "trace_lower_s": round(led["trace_s"] + led["lower_s"], 2),
+            "programs": led["programs"],
+            "cache_hits": led["hits"], "cache_misses": led["misses"]}
 
 
 def _device_bytes(devices):
@@ -698,6 +667,7 @@ def main(argv=None):
 
     import jax
     from mxnet_tpu import _native, chip
+    from mxnet_tpu.observability import core as obs
     dev = chip.describe()
     if not args.rehearse and dev["platform"] != "tpu":
         print("chip_smoke: jax found platform %r, not a TPU; nothing "
@@ -709,7 +679,6 @@ def main(argv=None):
               % (args.chips, dev["count"]), file=sys.stderr)
         return 2
     cache_dir = chip.use_compile_cache()
-    clock = CompileClock()
     sizes = TINY if args.rehearse else REAL
     phases = FOUR_CHIPS if args.chips == 4 else ONE_CHIP
     if args.only:
@@ -726,9 +695,9 @@ def main(argv=None):
                                 "alone covers the Pallas kernels"}),
           flush=True)
 
-    t_all, ok, carry = time.time(), True, {}
+    t_all, ok, carry, totals = time.time(), True, {}, {}
     for name, fn in phases:
-        snap, t0 = clock.snapshot(), time.time()
+        t0, since = time.time(), obs.now_ns()
         try:
             rec = fn(sizes, args.rehearse, carry)
             if name == args.break_phase:
@@ -740,7 +709,11 @@ def main(argv=None):
                 type(exc).__name__, str(exc)[:2000])}
         line = {"phase": name, "ok": bool(rec.pop("ok")),
                 "seconds": round(time.time() - t0, 2)}
-        line.update(clock.since(snap))
+        built = _compile_ledger(since)
+        # summed here: the ledger is bounded, and a long run outlives it
+        totals = {k: round(totals.get(k, 0) + v, 2)
+                  for k, v in built.items()}
+        line.update(built)
         line.update(rec)
         # the peak is the process's so far (PJRT keeps no per-phase
         # peak); bytes_in_use is what this phase left behind
@@ -748,7 +721,6 @@ def main(argv=None):
             jax.devices()[:args.chips])
         print(json.dumps(line), flush=True)
         ok = ok and line["ok"]
-    totals = clock.since((0.0, 0.0, 0, 0, 0.0))
     print(json.dumps(dict(phase="total", ok=ok,
                           seconds=round(time.time() - t_all, 2),
                           **totals)), flush=True)

@@ -10,6 +10,9 @@ API surface mirrors the reference (nswamy/incubator-mxnet):
   autograd, optimizer, kvstore, io, metric, initializer, ...
 """
 
+import time as _time
+_import_t0 = _time.perf_counter_ns()     # span startup.import, closed below
+
 __version__ = "0.1.0"
 
 from . import base
@@ -79,3 +82,6 @@ from . import gluon
 from . import rnn
 from . import numpy as np
 from . import numpy_extension as npx
+
+# the package's import, jax's included, as a start-up span
+observability.core.record_startup("startup.import", _import_t0)

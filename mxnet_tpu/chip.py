@@ -14,6 +14,7 @@ is part of the cache key, so a directory that moves never hits).
 
 import collections
 import os
+import time
 
 import jax
 
@@ -37,9 +38,29 @@ MODELLED_KIND = "TPU v5 lite"
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+_asked = False
+
+
+def devices():
+    """``jax.devices()``. The process's first call brings the backend up
+    (on a TPU the runtime's 12-17 s of every run): it runs under the
+    span ``startup.backend``, after the compile ledger's listener is
+    installed, so that every program built on those devices is in it."""
+    global _asked
+    if _asked:
+        return jax.devices()
+    from .observability import core, recompile
+    recompile.install()
+    t0 = time.perf_counter_ns()
+    devs = jax.devices()
+    core.record_startup("startup.backend", t0)
+    _asked = True
+    return devs
+
+
 def describe():
     """{"platform", "kind", "count"} exactly as JAX reports them."""
-    devs = jax.devices()
+    devs = devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
 
@@ -60,7 +81,7 @@ def peaks(device_kind=None):
     """Published peaks of ``device_kind`` (default: the first device of
     this process; :data:`MODELLED_KIND` in a CPU-pinned process)."""
     if device_kind is None:
-        dev = jax.devices()[0]
+        dev = devices()[0]
         device_kind = (MODELLED_KIND if dev.platform == "cpu"
                        else dev.device_kind)
     try:

@@ -10,6 +10,7 @@ import threading
 
 import jax
 
+from . import chip
 from .base import MXNetError
 
 _thread_local = threading.local()
@@ -107,6 +108,7 @@ def _devices_by_platform(platform):
     cpu(0)/tpu(0) means local device 0 (reference semantics: each worker
     sees its own GPUs); the global mesh is the parallel layer's job."""
     try:
+        chip.devices()              # the first query: startup.backend
         if jax.process_count() > 1:
             return [d for d in jax.local_devices()
                     if d.platform == platform]
@@ -122,9 +124,10 @@ def _cpu_pinned():
 
 
 def _accelerators():
+    devs = chip.devices()
     if jax.process_count() > 1:
-        return [d for d in jax.local_devices() if d.platform != "cpu"]
-    return [d for d in jax.devices() if d.platform != "cpu"]
+        devs = jax.local_devices()
+    return [d for d in devs if d.platform != "cpu"]
 
 
 def _default_context():

@@ -351,10 +351,11 @@ class Block(object):
     def __call__(self, *args):
         # step-phase telemetry: ONE "forward" span per outermost block
         # call (children nest inside it, per-layer spans would drown
-        # the ring); depth tracked per thread
+        # the ring); depth tracked per thread. Opened whatever the gates:
+        # a span that is off still charges a cold call to cold_totals()
         depth = getattr(_CALL_DEPTH, "v", 0)
         fwd_span = None
-        if depth == 0 and _obs.active():
+        if depth == 0:
             fwd_span = _obs.span("forward", cat="step",
                                  block=self._name or
                                  type(self).__name__).start()

@@ -885,6 +885,7 @@ class ContinuousBatcher(object):
                  draft_cfg=None, brownout=None, brownout_attain=None,
                  brownout_trip=None, brownout_clear=None,
                  journal=None):
+        t_build = time.perf_counter_ns()     # span startup.batcher
         if cfg.max_len < 8:
             raise ValueError("max_len too small for the bucket floor")
         if chunk_size < 1:
@@ -1198,6 +1199,8 @@ class ContinuousBatcher(object):
                 "serving.%s" % (self.name or "batcher"),
                 self.health_snapshot)
             _timeseries.maybe_start()
+        # cache rows, lane state, the carry's constructors
+        _obs.record_startup("startup.batcher", t_build)
 
     # ---- admission ----
 
@@ -1247,7 +1250,9 @@ class ContinuousBatcher(object):
         """The per-replica routing signals, /healthz-shaped (same names
         a scraper reads off MXNET_OBS_HTTP's /healthz `counters`):
         lane occupancy, paged-pool headroom, rolling SLO attainment,
-        the weight-version fingerprint.
+        the weight-version fingerprint, and under ``"startup"`` what
+        the process has spent building programs so far (the compile
+        ledger's ``recompile.summary()``: always kept).
         models/router.py polls this for in-process replicas; a
         multi-process fleet scrapes the HTTP endpoint instead."""
         active = self.active_count
@@ -1260,6 +1265,7 @@ class ContinuousBatcher(object):
             "serving.slo_attainment": _slo.attainment(),
             "serving.weight_fingerprint": self.weight_fingerprint,
             "serving.weight_version": int(self.weight_fingerprint, 16),
+            "startup": _obs_recompile.summary(),
         }
         if self._journal is not None:
             snap["serving.journal_depth_bytes"] = \

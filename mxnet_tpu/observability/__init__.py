@@ -20,6 +20,13 @@ hot paths (kvstore dispatch, trainer step, io.next) stay within noise
 (``set_state('run')``, N steps, ``set_state('stop')``,
 ``dumps(aggregate=True)``) is in docs/OBSERVABILITY.md.
 
+Start-up keeps its own record whatever the gates say, on cold paths
+only: the compile ledger (``recompile.summary()``: every trace, lowering
+and executable build by jax's program name, with the persistent cache's
+hits, misses and load seconds), the calls inside which it moved
+(``core.cold_totals()``) and the ``startup.*`` spans
+(docs/OBSERVABILITY.md "Start-up").
+
 Instrumented out of the box: Trainer/Module step phases (forward /
 backward / allreduce / update), KVStore push/pull/pushpull_fused
 (per-bucket bytes, dtype lane, dispatch counts, wall time), the io.py
@@ -69,7 +76,7 @@ from .attribution import (ops_enabled, format_ops_table,
                           compare_summaries)
 from .attribution import summary as ops_summary
 from .core import (enabled, active, set_enabled, span, span_totals,
-                   counter, gauge, record_span, record_instant, record_flow, records,
+                   cold_totals, counter, gauge, record_span, record_instant, record_flow, records,
                    counters, dropped, reset)
 from .core import histogram as get_histogram
 from .histogram import Histogram
@@ -100,7 +107,8 @@ __all__ = ["chaos", "core", "dist", "events", "export", "flight",
            "event", "record_incident", "note_exit",
            "watchdog", "ops_enabled", "format_ops_table",
            "compare_summaries", "ops_summary", "enabled", "active",
-           "set_enabled", "span", "span_totals", "counter", "gauge", "get_histogram",
+           "set_enabled", "span", "span_totals", "cold_totals", "counter",
+           "gauge", "get_histogram",
            "Histogram", "record_span", "record_instant", "record_flow",
            "records", "counters", "dropped", "reset",
            "start_http_server", "stop_http_server",

@@ -24,8 +24,11 @@ Design constraints (ISSUE 2 tentpole):
 * near-zero cost when off — every instrumentation site guards on
   ``enabled()``, a module override check + one `_fastenv` dict read
   (~0.1 us); a disabled ``span`` allocates one slotted object, asks both
-  gates (the session check is ~20 ns) and does nothing else. No locks, no
-  time syscalls, no string formatting.
+  gates (the session check is ~20 ns), keeps the compile ledger's
+  ``seq`` and one clock reading, and on ``stop()`` compares ``seq``: only
+  a span inside which something was traced, lowered, compiled or loaded
+  (a COLD call: milliseconds at least) reads the clock again and adds
+  itself to ``cold_totals()``. No locks, no string formatting.
 * thread-safe when on — the prefetch threads (io.py), the main step
   loop and jax.monitoring callbacks all record concurrently; one lock
   guards the ring head and the counter registry, and record payloads
@@ -47,7 +50,8 @@ from jax.profiler import TraceAnnotation as _Annotation
 from .. import _fastenv
 
 __all__ = ["enabled", "active", "set_enabled", "span", "span_totals",
-           "reset_span_totals", "counter", "gauge",
+           "reset_span_totals", "cold_totals", "record_startup",
+           "first_session_ns", "now_ns", "counter", "gauge",
            "histogram", "record_span", "record_instant", "record_flow",
            "records", "counters", "dropped", "reset", "ring_capacity",
            "Counter", "Gauge"]
@@ -69,6 +73,12 @@ _counters = {}
 # name -> [count, total_ns, self_ns, max_ns] of the spans that ran under a
 # profiler session; kept in memory, read by span_totals()
 _totals = {}
+# name -> [count, total_ns] of the spans, on or off, inside which the
+# compile ledger moved (recompile.seq); read by cold_totals()
+_cold = {}
+# when the process's first profiler session was seen live, ns on the
+# epoch: where a benchmark's set-up ends (recompile.summary(before=...))
+_first_session_ns = None
 _local = threading.local()
 
 # a live jax.profiler session, whoever started it (~20 ns)
@@ -107,8 +117,14 @@ def _ensure_ring():
     return _ring
 
 
+def now_ns():
+    """Now, in ns on the records' epoch: what the compile ledger stamps
+    its entries with and ``recompile.summary(since=, before=)`` cuts by."""
+    return time.perf_counter_ns() - _EPOCH_NS
+
+
 def _now_us():
-    return (time.perf_counter_ns() - _EPOCH_NS) // 1000
+    return now_ns() // 1000
 
 
 def _append(rec):
@@ -157,10 +173,12 @@ class span(object):
     profiler's clock and an entry in ``span_totals()``. A per-thread stack
     gives it its parent, to which its duration is charged as child time.
     Usable as a context manager or via explicit start()/stop(); stop()
-    returns the duration in ns, or None when nothing was recorded."""
+    returns the duration in ns, or None when nothing was recorded.
+    On or off, a span inside which the compile ledger moved adds its
+    count and duration to ``cold_totals()``."""
 
     __slots__ = ("name", "cat", "args", "_t0", "_ann", "_ring",
-                 "_child_ns")
+                 "_child_ns", "_seq", "_on")
 
     def __init__(self, name, cat="phase", **args):
         self.name = name
@@ -170,8 +188,11 @@ class span(object):
         self._ann = None
 
     def start(self):
+        global _first_session_ns
         ring, live = enabled(), _session_live()
-        if ring or live:
+        self._seq = _recompile.seq
+        on = self._on = ring or live
+        if on:
             self._ring = ring
             self._child_ns = 0
             try:
@@ -181,16 +202,24 @@ class span(object):
             if live:
                 self._ann = _Annotation("mx." + self.name)
                 self._ann.__enter__()
-            self._t0 = time.perf_counter_ns()
+                if _first_session_ns is None:
+                    _first_session_ns = now_ns()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def stop(self):
         t0 = self._t0
         if t0 is None:
             return None
-        t1 = time.perf_counter_ns()
         self._t0 = None
+        if not self._on:
+            if self._seq != _recompile.seq:
+                _add_cold(self.name, time.perf_counter_ns() - t0)
+            return None
+        t1 = time.perf_counter_ns()
         dur = t1 - t0
+        if self._seq != _recompile.seq:
+            _add_cold(self.name, dur)
         stack = getattr(_local, "stack", ())
         if stack and stack[-1] is self:
             stack.pop()
@@ -203,15 +232,7 @@ class span(object):
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-            with _lock:
-                t = _totals.get(self.name)
-                if t is None:
-                    t = _totals[self.name] = [0, 0, 0, 0]
-                t[0] += 1
-                t[1] += dur
-                t[2] += dur - self._child_ns
-                if dur > t[3]:
-                    t[3] = dur
+            _add_total(self.name, dur, dur - self._child_ns)
         if self._ring:
             record_span(self.name, self.cat, t0, t1, self.args,
                         self._child_ns)
@@ -223,10 +244,24 @@ class span(object):
         self.stop()
 
 
+def _add_total(name, dur, self_dur):
+    with _lock:
+        t = _totals.get(name)
+        if t is None:
+            t = _totals[name] = [0, 0, 0, 0]
+        t[0] += 1
+        t[1] += dur
+        t[2] += self_dur
+        if dur > t[3]:
+            t[3] = dur
+
+
 def span_totals():
     """{name: {"count", "total_ns", "self_ns", "max_ns"}} of the spans
-    that ran under a profiler session since the last reset. Self time is
-    the total less the spans opened inside it on the same thread."""
+    that ran under a profiler session since the last reset, and of the
+    ``startup.*`` spans (``record_startup``), which record whatever the
+    gates. Self time is the total less the spans opened inside it on the
+    same thread."""
     with _lock:
         return {name: {"count": t[0], "total_ns": t[1], "self_ns": t[2],
                        "max_ns": t[3]}
@@ -236,6 +271,46 @@ def span_totals():
 def reset_span_totals():
     with _lock:
         _totals.clear()
+
+
+def _add_cold(name, dur):
+    with _lock:
+        c = _cold.get(name)
+        if c is None:
+            c = _cold[name] = [0, 0]
+        c[0] += 1
+        c[1] += dur
+
+
+def cold_totals():
+    """{name: {"count", "total_ns"}} of the spans, whatever the gates,
+    inside which jax traced, lowered, compiled or loaded a program (the
+    compile ledger's ``seq`` moved between start and stop): the calls
+    that paid for start-up, seen from inside. Nested cold spans each
+    carry their own whole duration."""
+    with _lock:
+        return {name: {"count": c[0], "total_ns": c[1]}
+                for name, c in _cold.items()}
+
+
+def record_startup(name, t0_ns):
+    """Close a span of category ``startup`` that opened at ``t0_ns``
+    (a ``perf_counter_ns`` reading). It records into ``span_totals()``
+    whatever the gates, and into the ring under ``enabled()``, so it may
+    sit only where a process passes once: the package's import, the
+    first device query, a batcher's construction."""
+    t1 = time.perf_counter_ns()
+    dur = t1 - t0_ns
+    _add_total(name, dur, dur)
+    if enabled():
+        record_span(name, "startup", t0_ns, t1)
+    return dur
+
+
+def first_session_ns():
+    """When a span first saw a live profiler session, ns on the epoch
+    (the ledger's ``t_ns``), or None while there has been none."""
+    return _first_session_ns
 
 
 class Counter(object):
@@ -362,9 +437,15 @@ def reset():
         _total = 0
         _counters.clear()
         _totals.clear()
+        _cold.clear()
     from . import histogram as _h
     _h.reset()
     from . import events as _ev
     _ev.reset()
     from . import timeseries as _ts
     _ts.reset()
+
+
+# the compile ledger's ``seq`` (span.start / stop). recompile imports
+# this module back; every name it uses is defined above.
+from . import recompile as _recompile      # noqa: E402
