@@ -286,6 +286,21 @@ def _jitted_admit_token(cfg, greedy, temperature, top_k, top_p):
         cfg, build)
 
 
+def _jitted_fresh_row(cfg):
+    """The zeroed one-lane row an admission without a cached prefix
+    starts from, tf.init_cache(cfg, 1), as ONE small program: no
+    argument, nothing donated, the same tree, shapes and dtypes. Eagerly
+    the row costs a launch a leaf (~100 one-element programs for a
+    24-layer model), which the host issues while the device has only the
+    round in flight to work on. Its zeros are broadcasts, not literals:
+    the executable stays under a megabyte whatever the row's bytes."""
+    def build(fz):
+        def fresh_row():
+            return tf.init_cache(fz, 1)
+        return jax.jit(fresh_row)
+    return tf._serving_jit("fresh_row", cfg, build)
+
+
 def _jitted_slot_write(cfg):
     """Write a 1-row prefilled cache into slot `i` of the pool cache.
 
@@ -1419,7 +1434,7 @@ class ContinuousBatcher(object):
         hit = self._prefix_cache.pop(key, None)
         if hit is None:
             logits, row_cache = tf._jitted_prefill_chunk_row(self.cfg)(
-                self.params, tf.init_cache(self.cfg, 1),
+                self.params, self._fresh_row(),
                 jnp.asarray([toks], jnp.int32),
                 jnp.int32(0), jnp.int32(len(toks) - 1))
             hit = (row_cache, logits)
@@ -1459,7 +1474,7 @@ class ContinuousBatcher(object):
             row = _jitted_gather_row(self.cfg, nb_sub)(
                 self._pool, jnp.asarray(sub_blocks[:nb_sub], jnp.int32))
         else:
-            row = tf.init_cache(self.cfg, 1)
+            row = self._fresh_row()
         # exact-length suffix prefill (no bucket pad): the cached
         # blocks hold zeros beyond the prefix, so nothing stale is
         # ever attendable through a sharer's table
@@ -1482,6 +1497,15 @@ class ContinuousBatcher(object):
             self._publish_occupancy()
         return p
 
+    def _fresh_row(self, cfg=None):
+        """A zeroed one-lane row of `cfg` (the target's, or the draft's)
+        in one launch (_jitted_fresh_row). While spans record, the
+        counter serving.fresh_rows counts them: one an admission that
+        found no cached prefix to start from."""
+        if _obs.active():
+            _obs.counter("serving.fresh_rows").add(1)
+        return _jitted_fresh_row(self.cfg if cfg is None else cfg)()
+
     def _lookup_prefix(self, prompt):
         """Longest cached prefix of `prompt` -> (p_len, row_cache,
         last_row_logits-or-None). The cached trees are never mutated
@@ -1494,7 +1518,7 @@ class ContinuousBatcher(object):
                 if best is None or len(key) > len(best):
                     best = key
         if best is None:
-            return 0, tf.init_cache(self.cfg, 1), None
+            return 0, self._fresh_row(), None
         hit = self._prefix_cache.pop(best)
         self._prefix_cache[best] = hit               # LRU refresh
         return len(best), hit[0], hit[1]
@@ -1514,7 +1538,7 @@ class ContinuousBatcher(object):
                 self._pool,
                 jnp.asarray(pfx_blocks[:nb_pfx], jnp.int32))
         else:
-            row_cache = tf.init_cache(self.cfg, 1)
+            row_cache = self._fresh_row()
         if p_len == t_p:
             return pfx_logits[0], row_cache
         width = min(_bucket(t_p - p_len), self.cfg.max_len - p_len)
@@ -1835,7 +1859,7 @@ class ContinuousBatcher(object):
                              lane=slot, kind="resume",
                              prompt_tokens=m).start()
         ctx, last = tokens[:-1], tokens[-1]
-        row_cache = tf.init_cache(self.cfg, 1)
+        row_cache = self._fresh_row()
         width = min(_bucket(m), self.cfg.max_len)
         padded = np.zeros((1, width), np.int32)
         padded[0, :m] = ctx
@@ -2763,7 +2787,7 @@ class ContinuousBatcher(object):
                 self._dev_hist = self._hist_fn(
                     self._dev_hist, jnp.int32(slot), jnp.asarray(row))
             return
-        drow = tf.init_cache(self.draft_cfg, 1)
+        drow = self._fresh_row(self.draft_cfg)
         width = min(_bucket(t_p), self.draft_cfg.max_len)
         padded = np.zeros((1, width), np.int32)
         padded[0, :t_p] = ctx
@@ -2896,7 +2920,7 @@ class ContinuousBatcher(object):
         ctx, last = req.tokens[:-1], req.tokens[-1]
         m = len(ctx)
         assert m >= 1, "a live request always has prompt + first token"
-        row_cache = tf.init_cache(self.cfg, 1)
+        row_cache = self._fresh_row()
         width = min(_bucket(m), self.cfg.max_len)
         padded = np.zeros((1, width), np.int32)
         padded[0, :m] = ctx

@@ -24,3 +24,38 @@ def _seed():
     import mxnet_tpu as mx
     mx.random.seed(seed)
     yield
+
+
+@pytest.fixture
+def fresh_rows(monkeypatch):
+    """Who made the one-lane rows of a test's admissions. `.eager` lists
+    the batch of every tf.init_cache call that ran eagerly (a launch a
+    leaf), `.traced` counts the times its one-lane body ran under a trace
+    (a batcher's eval_shape, the row's program being built) and `.made`
+    lists the config of every row ContinuousBatcher._fresh_row handed out.
+    The row's programs of earlier tests are dropped, so the first row of
+    a config is always the one that traces."""
+    import types
+    import jax
+    from mxnet_tpu.models import serving, transformer as tf
+
+    seen = types.SimpleNamespace(eager=[], traced=0, made=[])
+    for key in [k for k in tf._PREFILL_JIT_CACHE if k[0] == "fresh_row"]:
+        del tf._PREFILL_JIT_CACHE[key]
+    init_cache = tf.init_cache
+    fresh_row = serving.ContinuousBatcher._fresh_row
+
+    def spy_init(cfg, batch):
+        cache = init_cache(cfg, batch)
+        if isinstance(jax.tree.leaves(cache)[0], jax.core.Tracer):
+            seen.traced += batch == 1
+        else:
+            seen.eager.append(batch)
+        return cache
+
+    def spy_row(self, cfg=None):
+        seen.made.append(self.cfg if cfg is None else cfg)
+        return fresh_row(self, cfg)
+    monkeypatch.setattr(tf, "init_cache", spy_init)
+    monkeypatch.setattr(serving.ContinuousBatcher, "_fresh_row", spy_row)
+    return seen

@@ -849,3 +849,98 @@ def test_prefix_cache_longest_match_and_sampling():
                         5, cfg, temperature=0.7, top_k=13, seed=9)
     np.testing.assert_array_equal(np.asarray(out[rid2]),
                                   np.asarray(want2[0]))
+
+
+# ---- the fresh row: one launch an admission ---------------------------
+# An admission that finds no cached prefix starts from a zeroed one-lane
+# row. Eagerly that is a launch a leaf; the batcher takes it from ONE
+# small jitted program (serving._jitted_fresh_row), whatever state the
+# model's layers keep.
+
+_ROW_CFGS = {
+    "attention": dict(),
+    "int8": dict(kv_cache_int8=True),
+    "mamba": dict(n_kv_heads=1, n_layers=3,
+                  layer_kinds=("mamba", "attention", "mamba"),
+                  ffn="gated_silu", positions="none", ssm_state=8,
+                  ssm_dt_rank=4),
+    "kda-mla": dict(n_layers=2, layer_kinds=("kda", "mla"),
+                    positions="none", kda_heads=4, kda_head_dim=8,
+                    kda_conv=4, mla_rank=16, mla_nope_dim=8,
+                    mla_rope_dim=4, mla_v_dim=8),
+}
+row_kinds = pytest.mark.parametrize("kind",
+                                    ["attention", "mamba", "kda-mla"])
+
+
+@pytest.mark.parametrize("kind", sorted(_ROW_CFGS))
+def test_the_rows_program_makes_init_caches_row(kind):
+    """Leaf by leaf the same tree, shapes, dtypes and zeros, whatever
+    dtype the cache states (bf16 rows beside float32 state and int8
+    codes beside their float32 scales)."""
+    from mxnet_tpu.models.serving import _jitted_fresh_row
+    cfg = _cfg(dtype=jnp.bfloat16, **_ROW_CFGS[kind])
+    want = tf.init_cache(cfg, 1)
+    make = _jitted_fresh_row(cfg)
+    assert _jitted_fresh_row(_cfg(dtype=jnp.bfloat16,
+                                  **_ROW_CFGS[kind])) is make
+    got = make()
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        assert g.shape[0] == 1 and not np.asarray(g, np.float32).any()
+    # every leaf of every row is a buffer of its own, though all the
+    # zeros of a shape are one value to the compiler
+    leaves = jax.tree.leaves([got, make()])
+    assert len({x.unsafe_buffer_pointer() for x in leaves}) == len(leaves)
+
+
+@row_kinds
+def test_five_admissions_trace_the_row_once_and_launch_it_five_times(
+        kind, fresh_rows):
+    from mxnet_tpu.observability import core as obs
+    cfg = _cfg(**_ROW_CFGS[kind])
+    params = tf.init_params(cfg, seed=3)
+    jobs = [(p, 4) for p in _prompts(np.random.RandomState(5), 5)]
+    obs.reset()
+    obs.set_enabled(True)
+    try:
+        srv = ContinuousBatcher(params, cfg, max_batch=2)
+        built, traced = list(fresh_rows.eager), fresh_rows.traced
+        results, order = srv.run(jobs)
+        rows = obs.counter("serving.fresh_rows").value
+    finally:
+        obs.set_enabled(None)
+        obs.reset()
+    # init_cache's body ran once more, under the program's trace, and
+    # never eagerly: a launch a row, not a launch a leaf
+    assert fresh_rows.traced - traced == 1
+    assert fresh_rows.eager == built == [2]
+    assert rows == len(fresh_rows.made) == 5
+    for rid, (p, n) in zip(order, jobs):
+        want = tf.generate(params, jnp.asarray([p], jnp.int32), n, cfg)
+        np.testing.assert_array_equal(np.asarray(results[rid]),
+                                      np.asarray(want[0]))
+
+
+@row_kinds
+def test_an_admission_from_a_cached_prefix_needs_no_fresh_row(
+        kind, fresh_rows):
+    """cache_prefix starts from one; the admissions that continue from
+    its row make none, a miss makes its own. With telemetry off the
+    counter stays untouched."""
+    from mxnet_tpu.observability import core as obs
+    cfg = _cfg(**_ROW_CFGS[kind])
+    params = tf.init_params(cfg, seed=3)
+    system = [7, 3, 9, 1, 4]
+    srv = ContinuousBatcher(params, cfg, max_batch=2)
+    srv.cache_prefix(system)
+    assert len(fresh_rows.made) == 1
+    srv.admit(system + [11, 22], 3)
+    srv.admit(system, 3)
+    assert len(fresh_rows.made) == 1
+    while srv.active_count:
+        srv.step()
+    srv.admit([5, 6], 3)
+    assert len(fresh_rows.made) == 2 and 1 not in fresh_rows.eager
+    assert "serving.fresh_rows" not in obs.counters()

@@ -161,7 +161,7 @@ def test_journal_off_path_identity(setup, tmp_path, loop):
 
 @both_loops
 @pytest.mark.parametrize("greedy", [True, False])
-def test_recover_bit_exact(setup, tmp_path, greedy, loop):
+def test_recover_bit_exact(setup, tmp_path, greedy, loop, fresh_rows):
     """Drop the batcher mid-flight (simulated crash: the journal is
     all that survives); a fresh batcher's recover() + stepping yields
     exactly the uninterrupted run's streams — greedy and sampled."""
@@ -182,9 +182,13 @@ def test_recover_bit_exact(setup, tmp_path, greedy, loop):
 
     srv2 = ContinuousBatcher(params, cfg, max_batch=4, greedy=greedy,
                              journal=str(tmp_path), **loop)
+    made = len(fresh_rows.made)
     resumed, done, skipped = srv2.recover()
     assert skipped == []
     assert resumed                     # genuinely mid-flight
+    # a resumed stream's row is one launch, like an admission's
+    assert len(fresh_rows.made) - made == len(resumed)
+    assert fresh_rows.eager == [4] * 3     # the three batchers' caches
     got = dict(done)
     new2old = {v: k for k, v in resumed.items() if v is not None}
     for _ in range(200):

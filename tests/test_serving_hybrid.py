@@ -71,7 +71,8 @@ def test_sampled_streams_equal_solo_generate(params):
         assert list(got[rid]) == [int(t) for t in np.asarray(solo)[0]]
 
 
-def test_streams_equal_solo_generate_through_cache_prefix(params):
+def test_streams_equal_solo_generate_through_cache_prefix(params,
+                                                          fresh_rows):
     rng = np.random.RandomState(8)
     prefix = list(rng.randint(1, 97, 11))
     srv = ContinuousBatcher(params, CFG, max_batch=2)
@@ -80,11 +81,15 @@ def test_streams_equal_solo_generate_through_cache_prefix(params):
     jobs.append((prefix, 5))                    # the prefix is the prompt
     jobs.append((list(rng.randint(1, 97, 9)), 4))        # a miss
     got, order = srv.run(jobs)
+    # the prefix's own row and the miss's: both kinds of state, one
+    # launch each
+    assert fresh_rows.made == [CFG] * 2 and fresh_rows.eager == [2]
     for (prompt, n_new), rid in zip(jobs, order):
         assert list(got[rid]) == _solo(params, prompt, n_new)
 
 
-def test_a_cancelled_lane_and_a_continuation_leave_no_state_behind(params):
+def test_a_cancelled_lane_and_a_continuation_leave_no_state_behind(
+        params, fresh_rows):
     rng = np.random.RandomState(2)
     srv = ContinuousBatcher(params, CFG, max_batch=2)
     victim = srv.admit(list(rng.randint(1, 97, 14)), 30)
@@ -100,6 +105,8 @@ def test_a_cancelled_lane_and_a_continuation_leave_no_state_behind(params):
     while rid not in done or other not in done:
         done.update(srv.step())
     assert list(done[rid]) == want
+    # (generate() above builds its own cache of one lane eagerly)
+    assert fresh_rows.made == [CFG] * 3 and fresh_rows.eager == [2, 1]
 
 
 def test_the_two_kinds_of_state_are_published(params, monkeypatch):

@@ -43,10 +43,11 @@ def _solo(params, prompt, n, cfg, **kw):
     dict(), dict(chunk_size=3), dict(pipeline_depth=2),
     dict(pipeline_depth=2, chunk_size=3), dict(pipeline_depth=1),
     dict(pipeline_depth=1, chunk_size=3)])
-def test_paged_streams_bit_exact(kw):
+def test_paged_streams_bit_exact(kw, fresh_rows):
     """Greedy streams through the paged pool == solo generate(), in
     sync, chunked, and pipelined scheduling — and the pool drains back
-    to every block free with zero reservation."""
+    to every block free with zero reservation. Each admission's row is
+    one launch of the row's program."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     rng = np.random.RandomState(1)
@@ -55,6 +56,7 @@ def test_paged_streams_bit_exact(kw):
                             block_size=8, **kw)
     results, order = srv.run(jobs)
     assert len(results) == len(jobs)
+    assert fresh_rows.made == [cfg] * len(jobs) and not fresh_rows.eager
     for rid, (prompt, n_new) in zip(order, jobs):
         np.testing.assert_array_equal(
             np.asarray(results[rid]), _solo(params, prompt, n_new, cfg),
@@ -368,7 +370,7 @@ def test_paged_capacity_2x_dense_at_equal_hbm():
                                       _solo(params, p, n, cfg))
 
 
-def test_paged_requeue_on_dispatch_failure():
+def test_paged_requeue_on_dispatch_failure(fresh_rows):
     """The PR 6 recovery path composes: an injected dispatch fault
     frees the lanes, rebuilds pool + allocator, and requeues live
     requests from their token prefix — greedy streams stay
@@ -389,6 +391,8 @@ def test_paged_requeue_on_dispatch_failure():
         while r1 not in done or r2 not in done:
             done.update(srv.step())
         assert srv._alloc.free_blocks == srv.num_blocks - 1
+        # the re-admissions' rows come from the row's program too
+        assert fresh_rows.made == [cfg] * 4 and not fresh_rows.eager
         np.testing.assert_array_equal(np.asarray(done[r1]),
                                       _solo(params, p1, 12, cfg))
         np.testing.assert_array_equal(np.asarray(done[r2]),

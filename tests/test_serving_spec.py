@@ -126,9 +126,11 @@ def test_spec_round_matches_numpy_oracle():
 @pytest.mark.parametrize("provider", ["ngram", "model"])
 @pytest.mark.parametrize("paged", [False, True])
 @pytest.mark.parametrize("depth", [1, 2])
-def test_spec_streams_bitexact(provider, paged, depth):
+def test_spec_streams_bitexact(provider, paged, depth, fresh_rows):
     """Every spec-enabled greedy stream equals solo generate() —
-    dense/paged x depth 1/2 x both draft providers."""
+    dense/paged x depth 1/2 x both draft providers. An admission's
+    zeroed rows, the target's and a draft model's, are each one launch
+    of that config's program."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     kw = dict(max_batch=2, pipeline_depth=depth, spec_k=3, paged=paged)
@@ -141,6 +143,10 @@ def test_spec_streams_bitexact(provider, paged, depth):
     jobs = list(zip(_PROMPTS, _N_NEW))
     out, order = _run_pool(srv, jobs)
     assert len(out) == len(jobs)
+    assert fresh_rows.made.count(cfg) == len(jobs)
+    assert fresh_rows.made.count(_dcfg()) \
+        == (len(jobs) if provider == "model" else 0)
+    assert 1 not in fresh_rows.eager
     for rid, (p, n) in zip(order, jobs):
         np.testing.assert_array_equal(np.asarray(out[rid]),
                                       _solo(params, p, n, cfg))
@@ -216,7 +222,7 @@ def test_spec_cancel_mid_round_trims_draft_reservation():
 
 
 @pytest.mark.parametrize("provider", ["ngram", "model"])
-def test_spec_requeue_on_dispatch_failure(provider):
+def test_spec_requeue_on_dispatch_failure(provider, fresh_rows):
     """The PR 6 recovery contract holds under speculation: an injected
     dispatch fault rebuilds the pool AND the draft state (history rows
     / draft cache died with the donated carry), requeues from the
@@ -239,6 +245,12 @@ def test_spec_requeue_on_dispatch_failure(provider):
         while r0 not in done or r1 not in done:
             done.update(srv.step())
         assert srv._alloc.free_blocks == srv.num_blocks - 1
+        # two admissions and two re-admissions, a draft model's rows
+        # beside the target's, none of them built a launch a leaf
+        assert fresh_rows.made.count(cfg) == 4
+        assert fresh_rows.made.count(_dcfg()) \
+            == (4 if provider == "model" else 0)
+        assert 1 not in fresh_rows.eager
         np.testing.assert_array_equal(
             np.asarray(done[r0]), _solo(params, _PROMPTS[0], 12, cfg))
         np.testing.assert_array_equal(

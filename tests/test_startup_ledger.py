@@ -304,6 +304,31 @@ def test_a_batcher_records_its_construction_once(dark):
     assert core.records() == []
 
 
+def test_an_admissions_row_is_one_program_and_a_warm_admission_none(
+        dark, fresh_rows):
+    """The zeroed row an admission starts from is ONE entry of the
+    ledger, named for its maker, built by the first admission; on a
+    warmed batcher an admission moves the ledger not at all."""
+    srv = _tiny_batcher()
+    rows = [p for p in recompile.summary()["by_program"]
+            if "fresh_row" in p["program"]]
+    assert rows == []                    # the constructor builds none
+    srv.admit([3, 4, 5], 4)
+    row, = [p for p in recompile.summary()["by_program"]
+            if "fresh_row" in p["program"]]
+    assert (row["program"], row["builds"]) == ("jit(fresh_row)", 1)
+    assert [e["kind"] for e in _of(dark, "fresh_row")] == ["trace"]
+    while srv.active_count:
+        srv.step()
+    before, entries = recompile.seq, len(dark.events)
+    cold = core.cold_totals()["serving.admit"]
+    srv.admit([6, 7, 8], 4)              # the same bucket, a lane reused
+    srv.admit([9, 10], 4)
+    assert (recompile.seq, len(dark.events)) == (before, entries)
+    assert core.cold_totals()["serving.admit"] == cold
+    assert len(fresh_rows.made) == 3
+
+
 def test_health_snapshot_carries_the_ledger(dark):
     srv = _tiny_batcher()
     snap = srv.health_snapshot()["startup"]

@@ -139,3 +139,35 @@ def test_paged_attention(one_chip, int8, span):
     pos = _sds(one_chip, (DEC_B,), jnp.int32)
     _compile(functools.partial(paged_attention, interpret=False),
              q, pool, tables, pos)
+
+
+@pytest.mark.parametrize("name,runner", [
+    ("cerebras-gpt-1.3b", "lm_common"), ("jamba2-3b", "serve_jamba"),
+    ("kimi-linear-48b-a3b", "serve_kimi_linear")])
+def test_a_fresh_lanes_row_is_broadcasts_not_a_literal(one_chip, name,
+                                                       runner):
+    """The one program an admission's zeroed row comes from
+    (serving._jitted_fresh_row), at the three served configurations'
+    sizes: a row of up to 0.4 GB must not be folded into the executable,
+    which the persistent cache holds beside the depth-sized programs."""
+    import json
+    import os
+    from mxnet_tpu.models.serving import _jitted_fresh_row
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           name + ".json")) as f:
+        cfg = importlib.import_module(
+            "chipbench.runners." + runner).program_config(json.load(f))
+    body = _jitted_fresh_row(cfg).__wrapped__
+    row = jax.eval_shape(body)
+    compiled = jax.jit(body, out_shardings=jax.tree.map(
+        lambda _: one_chip, row)).lower().compile()
+    mem = compiled.memory_analysis()
+    row_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(row))
+    assert row_bytes > 10 * 2 ** 20
+    assert mem.generated_code_size_in_bytes < 2 ** 20
+    assert row_bytes <= mem.output_size_in_bytes < 1.05 * row_bytes
+    assert mem.temp_size_in_bytes == 0 and mem.argument_size_in_bytes == 0
+    text = compiled.as_text()
+    assert text.count(" broadcast(") == len(jax.tree.leaves(row))
+    assert len(text) < 2 ** 17           # no literal spelled out

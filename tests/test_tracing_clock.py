@@ -100,8 +100,9 @@ def _batcher(**kw):
 
 
 # what the batcher counts while its spans record (models/serving.py
-# _count_dispatch; a model with routed experts adds moe.*)
-WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead"}
+# _count_dispatch and _fresh_row; a model with routed experts adds moe.*)
+WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
+                      "serving.fresh_rows"}
 
 
 def _serve(srv, rounds):
