@@ -120,6 +120,34 @@ def _bucket(n, lo=8):
     return b
 
 
+# the stream elements (tokens x d_model) ONE prefill call of an admission
+# may hold: a longer run of tokens goes through prefill_chunk in whole
+# chunks of the largest power of two under it and a bucketed rest, so
+# that an admission's temporaries (some dozens of copies of the chunk's
+# stream, and k of them in a routed layer) are bounded by the model's
+# width and not by the prompt. 2^25: 16,384 tokens of a 2,048-wide
+# stream, 4,096 of a 7,168-wide one
+PREFILL_CHUNK_ELEMS = 1 << 25
+
+
+def prefill_widths(cfg, n, start=0, pad=True):
+    """The widths of the prefill calls that take `n` tokens into a lane's
+    row from position `start`: whole chunks, then the rest at its
+    bucket's width (`pad` False: at its exact length), clamped to the
+    row's end: the bucket can pass max_len (max_len 96, a rest of 70 ->
+    128) and the row is max_len wide; the caller has checked that
+    start + n <= max_len, so a width never falls under its tokens."""
+    chunk = 8
+    while 2 * chunk * cfg.d_model <= PREFILL_CHUNK_ELEMS:
+        chunk *= 2
+    whole, rest = divmod(n, chunk)
+    widths = [chunk] * whole
+    if rest:
+        widths.append(min(_bucket(rest) if pad else rest,
+                          cfg.max_len - start - whole * chunk))
+    return widths
+
+
 def _pick_next(logits, keys, greedy, temperature, top_k, top_p):
     """Every lane's next token from its logits row: argmax, or a sample.
 
@@ -1056,6 +1084,7 @@ class ContinuousBatcher(object):
         def nbytes(layers):
             return sum(x.size * x.dtype.itemsize
                        for layer in layers for x in layer.values())
+        self._latent_layers = sum(kind == "mla" for kind, _ in row)
         self._lane_state_bytes = nbytes(
             l for kind, l in row if kind in tf._RECURRENT)
         self._kv_pos_bytes = nbytes(
@@ -1433,10 +1462,8 @@ class ContinuousBatcher(object):
         key = tuple(toks)
         hit = self._prefix_cache.pop(key, None)
         if hit is None:
-            logits, row_cache = tf._jitted_prefill_chunk_row(self.cfg)(
-                self.params, self._fresh_row(),
-                jnp.asarray([toks], jnp.int32),
-                jnp.int32(0), jnp.int32(len(toks) - 1))
+            logits, row_cache = self._prefill_rows(
+                self._fresh_row(), toks, 0, pad=False)
             hit = (row_cache, logits)
         self._prefix_cache[key] = hit                # insert/refresh
         while len(self._prefix_cache) > self._prefix_slots:
@@ -1478,10 +1505,8 @@ class ContinuousBatcher(object):
         # exact-length suffix prefill (no bucket pad): the cached
         # blocks hold zeros beyond the prefix, so nothing stale is
         # ever attendable through a sharer's table
-        logits, row = tf._jitted_prefill_chunk_row(self.cfg)(
-            self.params, row,
-            jnp.asarray([toks[p_sub:]], jnp.int32),
-            jnp.int32(p_sub), jnp.int32(p - p_sub - 1))
+        logits, row = self._prefill_rows(row, toks[p_sub:], p_sub,
+                                         pad=False)
         own = self._alloc.alloc(own_n)
         if s:
             self._alloc.share(sub_blocks[:s])
@@ -1496,6 +1521,29 @@ class ContinuousBatcher(object):
         if _obs.enabled():
             self._publish_occupancy()
         return p
+
+    def _prefill_rows(self, row_cache, tokens, start, pad=True):
+        """`tokens` into the one-lane `row_cache` at positions `start`
+        onward, in calls of prefill_widths' widths (one compiled
+        prefill a width; `pad` False = the rest at its exact length, for
+        a row others will share) through the one program, prefill_chunk
+        with a logits row. Rows behind the last real token are the
+        bucket's padding: K/V or latent rows there are overwritten by
+        decode before attention can reach them, and a recurrent state
+        stops at the logits row (prefill_chunk) and continues from the
+        cache's own: zeros, a cached prefix's, or the chunk's before.
+        Returns (the last token's logits [1, vocab], row_cache)."""
+        fn = tf._jitted_prefill_chunk_row(self.cfg)
+        at = 0
+        for width in prefill_widths(self.cfg, len(tokens), start, pad):
+            part = tokens[at: at + width]
+            padded = np.zeros((1, width), np.int32)
+            padded[0, : len(part)] = part
+            logits, row_cache = fn(
+                self.params, row_cache, jnp.asarray(padded),
+                jnp.int32(start + at), jnp.int32(len(part) - 1))
+            at += width
+        return logits, row_cache
 
     def _fresh_row(self, cfg=None):
         """A zeroed one-lane row of `cfg` (the target's, or the draft's)
@@ -1541,12 +1589,8 @@ class ContinuousBatcher(object):
             row_cache = self._fresh_row()
         if p_len == t_p:
             return pfx_logits[0], row_cache
-        width = min(_bucket(t_p - p_len), self.cfg.max_len - p_len)
-        padded = np.zeros((1, width), np.int32)
-        padded[0, : t_p - p_len] = prompt[p_len:]
-        logits, row_cache = tf._jitted_prefill_chunk_row(self.cfg)(
-            self.params, row_cache, jnp.asarray(padded),
-            jnp.int32(p_len), jnp.int32(t_p - p_len - 1))
+        logits, row_cache = self._prefill_rows(row_cache, prompt[p_len:],
+                                               p_len)
         return logits[0], row_cache
 
     def _paged_map_lane(self, slot, t_p, row_cache, p_len, pfx_blocks,
@@ -1721,7 +1765,18 @@ class ContinuousBatcher(object):
             p_len, row_cache, pfx_logits = self._lookup_prefix(prompt)
             if p_len == t_p:
                 last = pfx_logits[0]   # whole prompt is the prefix
+            elif len(prefill_widths(self.cfg, t_p - p_len, p_len)) > 1:
+                # a suffix wider than one call's chunk goes in through
+                # _prefill_rows: whole chunks, then a bucketed rest
+                logits, row_cache = self._prefill_rows(
+                    row_cache, prompt[p_len:], p_len)
+                last = logits[0]
             else:
+                # one call, the code as it stood before _prefill_rows
+                # (which computes the same): through the helper the
+                # hybrid benchmark cell's warm set-up traced and lowered
+                # its programs twice (+16 s; PERF.md section 7, not
+                # explained), so this site keeps its own text.
                 # clamp: the bucket can pass max_len (e.g. max_len=96,
                 # suffix 70 -> bucket 128) and the cache axis is
                 # max_len wide; width >= suffix always holds since
@@ -1859,13 +1914,7 @@ class ContinuousBatcher(object):
                              lane=slot, kind="resume",
                              prompt_tokens=m).start()
         ctx, last = tokens[:-1], tokens[-1]
-        row_cache = self._fresh_row()
-        width = min(_bucket(m), self.cfg.max_len)
-        padded = np.zeros((1, width), np.int32)
-        padded[0, :m] = ctx
-        _, row_cache = tf._jitted_prefill_chunk_row(self.cfg)(
-            self.params, row_cache, jnp.asarray(padded),
-            jnp.int32(0), jnp.int32(m - 1))
+        _, row_cache = self._prefill_rows(self._fresh_row(), ctx, 0)
         key_np = self._resume_key(seed, emitted)
         if self.paged:
             self._paged_map_lane(slot, m, row_cache, 0, [], lifetime,
@@ -2304,6 +2353,10 @@ class ContinuousBatcher(object):
                     toks = np.asarray(toks)
                 if routing and _obs.active():
                     self._count_routing(routing[0])
+                if self._latent_layers and _obs.active():
+                    self._count_latent_rows(
+                        [len(r.tokens) for r in self._slots
+                         if r is not None], k)
                 toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
                 if self.paged:
                     self._pool = state
@@ -2370,6 +2423,19 @@ class ContinuousBatcher(object):
         into the counters moe.<name>."""
         for name, n in zip(tf.MOE_STATS, np.asarray(routing)):
             _obs.counter("moe." + name).add(int(n))
+
+    def _count_latent_rows(self, live, steps):
+        """A dispatch's latent rows (a model with latent attention)
+        into the counters mla.rows_read, what its decode contractions
+        read: max_len rows of every lane, live or not, a latent layer a
+        step; and mla.rows_live, those of them at or before a lane's
+        own position: `live` holds each live lane's rows at the
+        dispatch's first step (its tokens so far), one more a step."""
+        n = self._latent_layers
+        _obs.counter("mla.rows_read").add(
+            n * steps * self.max_batch * self.cfg.max_len)
+        _obs.counter("mla.rows_live").add(
+            n * (steps * sum(live) + len(live) * steps * (steps - 1) // 2))
 
     def _end_round(self):
         """Per-scheduling-round epilogue shared by every step path:
@@ -2496,6 +2562,12 @@ class ContinuousBatcher(object):
             toks = np.asarray(toks_dev).astype(np.int32)     # [k, B]
         if counting:
             self._count_routing(routing)
+        if self._latent_layers and _obs.active():
+            # the lanes this chunk still speaks for (the loop below)
+            self._count_latent_rows(
+                [len(r.tokens) for r, rid in zip(self._slots, lanes)
+                 if r is not None and r.rid == rid and not r.done],
+                toks.shape[0])
         obs_on = _obs.enabled()
         t_sync = time.perf_counter_ns() if obs_on else None
         finished = {}
@@ -2920,13 +2992,7 @@ class ContinuousBatcher(object):
         ctx, last = req.tokens[:-1], req.tokens[-1]
         m = len(ctx)
         assert m >= 1, "a live request always has prompt + first token"
-        row_cache = self._fresh_row()
-        width = min(_bucket(m), self.cfg.max_len)
-        padded = np.zeros((1, width), np.int32)
-        padded[0, :m] = ctx
-        _, row_cache = tf._jitted_prefill_chunk_row(self.cfg)(
-            self.params, row_cache, jnp.asarray(padded),
-            jnp.int32(0), jnp.int32(m - 1))
+        _, row_cache = self._prefill_rows(self._fresh_row(), ctx, 0)
         if self.greedy:
             key_np = np.zeros((2,), np.uint32)
         else:

@@ -19,6 +19,7 @@ matmuls with fp32 accumulation target the MXU.
 """
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -29,13 +30,32 @@ from . import kda, ssm
 from ..parallel.ring import ring_attention, ring_attention_sharded
 from ..parallel.pipeline import stack_stage_params, spmd_pipeline
 
-__all__ = ["TransformerConfig", "init_params", "forward", "loss_fn",
+__all__ = ["TransformerConfig", "YarnScaling", "init_params", "forward", "loss_fn",
            "make_train_step", "param_specs", "init_cache", "decode_step",
            "make_decode_step", "generate", "shard_cache", "prefill",
            "quantize_weights_int8", "beam_search", "prefill_chunk",
            "speculative_generate", "save_checkpoint", "load_checkpoint",
            "restore_train_state", "init_paged_cache", "decode_step_paged",
            "verify_chunk", "verify_chunk_paged"]
+
+
+class YarnScaling(NamedTuple):
+    """A rope-scaling record (YaRN, as the configurations that state
+    `rope_scaling.type: "yarn"` mean it): a rotary pair whose wavelength
+    fits the `original_max_len` positions the model first saw fewer than
+    `beta_slow` times keeps its frequency divided by `factor`, one that
+    fits more than `beta_fast` times keeps it whole, the pairs between
+    blend linearly (_rope_table); cos and sin are scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim) and the
+    softmax by mscale(factor, mscale_all_dim) ** 2, with mscale(f, m) =
+    0.1 m ln f + 1. A tuple, so a configuration that holds one still
+    hashes by value (_serving_jit)."""
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass
@@ -96,6 +116,10 @@ class TransformerConfig:
     # simply unused when rope=True
     rope: bool = False
     rope_base: float = 10000.0
+    # a YarnScaling record, or None = the frequencies as the base gives
+    # them. Only latent attention reads it (an "attention" layer beside
+    # one is refused: its softmax has no place for the record's scale)
+    rope_scaling: YarnScaling = None
     # "none" = no positional encoding anywhere (hybrid models whose
     # state-space layers carry the order): no `pos` table, no rotation.
     # Left None, `rope` says which of the other two it is
@@ -115,11 +139,15 @@ class TransformerConfig:
     kda_conv: int = 4
     # latent attention ("mla") sizes: the rank of the K/V latent, a
     # head's key part up-projected from it, its key part shared by all
-    # heads (cached beside the latent, never rotated), a head's values
+    # heads (cached beside the latent; with `rope` it and the query's
+    # part against it are rotated by position, the key's BEFORE it is
+    # stored), a head's values; `mla_q_rank` = the rank of a latent the
+    # query is projected through as well (None = one direct projection)
     mla_rank: int = None
     mla_nope_dim: int = None
     mla_rope_dim: int = None
     mla_v_dim: int = None
+    mla_q_rank: int = None
     use_ring_attention: bool = True
     # attention through the Pallas flash kernel (kernels/
     # flash_attention.py): single-device dense path AND the per-shard
@@ -206,6 +234,12 @@ def _learned_pos(cfg):
         raise ValueError(
             "positions=%r with rope=%r: positions is 'learned', 'rope' "
             "(with rope=True) or 'none'" % (cfg.positions, cfg.rope))
+    if cfg.rope_scaling is not None and (
+            not cfg.rope or "attention" in _layer_kinds(cfg)):
+        raise ValueError(
+            "rope_scaling is read by rotating latent attention alone: it "
+            "needs rope=True and no 'attention' layer (layer_kinds %r)"
+            % (_layer_kinds(cfg),))
     return not cfg.rope and cfg.positions != "none"
 
 
@@ -261,22 +295,60 @@ def _has_experts(cfg, i):
     return bool(cfg.n_experts) and i >= cfg.first_dense_layers
 
 
-def _rope(x, positions, base):
+def _yarn_mscale(factor, m):
+    return 0.1 * m * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_table(cfg, dim):
+    """(frequencies float32 [dim / 2], the factor on cos and sin) of a
+    rotation of `dim` features under cfg.rope_scaling, or None without a
+    record (then _rope takes its frequencies from the base). YaRN: pair
+    i, of frequency base^(-2i/dim), completes n(i) = original_max_len *
+    f_i / 2 pi turns over the positions the model first saw; the pairs
+    with n >= beta_fast keep their frequency, those with n <= beta_slow
+    have it divided by `factor`, and between the two pairs' indices
+    (the first rounded down, the second up) the two blend linearly."""
+    ys = cfg.rope_scaling
+    if ys is None:
+        return None
+    half = dim // 2
+    freqs = cfg.rope_base ** (-np.arange(half, dtype=np.float64) / half)
+
+    def pair_of(turns):
+        return dim * np.log(ys.original_max_len / (turns * 2 * np.pi)) \
+            / (2 * np.log(cfg.rope_base))
+
+    lo = max(np.floor(pair_of(ys.beta_fast)), 0)
+    hi = min(np.ceil(pair_of(ys.beta_slow)), dim - 1)
+    whole = 1.0 - np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return ((freqs / ys.factor * (1 - whole) + freqs * whole)
+            .astype(np.float32),
+            float(_yarn_mscale(ys.factor, ys.mscale)
+                  / _yarn_mscale(ys.factor, ys.mscale_all_dim)))
+
+
+def _rope(x, positions, base, table=None):
     """Rotary position encoding on [..., T, H, Dh] (or [..., H, Dh]
     with scalar/[B] positions at decode): rotate feature pairs
-    (half-split convention) by position-dependent angles."""
+    (half-split convention) by position-dependent angles. `table`
+    (_rope_table) replaces the base's frequencies and scales cos/sin."""
     dh = x.shape[-1]
     if dh % 2:
         raise ValueError(
             "rope needs an even head dim, got d_model/n_heads = %d" % dh)
     half = dh // 2
-    freqs = (1.0 / base) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    if table is None:
+        freqs = (1.0 / base) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = table[0]
     ang = jnp.asarray(positions, jnp.float32)[..., None] * freqs
     if jnp.ndim(positions) >= 1:
         # positions carry a T (or batch) axis that aligns with x's -3
         # axis; insert the broadcast head axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if table is not None and table[1] != 1.0:
+        cos, sin = cos * table[1], sin * table[1]
     x1, x2 = x[..., :half], x[..., half:]
     rot = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)
@@ -308,7 +380,9 @@ def param_specs(cfg):
         ("A_log", 1), ("b_proj", 2), ("g_a", 2), ("g_b", 2), ("o_norm", 1),
         ("out_proj", 2))}
     mla = {k: P(*(None,) * n) for k, n in (
-        ("wq", 3), ("wkva", 2), ("kv_norm", 1), ("wkvb", 3), ("wo", 3))}
+        (("wq_a", 2), ("q_norm", 1), ("wq_b", 3)) if cfg.mla_q_rank
+        else (("wq", 3),))
+        + (("wkva", 2), ("kv_norm", 1), ("wkvb", 3), ("wo", 3))}
     mixers = {"attention": attention, "mamba": mamba, "kda": kda_mixer,
               "mla": mla}
     gated = cfg.ffn == "gated_silu"
@@ -392,8 +466,14 @@ def init_params(cfg, seed=0):
 
     def mla():
         r, n, e, v = _mla_sizes(cfg)
+        rq = cfg.mla_q_rank
+        query = {"wq": dense(cfg.d_model, cfg.n_heads, n + e)} if not rq \
+            else {"wq_a": dense(cfg.d_model, rq),
+                  "q_norm": jnp.ones((rq,), dt),
+                  "wq_b": jnp.asarray(
+                      rng.randn(rq, cfg.n_heads, n + e) / np.sqrt(rq), dt)}
         return {
-            "wq": dense(cfg.d_model, cfg.n_heads, n + e),
+            **query,
             "wkva": dense(cfg.d_model, r + e),
             "kv_norm": jnp.ones((r,), dt),
             "wkvb": jnp.asarray(
@@ -547,16 +627,19 @@ def _paged_pallas_requested():
         "0", "", "false", "False", None)
 
 
-def _causal_attention(q, k, v, cfg, out_dtype):
+def _causal_attention(q, k, v, cfg, out_dtype, norm=None):
     """Single-device causal attention over [B, T, H, D] — flash kernel
     (one block when T fits/divides 128, else gcd(T, 128)-sized blocks,
     so ANY sequence length works) or the dense masked softmax. Shared
     by training forward and prefill. use_flash_kernel is a REQUEST,
     not a route: sequences below the measured crossover
     (MXNET_FLASH_MIN_SEQ, _flash_min_seq above) still take the dense
-    path, which the chip A/B has winning there."""
+    path, which the chip A/B has winning there. `norm` is what the
+    scores are divided by (None = sqrt(D))."""
     if cfg.use_flash_kernel and q.shape[1] >= _flash_min_seq():
         from ..kernels import flash_attention
+        if norm is not None:       # the kernel divides by sqrt(D) itself
+            q = (q * (np.sqrt(q.shape[-1]) / norm)).astype(q.dtype)
         # block sizing (128 default, MXNET_FLASH_BLOCK_Q/K override,
         # clamp + gcd for short/odd sequences) lives in
         # flash_attention itself — one source of truth
@@ -565,7 +648,7 @@ def _causal_attention(q, k, v, cfg, out_dtype):
     T = q.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32)
-    s = s / np.sqrt(q.shape[-1])
+    s = s / (np.sqrt(q.shape[-1]) if norm is None else norm)
     mask = jnp.tril(jnp.ones((T, T), bool))
     s = jnp.where(mask[None, None], s, -1e30)
     a = jax.nn.softmax(s, axis=-1)
@@ -774,7 +857,7 @@ def forward(params, tokens, cfg, mesh=None):
     # self-attention over the fresh K/V: training keeps no state
     mix = _mixer(cfg, lambda h, p, _: (
         _attention(h, p, cfg, mesh, manual_sp=ring), None),
-        _latent_attend(cfg, lambda layer, **rows: None,
+        _latent_attend(cfg, None, lambda layer, **rows: None,
                        _latent_self_attention(cfg)), from_zero=True)
     if n_stages > 1:
         # pipeline the homogeneous layer stack over pp: stage-major
@@ -1165,13 +1248,18 @@ def _cache_attend(cfg, where, store, read):
     return attend
 
 
-# Latent attention ("mla"): q = W_q x [H, N + E]; [c, k_r] = W_kva x;
-# the layer's row is {"c": rms(c) [R], "kr": k_r [E]}, all a position
-# keeps; keys and values are up-projected from it, k = [W_kvb_k c, k_r
-# shared by all heads], v = W_kvb_v c; scores q k^T / sqrt(N + E), no
-# rotation on either side. A chunk contracts through the up-projected
-# heads (_latent_chunk_attention); decode's one row absorbs W_kvb into
-# the query and the output and contracts over the rows directly
+# Latent attention ("mla"): q = W_q x [H, N + E], or with a query rank
+# W_qb rms(W_qa x); [c, k_r] = W_kva x; the layer's row is {"c": rms(c)
+# [R], "kr": k_r [E]}, all a position keeps; keys and values are
+# up-projected from it, k = [W_kvb_k c, k_r shared by all heads], v =
+# W_kvb_v c; scores q k^T / sqrt(N + E). A model without positions
+# rotates nothing; one with `rope` rotates the query's last E features
+# and k_r by the token's absolute position (_rope, under
+# cfg.rope_scaling), k_r BEFORE it is stored: a row holds its position,
+# and whatever moves rows must keep them at the place they were rotated
+# for. A chunk contracts through the up-projected heads
+# (_latent_chunk_attention); decode's one row absorbs W_kvb into the
+# query and the output and contracts over the rows directly
 # (_latent_decode_attention): the same sums in another order.
 
 # queries a block of _latent_chunk_attention: a whole bucket's scores
@@ -1182,18 +1270,43 @@ MLA_QUERY_BLOCK = 256
 MLA_KEY_BLOCK = 512
 
 
-def _latent_attend(cfg, store, contract):
-    """_mixer's `latent` for the entry points: project, `store(layer,
-    c=.., kr=..)` the fresh rows, then `contract(q, layer, rows, p)`:
-    the entry point's contraction over the stored rows (or, for training
-    and a prefill at position 0, over the fresh `rows` themselves). h is
-    [B, C, d], or decode's one row [B, d]."""
+def _latent_score_norm(cfg, width):
+    """What a latent layer's scores are divided by: sqrt(N + E), less the
+    scaling record's softmax scale mscale(factor, mscale_all_dim) ** 2."""
+    ys = cfg.rope_scaling
+    if ys is None:
+        return np.sqrt(width)
+    return np.sqrt(width) / _yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+
+
+def _latent_attend(cfg, where, store, contract):
+    """_mixer's `latent` for the entry points: project, rotate by the
+    positions `where` (a model with `rope`; None = the call's rows sit
+    at 0, 1, ...), `store(layer, c=.., kr=..)` the fresh rows, then
+    `contract(q, layer, rows, p)`: the entry point's contraction over
+    the stored rows (or, for training and a prefill at position 0, over
+    the fresh `rows` themselves). h is [B, C, d], or decode's one row
+    [B, d]."""
     def attend(h, p, layer):
-        r = _mla_sizes(cfg)[0]
-        q = jnp.einsum("...d,dhk->...hk", h, p["wq"])
+        r, n, e, _ = _mla_sizes(cfg)
+        if cfg.mla_q_rank:
+            q = jnp.einsum("...r,rhk->...hk", _rms_norm(
+                jnp.einsum("...d,dr->...r", h, p["wq_a"]), p["q_norm"],
+                cfg.norm_eps), p["wq_b"])
+        else:
+            q = jnp.einsum("...d,dhk->...hk", h, p["wq"])
         ckr = jnp.einsum("...d,df->...f", h, p["wkva"])
-        rows = {"c": _rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps),
-                "kr": ckr[..., r:]}
+        c = _rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
+        kr = ckr[..., r:]
+        if cfg.rope:
+            with jax.named_scope("mx.mla.rope"):
+                at = jnp.arange(h.shape[-2]) if where is None else where
+                table = _rope_table(cfg, e)
+                q = jnp.concatenate([q[..., :n], _rope(
+                    q[..., n:], at, cfg.rope_base, table)], axis=-1)
+                kr = _rope(kr[..., None, :], at, cfg.rope_base,
+                           table)[..., 0, :]
+        rows = {"c": c, "kr": kr}
         layer = store(layer, **rows)
         o = contract(q, layer, rows, p)
         return jnp.einsum("...hk,hkd->...d", o, p["wo"]), layer
@@ -1217,7 +1330,9 @@ def _latent_self_attention(cfg):
     prefill at position 0), as _latent_attend's contraction."""
     def contract(q, layer, rows, p):
         k, v = _latent_up(rows, p, cfg)
-        return _causal_attention(q, k, v, cfg, q.dtype)
+        return _causal_attention(
+            q, k, v, cfg, q.dtype, None if cfg.rope_scaling is None
+            else _latent_score_norm(cfg, q.shape[-1]))
     return contract
 
 
@@ -1244,7 +1359,7 @@ def _latent_chunk_attention(q, rows, positions, p, cfg):
                       for x in (k, v))
             s = jnp.einsum("bqhd,bthd->bhqt", qb, kj,
                            preferred_element_type=jnp.float32) \
-                / np.sqrt(q.shape[-1])
+                / _latent_score_norm(cfg, q.shape[-1])
             seen = j * width + jnp.arange(width) <= pb[..., None]
             s = jnp.where(seen[:, None], s, -1e30)
             new_top = jnp.maximum(top, jnp.max(s, axis=-1))
@@ -1295,7 +1410,7 @@ def _latent_decode_attention(q, rows, pos, p, cfg):
                         preferred_element_type=jnp.float32)
              + jnp.einsum("bhe,bte->bht", q[..., n:], kr,
                           preferred_element_type=jnp.float32)) \
-            / np.sqrt(q.shape[-1])
+            / _latent_score_norm(cfg, q.shape[-1])
         seen = jnp.arange(c.shape[1])[None, :] \
             <= jnp.atleast_1d(pos)[:, None]
         a = jax.nn.softmax(jnp.where(seen[:, None, :], s, -1e30), axis=-1)
@@ -1337,7 +1452,8 @@ def prefill(params, cache, tokens, cfg):
 
     # position 0: whatever recurrent state the cache held is dropped
     mix = _mixer(cfg, _cache_attend(cfg, jnp.arange(t_p), store, read),
-                 _latent_attend(cfg, store, _latent_self_attention(cfg)),
+                 _latent_attend(cfg, None, store,
+                                _latent_self_attention(cfg)),
                  from_zero=True)
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x[:, -1], params["ln_f"], cfg.norm_eps)
@@ -1468,7 +1584,7 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
 
     store, read = _dense_rows(cfg, start, contract)
     mix = _mixer(cfg, _cache_attend(cfg, positions, store, read),
-                 _latent_attend(cfg, store, latent),
+                 _latent_attend(cfg, positions, store, latent),
                  valid_len=None if logits_row is None else logits_row + 1)
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
@@ -1645,7 +1761,7 @@ def _decode(params, state, tables, tokens, pos, cfg, loads=None):
         cfg, tables, pos,
         lambda q, view: _decode_attention(q, view, pos, cfg))
     mix = _mixer(cfg, _cache_attend(cfg, pos, store, read), _latent_attend(
-        cfg, store, lambda q, layer, rows, p: _latent_decode_attention(
+        cfg, pos, store, lambda q, layer, rows, p: _latent_decode_attention(
             q, layer, pos, p, cfg)))
     x, new_state = _run_layers(x, params, state, cfg, mix, loads)
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)

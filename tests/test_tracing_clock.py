@@ -100,7 +100,8 @@ def _batcher(**kw):
 
 
 # what the batcher counts while its spans record (models/serving.py
-# _count_dispatch and _fresh_row; a model with routed experts adds moe.*)
+# _count_dispatch and _fresh_row; a model with routed experts adds moe.*,
+# one with latent layers mla.*)
 WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
                       "serving.fresh_rows"}
 
