@@ -31,7 +31,9 @@ labelled with the device.
 
 With default settings NO Pallas kernel is on paths A/B (flash attention
 needs cfg.use_flash_kernel and T >= 8192, the paged kernel needs
-MXNET_PAGED_DECODE_PALLAS=1): kernel coverage is phase K alone.
+MXNET_PAGED_DECODE_PALLAS=1; latent_decode is on every "mla" layer's
+decode path, and neither model here has one): kernel coverage is phase K
+alone.
 """
 
 import argparse
@@ -49,6 +51,8 @@ REAL = {
     "flash": dict(b=2, t=8192, h=16, d=128),
     "decode": dict(b=8, t=4096, h=16, d=128, kvh=2),
     "paged": dict(nblocks=2048, bs=16, kvh=4),
+    # the Kimi-K2.6 cell's decode contraction: 32 lanes x 19,456 rows
+    "latent": dict(b=32, h=64, t=19456, r=512, e=64),
 }
 TINY = {
     "resnet": dict(batch=8, size=32, classes=10, steps=3),
@@ -58,6 +62,7 @@ TINY = {
     "flash": dict(b=1, t=256, h=2, d=128),
     "decode": dict(b=2, t=256, h=4, d=128, kvh=2),
     "paged": dict(nblocks=33, bs=16, kvh=2),
+    "latent": dict(b=3, h=4, t=256, r=128, e=64),
 }
 
 # one fixed batch, no warm-up schedule: small enough that the first
@@ -554,6 +559,40 @@ def _k_paged(sz, dec, interpret):
     return out
 
 
+def _k_latent(sz, interpret):
+    """latent_decode (the one kernel on a default path: every "mla"
+    layer's decode contraction) against the two XLA passes it replaced,
+    at ragged lengths: the cell's cache, then one block that is no
+    multiple of 128 and three blocks of 128, the smallest the kernel
+    tiles with."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.kernels import latent_decode, latent_decode_reference
+    b, h, r, e = (sz[k] for k in "bhre")
+    norm = float(np.sqrt(r + e))
+    out = {"ok": True, "shape": [b, h, sz["t"], r + e]}
+    for t in (sz["t"], 192, 384):
+        ks = jax.random.split(jax.random.PRNGKey(t), 4)
+        q_lat = jax.random.normal(ks[0], (b, h, r), jnp.bfloat16)
+        q_r = jax.random.normal(ks[1], (b, h, e), jnp.bfloat16)
+        c = jax.random.normal(ks[2], (b, t, r), jnp.bfloat16)
+        kr = jax.random.normal(ks[3], (b, t, e), jnp.bfloat16)
+        lengths = jnp.asarray([1 + (t - 1) * i // max(b - 1, 1)
+                               for i in range(b)], jnp.int32)
+        got, kernel = _compiled(
+            lambda *a: latent_decode(*a, norm, interpret=interpret),
+            q_lat, q_r, c, kr, lengths)
+        ref = jax.jit(lambda *a: latent_decode_reference(*a, norm))(
+            q_lat, q_r, c, kr, lengths)
+        err, scale = _err(got, ref)
+        out["ok"] = out["ok"] and err <= BF16_TOL * scale \
+            and (kernel or interpret)
+        out["t%d" % t] = {"kernel_in_hlo": kernel,
+                          "max_abs_err": round(err, 5)}
+    return out
+
+
 def phase_kernels(sizes, rehearse, carry):
     import jax
     interpret = jax.default_backend() != "tpu"     # only under --rehearse
@@ -563,6 +602,7 @@ def phase_kernels(sizes, rehearse, carry):
         "flash_carry_block": _k_carry(sizes["flash"], interpret),
         "paged_attention": _k_paged(sizes["paged"], sizes["decode"],
                                     interpret),
+        "latent_decode": _k_latent(sizes["latent"], interpret),
     }
     rec = {"ok": all(p.pop("ok") for p in parts.values()),
            "interpreted": interpret, "tolerance": BF16_TOL}

@@ -10,6 +10,8 @@ interpret mode elsewhere, so their tests execute on any backend.
 from .flash_attention import (flash_attention, flash_decode,
                               dense_decode_with_lse)
 from .paged_decode import paged_attention
+from .latent_decode import latent_decode, latent_decode_reference
 
 __all__ = ["flash_attention", "flash_decode",
-           "dense_decode_with_lse", "paged_attention"]
+           "dense_decode_with_lse", "paged_attention",
+           "latent_decode", "latent_decode_reference"]

@@ -1063,13 +1063,14 @@ class ContinuousBatcher(object):
                                      jnp.int32)
             self._lane_blocks = [[] for _ in range(self.max_batch)]
             self._lane_need = [0] * self.max_batch
-            # scheduled position per lane = device pos after every
-            # dispatched chunk (the pipelined carry never syncs it);
-            # drives the lazy pre-dispatch block allocation
-            self._sched_pos = np.zeros((self.max_batch,), np.int64)
             self._cache = None
         else:
             self._cache = tf.init_cache(cfg, self.max_batch)
+        # under the device carry: the scheduled position a lane = the
+        # device's after every dispatched chunk (the pipelined carry
+        # never syncs it; a speculative dispatch's worst case until its
+        # sync reconciles it). It drives the lazy pre-dispatch block
+        # allocation and the count of latent rows fetched
         self._pos = np.zeros((self.max_batch,), np.int32)
         self._tok = np.zeros((self.max_batch,), np.int32)
         self._keys = np.zeros((self.max_batch, 2), np.uint32)
@@ -1091,8 +1092,10 @@ class ContinuousBatcher(object):
             l for kind, l in row if kind not in tf._RECURRENT) \
             // cfg.max_len
         if self._device_carry:
-            # device-resident lane carry (the host-side mirrors above
-            # go unused): tok/pos/keys live on device between
+            # device-resident lane carry (of the host-side mirrors
+            # above only _pos is kept, patched with the carry and
+            # advanced at every dispatch): tok/pos/keys live on device
+            # between
             # dispatches, so a chunk dispatch uploads nothing and a
             # chunk sync downloads only the [k, B] emissions
             self._dev_tok = jnp.zeros((self.max_batch,), jnp.int32)
@@ -1100,8 +1103,9 @@ class ContinuousBatcher(object):
             self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
             # in-flight dispatches, oldest first: (emissions [k, B],
             # per-lane rid snapshot at dispatch time, the chunk's
-            # routing counts or None) — speculative records carry
-            # (targets, emits, rids, keff) instead
+            # routing counts or None, the positions it was given) —
+            # speculative records carry (targets, emits, rids, keff)
+            # instead
             self._inflight = deque()
             # resolved once — a pipelined dispatch must not pay the
             # _serving_jit registry lookup per chunk
@@ -1619,7 +1623,6 @@ class ContinuousBatcher(object):
             self._tables, jnp.int32(slot), jnp.asarray(trow))
         self._lane_blocks[slot] = lane
         self._lane_need[slot] = lifetime
-        self._sched_pos[slot] = t_p
 
     def _ensure_coverage(self, k):
         """Allocate (lazily) the blocks the next k decode positions of
@@ -1632,10 +1635,8 @@ class ContinuousBatcher(object):
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
-            pos = int(self._sched_pos[i] if self._device_carry
-                      else self._pos[i])
-            end = min((pos + k - 1) // bs, self._lane_need[i] - 1,
-                      self._nb - 1)
+            end = min((int(self._pos[i]) + k - 1) // bs,
+                      self._lane_need[i] - 1, self._nb - 1)
             while len(self._lane_blocks[i]) <= end:
                 bid = self._alloc.alloc(1)[0]
                 self._alloc.unreserve(1)
@@ -1836,8 +1837,8 @@ class ContinuousBatcher(object):
             if not self.paged:         # paged: blocks already scattered
                 self._cache = _jitted_slot_write(self.cfg)(
                     self._cache, row_cache, jnp.int32(slot))
-            self._pos[slot] = t_p      # next decode writes position t_p
             self._tok[slot] = first
+        self._pos[slot] = t_p          # next decode writes position t_p
         if self._spec_on:
             self._spec_admit(slot, prompt, t_p, first)
         pre_span.stop()
@@ -1931,9 +1932,9 @@ class ContinuousBatcher(object):
                                    jnp.int32(last), jnp.int32(m),
                                    jnp.asarray(key_np))
         else:
-            self._pos[slot] = m
             self._tok[slot] = last
             self._keys[slot] = key_np
+        self._pos[slot] = m
         if self._spec_on:
             self._spec_admit(slot, ctx, m, last)
         pre_span.stop()
@@ -2355,8 +2356,8 @@ class ContinuousBatcher(object):
                     self._count_routing(routing[0])
                 if self._latent_layers and _obs.active():
                     self._count_latent_rows(
-                        [len(r.tokens) for r in self._slots
-                         if r is not None], k)
+                        self._pos, [len(r.tokens) for r in self._slots
+                                    if r is not None], k)
                 toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
                 if self.paged:
                     self._pool = state
@@ -2424,16 +2425,21 @@ class ContinuousBatcher(object):
         for name, n in zip(tf.MOE_STATS, np.asarray(routing)):
             _obs.counter("moe." + name).add(int(n))
 
-    def _count_latent_rows(self, live, steps):
+    def _count_latent_rows(self, pos, live, steps):
         """A dispatch's latent rows (a model with latent attention)
         into the counters mla.rows_read, what its decode contractions
-        read: max_len rows of every lane, live or not, a latent layer a
-        step; and mla.rows_live, those of them at or before a lane's
-        own position: `live` holds each live lane's rows at the
-        dispatch's first step (its tokens so far), one more a step."""
+        fetch: for every lane, with a request or not, whole blocks up to
+        the position the dispatch gave it (`pos`, all max_batch lanes at
+        the first step, one more a step: kernels/latent_decode.py
+        rows_fetched), a latent layer a step; and mla.rows_live, those
+        of them at or before a live lane's own position: `live` holds
+        each live lane's rows at the dispatch's first step (its tokens
+        so far), one more a step."""
+        from ..kernels.latent_decode import rows_fetched
         n = self._latent_layers
-        _obs.counter("mla.rows_read").add(
-            n * steps * self.max_batch * self.cfg.max_len)
+        _obs.counter("mla.rows_read").add(n * rows_fetched(
+            np.asarray(pos)[None, :] + 1 + np.arange(steps)[:, None],
+            self.cfg.max_len))
         _obs.counter("mla.rows_live").add(
             n * (steps * sum(live) + len(live) * steps * (steps - 1) // 2))
 
@@ -2531,15 +2537,14 @@ class ContinuousBatcher(object):
                 self._cache = state
         self._dispatch_failures = 0
         self._count_dispatch(ahead=bool(self._inflight))
-        if self.paged:
-            # every lane's device position advances k per chunk —
-            # mirror it so the NEXT dispatch's coverage is exact
-            self._sched_pos += self.chunk_size
         self._dev_tok, self._dev_pos, self._dev_keys = tok, pos, keys
         self._inflight.append(
             (toks, [r.rid if r is not None else None
                     for r in self._slots],
-             routing[0] if routing else None))
+             routing[0] if routing else None, self._pos.copy()))
+        # every lane's device position advances k per chunk — mirror
+        # it so the NEXT dispatch's coverage is exact
+        self._pos += self.chunk_size
         if _obs.enabled():
             _obs.gauge("serving.inflight_depth").set(
                 len(self._inflight))
@@ -2551,7 +2556,7 @@ class ContinuousBatcher(object):
         DISPATCHED (and still do): evicted or re-admitted lanes are
         discarded, a request ending mid-chunk keeps only its prefix.
         This is the only host-blocking point of the pipelined loop."""
-        toks_dev, lanes, routing = self._inflight.popleft()
+        toks_dev, lanes, routing, pos = self._inflight.popleft()
         counting = routing is not None and _obs.active()
         if counting:
             # a model with routed experts: the chunk's counts ride the
@@ -2565,8 +2570,8 @@ class ContinuousBatcher(object):
         if self._latent_layers and _obs.active():
             # the lanes this chunk still speaks for (the loop below)
             self._count_latent_rows(
-                [len(r.tokens) for r, rid in zip(self._slots, lanes)
-                 if r is not None and r.rid == rid and not r.done],
+                pos, [len(r.tokens) for r, rid in zip(self._slots, lanes)
+                      if r is not None and r.rid == rid and not r.done],
                 toks.shape[0])
         obs_on = _obs.enabled()
         t_sync = time.perf_counter_ns() if obs_on else None
@@ -2644,9 +2649,9 @@ class ContinuousBatcher(object):
         """Issue one speculative dispatch (chunk_size draft/verify
         rounds) against the device-resident carry. Paged coverage is
         reserved for the WORST case — every lane accepting every draft
-        every round — and the sync reconciles `_sched_pos` down to the
+        every round — and the sync reconciles `_pos` down to the
         measured acceptance, releasing the over-reserved draft blocks
-        (see _reconcile_sched_pos)."""
+        (see _reconcile_pos)."""
         worst = self.chunk_size * (self.spec_k + 1)
         if self.paged:
             self._ensure_coverage(worst)
@@ -2705,11 +2710,10 @@ class ContinuousBatcher(object):
                 self._cache, self._dcache = cache, dcache
         self._dispatch_failures = 0
         self._count_dispatch(ahead=bool(self._inflight))
-        if self.paged:
-            # worst-case position mirror so the NEXT dispatch's
-            # coverage is sufficient whatever this one accepts; the
-            # sync subtracts the measured shortfall back out
-            self._sched_pos += worst
+        # worst-case position mirror so the NEXT dispatch's coverage
+        # is sufficient whatever this one accepts; the sync subtracts
+        # the measured shortfall back out
+        self._pos += worst
         self._dev_tok, self._dev_pos = tok, pos
         self._inflight.append(
             (targets, emits,
@@ -2792,16 +2796,15 @@ class ContinuousBatcher(object):
                     self._note_finish(req, t_sync)
                 self._note_done(req)
                 self._free(i)
-        if self.paged:
-            self._reconcile_sched_pos(emits, lanes)
+        self._reconcile_pos(emits, lanes)
         if obs_on:
             _obs.gauge("serving.spec_draft_ratio").set(
                 self._spec_accepted / max(self._spec_drafted, 1))
             self._publish_occupancy()
         return finished
 
-    def _reconcile_sched_pos(self, emits, lanes):
-        """Walk `_sched_pos` back from the dispatch-time worst case to
+    def _reconcile_pos(self, emits, lanes):
+        """Walk `_pos` back from the dispatch-time worst case to
         the measured per-lane advance and release the block tail the
         lane over-reserved for drafts it did not accept. Only lanes
         whose occupant is UNCHANGED since dispatch (rid snapshot
@@ -2815,8 +2818,9 @@ class ContinuousBatcher(object):
             req = self._slots[i]
             if req is None or req.rid != rid:
                 continue
-            self._sched_pos[i] -= worst - int(advance[i])
-            self._trim_lane_blocks(i)
+            self._pos[i] -= worst - int(advance[i])
+            if self.paged:
+                self._trim_lane_blocks(i)
 
     def _trim_lane_blocks(self, i):
         """Release lane i's allocated blocks beyond its reconciled
@@ -2825,13 +2829,13 @@ class ContinuousBatcher(object):
         early for a worst case that did not happen). Safe against
         in-flight dispatches: their writes are bounded by the KEPT
         coverage (every dispatch's worst case beyond the synced one is
-        still counted in _sched_pos), and a trimmed block's positions
+        still counted in _pos), and a trimmed block's positions
         sit above every in-flight query position, so stale table
         snapshots can only reach it through masked-out attention rows.
         Trimmed blocks are always refcount-1: sharing only ever covers
         prompt-prefix blocks, which reconciled coverage never drops."""
         bs = self.block_size
-        keep = min(max(int(self._sched_pos[i]) - 1, 0) // bs,
+        keep = min(max(int(self._pos[i]) - 1, 0) // bs,
                    self._lane_need[i] - 1) + 1
         blocks = self._lane_blocks[i]
         while len(blocks) > max(keep, 1):
@@ -2932,7 +2936,6 @@ class ContinuousBatcher(object):
             self._alloc = BlockAllocator(self.num_blocks)
             self._lane_blocks = [[] for _ in range(self.max_batch)]
             self._lane_need = [0] * self.max_batch
-            self._sched_pos = np.zeros((self.max_batch,), np.int64)
             self._prefix_cache.clear()
             # the fresh allocator parks nothing: the brownout ledger
             # must agree, or its walk-down would grow past the
@@ -3018,9 +3021,9 @@ class ContinuousBatcher(object):
                                jnp.int32(last), jnp.int32(m),
                                jnp.asarray(key_np))
         else:
-            self._pos[slot] = m
             self._tok[slot] = last
             self._keys[slot] = key_np
+        self._pos[slot] = m
         if self._spec_on:
             # re-seed the lane's draft state from the synced prefix —
             # the requeue resumes exactly like a fresh admission whose
@@ -3268,7 +3271,6 @@ class ContinuousBatcher(object):
             self._alloc.unreserve(self._lane_need[i] - len(blocks))
             self._lane_blocks[i] = []
             self._lane_need[i] = 0
-            self._sched_pos[i] = 0
             self._tables = _jitted_table_row(self.cfg)(
                 self._tables, jnp.int32(i),
                 jnp.zeros((self._nb,), jnp.int32))
@@ -3281,8 +3283,8 @@ class ContinuousBatcher(object):
                                    jnp.int32(0), jnp.int32(0),
                                    jnp.zeros((2,), jnp.uint32))
         else:
-            self._pos[i] = 0
             self._tok[i] = 0
+        self._pos[i] = 0
         if self._spec_on:
             # reset the adaptive-k controller for the next occupant
             # (the hist row / draft cache need no clearing — the next

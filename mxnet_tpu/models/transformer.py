@@ -1400,22 +1400,24 @@ def _latent_decode_attention(q, rows, pos, p, cfg):
     rows ([B, T, ...]), attending t <= pos (scalar or [B]). W_kvb's key
     half moves into the query ([H, R]) and its value half behind the
     weighted sum, so the contraction runs over the latents as they lie
-    in the cache and no head's key or value is ever formed. Returns
-    [B, H, V]."""
+    in the cache and no head's key or value is ever formed; the
+    contraction itself is kernels/latent_decode.py: one pass over each
+    lane's rows up to its position, no score plane (the same sums as
+    two XLA passes over all T rows where the kernel cannot tile T:
+    latent_block). Returns [B, H, V]."""
+    from ..kernels.latent_decode import (
+        latent_block, latent_decode, latent_decode_reference)
     n = _mla_sizes(cfg)[1]
     c, kr = rows["c"], rows["kr"]
+    norm = _latent_score_norm(cfg, q.shape[-1])
     with jax.named_scope("mx.mla.absorbed"):
         q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :n], p["wkvb"][..., :n])
-        s = (jnp.einsum("bhr,btr->bht", q_lat, c,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bhe,bte->bht", q[..., n:], kr,
-                          preferred_element_type=jnp.float32)) \
-            / _latent_score_norm(cfg, q.shape[-1])
-        seen = jnp.arange(c.shape[1])[None, :] \
-            <= jnp.atleast_1d(pos)[:, None]
-        a = jax.nn.softmax(jnp.where(seen[:, None, :], s, -1e30), axis=-1)
-        o = jnp.einsum("bht,btr->bhr", a.astype(c.dtype), c,
-                       preferred_element_type=jnp.float32)
+        if latent_block(c.shape[1]) is None:
+            o = latent_decode_reference(
+                q_lat, q[..., n:], c, kr,
+                jnp.broadcast_to(pos + 1, c.shape[:1]), norm)
+        else:
+            o = latent_decode(q_lat, q[..., n:], c, kr, pos + 1, norm)
         return jnp.einsum("bhr,rhv->bhv", o.astype(q.dtype),
                           p["wkvb"][..., n:])
 

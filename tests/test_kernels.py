@@ -796,3 +796,151 @@ def test_dense_decode_with_lse_matches_flash_contract():
         assert np.abs(np.asarray(o_d[2])).max() == 0.0
         assert (np.asarray(lse_d[2]) < -1e29).all()
         assert (np.asarray(lse_f[2]) < -1e29).all()
+
+
+# ------------------------------------------- latent attention's decode ---
+
+def _latent_operands(seed, b, h, r, e, t, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, h, r), dtype),
+            jax.random.normal(ks[1], (b, h, e), dtype),
+            jax.random.normal(ks[2], (b, t, r), dtype),
+            jax.random.normal(ks[3], (b, t, e), dtype))
+
+
+def _nan_past(rows, lengths):
+    """rows [B, T, F] with every position at or past its lane's length
+    set to NaN: what must never reach a sum."""
+    dead = jnp.arange(rows.shape[1])[None, :, None] \
+        >= jnp.asarray(lengths)[:, None, None]
+    return jnp.where(dead, jnp.nan, rows)
+
+
+@pytest.mark.parametrize("t", [64, 192, 384, 2048],
+                         ids=["one-block", "one-odd-block", "three-of-128",
+                              "two-of-1024"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2),
+                                       (jnp.float32, 2e-5)],
+                         ids=["bf16", "float32"])
+@pytest.mark.parametrize("h", [32, 64])
+def test_latent_decode_matches_the_two_xla_passes(h, dtype, tol, t):
+    """The kernel (interpreted here) against the XLA text it replaced, at
+    ragged lengths: one row, a block's edge, the edge plus one, the whole
+    cache, a parked lane (position 0 with another request's rows behind
+    it) and a length past the cache, which is clamped. Every row at or
+    past a lane's length is NaN on the kernel's side: a dead block that
+    was read into a sum, or a dead row of the last live block that
+    reached the second dot, would show."""
+    from mxnet_tpu.kernels.latent_decode import (
+        latent_block, latent_decode, latent_decode_reference)
+    block = latent_block(t)
+    assert t // block == {64: 1, 192: 1, 384: 3, 2048: 2}[t]
+    lengths = [1, block, min(block + 1, t), t, 1, t + 5]
+    q_lat, q_r, c, kr = _latent_operands(h + t, len(lengths), h, 128, 64,
+                                         t, dtype)
+    norm = float(np.sqrt(192.0))
+    want = latent_decode_reference(
+        q_lat, q_r, c, kr, jnp.minimum(jnp.asarray(lengths), t), norm)
+    got = latent_decode(q_lat, q_r, _nan_past(c, lengths),
+                        _nan_past(kr, lengths), jnp.asarray(lengths), norm)
+    assert got.shape == (len(lengths), h, 128) and got.dtype == jnp.float32
+    assert not bool(jnp.isnan(got).any())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("pos", [7, [0, 127, 128, 383]],
+                         ids=["scalar", "per-lane"])
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "yarn"])
+def test_the_latent_decode_contraction_attends_up_to_pos(pos, scaled):
+    """transformer._latent_decode_attention (absorb, kernel, up-project)
+    with a scalar position and one a lane, with and without a scaling
+    record's softmax scale, against the absorbed contraction as plain
+    einsums."""
+    from mxnet_tpu.kernels.latent_decode import latent_decode_reference
+    from mxnet_tpu.models import transformer as tf
+    b, h, t = 4, 4, 384
+    cfg = tf.TransformerConfig(
+        n_heads=h, max_len=t, rope=scaled,
+        positions="rope" if scaled else "none",
+        layer_kinds=("mla",) * 2, mla_rank=32, mla_nope_dim=16,
+        mla_rope_dim=8, mla_v_dim=12,
+        rope_scaling=tf.YarnScaling(8.0, 32, 4.0, 1.0, 1.0, 0.707)
+        if scaled else None)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (b, h, 24))
+    rows = {"c": jax.random.normal(ks[1], (b, t, 32)),
+            "kr": jax.random.normal(ks[2], (b, t, 8))}
+    p = {"wkvb": jax.random.normal(ks[3], (32, h, 28)) / 6}
+    pos = jnp.asarray(pos, jnp.int32)
+    got = tf._latent_decode_attention(q, rows, pos, p, cfg)
+    o = latent_decode_reference(
+        jnp.einsum("bhn,rhn->bhr", q[..., :16], p["wkvb"][..., :16]),
+        q[..., 16:], rows["c"], rows["kr"],
+        jnp.broadcast_to(pos + 1, (b,)), tf._latent_score_norm(cfg, 24))
+    want = jnp.einsum("bhr,rhv->bhv", o, p["wkvb"][..., 16:])
+    assert got.shape == (b, h, 12)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("t,block", [(19456, 1024), (11264, 1024),
+                                     (4096, 1024), (1536, 512), (384, 128),
+                                     (20096, 128), (192, 192), (64, 64),
+                                     (100, 100), (1000, 1000), (1032, None),
+                                     (20000, None)])
+def test_a_latent_block_is_the_largest_power_of_two_dividing_the_cache(
+        t, block):
+    """Down to 128 (the rows lie on `kr`'s lanes); a cache nothing
+    divides is one block while it is no more than the largest, and past
+    that has none: the kernel refuses it by name."""
+    from mxnet_tpu.kernels.latent_decode import latent_block, latent_decode
+    assert latent_block(t) == block
+    if block is None:
+        q_lat, q_r, c, kr = _latent_operands(0, 1, 4, 32, 8, t, jnp.float32)
+        with pytest.raises(ValueError, match="cannot tile a cache of %d" % t):
+            latent_decode(q_lat, q_r, c, kr, 5, 1.0)
+
+
+@pytest.mark.parametrize("t,kernel", [(384, True), (1000, True),
+                                      (1032, False)])
+def test_the_latent_contraction_is_the_kernel_wherever_it_tiles(t, kernel):
+    """_latent_decode_attention chooses by the rows' shape: the kernel,
+    or for a cache of more than one block that 128 does not divide the
+    two XLA passes, with the same answer either way."""
+    from mxnet_tpu.kernels.latent_decode import latent_decode_reference
+    from mxnet_tpu.models import transformer as tf
+    b, h = 3, 4
+    cfg = tf.TransformerConfig(
+        n_heads=h, max_len=t, rope=False, positions="none",
+        layer_kinds=("mla",) * 2, mla_rank=32, mla_nope_dim=16,
+        mla_rope_dim=8, mla_v_dim=12)
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    q = jax.random.normal(ks[0], (b, h, 24))
+    rows = {"c": jax.random.normal(ks[1], (b, t, 32)),
+            "kr": jax.random.normal(ks[2], (b, t, 8))}
+    p = {"wkvb": jax.random.normal(ks[3], (32, h, 28)) / 6}
+    pos = jnp.asarray([0, 200, t - 1], jnp.int32)
+    fn = lambda q, rows, pos: tf._latent_decode_attention(q, rows, pos, p,
+                                                          cfg)
+    assert ("pallas_call" in str(jax.make_jaxpr(fn)(q, rows, pos))) == kernel
+    o = latent_decode_reference(
+        jnp.einsum("bhn,rhn->bhr", q[..., :16], p["wkvb"][..., :16]),
+        q[..., 16:], rows["c"], rows["kr"], pos + 1,
+        tf._latent_score_norm(cfg, 24))
+    np.testing.assert_allclose(
+        np.asarray(fn(q, rows, pos)),
+        np.asarray(jnp.einsum("bhr,rhv->bhv", o, p["wkvb"][..., 16:])),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_rows_fetched_are_whole_blocks_up_to_each_lanes_length():
+    """What serving.py's mla.rows_read adds: beside the kernel, from the
+    same block and the same clamp."""
+    from mxnet_tpu.kernels.latent_decode import rows_fetched
+    assert rows_fetched([1, 1024, 1025, 19456, 0, 20000], 19456) \
+        == 1024 + 1024 + 2048 + 19456 + 1024 + 19456
+    assert rows_fetched([[5, 128], [6, 129]], 384) == 128 + 128 + 128 + 256
+    # one block, and a cache the kernel cannot tile: all of it a lane
+    assert rows_fetched([5, 192], 192) == 2 * 192
+    assert rows_fetched([1, 500, 0], 20000) == 3 * 20000

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from chipbench import manifest
 from chipbench.reference import kimi_k2 as ref
 from chipbench.runners import serve_kimi_k2
+from mxnet_tpu.kernels.latent_decode import latent_block
 from mxnet_tpu.models import serving, transformer as tf
 from mxnet_tpu.models.serving import ContinuousBatcher
 from mxnet_tpu.observability import attribution, core as obs
@@ -422,18 +423,22 @@ def test_paged_blocks_int8_and_speculation_still_refuse_the_kind_by_name(
 
 # ------------------------------------------------------------ counters ---
 
-def test_a_decode_round_counts_the_rows_it_read_and_those_that_live(
+def test_a_decode_round_counts_the_rows_it_fetched_and_those_that_live(
         sides, telemetry):
-    """Three latent layers, two lanes of max_len 64: a round reads 3 x 2
-    x 64 rows whatever the contexts; live are the rows at or before each
-    lane's position (5 + 1 and 3 + 1 tokens at the first round, one more
-    a lane a round)."""
+    """Three latent layers, two lanes of max_len 64, which is one block
+    of the kernel: a round fetches a lane's one block, 3 x 2 x 64 rows,
+    wherever the lanes stand; live are the rows at or before each lane's
+    position (5 + 1 and 3 + 1 tokens at the first round, one more a lane
+    a round), never more than were fetched."""
     params, cfg, _ = sides
+    assert latent_block(cfg.max_len) == 64
     srv = ContinuousBatcher(params, cfg, max_batch=2, pipeline_depth=1)
     srv.admit([5, 6, 7, 8, 9], 6)
     srv.admit([1, 2, 3], 6)
     for _ in range(3):
         srv.step()
+        assert obs.counter("mla.rows_live").value \
+            <= obs.counter("mla.rows_read").value
     assert obs.counter("mla.rows_read").value == 3 * (3 * 2 * 64)
     assert obs.counter("mla.rows_live").value \
         == 3 * ((6 + 4) + (7 + 5) + (8 + 6))
@@ -444,9 +449,13 @@ def test_a_decode_round_counts_the_rows_it_read_and_those_that_live(
 
 
 def test_two_rounds_in_flight_count_the_rows_one_does(sides, telemetry):
-    """The counts are taken when a round's tokens are fetched, from what
-    the host knows of the lanes then, so the pipelined loop adds what the
-    synchronous one does; an idle lane's rows are read and none lives."""
+    """The counts are taken when a round's tokens are fetched: the rows
+    fetched from the positions the round was dispatched with, every lane
+    of max_batch among them, the live ones from what the host knows of
+    the lanes then; so the pipelined loop adds what the synchronous one
+    does. A lane without a request is parked at position 0 and fetched
+    up to where the carry has moved it since: one block here, and none
+    of its rows lives."""
     params, cfg, _ = sides
     jobs = [([5, 6, 7, 8, 9], 7), ([1, 2, 3], 7)]
     read = {}
@@ -460,6 +469,7 @@ def test_two_rounds_in_flight_count_the_rows_one_does(sides, telemetry):
     assert read[1] == read[2]
     assert read[1][0] == 6 * (3 * 3 * 64)              # six decode rounds
     assert read[1][1] == 3 * sum((6 + i) + (4 + i) for i in range(6))
+    assert read[1][1] <= read[1][0]
 
 
 @pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
@@ -471,6 +481,46 @@ def test_a_chunked_round_counts_every_step_of_it(sides, telemetry, loop):
     srv.step()
     assert obs.counter("mla.rows_read").value == 4 * (3 * 2 * 64)
     assert obs.counter("mla.rows_live").value == 3 * (6 + 7 + 8 + 9)
+
+
+@pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
+                         ids=["default", "depth1"])
+def test_the_rows_fetched_follow_the_lanes_lengths(sides, telemetry, loop):
+    """max_len 384 is three blocks of 128. Over 30 rounds a lane that
+    starts at 126 rows fetches one block until it holds 129, then two;
+    one that starts at 6 fetches one throughout; the third lane has no
+    request and is fetched up to its parked position's block: the same
+    counts with one round in flight and with two."""
+    params, cfg, _ = sides
+    cfg = dataclasses.replace(cfg, max_len=384)
+    assert latent_block(cfg.max_len) == 128
+    srv = ContinuousBatcher(params, cfg, max_batch=3, **loop)
+    srv.admit([5, 6, 7, 8, 9], 60)
+    srv.admit(list(_tokens(3, 125)), 60)
+    for _ in range(30):
+        srv.step()
+    blocks = lambda rows: -(-rows // 128) * 128
+    assert obs.counter("mla.rows_read").value == 3 * sum(
+        blocks(6 + i) + blocks(126 + i) + 128 for i in range(30)) \
+        == 3 * (30 * 128 + 3 * 128 + 27 * 256 + 30 * 128)
+    assert obs.counter("mla.rows_live").value == 3 * sum(
+        (6 + i) + (126 + i) for i in range(30))
+    assert obs.counter("mla.rows_read").value < 30 * (3 * 3 * 384)
+
+
+def test_a_cache_the_kernel_cannot_tile_counts_every_row(sides, telemetry):
+    """max_len 1,032 is more than one block and no multiple of 128: the
+    decode contraction is the two XLA passes over all of it, and the
+    counter says so: max_len rows a lane a latent layer a round."""
+    params, cfg, _ = sides
+    cfg = dataclasses.replace(cfg, max_len=1032)
+    assert latent_block(cfg.max_len) is None
+    srv = ContinuousBatcher(params, cfg, max_batch=2)
+    srv.admit([5, 6, 7, 8, 9], 6)
+    for _ in range(3):
+        srv.step()
+    assert obs.counter("mla.rows_read").value == 3 * (3 * 2 * 1032)
+    assert obs.counter("mla.rows_live").value == 3 * (6 + 7 + 8)
 
 
 def test_a_model_without_latent_layers_counts_no_rows(telemetry):

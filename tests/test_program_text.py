@@ -10,7 +10,11 @@ A change that means to alter one of these programs updates its hash:
 
     JAX_PLATFORMS=cpu python tests/test_program_text.py
 
-prints them all, and says in PERF.md what the chip read afterwards."""
+prints them all, and says in PERF.md what the chip read afterwards.
+
+PR 42 updated `kimi-linear.decode` on purpose: its two latent layers'
+decode contraction became the kernel of kernels/latent_decode.py
+(interpreted in the text a CPU lowers); the other nine are the parent's."""
 
 import hashlib
 import os
@@ -107,6 +111,7 @@ PROGRAMS = {
     "cerebras.train-step": (_train_step, "cerebras-gpt-1.3b-train-8k"),
 }
 # sha256 of the StableHLO text, first 16 hex digits, at the parent commit
+# (of PR 41; one line says where a later PR moved it)
 AT_THE_PARENT = {
     "cerebras.admission-512": "aba915c111ccf1db",
     "cerebras.decode": "59e8d1ef85648873",
@@ -115,7 +120,7 @@ AT_THE_PARENT = {
     "jamba.decode": "169f587ab80ff84e",
     "kimi-linear.admission-1024": "266ea9a975fca4c1",
     "kimi-linear.admission-8192": "02be86bc0f1a7253",
-    "kimi-linear.decode": "cdedc4186ecc1b7b",
+    "kimi-linear.decode": "7441c29a6496fec8",   # PR 42: mla_decode
     "kimi-linear.forward-256": "e346f4bf91718edf",
     "kimi-linear.prefill-64": "3169c5673d754d47",
 }

@@ -261,7 +261,7 @@ def test_spec_requeue_on_dispatch_failure(provider, fresh_rows):
 
 def test_spec_block_release_on_reject():
     """Paged composition invariants: every dispatch reserves worst-case
-    coverage, every sync walks `_sched_pos` back to measured acceptance
+    coverage, every sync walks `_pos` back to measured acceptance
     and RELEASES the over-materialized tail (back into reservation, so
     admission accounting never drifts). With depth 1 the reconciled
     state is exact after every step."""
@@ -285,7 +285,7 @@ def test_spec_block_release_on_reject():
             # depth 1: nothing in flight after step(), so the lane's
             # materialized blocks exactly cover its reconciled
             # position — the worst-case draft tail was trimmed
-            want = min((int(srv._sched_pos[i]) - 1) // bs + 1,
+            want = min((int(srv._pos[i]) - 1) // bs + 1,
                        srv._lane_need[i])
             assert len(srv._lane_blocks[i]) == want, (i, want)
             # released tail went back into reservation, not thin air

@@ -141,6 +141,65 @@ def test_paged_attention(one_chip, int8, span):
              q, pool, tables, pos)
 
 
+@pytest.mark.parametrize("heads,rows", [(64, 19456), (32, 11264)],
+                         ids=["kimi-k2.6", "kimi-linear"])
+def test_latent_decode(one_chip, heads, rows):
+    """The one kernel on a default path, at the two Kimi cells' real
+    shapes: 32 lanes, rank-512 latents and a 64-wide shared key part. The
+    chip keeps `kr` with T minor, the order the kernel's block reads it
+    in: no whole-array copy may stand in front of the call."""
+    from mxnet_tpu.kernels.latent_decode import latent_decode
+    lanes = 32
+    compiled = _compile(
+        functools.partial(latent_decode, norm=float(np.sqrt(192.0)),
+                          interpret=False),
+        _sds(one_chip, (lanes, heads, 512)), _sds(one_chip, (lanes, heads, 64)),
+        _sds(one_chip, (lanes, rows, 512)), _sds(one_chip, (lanes, rows, 64)),
+        _sds(one_chip, (lanes,), jnp.int32))
+    assert "mla_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [96, 192, 384, 20096],
+                         ids=["one-block-96", "one-block-192",
+                              "three-of-128", "157-of-128"])
+def test_latent_decode_at_cache_lengths_no_cell_has(one_chip, rows):
+    """At Kimi-K2.6's widths: one block that is no multiple of 128 (the
+    whole array, so Mosaic takes `kr`'s block with its rows on the
+    lanes) and blocks of 128, the smallest there is."""
+    from mxnet_tpu.kernels.latent_decode import latent_decode
+    lanes = 4
+    _compile(
+        functools.partial(latent_decode, norm=float(np.sqrt(192.0)),
+                          interpret=False),
+        _sds(one_chip, (lanes, 64, 512)), _sds(one_chip, (lanes, 64, 64)),
+        _sds(one_chip, (lanes, rows, 512)), _sds(one_chip, (lanes, rows, 64)),
+        _sds(one_chip, (lanes,), jnp.int32))
+
+
+def test_an_untileable_latent_cache_compiles_as_xla(one_chip):
+    """A long cache that 128 does not divide (20,000 rows) has no
+    kernel: transformer._latent_decode_attention keeps the XLA text for
+    it, which compiles for the chip, so a decode the parent ran at any
+    max_len still runs."""
+    from mxnet_tpu.models import transformer as tf
+    rows, lanes = 20000, 4
+    cfg = tf.TransformerConfig(
+        n_heads=64, max_len=rows, rope=False, positions="none",
+        layer_kinds=("mla",) * 2, mla_rank=512, mla_nope_dim=128,
+        mla_rope_dim=64, mla_v_dim=128)
+    lowered = jax.jit(
+        lambda q, c, kr, pos, w: tf._latent_decode_attention(
+            q, {"c": c, "kr": kr}, pos, {"wkvb": w}, cfg)).lower(
+        _sds(one_chip, (lanes, 64, 192)), _sds(one_chip, (lanes, rows, 512)),
+        _sds(one_chip, (lanes, rows, 64)),
+        _sds(one_chip, (lanes,), jnp.int32),
+        _sds(one_chip, (512, 64, 256)))
+    text = lowered.as_text()
+    assert "tpu_custom_call" not in text and "pallas" not in text
+    lowered.compile()
+
+
 @pytest.mark.parametrize("name,runner", [
     ("cerebras-gpt-1.3b", "lm_common"), ("jamba2-3b", "serve_jamba"),
     ("kimi-linear-48b-a3b", "serve_kimi_linear")])
