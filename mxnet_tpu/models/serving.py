@@ -36,6 +36,12 @@ Design notes (all static-shape, XLA-friendly):
   not, return beside their tokens the dispatch's routing counts
   (tf.MOE_STATS), which step() adds to the counters moe.<name> while
   spans record (the speculative programs count nothing).
+* HYPER-CONNECTIONS (cfg.hc_mult): the residual stream is n streams a
+  token across DEPTH, inside a program; nothing a lane keeps changes
+  (the streams are summed before the final norm). An admission's chunks
+  are sized by the n-stream carry (prefill_widths), and while spans
+  record the counter hc.rows counts the token rows x sub-layers every
+  dispatch and admission passed through the frame.
 * Idle slots keep lanes busy writing at position 0 of retired rows;
   the next admission's prefill overwrites them. Throughput is
   proportional to active lanes, latency to the slowest active row —
@@ -120,13 +126,14 @@ def _bucket(n, lo=8):
     return b
 
 
-# the stream elements (tokens x d_model) ONE prefill call of an admission
-# may hold: a longer run of tokens goes through prefill_chunk in whole
-# chunks of the largest power of two under it and a bucketed rest, so
-# that an admission's temporaries (some dozens of copies of the chunk's
-# stream, and k of them in a routed layer) are bounded by the model's
-# width and not by the prompt. 2^25: 16,384 tokens of a 2,048-wide
-# stream, 4,096 of a 7,168-wide one
+# the stream elements (tokens x streams x d_model; `hc_mult` streams a
+# token, else one) ONE prefill call of an admission may hold: a longer
+# run of tokens goes through prefill_chunk in whole chunks of the
+# largest power of two under it and a bucketed rest, so that an
+# admission's temporaries (some dozens of copies of the chunk's stream,
+# and k of them in a routed layer) are bounded by the model's width and
+# not by the prompt. 2^25: 16,384 tokens of a 2,048-wide stream, 4,096
+# of a 7,168-wide one, 2,048 of four streams of 3,584
 PREFILL_CHUNK_ELEMS = 1 << 25
 
 
@@ -138,7 +145,8 @@ def prefill_widths(cfg, n, start=0, pad=True):
     128) and the row is max_len wide; the caller has checked that
     start + n <= max_len, so a width never falls under its tokens."""
     chunk = 8
-    while 2 * chunk * cfg.d_model <= PREFILL_CHUNK_ELEMS:
+    while 2 * chunk * (cfg.hc_mult or 1) * cfg.d_model \
+            <= PREFILL_CHUNK_ELEMS:
         chunk *= 2
     whole, rest = divmod(n, chunk)
     widths = [chunk] * whole
@@ -1546,8 +1554,27 @@ class ContinuousBatcher(object):
             logits, row_cache = fn(
                 self.params, row_cache, jnp.asarray(padded),
                 jnp.int32(start + at), jnp.int32(len(part) - 1))
+            self._count_prefill(len(part), width)
             at += width
         return logits, row_cache
+
+    def _count_prefill(self, tokens, rows):
+        """While spans record: one prefill_chunk call of an admission
+        into the counters serving.prefill_tokens (the prompt's real
+        tokens it took in) and serving.prefill_rows (the rows it
+        computed, the bucket's padding included)."""
+        if _obs.active():
+            _obs.counter("serving.prefill_tokens").add(tokens)
+            _obs.counter("serving.prefill_rows").add(rows)
+            self._count_frame_rows(rows)
+
+    def _count_frame_rows(self, rows):
+        """While spans record, for a model with hyper-connections
+        (cfg.hc_mult): the token rows a dispatch passed through the
+        n-stream frame, one a sub-layer (two a layer), into the counter
+        hc.rows."""
+        if self.cfg.hc_mult is not None:
+            _obs.counter("hc.rows").add(2 * self.cfg.n_layers * rows)
 
     def _fresh_row(self, cfg=None):
         """A zeroed one-lane row of `cfg` (the target's, or the draft's)
@@ -1798,6 +1825,7 @@ class ContinuousBatcher(object):
                     tf._jitted_prefill_chunk_row(self.cfg)(
                         self.params, row_cache, jnp.asarray(padded),
                         jnp.int32(p_len), jnp.int32(t_p - p_len - 1))
+                self._count_prefill(t_p - p_len, width)
                 last = logits[0]
         if self._device_carry:
             # prefill-into-lane, all device-side: pick the first token
@@ -2368,7 +2396,7 @@ class ContinuousBatcher(object):
             self._end_round()
             return finished
         self._dispatch_failures = 0
-        self._count_dispatch(ahead=False)
+        self._count_dispatch(ahead=False, steps=k)
         t_sync = time.perf_counter_ns() if obs_on else None
         # np.array (copy): asarray would give a READ-ONLY view of the
         # device buffer and the next admit()'s in-place key write fails
@@ -2406,17 +2434,19 @@ class ContinuousBatcher(object):
         self._end_round()
         return finished
 
-    def _count_dispatch(self, ahead):
-        """One more target-model dispatch. While spans record, also the
-        counters serving.dispatches and serving.dispatch_ahead: the
-        dispatches issued while an older one was still unsynced, i.e.
-        with the device already fed — every pipelined dispatch but the
-        first after a drained window, none at depth 1."""
+    def _count_dispatch(self, ahead, steps):
+        """One more target-model dispatch, of `steps` token rows a
+        lane. While spans record, also the counters serving.dispatches
+        and serving.dispatch_ahead: the dispatches issued while an older
+        one was still unsynced, i.e. with the device already fed — every
+        pipelined dispatch but the first after a drained window, none
+        at depth 1; and hc.rows (_count_frame_rows) for every lane."""
         self.dispatch_count += 1
         if _obs.active():
             _obs.counter("serving.dispatches").add(1)
             if ahead:
                 _obs.counter("serving.dispatch_ahead").add(1)
+            self._count_frame_rows(steps * self.max_batch)
 
     @staticmethod
     def _count_routing(routing):
@@ -2536,7 +2566,8 @@ class ContinuousBatcher(object):
             else:
                 self._cache = state
         self._dispatch_failures = 0
-        self._count_dispatch(ahead=bool(self._inflight))
+        self._count_dispatch(ahead=bool(self._inflight),
+                             steps=self.chunk_size)
         self._dev_tok, self._dev_pos, self._dev_keys = tok, pos, keys
         self._inflight.append(
             (toks, [r.rid if r is not None else None
@@ -2709,7 +2740,9 @@ class ContinuousBatcher(object):
                     self._spec_fn(*args)
                 self._cache, self._dcache = cache, dcache
         self._dispatch_failures = 0
-        self._count_dispatch(ahead=bool(self._inflight))
+        # every round verifies a window of spec_k + 1 rows a lane
+        self._count_dispatch(ahead=bool(self._inflight),
+                             steps=self.chunk_size * (self.spec_k + 1))
         # worst-case position mirror so the NEXT dispatch's coverage
         # is sufficient whatever this one accepts; the sync subtracts
         # the measured shortfall back out

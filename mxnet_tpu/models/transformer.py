@@ -148,6 +148,18 @@ class TransformerConfig:
     mla_rope_dim: int = None
     mla_v_dim: int = None
     mla_q_rank: int = None
+    # the residual stream's width in streams: None = one stream and the
+    # frame x + f(norm(x)); n = manifold-constrained hyper-connections
+    # (_hyper_connect): n streams a token, every mixer and every FFN
+    # reads a learned mix of them and writes back through a doubly
+    # stochastic n x n matrix made by `hc_sinkhorn_iters` Sinkhorn-Knopp
+    # iterations (each divisor plus `hc_eps`) from logits clipped to
+    # [`hc_clamp_min`, `hc_clamp_max`]
+    hc_mult: int = None
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
     use_ring_attention: bool = True
     # attention through the Pallas flash kernel (kernels/
     # flash_attention.py): single-device dense path AND the per-shard
@@ -399,11 +411,16 @@ def param_specs(cfg):
         experts["gate_bias"] = P(None)
     if cfg.n_shared_experts:
         experts.update({"ws" + k[1:]: v for k, v in dense.items()})
+    # a hyper-connection frame's leaves are replicated (only shapes read
+    # them: a mesh refuses the n-stream carry, _refuse_streams)
+    frames = {} if cfg.hc_mult is None else {
+        "%s_%s" % (name, k): P(*(None,) * rank) for name in HC_FRAMES
+        for k, rank in HC_LEAVES}
     out = {
         "embed": P(None, None),
         "ln_f": P(None),
         "layers": [dict(experts if _has_experts(cfg, i) else dense,
-                        ln1=P(None), ln2=P(None), **mixers[kind])
+                        ln1=P(None), ln2=P(None), **frames, **mixers[kind])
                    for i, kind in enumerate(_layer_kinds(cfg))],
     }
     if not cfg.tied_head:
@@ -411,6 +428,27 @@ def param_specs(cfg):
     if _learned_pos(cfg):
         out["pos"] = P(None, None)
     return out
+
+
+# the two frames of a layer under `hc_mult` (around the mixer, around the
+# FFN) and each one's leaves with their ranks: p["hc1_phi"], ...
+HC_FRAMES = ("hc1", "hc2")
+HC_LEAVES = (("phi", 2), ("b", 1), ("a", 1))
+# a frame's initial gains (init_params), all three
+HC_INIT_GAIN = 0.5
+
+
+def _hc_init_biases(n):
+    """A frame's initial biases [n (n + 2)], in its projection's column
+    order: stream 0 is read with weight near 0.9 and written with weight
+    near 1, the others with 0.1 and 0.25, and the mix starts 1.5 heavier
+    on the diagonal. With HC_INIT_GAIN these put seeded weights away
+    from both the identity and the uniform mix: H_res's largest entry a
+    token lies in 0.4-0.9."""
+    first = np.arange(n) == 0
+    return np.concatenate([np.where(first, 2.0, -2.0),
+                           np.where(first, 0.0, -2.0),
+                           1.5 * np.eye(n).reshape(-1)])
 
 
 def init_params(cfg, seed=0):
@@ -516,11 +554,20 @@ def init_params(cfg, seed=0):
                 p["ws3"] = dense(cfg.d_model, fs)
         return p
 
+    def frame():
+        n, cols = _hc_sizes(cfg)
+        return {"phi": dense(n * cfg.d_model, cols),
+                "b": jnp.asarray(_hc_init_biases(n), jnp.float32),
+                "a": jnp.full((3,), HC_INIT_GAIN, jnp.float32)}
+
     def layer(i, kind):
         p = {
             "ln1": jnp.ones(_norm_shape(cfg), dt),
             "ln2": jnp.ones(_norm_shape(cfg), dt),
         }
+        if cfg.hc_mult is not None:
+            p.update({"%s_%s" % (name, k): v for name in HC_FRAMES
+                      for k, v in frame().items()})
         p.update(mixers[kind]())
         if _has_experts(cfg, i):
             p.update(experts())
@@ -555,6 +602,7 @@ def shard_params(params, cfg, mesh):
     weight's spec, its scale/dt sidecars replicate (scales are shared
     along the leading axis, which no spec here partitions alone)."""
     _refuse_dense_only(cfg, "mesh-sharded parameters (shard_params)")
+    _refuse_streams(cfg, "mesh-sharded parameters (shard_params)")
     specs = param_specs(cfg)
     if cfg.tp_axis and cfg.tp_axis in mesh.shape:
         tp_size = mesh.shape[cfg.tp_axis]
@@ -790,15 +838,136 @@ def _pp_size(cfg, mesh):
 def _layer(x, p, kind, cfg, mix, state=None, loads=None):
     """One transformer block, the residual frame every entry point
     runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
-    [B, d] for decode's one row. `mix(kind, h, p, state)` is the
-    entry point's mixer (_mixer) and returns (y, the layer's new
-    state); returns (x, that state). `loads`: see _expert_ffn."""
-    y, state = mix(kind, _rms_norm(x, p["ln1"], cfg.norm_eps), p, state)
-    x = x + y
-    h = _rms_norm(x, p["ln2"], cfg.norm_eps)
-    if h.ndim == 2:
-        return x + _ffn(h[:, None], p, cfg, loads)[:, 0], state
-    return x + _ffn(h, p, cfg, loads), state
+    [B, d] for decode's one row. With `cfg.hc_mult` the stream is n
+    streams wide (x [n, B, C, d] / [n, B, d]) and each of the two
+    sub-layers reads and writes it through _hyper_connect.
+    `mix(kind, h, p, state)` is the entry point's mixer (_mixer) and
+    returns (y, the layer's new state); returns (x, that state).
+    `loads`: see _expert_ffn."""
+    def mixer(h):
+        nonlocal state
+        y, state = mix(kind, _rms_norm(h, p["ln1"], cfg.norm_eps), p, state)
+        return y
+
+    def ffn(h):
+        h = _rms_norm(h, p["ln2"], cfg.norm_eps)
+        if h.ndim == 2:
+            return _ffn(h[:, None], p, cfg, loads)[:, 0]
+        return _ffn(h, p, cfg, loads)
+
+    if cfg.hc_mult is None:
+        x = x + mixer(x)
+        return x + ffn(x), state
+    x = _hyper_connect(x, p, "hc1", cfg, mixer)
+    return _hyper_connect(x, p, "hc2", cfg, ffn), state
+
+
+# ------------------------------------------------- hyper-connections ---
+# Manifold-constrained hyper-connections (Xie et al., arXiv:2512.24880,
+# over Zhu et al., arXiv:2409.19606): the residual stream is n =
+# cfg.hc_mult streams a token. The streams lead the array ([n, B, C, d],
+# [n, B, d] for decode's row): a stream is then a whole slab, and the
+# chip tiles the last two axes, which a 4 beside d would pad fourfold.
+
+def _hc_sizes(cfg):
+    """(n streams, columns of a frame's projection: n to read, n to
+    write, n * n to mix), checked."""
+    n = cfg.hc_mult
+    if not isinstance(n, int) or n < 2 or cfg.hc_sinkhorn_iters < 1 \
+            or not cfg.hc_clamp_min < cfg.hc_clamp_max:
+        raise ValueError(
+            "hc_mult=%r with hc_sinkhorn_iters=%r, hc_clamp_min=%r, "
+            "hc_clamp_max=%r: at least 2 streams, 1 iteration and a "
+            "clamp with min < max"
+            % (n, cfg.hc_sinkhorn_iters, cfg.hc_clamp_min,
+               cfg.hc_clamp_max))
+    return n, n * (n + 2)
+
+
+def _refuse_streams(cfg, mechanism):
+    """What moves the residual stream between devices knows one stream a
+    token: it says so instead of running the old frame."""
+    if cfg.hc_mult is not None:
+        raise ValueError(
+            "%s cannot carry a residual stream of hc_mult=%d streams; run "
+            "this model on one device" % (mechanism, cfg.hc_mult))
+
+
+def _streams_in(x, cfg):
+    """The embedded tokens as the stack's carry: with hc_mult, n copies
+    of them, the streams first."""
+    if cfg.hc_mult is None:
+        return x
+    return jnp.broadcast_to(x, (_hc_sizes(cfg)[0],) + x.shape)
+
+
+def _streams_out(x, cfg):
+    """The stack's carry as what the final norm reads: with hc_mult,
+    the sum of the streams."""
+    if cfg.hc_mult is None:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
+
+
+def _sinkhorn(m, iters, eps):
+    """Sinkhorn-Knopp on positive m [..., n, n]: `iters` times, every
+    column divided by its sum, then every row by its, each sum plus
+    `eps`. Rows then sum to 1 and columns nearly."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+    return m
+
+
+def _hc_weights(x, phi, b, a, cfg):
+    """A frame's three mixing weights from the stream x [n, ..., d], in
+    float32 whatever the stream's type: (H_pre [..., n] in (0, 1), H_post
+    [..., n] in (0, 2), H_res [..., n, n] doubly stochastic). The stream
+    is normed over all n * d features of a token with no weight; the
+    norm's scale is applied behind the projection, which commutes."""
+    n, cols = _hc_sizes(cfg)
+    with jax.named_scope("mx.hc.weights"):
+        xf = x.astype(jnp.float32)
+        scale = jax.lax.rsqrt(
+            jnp.mean(jnp.square(xf), axis=(0, -1)) + cfg.norm_eps)
+        z = jnp.einsum("n...d,ndk->...k", x,
+                       phi.reshape(n, x.shape[-1], cols),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        z = z * scale[..., None] * jnp.repeat(
+            a.astype(jnp.float32), np.array([n, n, n * n]),
+            total_repeat_length=cols) + b.astype(jnp.float32)
+        pre = jax.nn.sigmoid(z[..., :n])
+        post = 2.0 * jax.nn.sigmoid(z[..., n:2 * n])
+        res = _sinkhorn(
+            jnp.exp(jnp.clip(z[..., 2 * n:], cfg.hc_clamp_min,
+                             cfg.hc_clamp_max)).reshape(
+                z.shape[:-1] + (n, n)),
+            cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return pre, post, res
+
+
+def _hyper_connect(x, p, name, cfg, f):
+    """One sub-layer in the n-stream frame: h = H_pre @ x is what `f`
+    (norm + mixer, or norm + FFN) reads, and the stream becomes
+    H_res @ x + H_post (x) f(h). The frame's leaves are p[name + "_phi"]
+    [n * d, n * (n + 2)] (columns: read, write, mix), "_b" the biases
+    behind it and "_a" its three gains. n is small and static, so the
+    two products over the streams are sums of scaled slabs: one
+    elementwise pass each, and no batch of n x n matmuls."""
+    n = _hc_sizes(cfg)[0]
+    pre, post, res = _hc_weights(x, p[name + "_phi"], p[name + "_b"],
+                                 p[name + "_a"], cfg)
+    streams = [x[j].astype(jnp.float32) for j in range(n)]
+    with jax.named_scope("mx.hc.pre"):
+        h = sum(pre[..., j, None] * streams[j]
+                for j in range(n)).astype(x.dtype)
+    y = f(h)
+    with jax.named_scope("mx.hc.post"):
+        yf = y.astype(jnp.float32)
+        return jnp.stack([
+            sum(res[..., i, j, None] * streams[j] for j in range(n))
+            + post[..., i, None] * yf for i in range(n)]).astype(x.dtype)
 
 
 def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False):
@@ -832,13 +1001,15 @@ def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False):
 
 
 def _run_layers(x, params, state, cfg, mix, loads=None):
-    """x through every layer, each with its own state; returns (x, the
-    new states in layer order)."""
+    """The embedded x through every layer, each with its own state;
+    returns (what the final norm reads, the new states in layer
+    order)."""
     new_state = []
+    x = _streams_in(x, cfg)
     for kind, p, layer in zip(_layer_kinds(cfg), params["layers"], state):
         x, layer = _layer(x, p, kind, cfg, mix, layer, loads)
         new_state.append(layer)
-    return x, new_state
+    return _streams_out(x, cfg), new_state
 
 
 def forward(params, tokens, cfg, mesh=None):
@@ -850,7 +1021,10 @@ def forward(params, tokens, cfg, mesh=None):
     if mesh is not None:
         _refuse_dense_only(cfg, "the mesh-sharded forward (ring "
                           "attention, pipeline stages, tp)")
+        _refuse_streams(cfg, "the mesh-sharded forward (ring attention, "
+                        "pipeline stages over pp_axis, tp)")
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, act))
+    x = _streams_in(x, cfg)
     n_stages = _pp_size(cfg, mesh)
     # ring attention runs manually over sp inside a pipeline stage
     ring = n_stages > 1 and bool(cfg.use_ring_attention and cfg.sp_axis)
@@ -888,7 +1062,7 @@ def forward(params, tokens, cfg, mesh=None):
             layer_body = jax.checkpoint(layer_body, static_argnums=(2,))
         for kind, p in zip(_layer_kinds(cfg), params["layers"]):
             x = layer_body(p, x, kind)
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    x = _rms_norm(_streams_out(x, cfg), params["ln_f"], cfg.norm_eps)
     return jnp.einsum("btd,vd->btv", x, _head(params, cfg))
 
 
@@ -1086,7 +1260,8 @@ def quantize_weights_int8(params):
     for leaf, kind, what in (
             ("D", "mamba", "a state-space layer's"),
             ("b_proj", "kda", "a linear-attention layer's"),
-            ("wkva", "mla", "a latent-attention layer's")):
+            ("wkva", "mla", "a latent-attention layer's"),
+            ("hc1_phi", "hc_mult", "a hyper-connection frame's")):
         if any(leaf in layer for layer in params.get("layers", ())):
             raise ValueError(
                 "quantize_weights_int8 cannot carry %s parameters (the "

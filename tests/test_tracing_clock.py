@@ -100,10 +100,12 @@ def _batcher(**kw):
 
 
 # what the batcher counts while its spans record (models/serving.py
-# _count_dispatch and _fresh_row; a model with routed experts adds moe.*,
-# one with latent layers mla.*)
+# _count_dispatch, _fresh_row and _count_prefill; a model with routed
+# experts adds moe.*, one with latent layers mla.*, one with
+# hyper-connections hc.rows)
 WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
-                      "serving.fresh_rows"}
+                      "serving.fresh_rows", "serving.prefill_tokens",
+                      "serving.prefill_rows"}
 
 
 def _serve(srv, rounds):
