@@ -1351,6 +1351,10 @@ class ContinuousBatcher(object):
             snap["serving.spec_k_live"] = float(np.mean(self._keff))
         if self.brownout:
             snap["serving.brownout_rung"] = self._bo_rung
+        if self.cfg.n_experts:
+            # counted while spans record (_count_expert_matmuls)
+            for name in ("moe.grouped_kernel", "moe.grouped_reference"):
+                snap[name] = _obs.counter(name).value
         return snap
 
     def check_invariants(self, quiesce=False):
@@ -1567,6 +1571,20 @@ class ContinuousBatcher(object):
             _obs.counter("serving.prefill_tokens").add(tokens)
             _obs.counter("serving.prefill_rows").add(rows)
             self._count_frame_rows(rows)
+            self._count_expert_matmuls(rows)
+
+    def _count_expert_matmuls(self, rows, passes=1):
+        """While spans record, for a model with routed experts: the
+        expert layers' grouped matmuls of `passes` passes of `rows`
+        token rows each, into the counters moe.grouped_kernel (those
+        that ran kernels/grouped_matmul.py's kernel) and
+        moe.grouped_reference (those whose shapes kept
+        jax.lax.ragged_dot): tf.expert_matmuls, the call's own rule."""
+        if self.cfg.n_experts:
+            kernel, reference = tf.expert_matmuls(
+                self.params, self.cfg, rows)
+            _obs.counter("moe.grouped_kernel").add(passes * kernel)
+            _obs.counter("moe.grouped_reference").add(passes * reference)
 
     def _count_frame_rows(self, rows):
         """While spans record, for a model with hyper-connections
@@ -2434,19 +2452,22 @@ class ContinuousBatcher(object):
         self._end_round()
         return finished
 
-    def _count_dispatch(self, ahead, steps):
-        """One more target-model dispatch, of `steps` token rows a
-        lane. While spans record, also the counters serving.dispatches
+    def _count_dispatch(self, ahead, steps, window=1):
+        """One more target-model dispatch, of `steps` passes through
+        the layers with `window` token rows a lane each. While spans
+        record, also the counters serving.dispatches
         and serving.dispatch_ahead: the dispatches issued while an older
         one was still unsynced, i.e. with the device already fed — every
         pipelined dispatch but the first after a drained window, none
-        at depth 1; and hc.rows (_count_frame_rows) for every lane."""
+        at depth 1; hc.rows (_count_frame_rows) for every lane; and the
+        expert layers' grouped matmuls (_count_expert_matmuls)."""
         self.dispatch_count += 1
         if _obs.active():
             _obs.counter("serving.dispatches").add(1)
             if ahead:
                 _obs.counter("serving.dispatch_ahead").add(1)
-            self._count_frame_rows(steps * self.max_batch)
+            self._count_frame_rows(steps * window * self.max_batch)
+            self._count_expert_matmuls(window * self.max_batch, steps)
 
     @staticmethod
     def _count_routing(routing):
@@ -2742,7 +2763,7 @@ class ContinuousBatcher(object):
         self._dispatch_failures = 0
         # every round verifies a window of spec_k + 1 rows a lane
         self._count_dispatch(ahead=bool(self._inflight),
-                             steps=self.chunk_size * (self.spec_k + 1))
+                             steps=self.chunk_size, window=self.spec_k + 1)
         # worst-case position mirror so the NEXT dispatch's coverage
         # is sufficient whatever this one accepts; the sync subtracts
         # the measured shortfall back out

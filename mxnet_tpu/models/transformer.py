@@ -748,13 +748,16 @@ def _mlp(x, w1, w2, w3, cfg):
     return jnp.einsum("btf,fd->btd", h, w2)
 
 
-def _ffn(x, p, cfg, loads=None):
+def _ffn(x, p, cfg, loads=None, mesh=None):
     """A layer's feed-forward on x [B, T, d]: the dense form, or, for a
     layer with a router ("gate"), its routed experts (_expert_ffn)."""
     if cfg.ffn not in ("gelu", "gated_silu"):
         raise ValueError("ffn=%r: 'gelu' or 'gated_silu'" % (cfg.ffn,))
     if "gate" in p:
-        return _expert_ffn(x, p, cfg, loads)
+        if mesh is None:
+            return _expert_ffn(x, p, cfg, loads)
+        # the mesh-sharded forward's arm: GSPMD partitions the experts
+        return _expert_ffn(x, p, cfg, loads, mesh)
     return _mlp(x, p["w1"], p["w2"], p.get("w3"), cfg)
 
 
@@ -778,15 +781,36 @@ def moe_stats(loads, tokens, cfg):
         jnp.int32(load.size), jnp.int32(load.shape[0])]).astype(jnp.int32)
 
 
-def _expert_ffn(x, p, cfg, loads):
+def expert_matmuls(params, cfg, rows):
+    """Of the grouped matmuls ONE pass of `rows` token rows makes
+    through the expert layers of `params`: (those that run
+    kernels/grouped_matmul.py's kernel, those that keep
+    jax.lax.ragged_dot), by the call's own rule from the shapes
+    (grouped_tiles). serving.py adds them to the counters
+    moe.grouped_kernel / moe.grouped_reference."""
+    from ..kernels.grouped_matmul import grouped_tiles
+    if not cfg.n_experts:
+        return 0, 0
+    m = rows * _experts(cfg)[1]
+    kernel = [w.dtype == params["embed"].dtype and grouped_tiles(
+                  m, w.shape[1], w.shape[2], w.dtype.itemsize) is not None
+              for p in params["layers"] if "gate" in p
+              for w in (p[name] for name in ("w1", "w3", "w2") if name in p)]
+    return sum(kernel), len(kernel) - sum(kernel)
+
+
+def _expert_ffn(x, p, cfg, loads, mesh=None):
     """Routed experts on x [B, T, d]: every token is scored over all E
     experts and picks k of them; the picks that fall on the experts held
     here are sorted by expert and run as ONE grouped matmul a weight
-    (jax.lax.ragged_dot: each expert sees only its own tokens, no
-    capacity, no dropped token); the picks on experts held elsewhere
+    (kernels/grouped_matmul.py: each expert sees only its own tokens, no
+    capacity, no dropped token, and an expert without a pick is not
+    read; with `mesh`, where GSPMD partitions the experts over `ep`, as
+    jax.lax.ragged_dot); the picks on experts held elsewhere
     add nothing here, though they keep their share of the renormalised
     weights. A shared expert is a dense FFN added for every token. With
     `loads` a list, the per-expert token counts [held] are appended."""
+    from ..kernels.grouped_matmul import grouped_matmul
     _, k, first, held, _ = _experts(cfg)
     b, t, d = x.shape
     rows = x.reshape(b * t, d)
@@ -811,13 +835,17 @@ def _expert_ffn(x, p, cfg, loads):
         if loads is not None:
             loads.append(sizes)
     with jax.named_scope("mx.moe.experts"):
+        def matmul(a, weight):
+            return grouped_matmul(a, weight, sizes,
+                                  partitioned=mesh is not None)
+
         picked = rows[order // k]                        # [N * k, d]
-        h = jax.lax.ragged_dot(picked, p["w1"], sizes)
+        h = matmul(picked, p["w1"])
         if cfg.ffn == "gated_silu":
-            h = jax.nn.silu(h) * jax.lax.ragged_dot(picked, p["w3"], sizes)
+            h = jax.nn.silu(h) * matmul(picked, p["w3"])
         else:
             h = jax.nn.gelu(h)
-        y = jax.lax.ragged_dot(h, p["w2"], sizes)
+        y = matmul(h, p["w2"])
         # back in pick order; rows in no group hold nothing to read
         y = jnp.where(here.reshape(-1, 1), y[jnp.argsort(order)], 0)
         y = jnp.einsum("nkd,nk->nd", y.reshape(b * t, k, d),
@@ -835,7 +863,7 @@ def _pp_size(cfg, mesh):
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(cfg.pp_axis, 1)
 
 
-def _layer(x, p, kind, cfg, mix, state=None, loads=None):
+def _layer(x, p, kind, cfg, mix, state=None, loads=None, mesh=None):
     """One transformer block, the residual frame every entry point
     runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
     [B, d] for decode's one row. With `cfg.hc_mult` the stream is n
@@ -843,7 +871,7 @@ def _layer(x, p, kind, cfg, mix, state=None, loads=None):
     sub-layers reads and writes it through _hyper_connect.
     `mix(kind, h, p, state)` is the entry point's mixer (_mixer) and
     returns (y, the layer's new state); returns (x, that state).
-    `loads`: see _expert_ffn."""
+    `loads`, `mesh` (the mesh-sharded forward's): see _expert_ffn."""
     def mixer(h):
         nonlocal state
         y, state = mix(kind, _rms_norm(h, p["ln1"], cfg.norm_eps), p, state)
@@ -852,8 +880,8 @@ def _layer(x, p, kind, cfg, mix, state=None, loads=None):
     def ffn(h):
         h = _rms_norm(h, p["ln2"], cfg.norm_eps)
         if h.ndim == 2:
-            return _ffn(h[:, None], p, cfg, loads)[:, 0]
-        return _ffn(h, p, cfg, loads)
+            return _ffn(h[:, None], p, cfg, loads, mesh)[:, 0]
+        return _ffn(h, p, cfg, loads, mesh)
 
     if cfg.hc_mult is None:
         x = x + mixer(x)
@@ -1037,7 +1065,7 @@ def forward(params, tokens, cfg, mesh=None):
         # pipeline the homogeneous layer stack over pp: stage-major
         # stacked weights, ppermute microbatch schedule; tp/ep stay auto
         def layer_fn(p, xm):
-            return _layer(xm, p, "attention", cfg, mix)[0]
+            return _layer(xm, p, "attention", cfg, mix, mesh=mesh)[0]
 
         if cfg.remat_layers:
             layer_fn = jax.checkpoint(layer_fn)
@@ -1050,7 +1078,7 @@ def forward(params, tokens, cfg, mesh=None):
             else P())
     else:
         def layer_body(p, xl, kind):
-            xl = _layer(xl, p, kind, cfg, mix)[0]
+            xl = _layer(xl, p, kind, cfg, mix, mesh=mesh)[0]
             if mesh is not None:
                 xl = jax.lax.with_sharding_constraint(
                     xl, NamedSharding(mesh, act))

@@ -14,9 +14,18 @@ prints them all, and says in PERF.md what the chip read afterwards.
 
 PR 42 updated `kimi-linear.decode` on purpose: its two latent layers'
 decode contraction became the kernel of kernels/latent_decode.py
-(interpreted in the text a CPU lowers); the other nine are the parent's."""
+(interpreted in the text a CPU lowers).
+
+PR 44 re-pinned all five Kimi-Linear programs on purpose, and pinned two
+programs each of the other two configurations with a router (Kimi-K2.6,
+Xing4.0) at its own text: every expert layer's three grouped matmuls
+became the kernel of kernels/grouped_matmul.py behind _expert_ffn where
+they were jax.lax.ragged_dot. The five programs of the configurations
+WITHOUT a router (Cerebras-GPT, Jamba2) never reach that call and keep
+the hashes they had at PR 41's parent."""
 
 import hashlib
+import importlib
 import os
 import sys
 
@@ -33,25 +42,31 @@ def _shapes(specs, float32=()):
         else jnp.bfloat16) for name, shape, _ in specs}
 
 
+# a configuration's runner -> its reference (others: Cerebras-GPT's pair)
+REFERENCES = {"serve_kimi_linear": "kimi_linear", "serve_kimi_k2": "kimi_k2",
+              "serve_xing4": "xing4", "serve_jamba": "jamba"}
+
+
 def _sides(cell):
     """(program config, parameter shapes, lanes) of a serving cell."""
     from chipbench import manifest
     man = manifest.Manifest(ROOT)
     config = man.config_of(man.cell(cell))
     lanes = man.traffic_of(man.cell(cell)).get("clients")
-    if config["runner"] == "serve_kimi_linear":
-        from chipbench.reference import kimi_linear as ref
-        from chipbench.runners.serve_kimi_linear import program_config
-        shapes = _shapes(ref.leaf_specs(config), ref.FLOAT32_LEAVES)
-    elif config["runner"] == "serve_jamba":
-        from chipbench.reference import jamba as ref
-        from chipbench.runners.serve_jamba import program_config
-        shapes = _shapes(ref.leaf_specs(config))
+    ref = importlib.import_module(
+        "chipbench.reference."
+        + REFERENCES.get(config["runner"], "cerebras_gpt"))
+    runner = importlib.import_module(
+        "chipbench.runners." + (config["runner"] if config["runner"]
+                                in REFERENCES else "lm_common"))
+    shapes = _shapes(ref.leaf_specs(config),
+                     getattr(ref, "FLOAT32_LEAVES", ()))
+    if config["runner"] == "serve_xing4":    # a frame's leaves in three
+        params = jax.eval_shape(
+            lambda w: runner.program_params(w, config), shapes)
     else:
-        from chipbench.reference import cerebras_gpt as ref
-        from chipbench.runners.lm_common import program_config
-        shapes = _shapes(ref.leaf_specs(config))
-    return program_config(config), ref.as_tree(shapes, config), lanes
+        params = ref.as_tree(shapes, config)
+    return runner.program_config(config), params, lanes
 
 
 def _i32(*shape):
@@ -98,6 +113,7 @@ def _train_step(cell):
 
 KL, JA, CE = ("kimi-linear-48b-serve-reason32", "jamba2-3b-serve-chat64",
               "cerebras-gpt-1.3b-serve-closed24")
+K2, XI = "kimi-k2.6-serve-agent32", "xing4.0-29b-a4b-serve-rag32"
 PROGRAMS = {
     "kimi-linear.decode": (_decode, KL),
     "kimi-linear.admission-1024": (_admission, KL, 1024),
@@ -109,20 +125,30 @@ PROGRAMS = {
     "cerebras.decode": (_decode, CE),
     "cerebras.admission-512": (_admission, CE, 512),
     "cerebras.train-step": (_train_step, "cerebras-gpt-1.3b-train-8k"),
+    "kimi-k2.decode": (_decode, K2),
+    "kimi-k2.admission-4096": (_admission, K2, 4096),
+    "xing4.decode": (_decode, XI),
+    "xing4.admission-2048": (_admission, XI, 2048),
 }
 # sha256 of the StableHLO text, first 16 hex digits, at the parent commit
-# (of PR 41; one line says where a later PR moved it)
+# (of PR 41; a line says where a later PR moved or first pinned it)
 AT_THE_PARENT = {
     "cerebras.admission-512": "aba915c111ccf1db",
     "cerebras.decode": "59e8d1ef85648873",
     "cerebras.train-step": "42a91385a6f26e39",
     "jamba.admission-256": "1deb540c67da2cc8",
     "jamba.decode": "169f587ab80ff84e",
-    "kimi-linear.admission-1024": "266ea9a975fca4c1",
-    "kimi-linear.admission-8192": "02be86bc0f1a7253",
-    "kimi-linear.decode": "7441c29a6496fec8",   # PR 42: mla_decode
-    "kimi-linear.forward-256": "e346f4bf91718edf",
-    "kimi-linear.prefill-64": "3169c5673d754d47",
+    # PR 44: the expert layers' grouped matmuls are the kernel moe_gmm
+    "kimi-linear.admission-1024": "6e1223dfd86d2b04",
+    "kimi-linear.admission-8192": "faf23b2bd0db3785",
+    "kimi-linear.decode": "10a796152ba88425",   # and PR 42: mla_decode
+    "kimi-linear.forward-256": "f8167ddf6b0b1846",
+    "kimi-linear.prefill-64": "936d76aa83f8a957",
+    # first pinned at PR 44, with the kernel
+    "kimi-k2.admission-4096": "06e7c3dc780429ff",
+    "kimi-k2.decode": "da16f55ddbcc0ed7",
+    "xing4.admission-2048": "545b1a30387f51d9",
+    "xing4.decode": "2a6b7a33e77f280a",
 }
 
 

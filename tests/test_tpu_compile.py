@@ -200,6 +200,53 @@ def test_an_untileable_latent_cache_compiles_as_xla(one_chip):
     lowered.compile()
 
 
+EXPERT_SHAPES = {
+    # rows (lanes or a chunk's tokens x picks), experts held, d, width
+    "kimi-linear-decode": (256, 64, 2304, 1024),
+    "kimi-linear-chunk-8192": (65536, 64, 2304, 1024),
+    "xing4-decode": (128, 64, 3584, 1024),
+    "xing4-chunk-2048": (8192, 64, 3584, 1024),
+    "kimi-k2-decode": (256, 12, 7168, 2048),
+    "kimi-k2-chunk-4096": (32768, 12, 7168, 2048),
+    "24-lanes-of-8-picks": (192, 64, 2304, 1024),
+    "3-lanes-of-8-picks": (24, 12, 7168, 2048),
+}
+
+
+@pytest.mark.parametrize("m,held,d,width", EXPERT_SHAPES.values(),
+                         ids=EXPERT_SHAPES.keys())
+def test_grouped_matmul(one_chip, m, held, d, width):
+    """The routed experts' grouped matmul at the three expert cells' real
+    shapes, decode's row and a prefill chunk, in both orientations as
+    _expert_ffn chains them (in, gate, out): the kernel is in the
+    program three times and no copy or transpose of a weight stands in
+    front of it (the weights go in as the parameters they are)."""
+    from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
+
+    def experts(rows, w1, w3, w2, sizes):
+        h = jax.nn.silu(grouped_matmul(rows, w1, sizes, interpret=False)) \
+            * grouped_matmul(rows, w3, sizes, interpret=False)
+        return grouped_matmul(h, w2, sizes, interpret=False)
+
+    compiled = _compile(
+        experts, _sds(one_chip, (m, d)), _sds(one_chip, (held, d, width)),
+        _sds(one_chip, (held, d, width)), _sds(one_chip, (held, width, d)),
+        _sds(one_chip, (held,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "moe_gmm" in text and "ragged-dot" not in text
+    weights = ("bf16[%d,%d,%d]" % (held, d, width),
+               "bf16[%d,%d,%d]" % (held, width, d))
+    moved = [line for line in text.splitlines()
+             if (" copy(" in line or " transpose(" in line)
+             and any(w in line for w in weights)]
+    assert not moved, moved
+    # nothing but the hidden rows between the calls: no copy of a weight
+    # among the temporaries either
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * m * max(d, width) * 2 + 2 ** 20
+
+
 @pytest.mark.parametrize("name,runner", [
     ("cerebras-gpt-1.3b", "lm_common"), ("jamba2-3b", "serve_jamba"),
     ("kimi-linear-48b-a3b", "serve_kimi_linear")])
