@@ -31,9 +31,9 @@ labelled with the device.
 
 With default settings NO Pallas kernel is on paths A/B (flash attention
 needs cfg.use_flash_kernel and T >= 8192, the paged kernel needs
-MXNET_PAGED_DECODE_PALLAS=1; latent_decode is on every "mla" layer's
-decode path, and neither model here has one): kernel coverage is phase K
-alone.
+MXNET_PAGED_DECODE_PALLAS=1; latent_decode and latent_row_store are on
+every "mla" layer's decode path, and neither model here has one): kernel
+coverage is phase K alone.
 """
 
 import argparse
@@ -560,15 +560,18 @@ def _k_paged(sz, dec, interpret):
 
 
 def _k_latent(sz, interpret):
-    """latent_decode (the one kernel on a default path: every "mla"
-    layer's decode contraction) against the two XLA passes it replaced,
-    at ragged lengths: the cell's cache, then one block that is no
-    multiple of 128 and three blocks of 128, the smallest the kernel
-    tiles with."""
+    """latent_decode (every "mla" layer's decode contraction) against
+    the two XLA passes it replaced, at ragged lengths, and beside it
+    latent_row_store (the decode store of the round's fresh `kr` rows,
+    in place) against the scatter it replaced, bit for bit, a lane at
+    the row its length ends on and one past the cache (dropped): the
+    cell's cache, then one block that is no multiple of 128 and three
+    blocks of 128, the smallest the kernels tile with."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from mxnet_tpu.kernels import latent_decode, latent_decode_reference
+    from mxnet_tpu.kernels import (latent_decode, latent_decode_reference,
+                                   latent_row_store)
     b, h, r, e = (sz[k] for k in "bhre")
     norm = float(np.sqrt(r + e))
     out = {"ok": True, "shape": [b, h, sz["t"], r + e]}
@@ -586,10 +589,20 @@ def _k_latent(sz, interpret):
         ref = jax.jit(lambda *a: latent_decode_reference(*a, norm))(
             q_lat, q_r, c, kr, lengths)
         err, scale = _err(got, ref)
+        fresh = jax.random.normal(ks[0], (b, e), jnp.bfloat16)
+        pos = (lengths - 1).at[1].set(t)
+        stored, writer = _compiled(
+            lambda *a: latent_row_store(*a, interpret=interpret),
+            kr, fresh, pos)
+        same = bool(jnp.array_equal(stored, jax.jit(
+            lambda kr_, rows, at: kr_.at[jnp.arange(b), at].set(rows))(
+            kr, fresh, pos)))
         out["ok"] = out["ok"] and err <= BF16_TOL * scale \
-            and (kernel or interpret)
+            and (kernel or interpret) and same and (writer or interpret)
         out["t%d" % t] = {"kernel_in_hlo": kernel,
-                          "max_abs_err": round(err, 5)}
+                          "max_abs_err": round(err, 5),
+                          "row_store_in_hlo": writer,
+                          "row_store_equals_scatter": same}
     return out
 
 

@@ -10,10 +10,11 @@ interpret mode elsewhere, so their tests execute on any backend.
 from .flash_attention import (flash_attention, flash_decode,
                               dense_decode_with_lse)
 from .paged_decode import paged_attention
-from .latent_decode import latent_decode, latent_decode_reference
+from .latent_decode import (latent_decode, latent_decode_reference,
+                            latent_row_store)
 from .grouped_matmul import grouped_matmul, grouped_matmul_reference
 
 __all__ = ["flash_attention", "flash_decode",
            "dense_decode_with_lse", "paged_attention",
-           "latent_decode", "latent_decode_reference",
+           "latent_decode", "latent_decode_reference", "latent_row_store",
            "grouped_matmul", "grouped_matmul_reference"]

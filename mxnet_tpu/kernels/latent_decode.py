@@ -29,6 +29,11 @@ one block of a short cache; a long cache that 128 does not divide has
 none, and the caller keeps the XLA text for it); rows_fetched says what
 a dispatch's lengths make the contraction fetch, and serving.py's
 counter mla.rows_read reads it from here so the two cannot drift.
+
+latent_row_store is decode's store of a round's fresh `kr` rows, one a
+lane, in that same [B, E, T] order and in place: as a scatter
+(`kr.at[lanes, pos].set(rows)`) XLA wants E minor, and copies the whole
+leaf into that order and back around it, every layer of every round.
 """
 
 import functools
@@ -42,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .common import NEG_INF, STAT_LANES, choose_block_k, length_mask
 
 __all__ = ["latent_decode", "latent_decode_reference", "latent_block",
-           "rows_fetched"]
+           "latent_row_store", "rows_fetched"]
 
 # a 1,024-row block of 512 + 64 bf16 latents is 1.15 MB, ~1.4 us at a
 # v5e's HBM peak against ~0.35 us a grid step (on the chip 1,024 read
@@ -205,3 +210,67 @@ def latent_decode(q_lat, q_r, c, kr, lengths, norm, interpret=None):
     lengths = jnp.clip(jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32), (b,)), 1, t)
     return _call(q_lat, q_r, c, kr, lengths, float(norm), bool(interpret))
+
+
+def _store_kernel(pos_ref, row_ref, krt_ref, o_ref, *, block, last):
+    at = pos_ref[pl.program_id(0)]
+    # the tile's columns by their position, the tile being the one the
+    # index map chose; a position outside the cache is no column of it,
+    # and the tile goes back as it came
+    start = jnp.clip(at // block, 0, last) * block
+    column = start + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o_ref[...] = jnp.where(column == at, row_ref[...], krt_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _store_call(kr, rows, pos, interpret):
+    b, t, e = kr.shape
+    # a tile of 128 positions is the least Mosaic takes on the lanes
+    # (E x 128 bf16: 16 KB in and out a lane), or the whole of a short
+    # cache that 128 does not divide
+    block = BLOCKS[-1] if t % BLOCKS[-1] == 0 else t
+    last = t // block - 1
+
+    def tile(b_, pos_ref):
+        return (b_, 0, jnp.clip(pos_ref[b_] // block, 0, last))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((None, e, 1), lambda b_, pos_ref: (b_, 0, 0)),
+                  pl.BlockSpec((None, e, block), tile)],
+        out_specs=pl.BlockSpec((None, e, block), tile))
+    kr_t = pl.pallas_call(
+        functools.partial(_store_kernel, block=block, last=last),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, e, t), kr.dtype),
+        # the leaf itself (operand 2, after the positions and the rows)
+        # is the output: only the tiles the grid visits move
+        input_output_aliases={2: 0},
+        name="mla_row_store",
+        interpret=interpret,
+    )(pos, rows[:, :, None], jnp.swapaxes(kr, 1, 2))
+    return jnp.swapaxes(kr_t, 1, 2)
+
+
+def latent_row_store(kr, rows, pos, interpret=None):
+    """`kr` [B, T, E] with row pos[b] of lane b replaced by rows[b]
+    ([B, E], cast to the leaf's dtype; pos int32 [B]) and everything
+    else as it was: `kr.at[arange(B), pos].set(rows)` bit for bit, a
+    negative position counted from the end and one outside the cache
+    dropped. Written through the [B, E, T] view latent_decode reads
+    (_call), with the leaf aliased to the result: a lane fetches and
+    writes back the one tile of positions that holds its row, where the
+    scatter copies the whole leaf twice. Takes the caches latent_decode
+    takes (latent_block)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t = kr.shape[1]
+    if latent_block(t) is None:
+        raise ValueError(
+            "latent_row_store cannot tile a cache of %d rows (latent_block; "
+            "kr.at[lanes, pos].set(rows) is the same store as an XLA "
+            "scatter)" % t)
+    pos = jnp.asarray(pos, jnp.int32)
+    return _store_call(kr, rows.astype(kr.dtype),
+                       jnp.where(pos < 0, pos + t, pos), bool(interpret))

@@ -1355,6 +1355,10 @@ class ContinuousBatcher(object):
             # counted while spans record (_count_expert_matmuls)
             for name in ("moe.grouped_kernel", "moe.grouped_reference"):
                 snap[name] = _obs.counter(name).value
+        if self._latent_layers:
+            # counted while spans record (_count_row_stores)
+            for name in ("mla.row_store_kernel", "mla.row_store_scatter"):
+                snap[name] = _obs.counter(name).value
         return snap
 
     def check_invariants(self, quiesce=False):
@@ -2459,8 +2463,9 @@ class ContinuousBatcher(object):
         and serving.dispatch_ahead: the dispatches issued while an older
         one was still unsynced, i.e. with the device already fed — every
         pipelined dispatch but the first after a drained window, none
-        at depth 1; hc.rows (_count_frame_rows) for every lane; and the
-        expert layers' grouped matmuls (_count_expert_matmuls)."""
+        at depth 1; hc.rows (_count_frame_rows) for every lane; the
+        expert layers' grouped matmuls (_count_expert_matmuls); and the
+        latent layers' stores of a step's `kr` rows (_count_row_stores)."""
         self.dispatch_count += 1
         if _obs.active():
             _obs.counter("serving.dispatches").add(1)
@@ -2468,6 +2473,7 @@ class ContinuousBatcher(object):
                 _obs.counter("serving.dispatch_ahead").add(1)
             self._count_frame_rows(steps * window * self.max_batch)
             self._count_expert_matmuls(window * self.max_batch, steps)
+            self._count_row_stores(steps)
 
     @staticmethod
     def _count_routing(routing):
@@ -2475,6 +2481,20 @@ class ContinuousBatcher(object):
         into the counters moe.<name>."""
         for name, n in zip(tf.MOE_STATS, np.asarray(routing)):
             _obs.counter("moe." + name).add(int(n))
+
+    def _count_row_stores(self, steps):
+        """While spans record, for a model with latent attention: the
+        latent layers' stores of the fresh `kr` rows of a dispatch of
+        `steps` steps, into the counters mla.row_store_kernel (written
+        in place by kernels/latent_decode.py latent_row_store) and
+        mla.row_store_scatter (max_len rows that kernel cannot tile:
+        the XLA scatter), by tf._dense_rows' own rule, latent_block."""
+        if self._latent_layers:
+            from ..kernels.latent_decode import latent_block
+            tiled = latent_block(self.cfg.max_len) is not None
+            _obs.counter("mla.row_store_kernel" if tiled else
+                         "mla.row_store_scatter").add(
+                steps * self._latent_layers)
 
     def _count_latent_rows(self, pos, live, steps):
         """A dispatch's latent rows (a model with latent attention)

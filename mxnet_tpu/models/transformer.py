@@ -1202,8 +1202,9 @@ def _dense_rows(cfg, where, contract):
     """Dense rows: leaves {"k", "v"[, "ks", "vs"]} [B, Tmax, KVH, ...]
     or a latent layer's {"c", "kr"} [B, Tmax, R | E], a lane a row. `where`
     is a scalar start (every lane's fresh [B, C, ...] lands on
-    [start, start+C)), [B] (row i's one entry at where[i]) or [B, C]
-    (row i's window at where[i, :])."""
+    [start, start+C)), [B] (row i's one entry at where[i]: a scatter, or
+    for a latent layer's `kr` kernels/latent_decode.py's writer, the same
+    store in place) or [B, C] (row i's window at where[i, :])."""
     def store(layer, **fresh):
         if jnp.ndim(where) == 0:
             def put(leaf, arr):
@@ -1216,6 +1217,16 @@ def _dense_rows(cfg, where, contract):
 
             def put(leaf, arr):
                 return leaf.at[rows, where].set(arr)
+            from ..kernels.latent_decode import latent_block, latent_row_store
+            if "kr" in fresh and latent_block(
+                    layer["kr"].shape[1]) is not None:
+                # a latent layer's narrow leaf lies on the chip with its
+                # positions minor, the order latent_decode reads it in;
+                # the scatter wants them major and copies the whole leaf
+                # there and back. The same store, in place (wherever
+                # that kernel runs: _latent_decode_attention)
+                kr = latent_row_store(layer["kr"], fresh.pop("kr"), where)
+                return dict(_kv_store(layer, fresh, cfg, put), kr=kr)
         else:
             # out-of-bounds positions (a lane's window running past
             # max_len) are DROPPED by the scatter rather than clamped,

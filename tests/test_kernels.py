@@ -944,3 +944,92 @@ def test_rows_fetched_are_whole_blocks_up_to_each_lanes_length():
     # one block, and a cache the kernel cannot tile: all of it a lane
     assert rows_fetched([5, 192], 192) == 2 * 192
     assert rows_fetched([1, 500, 0], 20000) == 3 * 20000
+
+
+# -------------------------------------- latent attention's decode store ---
+
+def _bits(x):
+    """An array's bytes: equal where NaN is too."""
+    return np.asarray(x).tobytes()
+
+
+@pytest.mark.parametrize("t", [384, 96, 192],
+                         ids=["three-of-128", "one-block-96",
+                              "one-block-192"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32"])
+def test_latent_row_store_equals_the_scatter(dtype, t):
+    """The writer (interpreted here) against the scatter it replaces,
+    bit for bit: a block's first and last column and the next block's
+    first, the cache's last row, a position at T and past it (dropped:
+    the lane's rows stay), negative positions as the scatter counts
+    them; float32 rows cast to the leaf's dtype; the leaf NaN wherever
+    its lane index and position are both odd, which must come back."""
+    from mxnet_tpu.kernels.latent_decode import latent_row_store
+    pos = [0, min(127, t - 2), min(128, t - 1), t - 1, t, t + 300, -1,
+           -t - 1]
+    b, e = len(pos), 64
+    ks = jax.random.split(jax.random.PRNGKey(t), 2)
+    kr = jax.random.normal(ks[0], (b, t, e), dtype)
+    odd = (jnp.arange(b)[:, None, None] % 2 == 1) \
+        & (jnp.arange(t)[None, :, None] % 2 == 1)
+    kr = jnp.where(odd, jnp.nan, kr)
+    rows = jax.random.normal(ks[1], (b, e), jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    want = kr.at[jnp.arange(b), pos].set(rows.astype(dtype))
+    got = latent_row_store(kr, rows, pos)
+    assert got.shape == kr.shape and got.dtype == kr.dtype
+    assert _bits(got) == _bits(want)
+    # the dropped lanes came back as they went in, NaN and all
+    assert _bits(got[4:6]) == _bits(kr[4:6]) and _bits(got[7]) == _bits(kr[7])
+    assert _bits(got[:4]) != _bits(kr[:4])
+
+
+def test_latent_row_store_refuses_a_cache_it_cannot_tile():
+    from mxnet_tpu.kernels.latent_decode import latent_row_store
+    with pytest.raises(ValueError, match="cannot tile a cache of 1032"):
+        latent_row_store(jnp.zeros((2, 1032, 8)), jnp.zeros((2, 8)),
+                         jnp.zeros((2,), jnp.int32))
+
+
+@pytest.mark.parametrize("t,kernel", [(384, True), (1000, True),
+                                      (1032, False)])
+def test_decode_stores_a_latent_rows_kr_in_place_wherever_it_tiles(
+        t, kernel, monkeypatch):
+    """decode_step with a position a lane over two latent layers: the
+    `kr` rows go in through the writer wherever latent_decode runs
+    (_dense_rows chooses by the leaf's shape), through the scatter at a
+    cache of more than one block that 128 does not divide; either way
+    the cache and the logits are those of the parent's text, the scatter
+    for every leaf, bit for bit. One lane stands at the cache's end."""
+    import importlib
+    from mxnet_tpu.models import transformer as tf
+    # the package re-exports the function under the module's own name
+    ld = importlib.import_module("mxnet_tpu.kernels.latent_decode")
+    cfg = tf.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+        max_len=t, rope=False, positions="none",
+        layer_kinds=("mla",) * 2, mla_rank=32, mla_nope_dim=16,
+        mla_rope_dim=8, mla_v_dim=12)
+    params = tf.init_params(cfg, 3)
+    b = 3
+    cache = jax.tree.map(
+        lambda x: jax.random.normal(jax.random.PRNGKey(t), x.shape, x.dtype),
+        tf.init_cache(cfg, b))
+    tokens = jnp.asarray([5, 6, 7], jnp.int32)
+    pos = jnp.asarray([0, 200, t - 1], jnp.int32)
+    step = lambda: tf.decode_step(params, cache, tokens, pos, cfg)
+    # a fresh function a trace: make_jaxpr keeps what it traced
+    text = lambda: str(jax.make_jaxpr(lambda: step())())
+    assert ("mla_row_store" in text()) == kernel
+    logits, new = step()
+    monkeypatch.setattr(
+        ld, "latent_row_store", lambda kr, rows, where: kr.at[
+            jnp.arange(kr.shape[0]), where].set(rows.astype(kr.dtype)))
+    assert "mla_row_store" not in text()
+    want_logits, want = step()
+    assert _bits(logits) == _bits(want_logits)
+    for layer, want_layer, old in zip(new, want, cache):
+        for name in ("c", "kr"):
+            assert _bits(layer[name]) == _bits(want_layer[name]) \
+                != _bits(old[name])

@@ -523,12 +523,49 @@ def test_a_cache_the_kernel_cannot_tile_counts_every_row(sides, telemetry):
     assert obs.counter("mla.rows_live").value == 3 * (6 + 7 + 8)
 
 
+@pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
+                         ids=["default", "depth1"])
+@pytest.mark.parametrize("max_len,chunk,ran", [
+    (64, 1, "kernel"), (384, 4, "kernel"), (1032, 1, "scatter")],
+    ids=["64", "384-chunked", "1032"])
+def test_a_dispatch_counts_its_latent_layers_row_stores(
+        sides, telemetry, loop, max_len, chunk, ran):
+    """A decode step stores one `kr` row a lane a latent layer: through
+    the writer of kernels/latent_decode.py wherever latent_block tiles
+    max_len (one block of 64, three of 128), through the scatter at
+    1,032. Counted a dispatch, three latent layers a step of it, with
+    one round in flight and with two; the other counter stays 0 and
+    both are in health_snapshot()."""
+    params, cfg, _ = sides
+    cfg = dataclasses.replace(cfg, max_len=max_len)
+    assert (latent_block(max_len) is not None) == (ran == "kernel")
+    srv = ContinuousBatcher(params, cfg, max_batch=2, chunk_size=chunk,
+                            **loop)
+    srv.admit([5, 6, 7, 8, 9], 20)
+    for _ in range(3):
+        srv.step()
+    assert srv.dispatch_count >= 3
+    snap = srv.health_snapshot()
+    assert snap["mla.row_store_" + ran] \
+        == obs.counter("mla.row_store_" + ran).value \
+        == 3 * chunk * srv.dispatch_count
+    assert snap["mla.row_store_" + {"kernel": "scatter",
+                                    "scatter": "kernel"}[ran]] == 0
+    # nothing is counted while nothing records
+    telemetry.setenv("MXNET_OBS", "0")
+    srv.step()
+    assert obs.counter("mla.row_store_" + ran).value \
+        == snap["mla.row_store_" + ran]
+
+
 def test_a_model_without_latent_layers_counts_no_rows(telemetry):
     cfg = tf.TransformerConfig(max_len=32)
     srv = ContinuousBatcher(tf.init_params(cfg, 0), cfg, max_batch=2)
     srv.admit([1, 2, 3], 4)
     srv.step()
     assert "mla.rows_read" not in obs.counters()
+    assert "mla.row_store_kernel" not in obs.counters()
+    assert "mla.row_store_kernel" not in srv.health_snapshot()
 
 
 # ----------------------------------------------------- the shares add up

@@ -22,7 +22,15 @@ Xing4.0) at its own text: every expert layer's three grouped matmuls
 became the kernel of kernels/grouped_matmul.py behind _expert_ffn where
 they were jax.lax.ragged_dot. The five programs of the configurations
 WITHOUT a router (Cerebras-GPT, Jamba2) never reach that call and keep
-the hashes they had at PR 41's parent."""
+the hashes they had at PR 41's parent.
+
+PR 45 updated the three decode programs with a latent layer on purpose
+(`kimi-k2.decode`, `kimi-linear.decode`, `xing4.decode`): a step's store
+of a latent layer's fresh `kr` rows became the writer of
+kernels/latent_decode.py (latent_row_store, in place) where it was a
+scatter. Their admissions, prefills and forward store a chunk at a
+scalar start and keep their text, as does every program without a
+latent layer."""
 
 import hashlib
 import importlib
@@ -141,14 +149,15 @@ AT_THE_PARENT = {
     # PR 44: the expert layers' grouped matmuls are the kernel moe_gmm
     "kimi-linear.admission-1024": "6e1223dfd86d2b04",
     "kimi-linear.admission-8192": "faf23b2bd0db3785",
-    "kimi-linear.decode": "10a796152ba88425",   # and PR 42: mla_decode
+    # and PR 42: mla_decode; PR 45: mla_row_store
+    "kimi-linear.decode": "d232a191db71c85b",
     "kimi-linear.forward-256": "f8167ddf6b0b1846",
     "kimi-linear.prefill-64": "936d76aa83f8a957",
     # first pinned at PR 44, with the kernel
     "kimi-k2.admission-4096": "06e7c3dc780429ff",
-    "kimi-k2.decode": "da16f55ddbcc0ed7",
+    "kimi-k2.decode": "2c34bb63450d0158",       # PR 45: mla_row_store
     "xing4.admission-2048": "545b1a30387f51d9",
-    "xing4.decode": "2a6b7a33e77f280a",
+    "xing4.decode": "5b9cc8432b52af5d",         # PR 45: mla_row_store
 }
 
 

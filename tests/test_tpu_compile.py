@@ -69,6 +69,15 @@ def _sds(one_chip, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _moved(text, shapes):
+    """The lines of a compiled program's text that copy or transpose an
+    array of one of `shapes` (as the text spells them)."""
+    return [line for line in text.splitlines()
+            if any(op in line for op in (" copy(", " transpose(",
+                                         " copy-start("))
+            and any(shape in line for shape in shapes)]
+
+
 def test_flash_attention_forward(one_chip):
     q = _sds(one_chip, (B, T, H, D))
     _compile(functools.partial(fa.flash_attention, causal=True,
@@ -144,8 +153,9 @@ def test_paged_attention(one_chip, int8, span):
 @pytest.mark.parametrize("heads,rows", [(64, 19456), (32, 11264)],
                          ids=["kimi-k2.6", "kimi-linear"])
 def test_latent_decode(one_chip, heads, rows):
-    """The one kernel on a default path, at the two Kimi cells' real
-    shapes: 32 lanes, rank-512 latents and a 64-wide shared key part. The
+    """The decode contraction on every "mla" layer's path, at the two
+    Kimi cells' real shapes: 32 lanes, rank-512 latents and a 64-wide
+    shared key part. The
     chip keeps `kr` with T minor, the order the kernel's block reads it
     in: no whole-array copy may stand in front of the call."""
     from mxnet_tpu.kernels.latent_decode import latent_decode
@@ -175,6 +185,61 @@ def test_latent_decode_at_cache_lengths_no_cell_has(one_chip, rows):
         _sds(one_chip, (lanes, 64, 512)), _sds(one_chip, (lanes, 64, 64)),
         _sds(one_chip, (lanes, rows, 512)), _sds(one_chip, (lanes, rows, 64)),
         _sds(one_chip, (lanes,), jnp.int32))
+
+
+@pytest.mark.parametrize("heads,rows", [(64, 19456), (32, 11264),
+                                        (32, 9216)],
+                         ids=["kimi-k2.6", "kimi-linear", "xing4.0"])
+def test_latent_row_store_feeds_latent_decode_in_place(one_chip, heads, rows):
+    """A decode round's store of the fresh `kr` rows and the contraction
+    behind it, at the three latent cells' shapes with the leaf donated
+    as the decode programs donate the cache: the writer takes and leaves
+    the leaf in the order the chip keeps it and `mla_decode` reads it,
+    so no copy or transpose of an array of the leaf's size stands before,
+    between or after them (the scatter it replaces had two, 152 MB of
+    temporaries at Kimi-K2.6's shape) and nothing of that size is a
+    temporary."""
+    from mxnet_tpu.kernels.latent_decode import (latent_decode,
+                                                 latent_row_store)
+    lanes = 32
+
+    def store_and_attend(kr, fresh, pos, q_lat, q_r, c):
+        kr = latent_row_store(kr, fresh, pos, interpret=False)
+        return kr, latent_decode(q_lat, q_r, c, kr, pos + 1,
+                                 float(np.sqrt(192.0)), interpret=False)
+
+    lowered = jax.jit(store_and_attend, donate_argnums=(0,)).lower(
+        _sds(one_chip, (lanes, rows, 64)), _sds(one_chip, (lanes, 64)),
+        _sds(one_chip, (lanes,), jnp.int32),
+        _sds(one_chip, (lanes, heads, 512)), _sds(one_chip, (lanes, heads, 64)),
+        _sds(one_chip, (lanes, rows, 512)))
+    assert lowered.as_text().count("tpu_custom_call") >= 2
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "mla_row_store" in text and "mla_decode" in text
+    assert "scatter" not in text
+    moved = _moved(text, ("[%d,%d,64]" % (lanes, rows),
+                          "[%d,64,%d]" % (lanes, rows)))
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [96, 192, 384],
+                         ids=["one-block-96", "one-block-192",
+                              "three-of-128"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32"])
+def test_latent_row_store_at_cache_lengths_no_cell_has(one_chip, dtype, rows):
+    """One tile that is no multiple of 128 (the whole cache, so Mosaic
+    takes it with its rows on the lanes) and tiles of 128."""
+    from mxnet_tpu.kernels.latent_decode import latent_row_store
+    lanes = 4
+    compiled = _compile(
+        functools.partial(latent_row_store, interpret=False),
+        _sds(one_chip, (lanes, rows, 64), dtype),
+        _sds(one_chip, (lanes, 64), dtype),
+        _sds(one_chip, (lanes,), jnp.int32))
+    assert "mla_row_store" in compiled.as_text()
 
 
 def test_an_untileable_latent_cache_compiles_as_xla(one_chip):
@@ -235,11 +300,8 @@ def test_grouped_matmul(one_chip, m, held, d, width):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "moe_gmm" in text and "ragged-dot" not in text
-    weights = ("bf16[%d,%d,%d]" % (held, d, width),
-               "bf16[%d,%d,%d]" % (held, width, d))
-    moved = [line for line in text.splitlines()
-             if (" copy(" in line or " transpose(" in line)
-             and any(w in line for w in weights)]
+    moved = _moved(text, ("bf16[%d,%d,%d]" % (held, d, width),
+                          "bf16[%d,%d,%d]" % (held, width, d)))
     assert not moved, moved
     # nothing but the hidden rows between the calls: no copy of a weight
     # among the temporaries either
