@@ -23,13 +23,6 @@ bound serving shape; decode_bench.py covers batched decode):
                   queue through the ContinuousBatcher slot pool vs
                   the same jobs sequentially through generate()
 
-With ``--pipeline-depth D`` the script instead runs ONLY the chunk-
-pipelining A/B: the mixed-arrival workload through the synchronous
-(depth=1) batcher vs the pipelined one at depth D — same jobs, same
-chunking, streams bit-identical (tested), the only variable being how
-many chunk dispatches ride in flight against the device-resident
-carry.
-
 With ``--paged`` it runs the paged-KV A/B instead: a mixed-length
 workload through the dense-lane batcher vs the paged one at an EQUAL
 cache-HBM budget (the paged pool holds exactly the dense lanes' cache
@@ -102,7 +95,6 @@ batcher's log-bucketed histograms, emit the same distributions as a
 machine-readable JSON line, and — with ``--json PATH`` — write them as an artifact file.
 
     python - < benchmark/serving_bench.py
-    python - --pipeline-depth 2 < benchmark/serving_bench.py
     python - --spec-k 4 < benchmark/serving_bench.py
     python - --json serving_latency.json < benchmark/serving_bench.py
     MXNET_SERVING_SMOKE=1 JAX_PLATFORMS=cpu python - < benchmark/serving_bench.py
@@ -130,18 +122,6 @@ def _time_tokens(fn, n_tokens, warm_runs=1, timed_runs=3):
         fn()
         rates.append(n_tokens / (time.time() - t0))
     return float(np.median(rates))
-
-
-def _pipeline_depth_arg(argv=None):
-    """--pipeline-depth D from the stdin-run argv (free-form words,
-    not argparse); None when absent."""
-    argv = sys.argv[1:] if argv is None else argv
-    for i, a in enumerate(argv):
-        if a == "--pipeline-depth" and i + 1 < len(argv):
-            return int(argv[i + 1])
-        if a.startswith("--pipeline-depth="):
-            return int(a.split("=", 1)[1])
-    return None
 
 
 def _spec_k_arg(argv=None):
@@ -221,86 +201,6 @@ def _write_artifact(path, reports):
     print("wrote latency artifact -> %s" % path, flush=True)
 
 
-def pipeline_ab(depth):
-    """The chunk-pipelining A/B (see the module docstring): mixed
-    arrivals through the synchronous batcher vs pipeline_depth=depth,
-    one JSON row with both rates and the speedup."""
-    from benchmark.common import fetch_barrier  # noqa: F401  (parity)
-    from mxnet_tpu.chip import use_compile_cache
-    use_compile_cache()
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu.models import transformer as tf
-    from mxnet_tpu.models.serving import ContinuousBatcher
-
-    backend = jax.default_backend()
-    if SMOKE:
-        # the smoke model is sized so compute does NOT swamp the
-        # per-dispatch host cost the A/B measures. At vocab 32000 the
-        # logits projection is ~all of the smoke step's FLOPs on a
-        # 1-core CPU host and buries the effect; 8192 keeps the ratio
-        # honest.
-        vocab = 8192
-        d_model, heads, layers, max_len = 32, 2, 1, 96
-        t_prompt, n_new = 24, 32
-        n_jobs, slots, chunk = 4, 2, 1
-    else:
-        vocab = 32000
-        d_model, heads, layers, max_len = 512, 8, 8, 4096
-        t_prompt, n_new = 512, 128
-        n_jobs, slots = 16, 8
-        chunk = int(os.environ.get("MXNET_SERVE_CHUNK", "16"))
-    # CPU bf16 is emulated (~2x compute) — f32 keeps the A/B about
-    # round trips, not emulation; TPU keeps the serving default bf16
-    dtype = jnp.float32 if backend == "cpu" else jnp.bfloat16
-    cfg = tf.TransformerConfig(
-        vocab_size=vocab, d_model=d_model, n_heads=heads,
-        n_layers=layers, d_ff=4 * d_model, max_len=max_len,
-        dtype=dtype)
-    params = tf.init_params(cfg, seed=0)
-    jrng = np.random.RandomState(1)
-    jobs = [(list(jrng.randint(1, vocab, int(jrng.randint(
-        max(2, t_prompt // 2), t_prompt)))), n_new)
-            for _ in range(n_jobs)]
-    total_new = sum(n for _, n in jobs)
-    print("serving pipeline A/B: backend=%s dtype=%s d_model=%d "
-          "layers=%d chunk=%d depth=%d"
-          % (backend, np.dtype(dtype).name, d_model, layers, chunk,
-             depth), flush=True)
-
-    def run_mixed(d):
-        srv = ContinuousBatcher(params, cfg, max_batch=slots,
-                                chunk_size=chunk, pipeline_depth=d)
-        waiting, arr_i, step_i = [], 0, 0
-        while arr_i < len(jobs) or waiting or srv.active_count:
-            if arr_i < len(jobs) and step_i % 2 == 0:
-                # arrival stamp: queue-wait / TTFT cover time spent
-                # waiting for a lane (only read when telemetry is on)
-                waiting.append((jobs[arr_i], time.perf_counter_ns()))
-                arr_i += 1
-            while waiting and srv.has_capacity:
-                (p, n), enq = waiting.pop(0)
-                srv.admit(p, n, enqueued_ns=enq)
-            srv.step()
-            step_i += 1
-
-    sync_rate = _time_tokens(lambda: run_mixed(1), total_new)
-    pipe_rate = _time_tokens(lambda: run_mixed(depth), total_new)
-    print('{"leg": "continuous_pipeline_ab", "pipeline_depth": %d, '
-          '"sync_tokens_per_s": %.1f, "pipelined_tokens_per_s": %.1f, '
-          '"speedup": %.2f, "chunk": %d, "slots": %d, "jobs": %d, '
-          '"vocab": %d, "dtype": "%s", "backend": "%s", '
-          '"arrival_every_steps": 2}'
-          % (depth, sync_rate, pipe_rate, pipe_rate / sync_rate,
-             chunk, slots, n_jobs, vocab, np.dtype(dtype).name,
-             backend), flush=True)
-    rep = _latency_report(lambda: run_mixed(depth),
-                          "continuous_pipeline_ab",
-                          pipeline_depth=depth, chunk=chunk,
-                          slots=slots, backend=backend)
-    _write_artifact(_json_arg(), [rep])
-
-
 def spec_ab(k):
     """The batched-speculation A/B (see the module docstring): the
     same request pool through the plain batcher vs spec_k=k n-gram
@@ -319,9 +219,9 @@ def spec_ab(k):
 
     backend = jax.default_backend()
     if SMOKE:
-        # unlike pipeline_ab, the headline column here is a DISPATCH
-        # COUNT ratio — timing-independent, so the compute-honesty
-        # vocab sizing doesn't bind. What the leg does need is a
+        # the headline column here is a DISPATCH COUNT ratio —
+        # timing-independent, so the vocabulary's size does not bind.
+        # What the leg does need is a
         # verified stream with real repetition: d_model 16 gives the
         # random-init smoke model a strong enough greedy attractor
         # that its own rollouts stand in for repetitive text
@@ -1314,11 +1214,8 @@ def main():
 
 
 if __name__ == "__main__":
-    _depth = _pipeline_depth_arg()
     _spec = _spec_k_arg()
-    if _depth is not None:
-        pipeline_ab(_depth)
-    elif _spec is not None:
+    if _spec is not None:
         spec_ab(_spec)
     elif "--paged" in sys.argv[1:]:
         paged_ab()

@@ -32,8 +32,8 @@ Design notes (all static-shape, XLA-friendly):
   its history again. Mechanisms that cannot carry it or latent rows
   (paged blocks, speculative rollback, int8 KV) are refused at
   construction.
-* ROUTED EXPERTS (cfg.n_experts): the decode programs, pipelined or
-  not, return beside their tokens the dispatch's routing counts
+* ROUTED EXPERTS (cfg.n_experts): the decode program returns beside
+  its tokens the dispatch's routing counts
   (tf.MOE_STATS), which step() adds to the counters moe.<name> while
   spans record (the speculative programs count nothing).
 * HYPER-CONNECTIONS (cfg.hc_mult): the residual stream is n streams a
@@ -46,15 +46,17 @@ Design notes (all static-shape, XLA-friendly):
   the next admission's prefill overwrites them. Throughput is
   proportional to active lanes, latency to the slowest active row —
   exactly the continuous-batching trade.
-* Chunk PIPELINING (pipeline_depth >= 2; the default is 2): the
-  decode carry — cache, per-lane tokens/positions, sample keys — stays
-  device-resident, so chunk k+1 dispatches against chunk k's output
-  buffers before anyone syncs chunk k's emissions: the host's share of
-  a round (fetch, retire loop, bookkeeping) runs while the device works
-  on the next one. Admission/eviction are jitted lane
-  patches sequenced after the in-flight chunks; emissions are credited
-  by dispatch-time lane identity, which is what keeps every stream
-  bit-identical to the synchronous pool and to solo generate().
+* ONE decode loop, step(), over a window of `pipeline_depth` chunk
+  dispatches in flight (the default is 2): the decode carry — cache,
+  per-lane tokens/positions, sample keys — stays device-resident, so
+  chunk k+1 dispatches against chunk k's output buffers before anyone
+  syncs chunk k's emissions: the host's share of a round (fetch, retire
+  loop, bookkeeping) runs while the device works on the next one.
+  Admission/eviction are jitted lane patches sequenced after the
+  in-flight chunks; emissions are credited by dispatch-time lane
+  identity, which is what keeps every stream bit-identical at every
+  width and to solo generate(). A window of one (pipeline_depth=1)
+  dispatches a chunk and syncs it in the same step().
 
 * PAGED KV cache (paged=True / MXNET_KV_PAGED): the per-lane dense
   [max_len] cache rows become one per-layer block pool + per-lane int32
@@ -179,10 +181,9 @@ def _pick_next(logits, keys, greedy, temperature, top_k, top_p):
 # either kind of cache: `tables` is None for the dense rows (an empty
 # pytree: no argument reaches the device) and the per-lane block tables
 # for the paged pool, and tf._decode_step_on picks the step at trace
-# time. The cache / pool is donated; tables are donated only by the
-# pipelined chunk (which carries them device-resident) — the sync
-# programs read them. `paged` is part of each _serving_jit key, so the
-# two kinds never share a wrapper.
+# time. The cache / pool and the tables are donated (the chunk carries
+# both device-resident). `paged` is part of each _serving_jit key, so
+# the two kinds never share a wrapper.
 
 def _decode_and_pick(fz, controls, params, cache, tables, tok, pos, keys):
     """One ragged decode step and every lane's next token. Returns
@@ -194,24 +195,6 @@ def _decode_and_pick(fz, controls, params, cache, tables, tok, pos, keys):
     nxt, keys = _pick_next(logits, keys, *controls)
     return nxt, keys, cache, \
         tf.moe_stats(loads, tok.shape[0], fz) if loads else None
-
-
-def _jitted_ragged_step(cfg, greedy, temperature, top_k, top_p, paged):
-    """One compiled program: ragged decode + per-row token choice (and
-    a model with routed experts' routing counts as a fourth output)."""
-    controls = (greedy, temperature, top_k, top_p)
-
-    def build(fz):
-        def step(params, cache, tables, tok, pos, keys):
-            nxt, keys, cache, routing = _decode_and_pick(
-                fz, controls, params, cache, tables, tok, pos, keys)
-            if routing is None:
-                return nxt, keys, cache
-            return nxt, keys, cache, routing
-        return jax.jit(step, donate_argnums=tf._serving_donate(1))
-    return tf._serving_jit(
-        ("decode_ragged", paged, greedy, float(temperature), top_k,
-         top_p), cfg, build)
 
 
 def _scan_steps(fz, controls, k, params, cache, tables, tok, pos, keys):
@@ -227,48 +210,25 @@ def _scan_steps(fz, controls, k, params, cache, tables, tok, pos, keys):
     return jax.lax.scan(body, (cache, tok, pos, keys), None, length=k)
 
 
-def _jitted_ragged_chunk(cfg, greedy, temperature, top_k, top_p, k,
-                         paged):
-    """`k` ragged decode steps as ONE compiled program (lax.scan) —
-    multi-step scheduling. Each scheduling round costs a dispatch plus
-    a result sync; where that host cost exceeds a decode step,
-    stepping once per token caps the pool at ~1/sync tokens per lane.
-    Scanning k steps on device amortizes the sync k-fold; the host applies the
-    [k, B] token block afterwards, discarding any tail a request
-    emitted past its stop token or budget (bounded waste, the
-    standard continuous-batching trade for chunked scheduling)."""
-    controls = (greedy, temperature, top_k, top_p)
-
-    def build(fz):
-        def chunk(params, cache, tables, tok, pos, keys):
-            (cache, _, _, keys), (toks, routing) = _scan_steps(
-                fz, controls, k, params, cache, tables, tok, pos, keys)
-            if routing is None:
-                return toks, keys, cache       # toks [k, B]
-            return toks, keys, cache, jnp.sum(routing, axis=0)
-        return jax.jit(chunk, donate_argnums=tf._serving_donate(1))
-    return tf._serving_jit(
-        ("decode_ragged_chunk", paged, greedy, float(temperature),
-         top_k, top_p, k), cfg, build)
-
-
 def _jitted_pipeline_chunk(cfg, greedy, temperature, top_k, top_p, k,
                            paged):
-    """`k` ragged decode steps that return the WHOLE rolling carry
-    (cache, tables, last token, advanced positions, key chain)
-    alongside the [k, B] emissions — the dispatch unit of the
-    PIPELINED batcher.
+    """`k` ragged decode steps as ONE compiled program (lax.scan) that
+    returns the WHOLE rolling carry (cache, tables, last token,
+    advanced positions, key chain) alongside the [k, B] emissions — the
+    batcher's one decode program, the unit step() dispatches.
 
-    The sync-mode chunk (_jitted_ragged_chunk) hands its carry back to
-    the host, which re-uploads it next step; here the carry never
-    leaves the device, so chunk k+1 can be dispatched against chunk
-    k's output buffers BEFORE anyone syncs chunk k's tokens. The
-    emissions are the only output the host ever fetches. The carry is
+    The carry never leaves the device, so chunk k+1 can be dispatched
+    against chunk k's output buffers BEFORE anyone syncs chunk k's
+    tokens. The emissions are the only output the host ever fetches;
+    it applies the [k, B] block afterwards, discarding any tail a
+    request emitted past its stop token or budget (bounded waste, the
+    standard trade for multi-step scheduling: k steps a dispatch
+    amortize the dispatch and the sync k-fold). The carry is
     donated on accelerators (tok/pos/keys included — they are dead the
     moment the next chunk is built from them); tables pass through
     unchanged (allocation patches apply between dispatches,
     host-side). A model with routed experts returns the chunk's summed
-    routing counts as one more output, as the sync-mode chunk does."""
+    routing counts as one more output."""
     controls = (greedy, temperature, top_k, top_p)
 
     def build(fz):
@@ -856,8 +816,8 @@ class ContinuousBatcher(object):
     tf.generate() run — greedy argmax, or the same per-row key chain
     (tested).
 
-    `chunk_size=k` runs k decode steps per step() in one device
-    dispatch (_jitted_ragged_chunk) — multi-step scheduling for
+    `chunk_size=k` runs k decode steps per dispatch in one device
+    program (_jitted_pipeline_chunk) — multi-step scheduling for
     high-dispatch-latency links. Token streams are unchanged (tested
     chunked == unchunked == solo); what changes is granularity:
     admission and eviction happen at chunk boundaries, and a lane
@@ -868,24 +828,26 @@ class ContinuousBatcher(object):
     cached prefix prefill only the suffix. LRU-bounded
     (prefix_cache_slots row caches on device).
 
-    `pipeline_depth=d` (default 2) is CHUNK PIPELINING: up to d chunk
-    dispatches ride in flight against the device-resident carry
-    (cache, lane tokens/positions, sample keys), and each step() syncs
-    only the OLDEST chunk's emissions — so the next round is on the
-    device before the last one's tokens are read, and the host's share
-    of a round no longer leaves the device idle (on the chip 3-4 ms
-    of every round at depth 1; depth 3 served no more tokens/s than 2
-    and a worse tail: docs/SERVING.md). Admissions and evictions become tiny jitted lane
+    `pipeline_depth=d` (default 2) is the WIDTH of step()'s window:
+    up to d chunk dispatches ride in flight against the
+    device-resident carry (cache, lane tokens/positions, sample keys),
+    and each step() syncs only the OLDEST chunk's emissions — so at
+    d >= 2 the next round is on the device before the last one's
+    tokens are read, and the host's share of a round does not leave
+    the device idle (on the chip 3-4 ms of every round at a window of
+    one; depth 3 served no more tokens/s than 2 and a worse tail:
+    docs/SERVING.md). Admissions and evictions are tiny jitted lane
     patches applied to the carry between dispatches (bounded
-    staleness: a token is returned one step() after the one that
-    dispatched its chunk; a request admitted while chunks are in
-    flight enters at the NEXT dispatch boundary; chunks already in
-    flight keep advancing its lane's previous occupant, whose
+    staleness at d >= 2: a token is returned d - 1 step()s after the
+    one that dispatched its chunk; a request admitted while chunks
+    are in flight enters at the NEXT dispatch boundary; chunks already
+    in flight keep advancing its lane's previous occupant, whose
     emissions are discarded by request identity at sync). Token
-    streams are bit-identical to pipeline_depth=1 and to solo
-    generate() (tested). `pipeline_depth=1` is the synchronous
-    batcher, for a caller who needs a round's tokens in the step()
-    that computed them.
+    streams are bit-identical at every width and to solo generate()
+    (tested). `pipeline_depth=1` is a window of one on the same loop:
+    step() dispatches one chunk and syncs it before it returns, for a
+    caller who needs a round's tokens in the step() that computed
+    them.
 
     `paged=True` (default: MXNET_KV_PAGED) virtualizes the cache into
     fixed-size blocks (`block_size`, default MXNET_KV_BLOCK_SIZE=16):
@@ -1013,16 +975,11 @@ class ContinuousBatcher(object):
             self.spec_accept_floor = 0.0
             self.draft_params = self.draft_cfg = None
             self._spec_provider = None
-        # target-model dispatches issued (sync steps, pipelined chunks,
-        # speculative rounds' verify passes all count one per device
+        # target-model dispatches issued (chunks and speculative
+        # rounds' verify passes all count one per device
         # dispatch) — the denominator of dispatches-per-token, and the
         # off-path-silence invariant tests pin spec_k=None against
         self.dispatch_count = 0
-        # speculative decode needs the device-resident carry even at
-        # depth 1 (per-lane positions advance by data-dependent
-        # accepted counts — mirroring them on the host would force a
-        # sync per dispatch); pipelining needs it by construction
-        self._device_carry = self.pipeline_depth > 1 or self._spec_on
         if paged is None:
             paged = (_fastenv.get("MXNET_KV_PAGED") or "") \
                 not in ("", "0", "false", "False")
@@ -1074,14 +1031,14 @@ class ContinuousBatcher(object):
             self._cache = None
         else:
             self._cache = tf.init_cache(cfg, self.max_batch)
-        # under the device carry: the scheduled position a lane = the
-        # device's after every dispatched chunk (the pipelined carry
-        # never syncs it; a speculative dispatch's worst case until its
-        # sync reconciles it). It drives the lazy pre-dispatch block
-        # allocation and the count of latent rows fetched
+        # the host's mirror of the carry's positions: the scheduled
+        # position a lane = the device's after every dispatched chunk
+        # (the carry itself is never synced; a speculative dispatch's
+        # worst case until its sync reconciles it), patched with the
+        # carry and advanced at every dispatch. It drives the lazy
+        # pre-dispatch block allocation and the count of latent rows
+        # fetched
         self._pos = np.zeros((self.max_batch,), np.int32)
-        self._tok = np.zeros((self.max_batch,), np.int32)
-        self._keys = np.zeros((self.max_batch, 2), np.uint32)
         self._slots = [None] * self.max_batch   # Request or None
         # what a live lane holds, for the serving.state_bytes /
         # serving.kv_bytes gauges: bytes of recurrent state a lane
@@ -1099,33 +1056,36 @@ class ContinuousBatcher(object):
         self._kv_pos_bytes = nbytes(
             l for kind, l in row if kind not in tf._RECURRENT) \
             // cfg.max_len
-        if self._device_carry:
-            # device-resident lane carry (of the host-side mirrors
-            # above only _pos is kept, patched with the carry and
-            # advanced at every dispatch): tok/pos/keys live on device
-            # between
-            # dispatches, so a chunk dispatch uploads nothing and a
-            # chunk sync downloads only the [k, B] emissions
-            self._dev_tok = jnp.zeros((self.max_batch,), jnp.int32)
-            self._dev_pos = jnp.zeros((self.max_batch,), jnp.int32)
-            self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
-            # in-flight dispatches, oldest first: (emissions [k, B],
-            # per-lane rid snapshot at dispatch time, the chunk's
-            # routing counts or None, the positions it was given) —
-            # speculative records carry (targets, emits, rids, keff)
-            # instead
-            self._inflight = deque()
-            # resolved once — a pipelined dispatch must not pay the
-            # _serving_jit registry lookup per chunk
-            if self._spec_on:
-                self._spec_fn = _jitted_spec_chunk(
-                    cfg, self.draft_cfg, self.spec_k,
-                    self.spec_ngram, self.chunk_size, self.paged,
-                    self._spec_provider == "model")
-            else:
-                self._pipe_fn = _jitted_pipeline_chunk(
-                    cfg, *self._controls, self.chunk_size, self.paged)
-            self._patch_fn = _jitted_lane_patch(cfg)
+        # the device-resident lane carry: tok/pos/keys live on device
+        # between dispatches, so a chunk dispatch uploads nothing and a
+        # chunk sync downloads only the [k, B] emissions
+        self._dev_tok = jnp.zeros((self.max_batch,), jnp.int32)
+        self._dev_pos = jnp.zeros((self.max_batch,), jnp.int32)
+        self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
+        # in-flight dispatches, oldest first: (emissions [k, B],
+        # per-lane rid snapshot at dispatch time, the chunk's
+        # routing counts or None, the positions it was given) —
+        # speculative records carry (targets, emits, rids, keff)
+        # instead
+        self._inflight = deque()
+        # the program and step()'s (dispatch, sync) pair, resolved
+        # once — a dispatch must not pay the _serving_jit registry
+        # lookup per chunk. The pair is the class's plain functions,
+        # called with self: a bound method kept on the instance would
+        # be a reference cycle, and the lanes' device memory would wait
+        # for a collector pass instead of the last reference
+        cls = type(self)
+        if self._spec_on:
+            self._spec_fn = _jitted_spec_chunk(
+                cfg, self.draft_cfg, self.spec_k,
+                self.spec_ngram, self.chunk_size, self.paged,
+                self._spec_provider == "model")
+            self._round = cls._dispatch_spec, cls._sync_oldest_spec
+        else:
+            self._pipe_fn = _jitted_pipeline_chunk(
+                cfg, *self._controls, self.chunk_size, self.paged)
+            self._round = cls._dispatch_chunk, cls._sync_oldest
+        self._patch_fn = _jitted_lane_patch(cfg)
         if self._spec_on:
             # per-lane adaptive k: effective draft length (masked
             # inside the static-width program) and the measured
@@ -1849,45 +1809,26 @@ class ContinuousBatcher(object):
                         jnp.int32(p_len), jnp.int32(t_p - p_len - 1))
                 self._count_prefill(t_p - p_len, width)
                 last = logits[0]
-        if self._device_carry:
-            # prefill-into-lane, all device-side: pick the first token
-            # on device (generate()'s exact chain), patch the row
-            # cache and the lane's (tok, pos, key) into the carry —
-            # the patches consume the LAST dispatch's output buffers,
-            # so they take effect at the next dispatch boundary while
-            # the chunks already in flight keep reading their own
-            # (older) buffers. The one host pull here is the first
-            # token SCALAR, not the [vocab] logits row.
-            first_dev, key = _jitted_admit_token(
-                self.cfg, *self._controls)(last, jnp.int32(seed))
-            with _obs.span("serving.patch", cat="serving", kind="admit",
-                           lane=slot):
-                if not self.paged:   # paged: blocks already scattered
-                    self._cache = _jitted_slot_write(self.cfg)(
-                        self._cache, row_cache, jnp.int32(slot))
-                self._dev_tok, self._dev_pos, self._dev_keys = \
-                    self._patch_fn(self._dev_tok, self._dev_pos,
-                                   self._dev_keys, jnp.int32(slot),
-                                   first_dev, jnp.int32(t_p), key)
-            first = int(first_dev)
-        else:
-            if self.greedy:
-                first = int(np.argmax(np.asarray(last)))
-            else:
-                # mirror generate()'s chain: key=PRNGKey(seed); split
-                # once for the prefill token, carry the key into the
-                # step loop
-                key = jax.random.PRNGKey(seed)
-                key, sub = jax.random.split(key)
-                _, temperature, top_k, top_p = self._controls
-                first = int(tf._sample_logits(last[None], sub,
-                                              temperature, top_k,
-                                              top_p)[0])
-                self._keys[slot] = np.asarray(key, np.uint32)
-            if not self.paged:         # paged: blocks already scattered
+        # prefill-into-lane, all device-side: pick the first token
+        # on device (generate()'s exact chain), patch the row
+        # cache and the lane's (tok, pos, key) into the carry —
+        # the patches consume the LAST dispatch's output buffers,
+        # so they take effect at the next dispatch boundary while
+        # the chunks already in flight keep reading their own
+        # (older) buffers. The one host pull here is the first
+        # token SCALAR, not the [vocab] logits row.
+        first_dev, key = _jitted_admit_token(
+            self.cfg, *self._controls)(last, jnp.int32(seed))
+        with _obs.span("serving.patch", cat="serving", kind="admit",
+                       lane=slot):
+            if not self.paged:   # paged: blocks already scattered
                 self._cache = _jitted_slot_write(self.cfg)(
                     self._cache, row_cache, jnp.int32(slot))
-            self._tok[slot] = first
+            self._dev_tok, self._dev_pos, self._dev_keys = \
+                self._patch_fn(self._dev_tok, self._dev_pos,
+                               self._dev_keys, jnp.int32(slot),
+                               first_dev, jnp.int32(t_p), key)
+        first = int(first_dev)
         self._pos[slot] = t_p          # next decode writes position t_p
         if self._spec_on:
             self._spec_admit(slot, prompt, t_p, first)
@@ -1973,17 +1914,13 @@ class ContinuousBatcher(object):
         else:
             self._cache = _jitted_slot_write(self.cfg)(
                 self._cache, row_cache, jnp.int32(slot))
-        if self._device_carry:
-            with _obs.span("serving.patch", cat="serving",
-                           kind="resume", lane=slot):
-                self._dev_tok, self._dev_pos, self._dev_keys = \
-                    self._patch_fn(self._dev_tok, self._dev_pos,
-                                   self._dev_keys, jnp.int32(slot),
-                                   jnp.int32(last), jnp.int32(m),
-                                   jnp.asarray(key_np))
-        else:
-            self._tok[slot] = last
-            self._keys[slot] = key_np
+        with _obs.span("serving.patch", cat="serving",
+                       kind="resume", lane=slot):
+            self._dev_tok, self._dev_pos, self._dev_keys = \
+                self._patch_fn(self._dev_tok, self._dev_pos,
+                               self._dev_keys, jnp.int32(slot),
+                               jnp.int32(last), jnp.int32(m),
+                               jnp.asarray(key_np))
         self._pos[slot] = m
         if self._spec_on:
             self._spec_admit(slot, ctx, m, last)
@@ -2326,135 +2263,69 @@ class ContinuousBatcher(object):
     # ---- decode ----
 
     def step(self):
-        """One scheduling step over all slots: `chunk_size` ragged
-        decode steps in one device dispatch (one for the default
-        chunk_size=1). Appends up to chunk_size tokens to every active
-        request; returns {rid: full token list} for the requests that
-        finished this step (their slots are freed). A request hitting
-        its stop token or budget mid-chunk ends there — the lane's
-        remaining in-chunk tokens are discarded and its slot frees at
-        the chunk boundary.
+        """One scheduling step over all slots, the batcher's ONE decode
+        loop: top the in-flight window up to `pipeline_depth`
+        dispatches (each issued against the previous dispatch's
+        device-resident carry — no host sync between them), then sync
+        ONLY the oldest one's emissions. A dispatch is `chunk_size`
+        ragged decode steps in one device program (one for the default
+        chunk_size=1) and appends up to chunk_size tokens to every
+        active request; returns {rid: full token list} for the requests
+        that finished this step (their slots are freed). A request
+        hitting its stop token or budget mid-chunk ends there — the
+        lane's remaining in-chunk tokens are discarded and its slot
+        frees at the chunk boundary.
 
-        With pipeline_depth > 1 (the default is 2) each step() keeps up
-        to depth chunk dispatches in flight and syncs only the oldest
-        one — same return contract, tokens arrive one dispatch later
-        (bounded staleness; see the class docstring).
+        At the default pipeline_depth=2 the host's share of a round runs
+        beside the device's next one and tokens arrive one dispatch
+        later (bounded staleness; see the class docstring); a window of
+        one, pipeline_depth=1, returns a round's tokens from the step()
+        that dispatched it.
 
         With spec_k set each dispatch is a speculative draft/verify
         round (up to chunk_size * (spec_k + 1) tokens per lane per
-        dispatch), pipelined the same way."""
+        dispatch) — per-lane emissions are ragged either way,
+        speculation only makes the raggedness data-dependent — through
+        the same window."""
+        dispatch, sync = self._round
         # the whole round: what lies beside dispatch and sync (retire
         # loop, coverage, lane bookkeeping) is this span's self time
         with _obs.span("serving.step", cat="serving"):
-            if self._spec_on:
-                return self._step_spec()
-            if self.pipeline_depth > 1:
-                return self._step_pipelined()
-            return self._step_sync()
-
-    def _step_sync(self):
-        """One unpipelined scheduling step: dispatch, then block on the
-        fetch of its tokens."""
-        obs_on = _obs.enabled()
-        finished = {}
-        if self._pending_finished:
-            # re-delivery of deduped already-finished streams (recover
-            # and idempotency hits) rides the next step's return
-            finished.update(self._pending_finished)
-            self._pending_finished.clear()
-        # retire requests already complete at admission (n_new=1, or a
-        # stop token straight out of the prefill logits)
-        for i, req in enumerate(self._slots):
-            if req is not None and req.done:
-                finished[req.rid] = list(req.tokens)
-                if obs_on:
-                    self._note_finish(req)
-                self._note_done(req)
-                self._free(i)
-        if not any(s is not None for s in self._slots):
+            obs_on = _obs.enabled()
+            finished = {}
+            if self._pending_finished:
+                # re-delivery of deduped already-finished streams
+                # (recover and idempotency hits) rides the next step's
+                # return
+                finished.update(self._pending_finished)
+                self._pending_finished.clear()
+            # retire requests already complete at admission (n_new=1,
+            # or a stop token straight out of the prefill logits)
+            for i, req in enumerate(self._slots):
+                if req is not None and req.done:
+                    finished[req.rid] = list(req.tokens)
+                    if obs_on:
+                        self._note_finish(req)
+                    self._note_done(req)
+                    self._free(i)
+            while (len(self._inflight) < self.pipeline_depth
+                   and any(s is not None for s in self._slots)):
+                try:
+                    dispatch(self)
+                except Exception as exc:  # noqa: BLE001 — requeue-or-raise
+                    self._recover_dispatch_failure(exc)
+                    self._end_round()
+                    return finished
+            if self._inflight:
+                finished.update(sync(self))
+            if not any(s is not None for s in self._slots):
+                # nothing live: the remaining in-flight chunks only
+                # advance parked lanes, so their emissions belong to no
+                # request — drop the records (the device work itself is
+                # already queued and harmless)
+                self._inflight.clear()
             self._end_round()
             return finished
-        k = self.chunk_size
-        try:
-            if self.paged:
-                self._ensure_coverage(k)
-            # the synchronous dispatch blocks through the host fetch:
-            # serving.sync, inside it, is the wait for the device alone
-            with _obs.span("serving.dispatch", cat="serving",
-                           mode="sync", chunk=k,
-                           lanes=self.active_count):
-                if _chaos.enabled():
-                    _chaos.fire(self._chaos_site, mode="sync")
-                args = (self.params,) + self._kv_args() + (
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._keys))
-                if k == 1:
-                    fn = _jitted_ragged_step(
-                        self.cfg, *self._controls, self.paged)
-                else:
-                    fn = _jitted_ragged_chunk(
-                        self.cfg, *self._controls, k, self.paged)
-                if _membudget.enabled():
-                    _membudget.preflight(self._chaos_site, fn, args)
-                if _attr.ops_enabled():
-                    self._register_dispatch("decode", fn, args)
-                toks, keys, state, *routing = fn(*args)
-                with _obs.span("serving.sync", cat="serving",
-                               mode="sync"):
-                    toks = np.asarray(toks)
-                if routing and _obs.active():
-                    self._count_routing(routing[0])
-                if self._latent_layers and _obs.active():
-                    self._count_latent_rows(
-                        self._pos, [len(r.tokens) for r in self._slots
-                                    if r is not None], k)
-                toks = toks.astype(np.int32).reshape(k, -1)   # [k, B]
-                if self.paged:
-                    self._pool = state
-                else:
-                    self._cache = state
-        except Exception as exc:     # noqa: BLE001 — requeue-or-raise
-            self._recover_dispatch_failure(exc)
-            self._end_round()
-            return finished
-        self._dispatch_failures = 0
-        self._count_dispatch(ahead=False, steps=k)
-        t_sync = time.perf_counter_ns() if obs_on else None
-        # np.array (copy): asarray would give a READ-ONLY view of the
-        # device buffer and the next admit()'s in-place key write fails
-        self._keys = np.array(keys, np.uint32)
-        for i, req in enumerate(self._slots):
-            if req is None:
-                continue
-            grew = req.emitted
-            for j in range(k):
-                req.tokens.append(int(toks[j, i]))
-                req.emitted += 1
-                if req.done:
-                    break
-            grew = req.emitted - grew
-            if self._journal is not None and grew:
-                self._journal.append_emit(
-                    req.rid, req.tokens[len(req.tokens) - grew:],
-                    req.emitted)
-            # the device advanced every lane k steps regardless of
-            # where its request ended; mirror that here so a
-            # CONTINUING lane's next chunk starts from the device's
-            # true rolling state (freed lanes reset below)
-            self._pos[i] += k
-            self._tok[i] = toks[k - 1, i]
-            if t_sync is not None:
-                self._note_progress(req, i, grew, t_sync)
-            if req.done:
-                finished[req.rid] = list(req.tokens)
-                if t_sync is not None:
-                    self._note_finish(req, t_sync)
-                self._note_done(req)
-                self._free(i)
-        if obs_on:
-            self._publish_occupancy()
-        self._end_round()
-        return finished
 
     def _count_dispatch(self, ahead, steps, window=1):
         """One more target-model dispatch, of `steps` passes through
@@ -2462,8 +2333,9 @@ class ContinuousBatcher(object):
         record, also the counters serving.dispatches
         and serving.dispatch_ahead: the dispatches issued while an older
         one was still unsynced, i.e. with the device already fed — every
-        pipelined dispatch but the first after a drained window, none
-        at depth 1; hc.rows (_count_frame_rows) for every lane; the
+        dispatch but the first after a drained window (a window of one
+        is synced before the next dispatch, so it is never ahead);
+        hc.rows (_count_frame_rows) for every lane; the
         expert layers' grouped matmuls (_count_expert_matmuls); and the
         latent layers' stores of a step's `kr` rows (_count_row_stores)."""
         self.dispatch_count += 1
@@ -2505,7 +2377,10 @@ class ContinuousBatcher(object):
         rows_fetched), a latent layer a step; and mla.rows_live, those
         of them at or before a live lane's own position: `live` holds
         each live lane's rows at the dispatch's first step (its tokens
-        so far), one more a step."""
+        so far), one more a step. Its one caller, _sync_oldest, takes
+        for live the lanes the chunk still speaks for at its sync (the
+        request that owned the lane at dispatch still does and is not
+        done), not every occupied slot."""
         from ..kernels.latent_decode import rows_fetched
         n = self._latent_layers
         _obs.counter("mla.rows_read").add(n * rows_fetched(
@@ -2535,49 +2410,7 @@ class ContinuousBatcher(object):
             from .. import storage as _storage
             _storage.maybe_publish_device_memory_gauges()
 
-    # ---- pipelined scheduling (pipeline_depth > 1) ----
-
-    def _step_pipelined(self):
-        """One pipelined scheduling step: top the dispatch window up
-        to `pipeline_depth` chunks (each issued against the previous
-        dispatch's device-resident carry — no host sync between
-        them), then sync ONLY the oldest chunk's emissions. The
-        host sync that gates every chunk at depth 1 thus amortizes
-        over `depth` chunks (docs/SERVING.md)."""
-        obs_on = _obs.enabled()
-        finished = {}
-        if self._pending_finished:
-            # re-delivery of deduped already-finished streams (recover
-            # and idempotency hits) rides the next step's return
-            finished.update(self._pending_finished)
-            self._pending_finished.clear()
-        # retire requests already complete at admission (n_new=1, or a
-        # stop token straight out of the prefill logits)
-        for i, req in enumerate(self._slots):
-            if req is not None and req.done:
-                finished[req.rid] = list(req.tokens)
-                if obs_on:
-                    self._note_finish(req)
-                self._note_done(req)
-                self._free(i)
-        while (len(self._inflight) < self.pipeline_depth
-               and any(s is not None for s in self._slots)):
-            try:
-                self._dispatch_chunk()
-            except Exception as exc:  # noqa: BLE001 — requeue-or-raise
-                self._recover_dispatch_failure(exc)
-                self._end_round()
-                return finished
-        if self._inflight:
-            finished.update(self._sync_oldest())
-        if not any(s is not None for s in self._slots):
-            # nothing live: the remaining in-flight chunks only advance
-            # parked lanes, so their emissions belong to no request —
-            # drop the records (the device work itself is already
-            # queued and harmless)
-            self._inflight.clear()
-        self._end_round()
-        return finished
+    # ---- the plain dispatch / sync pair ----
 
     def _dispatch_chunk(self):
         """Issue one chunk against the device-resident carry and
@@ -2627,7 +2460,7 @@ class ContinuousBatcher(object):
         them to the requests that owned each lane when it was
         DISPATCHED (and still do): evicted or re-admitted lanes are
         discarded, a request ending mid-chunk keeps only its prefix.
-        This is the only host-blocking point of the pipelined loop."""
+        This is the only host-blocking point of step()."""
         toks_dev, lanes, routing, pos = self._inflight.popleft()
         counting = routing is not None and _obs.active()
         if counting:
@@ -2676,46 +2509,7 @@ class ContinuousBatcher(object):
             self._publish_occupancy()
         return finished
 
-    # ---- speculative scheduling (spec_k set) ----
-
-    def _step_spec(self):
-        """One speculative scheduling step: top the in-flight window up
-        to `pipeline_depth` draft/verify dispatches (depth 1 means the
-        classic dispatch-then-sync round trip, just k+1 wide per lane
-        per round), then sync only the oldest. Identical skeleton to
-        _step_pipelined — per-lane emissions were ALREADY ragged there,
-        speculation only makes the raggedness data-dependent."""
-        obs_on = _obs.enabled()
-        finished = {}
-        if self._pending_finished:
-            # re-delivery of deduped already-finished streams (recover
-            # and idempotency hits) rides the next step's return
-            finished.update(self._pending_finished)
-            self._pending_finished.clear()
-        # retire requests already complete at admission (n_new=1, or a
-        # stop token straight out of the prefill logits)
-        for i, req in enumerate(self._slots):
-            if req is not None and req.done:
-                finished[req.rid] = list(req.tokens)
-                if obs_on:
-                    self._note_finish(req)
-                self._note_done(req)
-                self._free(i)
-        while (len(self._inflight) < self.pipeline_depth
-               and any(s is not None for s in self._slots)):
-            try:
-                self._dispatch_spec()
-            except Exception as exc:  # noqa: BLE001 — requeue-or-raise
-                self._recover_dispatch_failure(exc)
-                self._end_round()
-                return finished
-        if self._inflight:
-            finished.update(self._sync_oldest_spec())
-        if not any(s is not None for s in self._slots):
-            # nothing live: in-flight emissions belong to no request
-            self._inflight.clear()
-        self._end_round()
-        return finished
+    # ---- the speculative dispatch / sync pair (spec_k set) ----
 
     def _dispatch_spec(self):
         """Issue one speculative dispatch (chunk_size draft/verify
@@ -2801,8 +2595,8 @@ class ContinuousBatcher(object):
     def _sync_oldest_spec(self):
         """Fetch the oldest speculative dispatch's verified targets and
         emit counts, credit each lane's ACCEPTED tokens to the request
-        that owned it at dispatch time (rid snapshot, exactly the
-        pipelined rule), feed the measured acceptance into the per-lane
+        that owned it at dispatch time (rid snapshot, exactly
+        _sync_oldest's rule), feed the measured acceptance into the per-lane
         EWMA the adaptive-k controller reads, and reconcile paged
         block accounting down from worst case."""
         targets_dev, emits_dev, lanes, keffs = self._inflight.popleft()
@@ -3018,13 +2812,10 @@ class ContinuousBatcher(object):
         else:
             self._cache = tf.init_cache(self.cfg, self.max_batch)
         self._pos = np.zeros((self.max_batch,), np.int32)
-        self._tok = np.zeros((self.max_batch,), np.int32)
-        self._keys = np.zeros((self.max_batch, 2), np.uint32)
-        if self._device_carry:
-            self._inflight.clear()
-            self._dev_tok = jnp.zeros((self.max_batch,), jnp.int32)
-            self._dev_pos = jnp.zeros((self.max_batch,), jnp.int32)
-            self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
+        self._inflight.clear()
+        self._dev_tok = jnp.zeros((self.max_batch,), jnp.int32)
+        self._dev_pos = jnp.zeros((self.max_batch,), jnp.int32)
+        self._dev_keys = jnp.zeros((self.max_batch, 2), jnp.uint32)
         if self._spec_on:
             # the donated draft state died with the failed dispatch;
             # re-admission re-seeds each live lane's slice of it
@@ -3088,15 +2879,11 @@ class ContinuousBatcher(object):
         else:
             self._cache = _jitted_slot_write(self.cfg)(
                 self._cache, row_cache, jnp.int32(slot))
-        if self._device_carry:
-            self._dev_tok, self._dev_pos, self._dev_keys = \
-                self._patch_fn(self._dev_tok, self._dev_pos,
-                               self._dev_keys, jnp.int32(slot),
-                               jnp.int32(last), jnp.int32(m),
-                               jnp.asarray(key_np))
-        else:
-            self._tok[slot] = last
-            self._keys[slot] = key_np
+        self._dev_tok, self._dev_pos, self._dev_keys = \
+            self._patch_fn(self._dev_tok, self._dev_pos,
+                           self._dev_keys, jnp.int32(slot),
+                           jnp.int32(last), jnp.int32(m),
+                           jnp.asarray(key_np))
         self._pos[slot] = m
         if self._spec_on:
             # re-seed the lane's draft state from the synced prefix —
@@ -3268,12 +3055,9 @@ class ContinuousBatcher(object):
         # quiesce: sync every in-flight dispatch so no chunk computed
         # under the old weights lands after the swap (its emissions
         # deliver through _pending_finished at the next step())
-        inflight = getattr(self, "_inflight", None)
-        if inflight:
-            sync = (self._sync_oldest_spec if self._spec_on
-                    else self._sync_oldest)
-            while inflight:
-                self._pending_finished.update(sync())
+        _, sync = self._round
+        while self._inflight:
+            self._pending_finished.update(sync(self))
         pending = [r for r in self._slots if r is not None]
         if mode == "drain":
             # drop the old reference before materializing against the
@@ -3282,16 +3066,15 @@ class ContinuousBatcher(object):
             self.params = None
         self.params = params
         self._weight_fp = None
-        if pending or self.paged or self._device_carry:
-            # the cache/pool holds K/V computed under the OLD weights:
-            # rebuild from scratch and re-prefill every live request
-            # under the new ones (same path as the dispatch-failure
-            # requeue)
-            self._rebuild_state()
-            for req in pending:
-                self._readmit(req)
-        else:
-            self._prefix_cache.clear()
+        # the cache/pool holds K/V computed under the OLD weights, and
+        # so does a cached prefix's row (a paged prefix's blocks go with
+        # the pool): rebuild from scratch and re-prefill every live
+        # request under the new ones (same path as the dispatch-failure
+        # requeue)
+        self._rebuild_state()
+        self._prefix_cache.clear()
+        for req in pending:
+            self._readmit(req)
         new_fp = self.weight_fingerprint
         if _obs.enabled():
             _obs.counter("serving.weight_swaps").add(1)
@@ -3328,8 +3111,8 @@ class ContinuousBatcher(object):
         """Free slot i. Idle lanes keep decoding (static batch shape);
         parking them at position 0 means their garbage K/V lands where
         the next admission's prefill overwrites it — defense in depth
-        on top of the `attention <= pos` self-healing argument. Under
-        pipelining the park is a device-side lane patch sequenced
+        on top of the `attention <= pos` self-healing argument. The
+        park is a device-side lane patch sequenced
         after the in-flight chunks (whose writes to this lane are the
         already-harmless idle-lane garbage)."""
         self._slots[i] = None
@@ -3348,16 +3131,13 @@ class ContinuousBatcher(object):
             self._tables = _jitted_table_row(self.cfg)(
                 self._tables, jnp.int32(i),
                 jnp.zeros((self._nb,), jnp.int32))
-        if self._device_carry:
-            with _obs.span("serving.patch", cat="serving", kind="park",
-                           lane=i):
-                self._dev_tok, self._dev_pos, self._dev_keys = \
-                    self._patch_fn(self._dev_tok, self._dev_pos,
-                                   self._dev_keys, jnp.int32(i),
-                                   jnp.int32(0), jnp.int32(0),
-                                   jnp.zeros((2,), jnp.uint32))
-        else:
-            self._tok[i] = 0
+        with _obs.span("serving.patch", cat="serving", kind="park",
+                       lane=i):
+            self._dev_tok, self._dev_pos, self._dev_keys = \
+                self._patch_fn(self._dev_tok, self._dev_pos,
+                               self._dev_keys, jnp.int32(i),
+                               jnp.int32(0), jnp.int32(0),
+                               jnp.zeros((2,), jnp.uint32))
         self._pos[i] = 0
         if self._spec_on:
             # reset the adaptive-k controller for the next occupant

@@ -29,8 +29,9 @@ def _prompts(rng, n, vocab=211):
             for _ in range(n)]
 
 
-# the batcher as a user gets it (two rounds in flight) and the
-# synchronous loop, which stays an argument: both keep every case
+# the batcher as a user gets it (two rounds in flight) and a window of
+# one on the same loop (a round's tokens in the step() that dispatched
+# it), which stays an argument: both keep every case
 both_loops = pytest.mark.parametrize(
     "loop", [{}, {"pipeline_depth": 1}], ids=["default", "depth1"])
 
@@ -538,7 +539,7 @@ def test_chunked_churn_matches_oracle(loop):
 def test_pipelined_matches_sync_and_generate(kw):
     """Chunk pipelining (pipeline_depth>1; a default-constructed
     batcher runs it at depth 2) emits BIT-IDENTICAL greedy streams to
-    the synchronous pool and to solo generate(), across depths and
+    a window of one and to solo generate(), across depths and
     chunk sizes — the depth>1 vs depth=1 identity contract."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
@@ -548,7 +549,6 @@ def test_pipelined_matches_sync_and_generate(kw):
         params, cfg, max_batch=3, **dict(kw, pipeline_depth=1)).run(jobs)
     srv = ContinuousBatcher(params, cfg, max_batch=3, **kw)
     assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
-    assert srv._device_carry
     pipe, order_p = srv.run(jobs)
     assert len(pipe) == len(jobs)
     for rs, rp, (prompt, n_new) in zip(order_s, order_p, jobs):
@@ -756,7 +756,7 @@ def test_dispatch_counters_say_how_many_ran_ahead(depth):
     """While spans record, serving.dispatches counts every chunk
     dispatch and serving.dispatch_ahead those issued while an older
     chunk was still unsynced: every one but the first after a drained
-    window when pipelined, none in the synchronous loop."""
+    window when pipelined, none at a window of one."""
     from mxnet_tpu.observability import core as obs
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
@@ -778,6 +778,56 @@ def test_dispatch_counters_say_how_many_ran_ahead(depth):
     finally:
         obs.set_enabled(None)
         obs.reset()
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_window_of_one_returns_a_rounds_tokens_in_its_step(paged, chunk):
+    """pipeline_depth=1 is a window of one on the one loop: every
+    step() dispatches a chunk against the device-resident carry and
+    syncs it before it returns, so nothing stays in flight and each
+    live request grew by chunk_size tokens, or finished."""
+    cfg = _cfg()
+    params = tf.init_params(cfg, seed=3)
+    kw = dict(paged=True, block_size=8) if paged else {}
+    srv = ContinuousBatcher(params, cfg, max_batch=3, chunk_size=chunk,
+                            pipeline_depth=1, **kw)
+    jobs = [([4, 7, 2], 9), ([9, 1, 5, 3], 4), ([8, 8], 7)]
+    rids = [srv.admit(p, n) for p, n in jobs]
+    done, rounds = {}, 0
+    while srv.active_count:
+        live = {r.rid: len(r.tokens) for r in srv._slots if r is not None}
+        out = srv.step()
+        rounds += 1
+        assert len(srv._inflight) == 0
+        assert srv.dispatch_count == rounds
+        now = {r.rid: len(r.tokens) for r in srv._slots if r is not None}
+        assert set(live) == set(now) | set(out)
+        for rid, n in live.items():
+            assert rid in out or now[rid] == n + chunk
+        done.update(out)
+    for rid, (p, n) in zip(rids, jobs):
+        want = tf.generate(params, jnp.asarray([p], jnp.int32), n, cfg)
+        np.testing.assert_array_equal(np.asarray(done[rid]),
+                                      np.asarray(want[0]))
+
+
+@pytest.mark.parametrize("controls", [
+    {}, dict(greedy=False, temperature=0.8, top_k=5)],
+    ids=["greedy", "sampled"])
+def test_every_depth_shares_one_decode_program(controls):
+    """pipeline_depth is the width of one loop's window, not the choice
+    of a loop: batchers of one configuration at depths 1, 2 and 3 hold
+    the same compiled decode program, and the module has no other."""
+    from mxnet_tpu.models import serving
+    cfg = _cfg()
+    params = tf.init_params(cfg, seed=3)
+    fns = [ContinuousBatcher(params, cfg, max_batch=2, pipeline_depth=d,
+                             **controls)._pipe_fn for d in (1, 2, 3)]
+    assert fns[0] is fns[1] is fns[2]
+    for gone in ("_jitted_ragged_step", "_jitted_ragged_chunk"):
+        assert not hasattr(serving, gone)
+    assert not hasattr(ContinuousBatcher, "_step_sync")
 
 
 @both_loops

@@ -302,7 +302,7 @@ def test_three_staggered_requests_on_two_lanes_equal_each_served_alone(
         sides, kw):
     """The third request waits for a lane and overwrites its previous
     occupant's rotated rows whole; every stream equals the request
-    served alone by the synchronous loop, and solo generate()."""
+    served alone at a window of one, and solo generate()."""
     params, cfg, _ = sides
     rng = np.random.RandomState(9)
     jobs = [(list(rng.randint(1, 256, n)), m)
@@ -452,7 +452,7 @@ def test_two_rounds_in_flight_count_the_rows_one_does(sides, telemetry):
     """The counts are taken when a round's tokens are fetched: the rows
     fetched from the positions the round was dispatched with, every lane
     of max_batch among them, the live ones from what the host knows of
-    the lanes then; so the pipelined loop adds what the synchronous one
+    the lanes then; so two rounds in flight add what a window of one
     does. A lane without a request is parked at position 0 and fetched
     up to where the carry has moved it since: one block here, and none
     of its rows lives."""

@@ -235,7 +235,7 @@ def test_three_staggered_requests_on_two_lanes_equal_each_served_alone(
         sides, kw):
     """The third request waits for a lane and overwrites its previous
     occupant's latent rows, conv windows and matrix states whole; every
-    stream equals the request served alone by the synchronous loop, and
+    stream equals the request served alone at a window of one, and
     solo generate(). The defaults keep two rounds in flight."""
     params, cfg, _ = sides
     rng = np.random.RandomState(9)
@@ -243,7 +243,6 @@ def test_three_staggered_requests_on_two_lanes_equal_each_served_alone(
             for n, m in ((5, 9), (13, 4), (9, 7))]
     srv = ContinuousBatcher(params, cfg, max_batch=2, **kw)
     assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
-    assert srv._device_carry == (srv.pipeline_depth > 1)
     got, order = srv.run(jobs)
     assert len(got) == 3
     for (prompt, n_new), rid in zip(jobs, order):
@@ -304,11 +303,11 @@ def test_a_decode_round_counts_its_routing(sides, telemetry):
 
 
 def test_two_rounds_in_flight_count_the_routing_one_does(sides, telemetry):
-    """The pipelined chunk returns its routing counts as the unpipelined
-    programs do, the in-flight record carries them and they are added
+    """The chunk returns its routing counts, the in-flight record
+    carries them and they are added
     when the chunk's tokens are fetched: over the same requests (a full
     pool, equal budgets, so no lane parks early) every moe.* counter
-    reads what the synchronous loop adds. The chunk still in flight when
+    reads what a window of one adds. The chunk still in flight when
     the last stream ends is dropped unfetched, and uncounted."""
     params, cfg, _ = sides
     jobs = [([5, 6, 7, 8, 9], 7), ([1, 2, 3], 7)]

@@ -44,7 +44,7 @@ def test_router_streams_bit_exact(paged, loop):
     """Jobs spread over 2 replicas all emit exactly their solo greedy
     streams, and the fleet balances (both replicas served work):
     replicas as built by default (two rounds in flight) and replicas
-    on the synchronous loop."""
+    with a window of one."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     rng = np.random.RandomState(1)

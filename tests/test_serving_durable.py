@@ -355,6 +355,25 @@ def test_swap_weights_verified(setup, tmp_path, loop):
     srv.check_invariants(quiesce=True)
 
 
+@both_loops
+def test_swap_weights_drops_cached_prefixes(setup, loop):
+    """A cached prefix's row holds K/V of the OLD weights: after a swap
+    an admission that starts with it prefills the whole prompt again
+    and streams what solo generate() streams under the new ones."""
+    cfg, params = setup
+    p1 = tf.init_params(cfg, seed=1)
+    srv = ContinuousBatcher(params, cfg, max_batch=2, journal=False,
+                            **loop)
+    prefix = [1, 2, 3, 4, 5, 6, 7, 8]
+    srv.cache_prefix(prefix)
+    srv.swap_weights(p1)
+    prompt = prefix + [9, 10]
+    got, order = srv.run([(prompt, 8)])
+    want = tf.generate(p1, jnp.asarray([prompt], jnp.int32), 8, cfg)
+    np.testing.assert_array_equal(np.asarray(got[order[0]]),
+                                  np.asarray(want[0]))
+
+
 def test_swap_weights_refuses_unverified(setup):
     """A fingerprint mismatch against the manifest refuses the swap
     BEFORE the serving weights change."""

@@ -46,12 +46,11 @@ def _solo(params, prompt, n_new):
 def test_streams_equal_solo_generate_with_lane_reuse(params, kw):
     """Seven requests through two lanes: every lane is reused, each
     admission writes a whole row of both kinds of state over the
-    previous occupant's. The defaults keep two rounds in flight; the
-    synchronous loop (depth 1) serves the same streams."""
+    previous occupant's. The defaults keep two rounds in flight; a
+    window of one (depth 1) serves the same streams."""
     jobs = _jobs(np.random.RandomState(3), 7)
     srv = ContinuousBatcher(params, CFG, max_batch=2, **kw)
     assert srv.pipeline_depth == kw.get("pipeline_depth", 2)
-    assert srv._device_carry == (srv.pipeline_depth > 1)
     got, order = srv.run(jobs)
     assert len(got) == len(order) == len(jobs)
     for (prompt, n_new), rid in zip(jobs, order):

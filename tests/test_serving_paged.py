@@ -45,7 +45,7 @@ def _solo(params, prompt, n, cfg, **kw):
     dict(pipeline_depth=1, chunk_size=3)])
 def test_paged_streams_bit_exact(kw, fresh_rows):
     """Greedy streams through the paged pool == solo generate(), in
-    sync, chunked, and pipelined scheduling — and the pool drains back
+    a window of one, chunked, and pipelined scheduling — and the pool drains back
     to every block free with zero reservation. Each admission's row is
     one launch of the row's program."""
     cfg = _cfg()
@@ -289,25 +289,21 @@ def test_paged_int8_kv_matches_dense_int8():
 @pytest.mark.parametrize("controls", [
     (True, 1.0, None, None), (False, 0.8, 5, 0.9)],
     ids=["greedy", "sampled"])
-@pytest.mark.parametrize("build,extra,cache_at,tables_at", [
-    (serving._jitted_ragged_step, (), 2, None),
-    (serving._jitted_ragged_chunk, (3,), 2, None),
-    (serving._jitted_pipeline_chunk, (3,), 1, 2)],
-    ids=["step", "chunk", "pipeline"])
-def test_one_decode_program_serves_both_caches(build, extra, cache_at,
-                                               tables_at, controls):
-    """Each decode program is written once over (cache, tables): built
+def test_one_decode_program_serves_both_caches(controls):
+    """The decode program is written once over (cache, tables): built
     dense (tables None) and paged it is two entries of _serving_jit,
     never one wrapper, and on a pool that gathers to the dense cache
     the two emit the same tokens, advance the same keys and leave the
     same K/V."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
-    dense = build(cfg, *controls, *extra, False)
-    paged = build(cfg, *controls, *extra, True)
+    build = serving._jitted_pipeline_chunk
+    dense = build(cfg, *controls, 3, False)
+    paged = build(cfg, *controls, 3, True)
     assert dense is not paged
-    assert build(cfg, *controls, *extra, False) is dense
-    assert build(cfg, *controls, *extra, True) is paged
+    assert build(cfg, *controls, 3, False) is dense
+    assert build(cfg, *controls, 3, True) is paged
+    cache_at, tables_at = 1, 2      # of (toks, cache, tables, tok, ...)
     b, bs = 3, 8
     nb = cfg.max_len // bs
     rng = np.random.RandomState(4)

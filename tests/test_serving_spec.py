@@ -394,7 +394,7 @@ def test_spec_off_path_silence(paged):
     """spec_k unset => ZERO behavior change: identical streams AND an
     identical dispatch count to the pre-speculation batcher (the
     counter is the invariant the A/B bench divides by). One dispatch a
-    step is the synchronous loop's promise, so depth 1 is named."""
+    step is a window of one's promise, so depth 1 is named."""
     cfg = _cfg()
     params = tf.init_params(cfg, seed=3)
     jobs = list(zip(_PROMPTS, _N_NEW))

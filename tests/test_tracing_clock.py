@@ -135,7 +135,8 @@ def traced(tmp_path_factory):
             with jax.profiler.TraceAnnotation("bench.step"):
                 step()
     out["gluon"] = (core.span_totals(), s.host_events())
-    for name, kw in (("serve", {}), ("serve_sync", {"pipeline_depth": 1})):
+    for name, kw in (("serve", {}),
+                     ("serve_depth1", {"pipeline_depth": 1})):
         core.reset()
         _serve(_batcher(**kw), 1)
         srv = _batcher(**kw)
@@ -459,38 +460,42 @@ def test_serving_span_fires_this_often(traced, name):
         else PER_ROUND[name] * ROUNDS + FILL.get(name, 0)
     assert totals[name]["count"] == want
     assert len([e for e in events if e[0] == "mx." + name]) == want
-    if name in PER_ROUND:               # the synchronous loop: no fill
-        assert traced["serve_sync"][0][name]["count"] \
+    if name in PER_ROUND:               # a window of one: no fill
+        assert traced["serve_depth1"][0][name]["count"] \
             == PER_ROUND[name] * ROUNDS
 
 
-def test_serving_sync_lies_inside_dispatch_inside_step(traced):
-    """The synchronous loop (pipeline_depth=1) blocks inside its
-    dispatch; pipelined, sync follows dispatch inside step (below)."""
-    totals, events = traced["serve_sync"]
+def test_a_window_of_one_syncs_its_own_dispatch_inside_one_step(traced):
+    """At pipeline_depth=1 the n-th serving.sync follows the n-th
+    serving.dispatch, its own round's, inside the n-th serving.step:
+    a round's tokens come back in the step() that dispatched it, and
+    the wait is no part of the dispatch's time."""
+    totals, events = traced["serve_depth1"]
 
     def of(name):
         return sorted((a, b) for n, a, b, _ in events if n == "mx." + name)
-    for (sa, sb), (da, db), (ya, yb) in zip(of("serving.step"),
-                                            of("serving.dispatch"),
-                                            of("serving.sync")):
-        assert sa <= da <= ya < yb <= db <= sb
+    rounds = list(zip(of("serving.step"), of("serving.dispatch"),
+                      of("serving.sync")))
+    assert len(rounds) == ROUNDS
+    for (sa, sb), (da, db), (ya, yb) in rounds:
+        assert sa <= da < db <= ya < yb <= sb
     for (aa, ab), (pa, pb) in zip(of("serving.admit"),
                                   of("serving.prefill")):
         assert aa <= pa < pb <= ab
     assert totals["serving.sync"]["self_ns"] \
         == totals["serving.sync"]["total_ns"]
-    assert totals["serving.dispatch"]["self_ns"] == (
-        totals["serving.dispatch"]["total_ns"]
-        - totals["serving.sync"]["total_ns"])
+    assert totals["serving.dispatch"]["self_ns"] \
+        == totals["serving.dispatch"]["total_ns"]
     assert totals["serving.admit"]["self_ns"] == (
         totals["serving.admit"]["total_ns"]
         - totals["serving.prefill"]["total_ns"])
 
 
 @pytest.mark.parametrize("kw", [dict(), dict(pipeline_depth=2),
-                                dict(spec_k=2)],
-                         ids=["default", "pipelined", "speculative"])
+                                dict(spec_k=2), dict(pipeline_depth=1),
+                                dict(spec_k=2, pipeline_depth=1)],
+                         ids=["default", "pipelined", "speculative",
+                              "depth1", "speculative-depth1"])
 def test_every_step_variant_is_one_serving_step_a_round(dark, tmp_path, kw):
     srv = _batcher(**kw)
     _serve(srv, 1)
@@ -502,7 +507,7 @@ def test_every_step_variant_is_one_serving_step_a_round(dark, tmp_path, kw):
     assert t["serving.step"]["count"] == ROUNDS
     assert t["serving.sync"]["count"] == ROUNDS
     assert t["serving.admit"]["count"] == 2
-    # sync is inside step, never inside dispatch, when pipelined
+    # sync is inside step, never inside dispatch, at every depth
     assert t["serving.dispatch"]["self_ns"] == t["serving.dispatch"][
         "total_ns"]
     assert t["serving.step"]["self_ns"] <= (

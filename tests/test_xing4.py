@@ -546,7 +546,7 @@ def test_the_frames_device_operations_carry_their_scopes(sides):
 
 
 @pytest.mark.parametrize("loop", [{}, {"pipeline_depth": 1}],
-                         ids=["two-in-flight", "synchronous"])
+                         ids=["two-in-flight", "depth1"])
 def test_admissions_and_rounds_count_their_rows(sides, telemetry, loop,
                                                 chunks_of_8):
     """An admission of 13 tokens is a chunk of 8 and a rest of 5 in its
