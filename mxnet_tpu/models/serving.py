@@ -984,11 +984,13 @@ class ContinuousBatcher(object):
             paged = (_fastenv.get("MXNET_KV_PAGED") or "") \
                 not in ("", "0", "false", "False")
         self.paged = bool(paged)
-        # only dense lanes carry a recurrent state or latent rows
-        # (tf._DENSE_ONLY): a block holds K/V positions of heads and a
-        # state has none to live in or be shared through; a rejected
-        # draft is already folded into a state and cannot be rolled
-        # back; the int8 layout has no place for either
+        # only dense lanes carry a recurrent state, latent rows or a
+        # window layer's ring (tf._DENSE_ONLY): a block holds K/V
+        # positions of heads and a state has none to live in or be
+        # shared through, nor is a block freed behind a window; a
+        # rejected draft is already folded into a state, or has
+        # overwritten a ring's oldest rows, and cannot be rolled
+        # back; the int8 layout has no place for any of them
         for on, what in ((self.paged, "paged=True (or MXNET_KV_PAGED)"),
                          (self._spec_on, "spec_k (or MXNET_SPEC_K)"),
                          (cfg.kv_cache_int8, "kv_cache_int8")):
@@ -1043,19 +1045,27 @@ class ContinuousBatcher(object):
         # what a live lane holds, for the serving.state_bytes /
         # serving.kv_bytes gauges: bytes of recurrent state a lane
         # (whatever its length: a Mamba layer's, a KDA layer's matrices)
-        # and bytes of rows a position (K/V heads, or a latent)
+        # and, for every layer that keeps rows (K/V heads, or a
+        # latent), the rows its leaf holds a lane (max_len, or a window
+        # layer's ring) with the bytes of one
         row = list(zip(tf._layer_kinds(cfg),
                        jax.eval_shape(lambda: tf.init_cache(cfg, 1))))
 
-        def nbytes(layers):
-            return sum(x.size * x.dtype.itemsize
-                       for layer in layers for x in layer.values())
+        def nbytes(layer):
+            return sum(x.size * x.dtype.itemsize for x in layer.values())
         self._latent_layers = sum(kind == "mla" for kind, _ in row)
-        self._lane_state_bytes = nbytes(
-            l for kind, l in row if kind in tf._RECURRENT)
-        self._kv_pos_bytes = nbytes(
-            l for kind, l in row if kind not in tf._RECURRENT) \
-            // cfg.max_len
+        self._lane_state_bytes = sum(
+            nbytes(l) for kind, l in row if kind in tf._RECURRENT)
+        held = [(next(iter(layer.values())).shape[1], nbytes(layer),
+                 kind == "window")
+                for kind, layer in row if kind not in tf._RECURRENT]
+        # rows of every such leaf; bytes a position of those max_len
+        # long; (rows, bytes of one) of each ring
+        self._leaf_rows = [rows for rows, _, _ in held]
+        self._kv_pos_bytes = sum(size // rows for rows, size, ring in held
+                                 if not ring)
+        self._rings = [(rows, size // rows) for rows, size, ring in held
+                       if ring]
         # the device-resident lane carry: tok/pos/keys live on device
         # between dispatches, so a chunk dispatch uploads nothing and a
         # chunk sync downloads only the [k, B] emissions
@@ -1276,8 +1286,7 @@ class ContinuousBatcher(object):
             "serving.lane_occupancy": active,
             "serving.lane_utilization": active / float(self.max_batch),
             "serving.state_bytes": active * self._lane_state_bytes,
-            "serving.kv_bytes": self._kv_pos_bytes * sum(
-                len(r.tokens) for r in self._slots if r is not None),
+            "serving.kv_bytes": self._kv_bytes(),
             "serving.slo_attainment": _slo.attainment(),
             "serving.weight_fingerprint": self.weight_fingerprint,
             "serving.weight_version": int(self.weight_fingerprint, 16),
@@ -1319,7 +1328,19 @@ class ContinuousBatcher(object):
             # counted while spans record (_count_row_stores)
             for name in ("mla.row_store_kernel", "mla.row_store_scatter"):
                 snap[name] = _obs.counter(name).value
+        if self._rings:
+            # counted while spans record (_count_kv_rows)
+            for name in ("kv.rows_read", "kv.rows_live", "kv.rows_ring"):
+                snap[name] = _obs.counter(name).value
         return snap
+
+    def _kv_bytes(self):
+        """Bytes of rows the live lanes hold (the serving.kv_bytes
+        gauge): a position's worth a token in every layer that keeps
+        rows, a window layer's ring counted at its own rows."""
+        held = [len(r.tokens) for r in self._slots if r is not None]
+        return self._kv_pos_bytes * sum(held) + sum(
+            min(n, rows) * each for n in held for rows, each in self._rings)
 
     def check_invariants(self, quiesce=False):
         """Audit paged block accounting against every live mapping —
@@ -2389,6 +2410,28 @@ class ContinuousBatcher(object):
         _obs.counter("mla.rows_live").add(
             n * (steps * sum(live) + len(live) * steps * (steps - 1) // 2))
 
+    def _count_kv_rows(self, live, steps):
+        """A dispatch's K/V rows (a model with window layers) into the
+        counters kv.rows_read, the rows its decode contractions read:
+        every one of the max_batch lanes, with a request or not, times
+        the rows each K/V layer's leaf holds (max_len, or a window
+        layer's ring: tf._decode_attention contracts over the whole leaf
+        and masks), a layer a step; kv.rows_ring, the part of them that
+        lies in rings; and kv.rows_live, those a live lane's mask
+        admits: `live` holds each live lane's positions at the
+        dispatch's first step (its tokens so far), one more a step, and
+        a ring admits no more than its rows. COMPUTED on the host from
+        the leaves' shapes and the positions dispatched, as
+        mla.rows_read is, not observed on the device. Called like
+        _count_latent_rows."""
+        _obs.counter("kv.rows_read").add(
+            steps * self.max_batch * sum(self._leaf_rows))
+        _obs.counter("kv.rows_ring").add(
+            steps * self.max_batch * sum(rows for rows, _ in self._rings))
+        _obs.counter("kv.rows_live").add(sum(
+            min(n + j, rows) for n in live for j in range(steps)
+            for rows in self._leaf_rows))
+
     def _end_round(self):
         """Per-scheduling-round epilogue shared by every step path:
         the brownout controller's tick, the MXNET_SERVING_DEBUG
@@ -2472,12 +2515,14 @@ class ContinuousBatcher(object):
             toks = np.asarray(toks_dev).astype(np.int32)     # [k, B]
         if counting:
             self._count_routing(routing)
-        if self._latent_layers and _obs.active():
+        if (self._latent_layers or self._rings) and _obs.active():
             # the lanes this chunk still speaks for (the loop below)
-            self._count_latent_rows(
-                pos, [len(r.tokens) for r, rid in zip(self._slots, lanes)
-                      if r is not None and r.rid == rid and not r.done],
-                toks.shape[0])
+            live = [len(r.tokens) for r, rid in zip(self._slots, lanes)
+                    if r is not None and r.rid == rid and not r.done]
+            if self._latent_layers:
+                self._count_latent_rows(pos, live, toks.shape[0])
+            if self._rings:
+                self._count_kv_rows(live, toks.shape[0])
         obs_on = _obs.enabled()
         t_sync = time.perf_counter_ns() if obs_on else None
         finished = {}
@@ -3257,7 +3302,7 @@ class ContinuousBatcher(object):
             ctx / float(self.max_batch * self.cfg.max_len))
         _obs.gauge("serving.state_bytes").set(
             active * self._lane_state_bytes)
-        _obs.gauge("serving.kv_bytes").set(ctx * self._kv_pos_bytes)
+        _obs.gauge("serving.kv_bytes").set(self._kv_bytes())
         if self.paged:
             usable = self.num_blocks - 1
             free = self._alloc.free_blocks

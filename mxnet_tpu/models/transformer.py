@@ -68,16 +68,25 @@ class TransformerConfig:
     # lever; the flash-decode kernel reads each cache block once per
     # GROUP of query heads
     n_kv_heads: int = None
+    # a head's width in an "attention" or "window" layer (None =
+    # d_model // n_heads): n_heads x attn_head_dim need not be d_model
+    attn_head_dim: int = None
     n_layers: int = 2
     # each layer's mixer, a tuple of n_layers names: "attention" (the K/V
-    # cache kind), "mamba" (models/ssm.py: fixed-size recurrent state
-    # instead of K/V rows), "kda" (models/kda.py: a matrix-valued state a
-    # head) or "mla" (latent attention: one latent row a position instead
-    # of K/V heads). None = every layer attention
+    # cache kind), "window" (the same heads attending the last
+    # `attn_window` positions only, its K/V rows a ring of that many),
+    # "mamba" (models/ssm.py: fixed-size recurrent state instead of K/V
+    # rows), "kda" (models/kda.py: a matrix-valued state a head) or "mla"
+    # (latent attention: one latent row a position instead of K/V
+    # heads). None = every layer attention
     layer_kinds: tuple = None
+    # a "window" layer's span: a query at position i sees positions
+    # i - attn_window + 1 .. i
+    attn_window: int = None
     d_ff: int = 128
     # the feed-forward's form, dense or a routed expert's: "gelu" =
-    # w2 gelu(w1 x); "gated_silu" = w2 (silu(w1 x) * w3 x)
+    # w2 gelu(w1 x); "gated_silu" = w2 (silu(w1 x) * w3 x); "gated_relu"
+    # = w2 (relu(w1 x) * w3 x)
     ffn: str = "gelu"
     # routed experts: 0 = a dense FFN in every layer; E > 0 = the layers
     # from `first_dense_layers` on route each token over E experts of
@@ -86,12 +95,17 @@ class TransformerConfig:
     # expert) are chosen by `expert_scoring`: "softmax" = the softmax
     # over all E as the weights; "sigmoid" = sigmoid scores, the k
     # chosen (by score + the layer's "gate_bias") renormalised to sum to
-    # `expert_scale`. `experts_held` = (first, count): the contiguous
+    # `expert_scale`; "softmax_topk" = the softmax over the k chosen
+    # logits. `experts_held` = (first, count): the contiguous
     # range of the E this program holds weights for (None = all); it
-    # routes over all E and computes its own experts' part of the result
+    # routes over all E and computes its own experts' part of the result.
+    # `router_input`: what the router scores, "ffn" = the feed-forward's
+    # normed input, "layer" = the residual stream as the layer receives
+    # it, before the mixer and un-normed
     n_experts: int = 0
     experts_per_token: int = None
     expert_scoring: str = "softmax"
+    router_input: str = "ffn"
     expert_scale: float = 1.0
     experts_held: tuple = None
     d_expert: int = None
@@ -124,6 +138,10 @@ class TransformerConfig:
     # state-space layers carry the order): no `pos` table, no rotation.
     # Left None, `rope` says which of the other two it is
     positions: str = None
+    # under `rope`, which "attention" and "window" layers rotate: a
+    # tuple of n_layers flags (None = every one); a layer whose flag is
+    # off attends without positional encoding
+    rope_layers: tuple = None
     # Mamba mixer sizes: states a channel, conv taps, channels as a
     # multiple of d_model, rank of the step-size projection (None =
     # ceil(d_model / 16), the family's rule)
@@ -200,7 +218,8 @@ def _kvh(cfg):
 # and a mesh refuse these kinds by name (_refuse_dense_only)
 _DENSE_ONLY = {"mamba": "a state-space layer's recurrent state",
                "kda": "a linear-attention layer's matrix state",
-               "mla": "a latent-attention layer's latent rows"}
+               "mla": "a latent-attention layer's latent rows",
+               "window": "a window layer's ring of K/V rows"}
 # the kinds whose state is a recurrence: fixed-size, and not healed by
 # position as K/V or latent rows are
 _RECURRENT = ("mamba", "kda")
@@ -212,9 +231,40 @@ def _layer_kinds(cfg):
     if len(kinds) != cfg.n_layers \
             or set(kinds) - {"attention"} - set(_DENSE_ONLY):
         raise ValueError(
-            "layer_kinds must name %d layers, each 'attention', 'mamba', "
-            "'kda' or 'mla'; got %r" % (cfg.n_layers, kinds))
+            "layer_kinds must name %d layers, each 'attention', 'window', "
+            "'mamba', 'kda' or 'mla'; got %r" % (cfg.n_layers, kinds))
     return tuple(kinds)
+
+
+def _head_dim(cfg):
+    """A head's width in an "attention" or "window" layer."""
+    return cfg.attn_head_dim or cfg.d_model // cfg.n_heads
+
+
+def _window(cfg):
+    """A "window" layer's span in positions, checked: such a layer
+    keeps that many K/V rows a lane (fewer where max_len is), position
+    p at slot p mod that many (_ring_rows)."""
+    w = cfg.attn_window
+    if not isinstance(w, int) or w < 1 or cfg.use_flash_kernel:
+        raise ValueError(
+            "a 'window' layer needs attn_window >= 1 (got %r) and no "
+            "use_flash_kernel: the flash kernels have no window mask"
+            % (w,))
+    return w
+
+
+def _layer_rope(cfg):
+    """Whether each layer's "attention" or "window" mixer rotates its
+    queries and keys, checked."""
+    flags = cfg.rope_layers
+    if flags is None:
+        return (bool(cfg.rope),) * cfg.n_layers
+    if not cfg.rope or len(flags) != cfg.n_layers:
+        raise ValueError(
+            "rope_layers=%r says which of %d layers rotate under "
+            "rope=True (rope=%r)" % (flags, cfg.n_layers, cfg.rope))
+    return tuple(bool(f) for f in flags)
 
 
 def _recurrent(cfg):
@@ -290,21 +340,32 @@ def _experts(cfg):
         return None
     k = cfg.experts_per_token or e
     first, held = cfg.experts_held or (0, e)
-    if cfg.expert_scoring not in ("softmax", "sigmoid") \
+    if cfg.expert_scoring not in ("softmax", "sigmoid", "softmax_topk") \
             or not 1 <= k <= e or first < 0 or held < 1 \
             or first + held > e:
         raise ValueError(
             "n_experts=%d with experts_per_token=%r, experts_held=%r, "
             "expert_scoring=%r: k is in 1..E, the range held lies in "
-            "0..E, scoring is 'softmax' or 'sigmoid'"
+            "0..E, scoring is 'softmax', 'sigmoid' or 'softmax_topk'"
             % (e, cfg.experts_per_token, cfg.experts_held,
                cfg.expert_scoring))
+    if cfg.router_input not in ("ffn", "layer") or (
+            cfg.router_input == "layer" and cfg.hc_mult is not None):
+        raise ValueError(
+            "router_input=%r: 'ffn' or 'layer', and a router that reads "
+            "the layer's input cannot sit in a residual stream of "
+            "hc_mult=%r streams (which of them would it read?)"
+            % (cfg.router_input, cfg.hc_mult))
     return e, k, first, held, cfg.d_expert or cfg.d_ff
 
 
 def _has_experts(cfg, i):
     """Whether layer i routes over experts or has the dense FFN."""
     return bool(cfg.n_experts) and i >= cfg.first_dense_layers
+
+
+# the gated forms of cfg.ffn, w2 (act(w1 x) * w3 x), and each one's act
+_GATED = {"gated_silu": jax.nn.silu, "gated_relu": jax.nn.relu}
 
 
 def _yarn_mscale(factor, m):
@@ -395,9 +456,9 @@ def param_specs(cfg):
         (("wq_a", 2), ("q_norm", 1), ("wq_b", 3)) if cfg.mla_q_rank
         else (("wq", 3),))
         + (("wkva", 2), ("kv_norm", 1), ("wkvb", 3), ("wo", 3))}
-    mixers = {"attention": attention, "mamba": mamba, "kda": kda_mixer,
-              "mla": mla}
-    gated = cfg.ffn == "gated_silu"
+    mixers = {"attention": attention, "window": attention, "mamba": mamba,
+              "kda": kda_mixer, "mla": mla}
+    gated = cfg.ffn in _GATED
     dense = {"w1": P(None, tp), "w2": P(tp, None)}
     if gated:
         dense["w3"] = P(None, tp)
@@ -454,7 +515,7 @@ def _hc_init_biases(n):
 def init_params(cfg, seed=0):
     rng = np.random.RandomState(seed)
     dt = cfg.dtype
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
 
     def dense(*shape):
         scale = 1.0 / np.sqrt(shape[0] if len(shape) == 2 else cfg.d_model)
@@ -527,9 +588,9 @@ def init_params(cfg, seed=0):
             "wo": dense(cfg.n_heads, hd, cfg.d_model),
         }
 
-    mixers = {"attention": attention, "mamba": mamba, "kda": kda_mixer,
-              "mla": mla}
-    gated = cfg.ffn == "gated_silu"
+    mixers = {"attention": attention, "window": attention, "mamba": mamba,
+              "kda": kda_mixer, "mla": mla}
+    gated = cfg.ffn in _GATED
 
     def experts():
         _, _, _, held, f = _experts(cfg)
@@ -675,7 +736,7 @@ def _paged_pallas_requested():
         "0", "", "false", "False", None)
 
 
-def _causal_attention(q, k, v, cfg, out_dtype, norm=None):
+def _causal_attention(q, k, v, cfg, out_dtype, norm=None, window=None):
     """Single-device causal attention over [B, T, H, D] — flash kernel
     (one block when T fits/divides 128, else gcd(T, 128)-sized blocks,
     so ANY sequence length works) or the dense masked softmax. Shared
@@ -683,7 +744,9 @@ def _causal_attention(q, k, v, cfg, out_dtype, norm=None):
     not a route: sequences below the measured crossover
     (MXNET_FLASH_MIN_SEQ, _flash_min_seq above) still take the dense
     path, which the chip A/B has winning there. `norm` is what the
-    scores are divided by (None = sqrt(D))."""
+    scores are divided by (None = sqrt(D)); `window`: a "window" layer's
+    span, a query seeing that many positions up to its own (the dense
+    path: _window refuses the kernel)."""
     if cfg.use_flash_kernel and q.shape[1] >= _flash_min_seq():
         from ..kernels import flash_attention
         if norm is not None:       # the kernel divides by sqrt(D) itself
@@ -698,15 +761,17 @@ def _causal_attention(q, k, v, cfg, out_dtype, norm=None):
                    preferred_element_type=jnp.float32)
     s = s / (np.sqrt(q.shape[-1]) if norm is None else norm)
     mask = jnp.tril(jnp.ones((T, T), bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((T, T), bool), -window)
     s = jnp.where(mask[None, None], s, -1e30)
     a = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", a,
                       v.astype(a.dtype)).astype(out_dtype)
 
 
-def _attention(x, p, cfg, mesh, manual_sp=False):
+def _attention(x, p, cfg, mesh, manual_sp=False, rotate=True, window=None):
     q, k, v = _qkv(x, p)
-    if cfg.rope:
+    if cfg.rope and rotate:
         T = x.shape[1]
         if manual_sp:
             # local shard inside shard_map: global positions start at
@@ -734,30 +799,33 @@ def _attention(x, p, cfg, mesh, manual_sp=False):
                                    causal=True,
                                    use_flash_kernel=cfg.use_flash_kernel)
     else:
-        o = _causal_attention(q, k, v, cfg, x.dtype)
+        o = _causal_attention(q, k, v, cfg, x.dtype, window=window)
     return jnp.einsum("bthk,hkd->btd", o, p["wo"])
 
 
 def _mlp(x, w1, w2, w3, cfg):
     """The dense feed-forward on x [B, T, d], in cfg.ffn's form."""
     h = jnp.einsum("btd,df->btf", x, w1)
-    if cfg.ffn == "gated_silu":
-        h = jax.nn.silu(h) * jnp.einsum("btd,df->btf", x, w3)
+    if cfg.ffn in _GATED:
+        h = _GATED[cfg.ffn](h) * jnp.einsum("btd,df->btf", x, w3)
     else:
         h = jax.nn.gelu(h)
     return jnp.einsum("btf,fd->btd", h, w2)
 
 
-def _ffn(x, p, cfg, loads=None, mesh=None):
+def _ffn(x, p, cfg, loads=None, mesh=None, route_from=None):
     """A layer's feed-forward on x [B, T, d]: the dense form, or, for a
-    layer with a router ("gate"), its routed experts (_expert_ffn)."""
-    if cfg.ffn not in ("gelu", "gated_silu"):
-        raise ValueError("ffn=%r: 'gelu' or 'gated_silu'" % (cfg.ffn,))
+    layer with a router ("gate"), its routed experts (_expert_ffn;
+    `route_from`: what the router scores where that is not x)."""
+    if cfg.ffn != "gelu" and cfg.ffn not in _GATED:
+        raise ValueError("ffn=%r: 'gelu', 'gated_silu' or 'gated_relu'"
+                         % (cfg.ffn,))
     if "gate" in p:
-        if mesh is None:
+        if mesh is None and route_from is None:
             return _expert_ffn(x, p, cfg, loads)
-        # the mesh-sharded forward's arm: GSPMD partitions the experts
-        return _expert_ffn(x, p, cfg, loads, mesh)
+        # the mesh-sharded forward's arm (GSPMD partitions the experts),
+        # or a router that reads the layer's input
+        return _expert_ffn(x, p, cfg, loads, mesh, route_from)
     return _mlp(x, p["w1"], p["w2"], p.get("w3"), cfg)
 
 
@@ -799,8 +867,10 @@ def expert_matmuls(params, cfg, rows):
     return sum(kernel), len(kernel) - sum(kernel)
 
 
-def _expert_ffn(x, p, cfg, loads, mesh=None):
-    """Routed experts on x [B, T, d]: every token is scored over all E
+def _expert_ffn(x, p, cfg, loads, mesh=None, route_from=None):
+    """Routed experts on x [B, T, d]: every token is scored (from its
+    row of x, or of `route_from` [B, T, d] where the router reads
+    something else: cfg.router_input) over all E
     experts and picks k of them; the picks that fall on the experts held
     here are sorted by expert and run as ONE grouped matmul a weight
     (kernels/grouped_matmul.py: each expert sees only its own tokens, no
@@ -815,14 +885,19 @@ def _expert_ffn(x, p, cfg, loads, mesh=None):
     b, t, d = x.shape
     rows = x.reshape(b * t, d)
     with jax.named_scope("mx.moe.route"):
-        logits = jnp.einsum("nd,de->ne", rows, p["gate"],
-                            preferred_element_type=jnp.float32)
+        logits = jnp.einsum(
+            "nd,de->ne",
+            rows if route_from is None else route_from.reshape(b * t, d),
+            p["gate"], preferred_element_type=jnp.float32)
         if cfg.expert_scoring == "sigmoid":
             score = jax.nn.sigmoid(logits)
             _, top = jax.lax.top_k(
                 score + p["gate_bias"].astype(jnp.float32), k)
             w = jnp.take_along_axis(score, top, axis=-1)
             w = cfg.expert_scale * w / jnp.sum(w, axis=-1, keepdims=True)
+        elif cfg.expert_scoring == "softmax_topk":
+            w, top = jax.lax.top_k(logits, k)
+            w = jax.nn.softmax(w, axis=-1)
         else:
             w, top = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
         # a pick's group: its expert's place among those held, or one
@@ -841,8 +916,8 @@ def _expert_ffn(x, p, cfg, loads, mesh=None):
 
         picked = rows[order // k]                        # [N * k, d]
         h = matmul(picked, p["w1"])
-        if cfg.ffn == "gated_silu":
-            h = jax.nn.silu(h) * matmul(picked, p["w3"])
+        if cfg.ffn in _GATED:
+            h = _GATED[cfg.ffn](h) * matmul(picked, p["w3"])
         else:
             h = jax.nn.gelu(h)
         y = matmul(h, p["w2"])
@@ -863,25 +938,33 @@ def _pp_size(cfg, mesh):
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(cfg.pp_axis, 1)
 
 
-def _layer(x, p, kind, cfg, mix, state=None, loads=None, mesh=None):
+def _layer(x, p, kind, cfg, mix, state=None, loads=None, mesh=None,
+           rotate=True):
     """One transformer block, the residual frame every entry point
     runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
     [B, d] for decode's one row. With `cfg.hc_mult` the stream is n
     streams wide (x [n, B, C, d] / [n, B, d]) and each of the two
     sub-layers reads and writes it through _hyper_connect.
-    `mix(kind, h, p, state)` is the entry point's mixer (_mixer) and
+    `mix(kind, h, p, state, rotate)` is the entry point's mixer (_mixer;
+    `rotate`: this layer's flag of _layer_rope) and
     returns (y, the layer's new state); returns (x, that state).
     `loads`, `mesh` (the mesh-sharded forward's): see _expert_ffn."""
+    # a router placed before the mixer scores the layer's own input
+    route_from = x if "gate" in p and cfg.router_input == "layer" else None
+
     def mixer(h):
         nonlocal state
-        y, state = mix(kind, _rms_norm(h, p["ln1"], cfg.norm_eps), p, state)
+        y, state = mix(kind, _rms_norm(h, p["ln1"], cfg.norm_eps), p, state,
+                       rotate)
         return y
 
     def ffn(h):
         h = _rms_norm(h, p["ln2"], cfg.norm_eps)
         if h.ndim == 2:
-            return _ffn(h[:, None], p, cfg, loads, mesh)[:, 0]
-        return _ffn(h, p, cfg, loads, mesh)
+            return _ffn(h[:, None], p, cfg, loads, mesh,
+                        None if route_from is None
+                        else route_from[:, None])[:, 0]
+        return _ffn(h, p, cfg, loads, mesh, route_from)
 
     if cfg.hc_mult is None:
         x = x + mixer(x)
@@ -998,18 +1081,22 @@ def _hyper_connect(x, p, name, cfg, f):
             + post[..., i, None] * yf for i in range(n)]).astype(x.dtype)
 
 
-def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False):
+def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False,
+           window=None):
     """A layer's mix by its KIND, the one place a kind is decided.
-    "attention" is `attend(h, p, state)`, the entry point's form of it,
-    and "mla" is `latent(h, p, state)`, its form of latent attention
-    (_latent_attend; an entry point that has none refuses the kind).
+    "attention" is `attend(h, p, state, rotate)`, the entry point's form
+    of it, "window" is `window(h, p, state, rotate)`, its form over the
+    last cfg.attn_window positions (`rotate`: whether this layer rotates,
+    _layer_rope), and "mla" is `latent(h, p, state)`, its form of latent
+    attention (_latent_attend). An entry point that has no form of a
+    kind refuses it (_refuse_dense_only).
     "mamba" and "kda" keep a recurrent state ({"conv", "ssm"} /
     {"conv", "kda"}) where an attention layer keeps K/V: the step form
     for decode's one row [B, d], the sequence form for [B, C, d], which
     with `valid_len` stops after that many rows (ssm / kda .mixer_seq)
     and with `from_zero` starts from a zero state whatever it was handed
     (training, and a prefill at position 0)."""
-    def mix(kind, h, p, state):
+    def mix(kind, h, p, state, rotate=True):
         if kind == "mamba":
             if h.ndim == 2:
                 return ssm.mixer_step(h, p, state)
@@ -1024,7 +1111,9 @@ def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False):
             return kda.mixer_seq(h, p, state, valid_len, cfg.norm_eps)
         if kind == "mla":
             return latent(h, p, state)
-        return attend(h, p, state)
+        if kind == "window":
+            return window(h, p, state, rotate)
+        return attend(h, p, state, rotate)
     return mix
 
 
@@ -1034,8 +1123,9 @@ def _run_layers(x, params, state, cfg, mix, loads=None):
     order)."""
     new_state = []
     x = _streams_in(x, cfg)
-    for kind, p, layer in zip(_layer_kinds(cfg), params["layers"], state):
-        x, layer = _layer(x, p, kind, cfg, mix, layer, loads)
+    for kind, rotate, p, layer in zip(_layer_kinds(cfg), _layer_rope(cfg),
+                                      params["layers"], state):
+        x, layer = _layer(x, p, kind, cfg, mix, layer, loads, rotate=rotate)
         new_state.append(layer)
     return _streams_out(x, cfg), new_state
 
@@ -1057,10 +1147,13 @@ def forward(params, tokens, cfg, mesh=None):
     # ring attention runs manually over sp inside a pipeline stage
     ring = n_stages > 1 and bool(cfg.use_ring_attention and cfg.sp_axis)
     # self-attention over the fresh K/V: training keeps no state
-    mix = _mixer(cfg, lambda h, p, _: (
-        _attention(h, p, cfg, mesh, manual_sp=ring), None),
+    mix = _mixer(cfg, lambda h, p, _, rotate: (
+        _attention(h, p, cfg, mesh, manual_sp=ring, rotate=rotate), None),
         _latent_attend(cfg, None, lambda layer, **rows: None,
-                       _latent_self_attention(cfg)), from_zero=True)
+                       _latent_self_attention(cfg)), from_zero=True,
+        window=lambda h, p, _, rotate: (
+            _attention(h, p, cfg, mesh, rotate=rotate,
+                       window=_window(cfg)), None))
     if n_stages > 1:
         # pipeline the homogeneous layer stack over pp: stage-major
         # stacked weights, ppermute microbatch schedule; tp/ep stay auto
@@ -1069,6 +1162,9 @@ def forward(params, tokens, cfg, mesh=None):
 
         if cfg.remat_layers:
             layer_fn = jax.checkpoint(layer_fn)
+        if cfg.rope_layers is not None:
+            raise ValueError("pipeline stages stack one layer body: "
+                             "rope_layers cannot differ by layer there")
         stacked = stack_stage_params(params["layers"], n_stages)
         x = spmd_pipeline(
             layer_fn, stacked, x, mesh, axis_name=cfg.pp_axis,
@@ -1077,8 +1173,8 @@ def forward(params, tokens, cfg, mesh=None):
             microbatch_spec=P(None, None, cfg.sp_axis, None) if ring
             else P())
     else:
-        def layer_body(p, xl, kind):
-            xl = _layer(xl, p, kind, cfg, mix, mesh=mesh)[0]
+        def layer_body(p, xl, kind, rotate):
+            xl = _layer(xl, p, kind, cfg, mix, mesh=mesh, rotate=rotate)[0]
             if mesh is not None:
                 xl = jax.lax.with_sharding_constraint(
                     xl, NamedSharding(mesh, act))
@@ -1087,9 +1183,10 @@ def forward(params, tokens, cfg, mesh=None):
         if cfg.remat_layers:
             # save only layer boundaries; backward recomputes each
             # layer's internals (attention scores, ffn hidden) on the fly
-            layer_body = jax.checkpoint(layer_body, static_argnums=(2,))
-        for kind, p in zip(_layer_kinds(cfg), params["layers"]):
-            x = layer_body(p, x, kind)
+            layer_body = jax.checkpoint(layer_body, static_argnums=(2, 3))
+        for kind, rotate, p in zip(_layer_kinds(cfg), _layer_rope(cfg),
+                                   params["layers"]):
+            x = layer_body(p, x, kind, rotate)
     x = _rms_norm(_streams_out(x, cfg), params["ln_f"], cfg.norm_eps)
     return jnp.einsum("btd,vd->btv", x, _head(params, cfg))
 
@@ -1130,18 +1227,22 @@ def init_cache(cfg, batch):
     latent a position, {"c": [B, max_len, R], "kr": [B, max_len, E]}:
     the normed K/V latent and the key part all heads share (two leaves
     because the chip tiles a leaf's last axis by 128: R + E = 576 in one
-    leaf costs two copies of the whole cache a decode round)."""
+    leaf costs two copies of the whole cache a decode round). A window
+    layer holds K/V rows like an attention layer's, but a RING of
+    min(attn_window, max_len) of them (_ring_rows)."""
     if cfg.kv_cache_int8:
         _refuse_dense_only(cfg, "kv_cache_int8")
     states = {"mamba": _mamba_state, "kda": _kda_state}
     return [states[kind](cfg, batch) if kind in states else
-            _kv_leaves(cfg, batch, cfg.max_len, kind)
+            _kv_leaves(cfg, batch, min(_window(cfg), cfg.max_len)
+                       if kind == "window" else cfg.max_len, kind)
             for kind in _layer_kinds(cfg)]
 
 
 def _kv_leaves(cfg, n, t, kind="attention"):
     """An attention layer's zeroed K/V leaves [n, t, KVH, D]: rows of a
-    dense cache (n lanes, t = max_len) or blocks of a paged pool (n
+    dense cache (n lanes, t = max_len; a window layer's ring, t = its
+    rows) or blocks of a paged pool (n
     blocks of t positions). Under kv_cache_int8, int8 codes plus the
     fp32 scale planes "ks"/"vs" [n, t, KVH]. An "mla" layer's leaves
     are its latent rows, "c" [n, t, R] and "kr" [n, t, E]."""
@@ -1149,7 +1250,7 @@ def _kv_leaves(cfg, n, t, kind="attention"):
         r, _, e, _ = _mla_sizes(cfg)
         return {"c": jnp.zeros((n, t, r), cfg.dtype),
                 "kr": jnp.zeros((n, t, e), cfg.dtype)}
-    shape = (n, t, _kvh(cfg), cfg.d_model // cfg.n_heads)
+    shape = (n, t, _kvh(cfg), _head_dim(cfg))
     if cfg.kv_cache_int8:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "ks": jnp.zeros(shape[:3], jnp.float32),
@@ -1195,8 +1296,9 @@ def _kv_store(layer, fresh, cfg, put):
 # layer as [B, T, KVH, D], position-ordered, so every kind feeds the
 # SAME contractions (_decode_attention, _cached_attention). A latent
 # layer's rows are dense rows too, store(layer, c=c, kr=kr), read by its
-# own two contractions (_latent_attend). The recurrent kinds' state is
-# _mixer's.
+# own two contractions (_latent_attend). A window layer keeps a third
+# kind, ring rows (_ring_rows), whatever the caller holds. The recurrent
+# kinds' state is _mixer's.
 
 def _dense_rows(cfg, where, contract):
     """Dense rows: leaves {"k", "v"[, "ks", "vs"]} [B, Tmax, KVH, ...]
@@ -1240,6 +1342,61 @@ def _dense_rows(cfg, where, contract):
 
     def read(q, layer, k, v):
         return contract(q, layer)
+
+    return store, read
+
+
+def _ring_rows(cfg, where, contract, valid_len=None):
+    """Ring rows: a window layer's leaves {"k", "v"} [B, R, KVH, D], R =
+    min(attn_window, max_len) rows a lane, position p at slot p mod R: a
+    row is overwritten when it has left every later query's window.
+    INVARIANT: before a call whose first position is s, slot j holds the
+    newest position below s that is congruent to j mod R (a slot whose
+    such position is negative holds nothing a mask admits), so every
+    contraction masks by the ABSOLUTE position a slot holds, and a
+    lane's next occupant never sees the last one's rows. `where` is a
+    scalar start with a chunk's fresh [B, C, ...] for [start, start + C),
+    of which the first `valid_len` are real (None = all; the bucket's
+    padding behind them is NOT stored: in a ring it would overwrite rows
+    the next query still sees), or decode's one row a lane at a scalar
+    or [B] position. A chunk READS BEFORE IT STORES (_cache_attend's
+    `read_first`): its early queries still see rows its late ones
+    overwrite, so `read` hands `contract(q, view, first)` the ring as it
+    was, unrolled into position order from position `first` = start - R,
+    with the chunk's fresh rows behind it; decode's row stores first and
+    `contract(q, layer)` masks the ring by position
+    (_decode_attention)."""
+    def store(layer, **fresh):
+        rows = layer["k"].shape[1]
+        if jnp.ndim(where) == 0:
+            def put(leaf, arr):
+                if arr.ndim < leaf.ndim:    # decode's one row
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        leaf, arr[:, None], where % rows, axis=1)
+                # the newest real position each slot holds after the
+                # chunk: the chunk's row, where that is one of them
+                end = where + (arr.shape[1] if valid_len is None
+                               else valid_len)
+                newest = end - 1 - (end - 1 - jnp.arange(rows)) % rows
+                took = jnp.take(arr, jnp.clip(newest - where, 0,
+                                              arr.shape[1] - 1), axis=1)
+                return jnp.where((newest >= where)[None, :, None, None],
+                                 took, leaf)
+        else:
+            lanes = jnp.arange(where.shape[0])
+
+            def put(leaf, arr):
+                return leaf.at[lanes, where % rows].set(arr)
+        return _kv_store(layer, fresh, cfg, put)
+
+    def read(q, layer, k, v):
+        if q.ndim == 3:
+            return contract(q, layer)
+        rows = layer["k"].shape[1]
+        return contract(q, {name: jnp.concatenate(
+            [jnp.roll(layer[name], -(where % rows), axis=1),
+             arr.astype(layer[name].dtype)], axis=1)
+            for name, arr in (("k", k), ("v", v))}, where - rows)
 
     return store, read
 
@@ -1355,8 +1512,22 @@ def shard_cache(cache, cfg, mesh):
             x, NamedSharding(mesh, _cache_pspec(cfg, x))), cache)
 
 
-def _decode_attention(q, layer_cache, pos, cfg):
-    """q [B,H,D] vs cache [B,Tmax,KVH,D], attending positions <= pos."""
+def _attn_scope(window):
+    """The named scope of a K/V contraction, chunk or decode."""
+    return jax.named_scope("mx.attn.full" if window is None
+                           else "mx.attn.window")
+
+
+def _decode_attention(q, layer_cache, pos, cfg, window=None):
+    """q [B,H,D] vs cache [B,Tmax,KVH,D], attending positions <= pos;
+    with `window`, vs a window layer's ring [B,R,KVH,D] as it is after
+    the step's store (_ring_rows), attending the positions in
+    (pos - window, pos] that its slots hold."""
+    with _attn_scope(window):
+        return _decode_contraction(q, layer_cache, pos, cfg, window)
+
+
+def _decode_contraction(q, layer_cache, pos, cfg, window):
     cache_k, cache_v = layer_cache["k"], layer_cache["v"]
     if cfg.kv_cache_int8:
         return _decode_attention_int8(q, layer_cache, pos, cfg)
@@ -1384,7 +1555,13 @@ def _decode_attention(q, layer_cache, pos, cfg):
     t_pos = jnp.arange(cache_k.shape[1])
     # pos is a scalar (all rows at the same position) or [B] (ragged
     # decode — continuous batching); [1] broadcasts the scalar case
-    mask = t_pos[None, :] <= jnp.atleast_1d(pos)[:, None]
+    if window is None:
+        mask = t_pos[None, :] <= jnp.atleast_1d(pos)[:, None]
+    else:
+        # slot j holds the newest position <= pos congruent to j
+        at = jnp.atleast_1d(pos)[:, None]
+        held = at - (at - t_pos[None, :]) % cache_k.shape[1]
+        mask = (held >= 0) & (at - held < window)
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     a = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,btkd->bkgd", a.astype(cache_v.dtype), cache_v,
@@ -1405,7 +1582,94 @@ def _decode_attention_int8(q, layer_cache, pos, cfg):
     return o.reshape(b, h, d)
 
 
-def _cached_attention(q, view, positions, cfg, out_dtype):
+# score entries (queries x rows x heads of one sequence) up to which a
+# chunk contracts through ONE float32 score plane; past it (a chunk of
+# 8,192 queries against 16,384 rows of 28 heads is 15 GB of scores), and
+# for every window layer, it contracts in blocks (_blocked_attention) of
+# this many queries over this many rows
+ATTN_PLANE_ELEMS = 1 << 28
+ATTN_QUERY_BLOCK = 256
+ATTN_KEY_BLOCK = 512
+
+
+def _blocked_attention(q, k, v, positions, first=0, window=None):
+    """q [B, C, H, D] against rows k, v [B, T, KVH, D] that lie in
+    position order, row t at position first + t (`first` may be
+    negative: rows below position 0 are never seen), chunk row i
+    attending the rows at positions <= positions[i] ([C]) or
+    positions[b, i] ([B, C]) and, with `window`, above positions - window:
+    ATTN_QUERY_BLOCK queries at a time, each block over only the blocks
+    of ATTN_KEY_BLOCK rows that hold a position one of its queries may
+    see, with a running maximum and sum (the softmax's sums in blocks,
+    as _latent_chunk_attention), grouped like _cached_attention's plane.
+    Returns [B, C, H, D]."""
+    b, c, h, d = q.shape
+    kvh = k.shape[2]
+    width = min(ATTN_KEY_BLOCK, k.shape[1])
+    k, v = (jnp.pad(x, ((0, 0), (0, -x.shape[1] % width), (0, 0), (0, 0)))
+            for x in (k, v))
+    if positions.ndim == 1:
+        positions = positions[None]
+
+    def block(qb, pb):
+        qg = qb.reshape(b, qb.shape[1], kvh, h // kvh, d)
+
+        def part(j, carry):
+            top, total, acc = carry
+            kj, vj = (jax.lax.dynamic_slice_in_dim(x, j * width, width, 1)
+                      for x in (k, v))
+            s = jnp.einsum("bqkgd,btkd->bkgqt", qg, kj,
+                           preferred_element_type=jnp.float32) / np.sqrt(d)
+            at = first + j * width + jnp.arange(width)
+            seen = (at <= pb[..., None]) & (at >= 0)
+            if window is not None:
+                seen &= pb[..., None] - at < window
+            s = jnp.where(seen[:, None, None], s, -1e30)
+            new_top = jnp.maximum(top, jnp.max(s, axis=-1))
+            w = jnp.exp(s - new_top[..., None])
+            keep = jnp.exp(top - new_top)
+            return (new_top, keep * total + jnp.sum(w, axis=-1),
+                    keep[..., None] * acc + jnp.einsum(
+                        "bkgqt,btkd->bkgqd", w.astype(v.dtype), vj,
+                        preferred_element_type=jnp.float32))
+
+        # a row sees its own position, so some block gives it a real
+        # maximum, before which what it summed is scaled away; a block
+        # wholly outside a row's span adds 0 once it has one
+        lead = (b, kvh, h // kvh, qb.shape[1])
+        lo = 0 if window is None else jnp.maximum(
+            (jnp.min(pb) - window + 1 - first) // width, 0)
+        hi = jnp.minimum((jnp.max(pb) - first) // width + 1,
+                         k.shape[1] // width)
+        _, total, acc = jax.lax.fori_loop(
+            lo, hi, part, (jnp.full(lead, -1e30, jnp.float32),
+                           jnp.zeros(lead, jnp.float32),
+                           jnp.zeros(lead + (d,), jnp.float32)))
+        out = jnp.moveaxis(acc / total[..., None], 3, 1)    # [B,Q,KVH,G,D]
+        return out.reshape(b, qb.shape[1], h, d).astype(q.dtype)
+
+    size = ATTN_QUERY_BLOCK
+    if c <= size:
+        return block(q, positions)
+    # whole blocks; the padding rows repeat the last row's position
+    pad = -c % size
+    qs = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    ps = jnp.pad(positions, ((0, 0), (0, pad)), mode="edge")
+    out = jax.lax.map(
+        lambda xs: block(*xs),
+        (jnp.moveaxis(qs.reshape(b, -1, size, h, d), 1, 0),
+         jnp.moveaxis(ps.reshape(ps.shape[0], -1, size), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, -1, h, d)[:, :c]
+
+
+def _attn_blocked(queries, rows, cfg):
+    """Whether a chunk of `queries` against `rows` rows contracts in
+    blocks: where its one score plane would pass ATTN_PLANE_ELEMS."""
+    return queries * rows * cfg.n_heads > ATTN_PLANE_ELEMS
+
+
+def _cached_attention(q, view, positions, cfg, out_dtype, window=None,
+                      first=0):
     """The one chunk contraction against cached K/V: q [B, C, H, D]
     against a layer's view [B, T, KVH, D], chunk row i attending
     positions t <= positions[i] ([C], one window for the whole batch)
@@ -1414,7 +1678,20 @@ def _cached_attention(q, view, positions, cfg, out_dtype):
     cache is read once per GROUP of query heads (like _decode_attention,
     no materialized repeat on the hot path). Chunked prefill and both
     verifiers read through THIS function, which is what keeps
-    pool == solo and verify == decode bit-identical."""
+    pool == solo and verify == decode bit-identical. A window layer's
+    view ([the ring unrolled, the fresh rows], row t at position
+    `first` + t: _ring_rows) and a plane past ATTN_PLANE_ELEMS contract
+    in blocks (_blocked_attention): the same sums in another order."""
+    with _attn_scope(window):
+        if window is not None or (not cfg.kv_cache_int8 and _attn_blocked(
+                q.shape[1], view["k"].shape[1], cfg)):
+            return _blocked_attention(q, view["k"], view["v"], positions,
+                                      first, window).astype(out_dtype)
+        return _cached_plane(q, view, positions, cfg, out_dtype)
+
+
+def _cached_plane(q, view, positions, cfg, out_dtype):
+    """_cached_attention through one score plane [B, C, KVH, G, T]."""
     b, c, _, dh = q.shape
     kvh = _kvh(cfg)
     qg = q.reshape(b, c, kvh, cfg.n_heads // kvh, dh)
@@ -1436,26 +1713,32 @@ def _cached_attention(q, view, positions, cfg, out_dtype):
                       ).astype(out_dtype).reshape(b, c, cfg.n_heads, dh)
 
 
-def _cache_attend(cfg, where, store, read):
-    """_mixer's `attend` for the entry points that keep K/V: project,
-    rotate by the positions `where`, `store(layer, k, v)` the fresh
+def _cache_attend(cfg, where, store, read, read_first=False):
+    """_mixer's `attend` (and `window`) for the entry points that keep
+    K/V: project, rotate by the positions `where` (a layer whose
+    `rotate` is off has no positions), `store(layer, k, v)` the fresh
     k/v, then `read(q, layer, k, v)`: attention over the stored layer
-    (or, for a prefill at position 0, over the fresh k/v themselves).
-    h is [B, C, d], or decode's one row [B, d]."""
-    def attend(h, p, layer):
+    (or, for a prefill at position 0, over the fresh k/v themselves);
+    `read_first`: a chunk over ring rows reads the layer as it was, then
+    stores (_ring_rows). h is [B, C, d], or decode's one row [B, d]."""
+    def attend(h, p, layer, rotate=True):
         if h.ndim == 2:
             q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
             k = jnp.einsum("bd,dhk->bhk", h, p["wk"])
             v = jnp.einsum("bd,dhk->bhk", h, p["wv"])
         else:
             q, k, v = _qkv(h, p)
-        if cfg.rope:
+        if cfg.rope and rotate:
             # keys are cached ROTATED: their rotation depends only on
             # their own position, so decode never re-rotates the cache
             q = _rope(q, where, cfg.rope_base)
             k = _rope(k, where, cfg.rope_base)
-        layer = store(layer, k=k, v=v)
-        o = read(q, layer, k, v)
+        if read_first:
+            o = read(q, layer, k, v)
+            layer = store(layer, k=k, v=v)
+        else:
+            layer = store(layer, k=k, v=v)
+            o = read(q, layer, k, v)
         if h.ndim == 2:
             return jnp.einsum("bhk,hkd->bd", o, p["wo"]), layer
         return jnp.einsum("bchk,hkd->bcd", o, p["wo"]), layer
@@ -1659,18 +1942,27 @@ def prefill(params, cache, tokens, cfg):
         x = x + params["pos"][:t_p]
     g = cfg.n_heads // _kvh(cfg)
     store, _ = _dense_rows(cfg, 0, None)
+    at = jnp.arange(t_p)
 
     def read(q, layer, k, v):
         # self-attention over the fresh K/V: at position 0 the rows
         # just stored are all there is to read
+        if _attn_blocked(t_p, t_p, cfg) and not cfg.use_flash_kernel:
+            with _attn_scope(None):
+                return _blocked_attention(q, k, v, at)
         return _causal_attention(q, _repeat_kv(k, g), _repeat_kv(v, g),
                                  cfg, q.dtype)
 
+    def window_read(q, layer, k, v):
+        with _attn_scope(_window(cfg)):
+            return _blocked_attention(q, k, v, at, window=_window(cfg))
+
     # position 0: whatever recurrent state the cache held is dropped
-    mix = _mixer(cfg, _cache_attend(cfg, jnp.arange(t_p), store, read),
+    mix = _mixer(cfg, _cache_attend(cfg, at, store, read),
                  _latent_attend(cfg, None, store,
                                 _latent_self_attention(cfg)),
-                 from_zero=True)
+                 from_zero=True, window=_cache_attend(
+                     cfg, at, _ring_rows(cfg, 0, None)[0], window_read))
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x[:, -1], params["ln_f"], cfg.norm_eps)
     return jnp.einsum("bd,vd->bv", x, _head(params, cfg)), new_cache
@@ -1798,10 +2090,17 @@ def prefill_chunk(params, cache, tokens, start, cfg, logits_row=None,
             positions if logits_row is None
             else jnp.minimum(positions, start + logits_row), p, cfg)
 
+    def window_contract(q, view, first):
+        return _cached_attention(q, view, positions, cfg, q.dtype,
+                                 _window(cfg), first)
+
     store, read = _dense_rows(cfg, start, contract)
+    valid_len = None if logits_row is None else logits_row + 1
     mix = _mixer(cfg, _cache_attend(cfg, positions, store, read),
                  _latent_attend(cfg, positions, store, latent),
-                 valid_len=None if logits_row is None else logits_row + 1)
+                 valid_len=valid_len, window=_cache_attend(
+                     cfg, positions, *_ring_rows(cfg, start, window_contract,
+                                                 valid_len), read_first=True))
     x, new_cache = _run_layers(x, params, cache, cfg, mix)
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     if logits_row is not None:
@@ -1978,7 +2277,10 @@ def _decode(params, state, tables, tokens, pos, cfg, loads=None):
         lambda q, view: _decode_attention(q, view, pos, cfg))
     mix = _mixer(cfg, _cache_attend(cfg, pos, store, read), _latent_attend(
         cfg, pos, store, lambda q, layer, rows, p: _latent_decode_attention(
-            q, layer, pos, p, cfg)))
+            q, layer, pos, p, cfg)),
+        window=_cache_attend(cfg, pos, *_ring_rows(
+            cfg, pos, lambda q, view: _decode_attention(
+                q, view, pos, cfg, _window(cfg)))))
     x, new_state = _run_layers(x, params, state, cfg, mix, loads)
     x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return jnp.einsum("bd,vd->bv", x, _head(params, cfg)), new_state
@@ -2022,7 +2324,7 @@ def paged_cache_nbytes(cfg, num_blocks, block_size):
     build — mirrors its dtype geometry (int8 k/v + fp32 scale planes
     under kv_cache_int8, else ``cfg.dtype``) without allocating. The
     memory budget's preflight for pool init/grow reads this."""
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
     cells = num_blocks * block_size * _kvh(cfg)
     if cfg.kv_cache_int8:
         per_layer = 2 * cells * hd * 1 + 2 * cells * 4   # k/v + ks/vs

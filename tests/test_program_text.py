@@ -30,7 +30,14 @@ of a latent layer's fresh `kr` rows became the writer of
 kernels/latent_decode.py (latent_row_store, in place) where it was a
 scatter. Their admissions, prefills and forward store a chunk at a
 scalar start and keep their text, as does every program without a
-latent layer."""
+latent layer.
+
+PR 47 pinned three more programs of the accepted configurations at the
+text they had at its parent (a prefill of each K/V configuration and the
+hybrid forward: `prefill`, `_attention` and `_cache_attend` gained a
+window and a rotation flag that neither states), and three of the new
+configuration (SmallThinker: window layers, a ring, the blocked chunk
+contraction) at its own text. All fourteen older hashes stand."""
 
 import hashlib
 import importlib
@@ -52,7 +59,8 @@ def _shapes(specs, float32=()):
 
 # a configuration's runner -> its reference (others: Cerebras-GPT's pair)
 REFERENCES = {"serve_kimi_linear": "kimi_linear", "serve_kimi_k2": "kimi_k2",
-              "serve_xing4": "xing4", "serve_jamba": "jamba"}
+              "serve_xing4": "xing4", "serve_jamba": "jamba",
+              "serve_smallthinker": "smallthinker"}
 
 
 def _sides(cell):
@@ -122,6 +130,7 @@ def _train_step(cell):
 KL, JA, CE = ("kimi-linear-48b-serve-reason32", "jamba2-3b-serve-chat64",
               "cerebras-gpt-1.3b-serve-closed24")
 K2, XI = "kimi-k2.6-serve-agent32", "xing4.0-29b-a4b-serve-rag32"
+SM = "smallthinker-21b-serve-docchat32"
 PROGRAMS = {
     "kimi-linear.decode": (_decode, KL),
     "kimi-linear.admission-1024": (_admission, KL, 1024),
@@ -137,6 +146,12 @@ PROGRAMS = {
     "kimi-k2.admission-4096": (_admission, K2, 4096),
     "xing4.decode": (_decode, XI),
     "xing4.admission-2048": (_admission, XI, 2048),
+    "jamba.prefill-256": (_prefill, JA, 256),
+    "jamba.forward-256": (_forward, JA, 256),
+    "cerebras.prefill-512": (_prefill, CE, 512),
+    "smallthinker.decode": (_decode, SM),
+    "smallthinker.admission-8192": (_admission, SM, 8192),
+    "smallthinker.admission-256": (_admission, SM, 256),
 }
 # sha256 of the StableHLO text, first 16 hex digits, at the parent commit
 # (of PR 41; a line says where a later PR moved or first pinned it)
@@ -158,6 +173,14 @@ AT_THE_PARENT = {
     "kimi-k2.decode": "2c34bb63450d0158",       # PR 45: mla_row_store
     "xing4.admission-2048": "545b1a30387f51d9",
     "xing4.decode": "5b9cc8432b52af5d",         # PR 45: mla_row_store
+    # first pinned at PR 47, at the text of its parent (c1930bc)
+    "cerebras.prefill-512": "29a56c94f0c64e13",
+    "jamba.forward-256": "7859eda083ed7efe",
+    "jamba.prefill-256": "eb93d3a29c28a6da",
+    # first pinned at PR 47, at its own text
+    "smallthinker.admission-256": "5c3d4288cbea8573",
+    "smallthinker.admission-8192": "5fff65ce8f15c9e9",
+    "smallthinker.decode": "a2ae55f83da3f2b3",
 }
 
 

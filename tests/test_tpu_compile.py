@@ -339,3 +339,86 @@ def test_a_fresh_lanes_row_is_broadcasts_not_a_literal(one_chip, name,
     text = compiled.as_text()
     assert text.count(" broadcast(") == len(jax.tree.leaves(row))
     assert len(text) < 2 ** 17           # no literal spelled out
+
+
+# --------------------------------------------- the SmallThinker cell ---
+
+@pytest.fixture(scope="module")
+def smallthinker(one_chip):
+    """(program config, parameter shapes) of the cell
+    smallthinker-21b-serve-docchat32 on the described chip."""
+    import json
+    import os
+    from chipbench.reference import smallthinker as ref
+    from chipbench.runners import serve_smallthinker
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        config = json.load(f)
+    flat = {name: _sds(one_chip, shape)
+            for name, shape, _ in ref.leaf_specs(config)}
+    return serve_smallthinker.program_config(config), \
+        ref.as_tree(flat, config)
+
+
+def _on(one_chip, tree):
+    return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+
+def test_smallthinker_decode_round(one_chip, smallthinker, monkeypatch):
+    """The batcher's one decode program at the cell's shape, 32 lanes of
+    2 full layers x 16,384 rows and 6 rings x 4,096, the cache donated:
+    every store lands in place and every contraction reads the leaf as
+    it lies (the chip keeps a leaf `[B, T, 4, 128]` in tiles of one
+    position's 4 heads; the contraction's fusion takes them in its own
+    order as it reads, a `copy` INSIDE the fusion and not an array), so
+    no copy of a leaf is ever made: the lanes are aliased to the result
+    and the temporaries stay under 64 MB (14 MB when written: the score
+    planes and the experts' rows), where one ring leaf alone is 134 MB
+    and a full layer's 537 MB, beside 11.69 GB of weights and lanes; the
+    grouped matmul is the kernel."""
+    from mxnet_tpu.models import serving, transformer as tf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = smallthinker
+    lanes = 32
+    cache = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, lanes)))
+    fn = serving._jitted_pipeline_chunk(cfg, True, 1.0, None, None, 1, False)
+    lanes_i32 = _sds(one_chip, (lanes,), jnp.int32)
+    compiled = fn.lower(params, cache, None, lanes_i32, lanes_i32,
+                        _sds(one_chip, (lanes, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    print("smallthinker decode round: temporaries %d bytes, arguments %d"
+          % (mem.temp_size_in_bytes, mem.argument_size_in_bytes))
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+    assert 11.6e9 < mem.argument_size_in_bytes < 11.8e9
+    assert mem.alias_size_in_bytes > 3.7e9          # the lanes, in place
+
+
+@pytest.mark.parametrize("width,limit", [(8192, 1.2e9), (256, 0.6e9)])
+def test_smallthinker_admission_chunk(one_chip, smallthinker, monkeypatch,
+                                      width, limit):
+    """An admission's chunk at the cell's widest and narrowest widths
+    against a one-lane row: 8,192 queries against 16,384 rows of 28
+    heads would be 15 GB of scores in one plane and contract in blocks
+    (1.04 GB of temporaries when written, the experts' 49,152 picked
+    rows the largest of them); a chunk of 256 keeps the one plane (0.49
+    GB). Both fit beside 11.69 GB of weights and lanes and the row twice
+    (0.23 GB) in the chip's 15.75 GB."""
+    from mxnet_tpu.models import transformer as tf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = smallthinker
+    row = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, 1)))
+    compiled = jax.jit(
+        lambda p, c, t, s, r: tf.prefill_chunk(p, c, t, s, cfg,
+                                               logits_row=r)).lower(
+        params, row, _sds(one_chip, (1, width), jnp.int32),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32)
+    ).compile()
+    assert "moe_gmm" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    print("smallthinker admission of %d: temporaries %d bytes"
+          % (width, mem.temp_size_in_bytes))
+    assert mem.temp_size_in_bytes < limit
+    assert mem.temp_size_in_bytes + 11.69e9 + 0.24e9 < 15.75 * 2 ** 30
