@@ -13,8 +13,10 @@ from .paged_decode import paged_attention
 from .latent_decode import (latent_decode, latent_decode_reference,
                             latent_row_store)
 from .grouped_matmul import grouped_matmul, grouped_matmul_reference
+from .kv_decode import kv_decode, kv_decode_reference
 
 __all__ = ["flash_attention", "flash_decode",
            "dense_decode_with_lse", "paged_attention",
            "latent_decode", "latent_decode_reference", "latent_row_store",
-           "grouped_matmul", "grouped_matmul_reference"]
+           "grouped_matmul", "grouped_matmul_reference",
+           "kv_decode", "kv_decode_reference"]

@@ -1056,12 +1056,21 @@ class ContinuousBatcher(object):
         self._latent_layers = sum(kind == "mla" for kind, _ in row)
         self._lane_state_bytes = sum(
             nbytes(l) for kind, l in row if kind in tf._RECURRENT)
+        rowed = [(kind, layer) for kind, layer in row
+                 if kind not in tf._RECURRENT]
         held = [(next(iter(layer.values())).shape[1], nbytes(layer),
-                 kind == "window")
-                for kind, layer in row if kind not in tf._RECURRENT]
-        # rows of every such leaf; bytes a position of those max_len
-        # long; (rows, bytes of one) of each ring
+                 kind == "window") for kind, layer in rowed]
+        # rows of every such leaf, beside the block decode's contraction
+        # fetches them in where it is kernels/kv_decode.py (None: the
+        # XLA text, the whole leaf; tf.kv_decode_block, the call's own
+        # rule); bytes a position of those max_len long; (rows, bytes of
+        # one) of each ring
         self._leaf_rows = [rows for rows, _, _ in held]
+        self._leaf_blocks = [
+            tf.kv_decode_block(cfg, layer["k"]) if kind == "attention"
+            else None for kind, layer in rowed]
+        self._kv_layers = sum(kind in ("attention", "window")
+                              for kind, _ in row)
         self._kv_pos_bytes = sum(size // rows for rows, size, ring in held
                                  if not ring)
         self._rings = [(rows, size // rows) for rows, size, ring in held
@@ -1327,6 +1336,10 @@ class ContinuousBatcher(object):
         if self._latent_layers:
             # counted while spans record (_count_row_stores)
             for name in ("mla.row_store_kernel", "mla.row_store_scatter"):
+                snap[name] = _obs.counter(name).value
+        if self._kv_layers:
+            # counted while spans record (_count_kv_contractions)
+            for name in ("kv.decode_kernel", "kv.decode_reference"):
                 snap[name] = _obs.counter(name).value
         if self._rings:
             # counted while spans record (_count_kv_rows)
@@ -2357,8 +2370,10 @@ class ContinuousBatcher(object):
         dispatch but the first after a drained window (a window of one
         is synced before the next dispatch, so it is never ahead);
         hc.rows (_count_frame_rows) for every lane; the
-        expert layers' grouped matmuls (_count_expert_matmuls); and the
-        latent layers' stores of a step's `kr` rows (_count_row_stores)."""
+        expert layers' grouped matmuls (_count_expert_matmuls); the
+        latent layers' stores of a step's `kr` rows (_count_row_stores);
+        and the K/V layers' decode contractions
+        (_count_kv_contractions)."""
         self.dispatch_count += 1
         if _obs.active():
             _obs.counter("serving.dispatches").add(1)
@@ -2367,6 +2382,8 @@ class ContinuousBatcher(object):
             self._count_frame_rows(steps * window * self.max_batch)
             self._count_expert_matmuls(window * self.max_batch, steps)
             self._count_row_stores(steps)
+            if window == 1:
+                self._count_kv_contractions(steps)
 
     @staticmethod
     def _count_routing(routing):
@@ -2389,6 +2406,22 @@ class ContinuousBatcher(object):
                          "mla.row_store_scatter").add(
                 steps * self._latent_layers)
 
+    def _count_kv_contractions(self, steps):
+        """While spans record, for a model with K/V layers: the decode
+        contractions of a dispatch of `steps` one-row steps, a K/V layer
+        a step, into the counters kv.decode_kernel (those that ran
+        kernels/kv_decode.py's kernel: one pass over a lane's rows up to
+        its position) and kv.decode_reference (a window layer's ring and
+        the rows that kernel has no block for: the XLA text over the
+        whole leaf), by the rows' shapes (tf.kv_decode_block, the call's
+        own rule). The target model's contractions; a speculative round
+        verifies through the chunk contraction and counts none."""
+        if self._kv_layers:
+            kernel = sum(block is not None for block in self._leaf_blocks)
+            _obs.counter("kv.decode_kernel").add(steps * kernel)
+            _obs.counter("kv.decode_reference").add(
+                steps * (self._kv_layers - kernel))
+
     def _count_latent_rows(self, pos, live, steps):
         """A dispatch's latent rows (a model with latent attention)
         into the counters mla.rows_read, what its decode contractions
@@ -2410,22 +2443,29 @@ class ContinuousBatcher(object):
         _obs.counter("mla.rows_live").add(
             n * (steps * sum(live) + len(live) * steps * (steps - 1) // 2))
 
-    def _count_kv_rows(self, live, steps):
+    def _count_kv_rows(self, pos, live, steps):
         """A dispatch's K/V rows (a model with window layers) into the
-        counters kv.rows_read, the rows its decode contractions read:
-        every one of the max_batch lanes, with a request or not, times
-        the rows each K/V layer's leaf holds (max_len, or a window
-        layer's ring: tf._decode_attention contracts over the whole leaf
-        and masks), a layer a step; kv.rows_ring, the part of them that
-        lies in rings; and kv.rows_live, those a live lane's mask
-        admits: `live` holds each live lane's positions at the
-        dispatch's first step (its tokens so far), one more a step, and
-        a ring admits no more than its rows. COMPUTED on the host from
-        the leaves' shapes and the positions dispatched, as
+        counters kv.rows_read, what its decode contractions fetch, a
+        layer a step, for every one of the max_batch lanes, with a
+        request or not: where the contraction is kernels/kv_decode.py's
+        kernel (self._leaf_blocks), whole blocks up to the position the
+        dispatch gave a lane (`pos`, all max_batch lanes at the first
+        step, one more a step: that file's rows_fetched); elsewhere the
+        rows the leaf holds (max_len, or a window layer's ring: the XLA
+        text contracts over the whole leaf and masks); kv.rows_ring, the
+        part of them that lies in rings; and kv.rows_live, those a live
+        lane's mask admits: `live` holds each live lane's positions at
+        the dispatch's first step (its tokens so far), one more a step,
+        and a ring admits no more than its rows. COMPUTED on the host
+        from the leaves' shapes and the positions dispatched, as
         mla.rows_read is, not observed on the device. Called like
         _count_latent_rows."""
-        _obs.counter("kv.rows_read").add(
-            steps * self.max_batch * sum(self._leaf_rows))
+        from ..kernels.kv_decode import rows_fetched
+        lengths = np.asarray(pos)[None, :] + 1 + np.arange(steps)[:, None]
+        _obs.counter("kv.rows_read").add(sum(
+            steps * self.max_batch * rows if block is None
+            else rows_fetched(lengths, rows, block)
+            for rows, block in zip(self._leaf_rows, self._leaf_blocks)))
         _obs.counter("kv.rows_ring").add(
             steps * self.max_batch * sum(rows for rows, _ in self._rings))
         _obs.counter("kv.rows_live").add(sum(
@@ -2522,7 +2562,7 @@ class ContinuousBatcher(object):
             if self._latent_layers:
                 self._count_latent_rows(pos, live, toks.shape[0])
             if self._rings:
-                self._count_kv_rows(live, toks.shape[0])
+                self._count_kv_rows(pos, live, toks.shape[0])
         obs_on = _obs.enabled()
         t_sync = time.perf_counter_ns() if obs_on else None
         finished = {}

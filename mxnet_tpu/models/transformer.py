@@ -1527,6 +1527,26 @@ def _decode_attention(q, layer_cache, pos, cfg, window=None):
         return _decode_contraction(q, layer_cache, pos, cfg, window)
 
 
+def kv_decode_block(cfg, rows, window=None, q_dtype=None):
+    """Rows a grid step of kernels/kv_decode.py where decode's
+    contraction over K/V rows `rows` ([B, T, KVH, D], an array or its
+    shape and dtype) runs that kernel, by what the call holds: None, and
+    the XLA text stays, for a window layer's ring (its live rows are no
+    prefix of the leaf's slots), an int8 cache, use_flash_kernel, a
+    query of another dtype than the rows, and the shapes
+    kernels.kv_decode.kv_block has no block for (a head size that is no
+    multiple of 128: toy widths; a cache 128 does not divide; one so
+    narrow that a block would be all of it). serving.py counts a
+    dispatch's contractions by this rule (kv.decode_kernel /
+    kv.decode_reference)."""
+    if window is not None or cfg.kv_cache_int8 or cfg.use_flash_kernel \
+            or q_dtype not in (None, rows.dtype):
+        return None
+    from ..kernels.kv_decode import kv_block
+    _, t, kvh, d = rows.shape
+    return kv_block(t, kvh, d, rows.dtype.itemsize)
+
+
 def _decode_contraction(q, layer_cache, pos, cfg, window):
     cache_k, cache_v = layer_cache["k"], layer_cache["v"]
     if cfg.kv_cache_int8:
@@ -1538,35 +1558,21 @@ def _decode_contraction(q, layer_cache, pos, cfg, window):
         block_k = math.gcd(cache_k.shape[1], 128)
         return flash_decode(q, cache_k, cache_v, pos + 1,
                             block_k=block_k)
-    b, h, d = q.shape
-    kvh = cache_k.shape[2]
-    g = h // kvh
-    # grouped contraction: the KVH-head cache is read once per GROUP —
-    # no materialized repeat in the bandwidth-bound decode loop.
-    # kernels.dense_decode_with_lse is the same contraction with a
-    # deliberately different numeric profile: it accumulates PV in
-    # fp32 and emits the lse the sequence-parallel shard combine
-    # needs; this serving hot loop contracts PV at cache dtype (bf16
-    # MXU pass) and needs no lse. A masking/scaling fix here likely
-    # applies there too.
-    qg = q.reshape(b, kvh, g, d)
-    s = jnp.einsum("bkgd,btkd->bkgt", qg, cache_k,
-                   preferred_element_type=jnp.float32) / np.sqrt(d)
-    t_pos = jnp.arange(cache_k.shape[1])
+    from ..kernels.kv_decode import kv_decode, kv_decode_reference
     # pos is a scalar (all rows at the same position) or [B] (ragged
-    # decode — continuous batching); [1] broadcasts the scalar case
-    if window is None:
-        mask = t_pos[None, :] <= jnp.atleast_1d(pos)[:, None]
+    # decode — continuous batching). One pass over each lane's rows up
+    # to its position, no score plane (kernels/kv_decode.py); the same
+    # sums as two XLA passes over all T rows where that kernel has no
+    # block for the rows, and for a ring (kv_decode_block).
+    # kernels.dense_decode_with_lse is the same contraction with a
+    # deliberately different numeric profile: it emits the lse the
+    # sequence-parallel shard combine needs; this serving hot loop
+    # needs none. A masking/scaling fix here likely applies there too.
+    if kv_decode_block(cfg, cache_k, window, q.dtype) is None:
+        o = kv_decode_reference(q, cache_k, cache_v, pos, window)
     else:
-        # slot j holds the newest position <= pos congruent to j
-        at = jnp.atleast_1d(pos)[:, None]
-        held = at - (at - t_pos[None, :]) % cache_k.shape[1]
-        mask = (held >= 0) & (at - held < window)
-    s = jnp.where(mask[:, None, None, :], s, -1e30)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", a.astype(cache_v.dtype), cache_v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(b, h, d).astype(q.dtype)
+        o = kv_decode(q, cache_k, cache_v, pos + 1)
+    return o.astype(q.dtype)
 
 
 def _decode_attention_int8(q, layer_cache, pos, cfg):

@@ -37,7 +37,18 @@ text they had at its parent (a prefill of each K/V configuration and the
 hybrid forward: `prefill`, `_attention` and `_cache_attend` gained a
 window and a rotation flag that neither states), and three of the new
 configuration (SmallThinker: window layers, a ring, the blocked chunk
-contraction) at its own text. All fourteen older hashes stand."""
+contraction) at its own text. All fourteen older hashes stand.
+
+PR 48 updated `cerebras.decode` and `smallthinker.decode` on purpose:
+decode's contraction over dense K/V rows became the kernel of
+kernels/kv_decode.py (one pass over each lane's rows up to its position)
+in Cerebras-GPT's 24 layers and SmallThinker's two full layers, where
+the rows' shape gives that kernel a block (kernels.kv_decode.kv_block).
+`jamba.decode` stands: its one K/V head's rows are so narrow that a
+block would be the whole cache, and the XLA text stays, letter for
+letter (kv_decode_reference), as it does for SmallThinker's six rings.
+The other seventeen hashes stand too: no admission, prefill, forward or
+train step reaches that call, and latent and KDA mixers bypass it."""
 
 import hashlib
 import importlib
@@ -157,7 +168,7 @@ PROGRAMS = {
 # (of PR 41; a line says where a later PR moved or first pinned it)
 AT_THE_PARENT = {
     "cerebras.admission-512": "aba915c111ccf1db",
-    "cerebras.decode": "59e8d1ef85648873",
+    "cerebras.decode": "f8fe6738e9d6206d",        # PR 48: kv_decode
     "cerebras.train-step": "42a91385a6f26e39",
     "jamba.admission-256": "1deb540c67da2cc8",
     "jamba.decode": "169f587ab80ff84e",
@@ -180,7 +191,7 @@ AT_THE_PARENT = {
     # first pinned at PR 47, at its own text
     "smallthinker.admission-256": "5c3d4288cbea8573",
     "smallthinker.admission-8192": "5fff65ce8f15c9e9",
-    "smallthinker.decode": "a2ae55f83da3f2b3",
+    "smallthinker.decode": "af1da804614b0d21",    # PR 48: kv_decode
 }
 
 

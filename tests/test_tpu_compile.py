@@ -265,6 +265,36 @@ def test_an_untileable_latent_cache_compiles_as_xla(one_chip):
     lowered.compile()
 
 
+KV_SHAPES = {
+    # lanes, rows, K/V heads, query heads a K/V head
+    "cerebras-gpt": (24, 2048, 16, 1),
+    "smallthinker-full-layer": (32, 16384, 4, 7),
+}
+
+
+@pytest.mark.parametrize("lanes,rows,kvh,group", KV_SHAPES.values(),
+                         ids=KV_SHAPES.keys())
+def test_kv_decode(one_chip, lanes, rows, kvh, group):
+    """The decode contraction over dense K/V rows at the two cells' real
+    shapes, blocks of 256 and 1,024 positions of all K/V heads read from
+    the 4-D leaf where it lies (the chip keeps `[.., 16, 128]` bf16 in
+    its own (16, 128) tiles and `[.., 4, 128]` in tiles of one
+    position's 4 heads; Mosaic takes either as the block's last two
+    dimensions): no copy or transpose of an array of a leaf's size
+    stands before the call and nothing of that size is a temporary."""
+    from mxnet_tpu.kernels.kv_decode import kv_decode
+    leaf = _sds(one_chip, (lanes, rows, kvh, 128))
+    compiled = _compile(
+        functools.partial(kv_decode, interpret=False),
+        _sds(one_chip, (lanes, kvh * group, 128)), leaf, leaf,
+        _sds(one_chip, (lanes,), jnp.int32))
+    text = compiled.as_text()
+    assert "kv_decode" in text
+    moved = _moved(text, ("[%d,%d,%d,128]" % (lanes, rows, kvh),))
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 EXPERT_SHAPES = {
     # rows (lanes or a chunk's tokens x picks), experts held, d, width
     "kimi-linear-decode": (256, 64, 2304, 1024),
@@ -343,29 +373,62 @@ def test_a_fresh_lanes_row_is_broadcasts_not_a_literal(one_chip, name,
 
 # --------------------------------------------- the SmallThinker cell ---
 
-@pytest.fixture(scope="module")
-def smallthinker(one_chip):
-    """(program config, parameter shapes) of the cell
-    smallthinker-21b-serve-docchat32 on the described chip."""
+def _cell_sides(one_chip, name, ref, runner):
+    """(program config, parameter shapes) of a served configuration of
+    chipbench/configs on the described chip."""
     import json
     import os
-    from chipbench.reference import smallthinker as ref
-    from chipbench.runners import serve_smallthinker
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chipbench", "configs",
-                           "smallthinker-21b-a3b.json")) as f:
+                           name + ".json")) as f:
         config = json.load(f)
-    flat = {name: _sds(one_chip, shape)
-            for name, shape, _ in ref.leaf_specs(config)}
-    return serve_smallthinker.program_config(config), \
-        ref.as_tree(flat, config)
+    flat = {leaf: _sds(one_chip, shape)
+            for leaf, shape, _ in ref.leaf_specs(config)}
+    return runner.program_config(config), ref.as_tree(flat, config)
+
+
+@pytest.fixture(scope="module")
+def smallthinker(one_chip):
+    """The cell smallthinker-21b-serve-docchat32's sides."""
+    from chipbench.reference import smallthinker as ref
+    from chipbench.runners import serve_smallthinker
+    return _cell_sides(one_chip, "smallthinker-21b-a3b", ref,
+                       serve_smallthinker)
+
+
+@pytest.fixture(scope="module")
+def cerebras(one_chip):
+    """The cell cerebras-gpt-1.3b-serve-closed24's sides."""
+    from chipbench.reference import cerebras_gpt as ref
+    from chipbench.runners import lm_common
+    return _cell_sides(one_chip, "cerebras-gpt-1.3b", ref, lm_common)
 
 
 def _on(one_chip, tree):
     return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
 
 
-def test_smallthinker_decode_round(one_chip, smallthinker, monkeypatch):
+def _decode_round(one_chip, cfg, params, lanes):
+    """The batcher's one decode program compiled with the lanes donated,
+    as the batcher runs it."""
+    from mxnet_tpu.models import serving, transformer as tf
+    cache = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, lanes)))
+    fn = serving._jitted_pipeline_chunk(cfg, True, 1.0, None, None, 1, False)
+    lanes_i32 = _sds(one_chip, (lanes,), jnp.int32)
+    return fn.lower(params, cache, None, lanes_i32, lanes_i32,
+                    _sds(one_chip, (lanes, 2), jnp.uint32)).compile()
+
+
+@pytest.fixture(scope="module")
+def smallthinker_round(one_chip, smallthinker):
+    """The SmallThinker cell's decode round, compiled once for the tests
+    that read it."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return _decode_round(one_chip, *smallthinker, 32)
+
+
+def test_smallthinker_decode_round(smallthinker_round):
     """The batcher's one decode program at the cell's shape, 32 lanes of
     2 full layers x 16,384 rows and 6 rings x 4,096, the cache donated:
     every store lands in place and every contraction reads the leaf as
@@ -377,15 +440,7 @@ def test_smallthinker_decode_round(one_chip, smallthinker, monkeypatch):
     planes and the experts' rows), where one ring leaf alone is 134 MB
     and a full layer's 537 MB, beside 11.69 GB of weights and lanes; the
     grouped matmul is the kernel."""
-    from mxnet_tpu.models import serving, transformer as tf
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, params = smallthinker
-    lanes = 32
-    cache = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, lanes)))
-    fn = serving._jitted_pipeline_chunk(cfg, True, 1.0, None, None, 1, False)
-    lanes_i32 = _sds(one_chip, (lanes,), jnp.int32)
-    compiled = fn.lower(params, cache, None, lanes_i32, lanes_i32,
-                        _sds(one_chip, (lanes, 2), jnp.uint32)).compile()
+    compiled = smallthinker_round
     text = compiled.as_text()
     assert "moe_gmm" in text and "ragged-dot" not in text
     mem = compiled.memory_analysis()
@@ -394,6 +449,46 @@ def test_smallthinker_decode_round(one_chip, smallthinker, monkeypatch):
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
     assert 11.6e9 < mem.argument_size_in_bytes < 11.8e9
     assert mem.alias_size_in_bytes > 3.7e9          # the lanes, in place
+
+
+def test_cerebras_decode_round_reads_its_rows_in_place(one_chip, cerebras,
+                                                       monkeypatch):
+    """24 lanes of 24 layers x 2,048 rows of 16 heads: every layer's
+    contraction is the kernel kv_decode behind a store that lands in
+    place, and no copy or transpose of a leaf (201 MB) stands between
+    them; the temporaries stay under 32 MiB beside 12.3 GB of weights
+    and lanes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = cerebras
+    compiled = _decode_round(one_chip, cfg, params, 24)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 24
+    assert "kv_decode" in text
+    moved = _moved(text, ("[24,2048,16,128]",))
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    print("cerebras decode round: temporaries %d bytes, arguments %d"
+          % (mem.temp_size_in_bytes, mem.argument_size_in_bytes))
+    assert mem.temp_size_in_bytes < 32 * 2 ** 20
+    assert mem.alias_size_in_bytes > 9.6e9          # the lanes, in place
+
+
+def test_smallthinker_decode_round_reads_its_rows_in_place(
+        smallthinker_round):
+    """The two full layers' contractions are the kernel kv_decode, with
+    no copy or transpose of a full layer's leaf (537 MB) anywhere, not
+    even inside a fusion (the XLA text's four fusions had one each, in
+    their own reading order), and the temporaries under 32 MiB (12 MB
+    when written). The six rings keep the XLA text and with it the
+    `copy` inside each of their fusions
+    (test_smallthinker_decode_round)."""
+    compiled = smallthinker_round
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 24
+    assert "kv_decode" in text and "moe_gmm" in text
+    moved = _moved(text, ("[32,16384,4,128]",))
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
 @pytest.mark.parametrize("width,limit", [(8192, 1.2e9), (256, 0.6e9)])

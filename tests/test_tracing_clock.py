@@ -100,12 +100,15 @@ def _batcher(**kw):
 
 
 # what the batcher counts while its spans record (models/serving.py
-# _count_dispatch, _fresh_row and _count_prefill; a model with routed
-# experts adds moe.*, one with latent layers mla.*, one with
-# hyper-connections hc.rows)
+# _count_dispatch, _fresh_row and _count_prefill; since PR 48 a model
+# with K/V layers, as this one, its decode contractions by the path they
+# took, _count_kv_contractions; a model with routed experts adds moe.*,
+# one with latent layers mla.*, one with window layers kv.rows_*, one
+# with hyper-connections hc.rows)
 WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
                       "serving.fresh_rows", "serving.prefill_tokens",
-                      "serving.prefill_rows"}
+                      "serving.prefill_rows", "kv.decode_kernel",
+                      "kv.decode_reference"}
 
 
 def _serve(srv, rounds):
