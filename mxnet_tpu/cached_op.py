@@ -16,6 +16,20 @@ statistics) leave the compiled forward as `has_aux` outputs, here and in
 Executor (executor.fwd_res_fn): their updates are computed and written
 back, never differentiated, so a backward is one program however many
 the block has.
+
+What a recorded call hands its backward (executor.fwd_res_fn, PR 49): by
+default the results of the MXU operations (matmuls, convolutions), of
+the reductions (a batch norm's statistics) and what a host callback
+returned; batch norm's normalisation, ReLU, casts,
+adds and pooling are recomputed inside the backward program, and the
+parameters and the batch a pullback reads are the caller's own arrays,
+bound again on the host, not copies the forward returns. The reference's
+mirror switch is off unless asked; here it is on unless refused
+(hybridize(backward_do_mirror=False) or the variable at 0, see
+executor.mirror_enabled: every intermediate is saved). While spans
+record, the counters
+cachedop.recorded_calls / cachedop.saved_buffers / cachedop.saved_bytes
+count the recorded calls and what each returned for its backward.
 """
 
 import jax
@@ -24,7 +38,8 @@ from . import autograd
 from . import engine as _engine
 from . import random as _random
 from .base import MXNetError
-from .executor import build_graph_fn, fwd_res_fn, mirror_enabled
+from .executor import (build_graph_fn, call_fwd_res, fwd_res_fn,
+                       mirror_enabled)
 from .observability import attribution as _obs_attr
 from .observability import core as _obs
 from .observability import membudget as _membudget
@@ -104,10 +119,11 @@ class CachedOp:
 
         if diff_names:
             # compile forward + residuals ONCE per signature (a per-call
-            # jax.vjp would re-trace the whole graph); under
-            # hybridize(backward_do_mirror=True) /
-            # MXNET_BACKWARD_DO_MIRROR backward recomputes activations
-            # under the mirror policy instead of storing them
+            # jax.vjp would re-trace the whole graph); unless
+            # hybridize(backward_do_mirror=False) or the environment
+            # refuse it, backward recomputes the cheap activations under
+            # the mirror policy instead of reading stored ones
+            # (executor.fwd_res_fn: what is saved)
             fn = jax.jit(fwd_res_fn(graph_fn, diff_names,
                                     mirror_enabled(self._flags)))
         else:
@@ -156,12 +172,20 @@ class CachedOp:
                     "CachedOp[%s].fwd" % self._obs_name(), fn,
                     (diff_list, args, aux, rng_key), signature=sig)
             try:
-                (outs, aux_up), vjp_fn = fn(diff_list, args, aux,
-                                            rng_key)
+                outs, aux_up, vjp_fn, saved = call_fwd_res(
+                    fn, diff_list, args, aux, rng_key)
             except Exception as exc:
                 _membudget.note_oom(
                     "CachedOp[%s].fwd" % self._obs_name(), exc)
                 raise
+            if _obs.active():
+                # what this recorded call handed its backward, from the
+                # outputs' avals (no device work)
+                _obs.counter("cachedop.recorded_calls").add()
+                _obs.counter("cachedop.saved_buffers").add(
+                    len(saved.saved))
+                _obs.counter("cachedop.saved_bytes", "bytes").add(
+                    saved.nbytes())
 
             diff_nds = [by_name[n] for n in diff_names]
 
@@ -185,8 +209,9 @@ class CachedOp:
                     # vjp inside ONE traced program keeps every
                     # backward instruction attributed to its block.
                     def _step(diff, rest, aux_a, key, ct):
-                        _o, v = fn(diff, rest, aux_a, key)
-                        return _o, autograd.apply_vjp(
+                        _o, _a, v, _s = call_fwd_res(fn, diff, rest,
+                                                     aux_a, key)
+                        return (_o, _a), autograd.apply_vjp(
                             v, out_shapes, out_dtypes)(ct)
                     _obs_attr.register_program(
                         origin, sig, jax.jit(_step),

@@ -794,6 +794,23 @@ def _stats(net):
             if "running" in n]
 
 
+def _cached_inputs(net):
+    """(the block's CachedOp, its arguments by name as they stand, its
+    auxiliary states, the names a recorded call differentiates)."""
+    x, _ = _bn_batch()
+    with autograd.pause():
+        net(x)                               # builds the CachedOp
+    op = net._cached_op
+    held = {n: p.data()._data for n, p in net.collect_params().items()}
+    held["data"] = x._data
+    inputs = {n: held.get(n, held.get(net.prefix + n))
+              for n in op._input_names}
+    assert all(v is not None for v in inputs.values())
+    return (op, {n: inputs[n] for n in op._arg_names},
+            {n: inputs[n] for n in op._aux_names},
+            tuple(n for n in op._arg_names if n != "data"))
+
+
 def _jitted_calls(tmp_path, fn):
     """Names of the jitted calls `fn()` makes, from a `jax.profiler` host
     trace (independent of the program's own counters). jax writes each call
@@ -825,14 +842,22 @@ def _jitted_calls(tmp_path, fn):
     return names[::2]
 
 
+# the two sides of ISSUE 49's default: a backward that recomputes the cheap
+# activations from the MXU results (unset), and one that reads every
+# intermediate the forward kept
+SAVED = [{}, {"backward_do_mirror": False}]
+SAVED_IDS = ["mxu-results", "everything"]
+
+
+@pytest.mark.parametrize("flags", SAVED, ids=SAVED_IDS)
 @pytest.mark.parametrize("heads", [False, True],
                          ids=["default-head", "given-head"])
 def test_hybridized_backward_does_not_grow_with_its_batch_norms(
-        tmp_path, heads):
+        tmp_path, heads, flags):
     calls = {}
     autograd._tape().clear()
     for n_bn in (1, 8):
-        net = _bn_net(n_bn)
+        net = _bn_net(n_bn, **flags)
         head = mx.nd.ones((4,)) if heads else None
         for _ in range(2):
             _bn_loss(net, heads).backward(head)
@@ -879,24 +904,13 @@ def test_running_statistics_are_written_back_as_before():
     recorded numbers."""
     from mxnet_tpu.executor import build_graph_fn
     net = _bn_net(2)
-    x, _ = _bn_batch()
-    with autograd.pause():
-        net(x)                               # builds the CachedOp
-    op = net._cached_op
-    before = {n: p.data()._data for n, p in net.collect_params().items()}
-    before["data"] = x._data
-    inputs = {n: before.get(n, before.get(net.prefix + n))
-              for n in op._input_names}
-    assert all(v is not None for v in inputs.values())
+    op, args, aux, diff = _cached_inputs(net)
     _bn_loss(net).backward()
     after = _stats(net)
     for got, want in zip(after, _PARENT_STATS):
         np.testing.assert_allclose(got, want, rtol=2e-6)
 
     graph_fn = build_graph_fn(op.symbol, is_train=True)
-    args = {n: inputs[n] for n in op._arg_names}
-    aux = {n: inputs[n] for n in op._aux_names}
-    diff = tuple(n for n in op._arg_names if n != "data")
 
     def fwd_res(diff_list, rest, aux, rng_key):
         def pure(d):
@@ -912,8 +926,9 @@ def test_running_statistics_are_written_back_as_before():
         np.testing.assert_array_equal(got, np.asarray(aux_up[name]))
 
 
-def test_grad_req_add_accumulates_over_two_hybridized_backwards():
-    net, once = _bn_net(2), _bn_net(2)
+@pytest.mark.parametrize("flags", SAVED, ids=SAVED_IDS)
+def test_grad_req_add_accumulates_over_two_hybridized_backwards(flags):
+    net, once = _bn_net(2, **flags), _bn_net(2, **flags)
     _bn_loss(once).backward()
     for p in net.collect_params().values():
         if p.grad_req != "null":
@@ -928,8 +943,9 @@ def test_grad_req_add_accumulates_over_two_hybridized_backwards():
         np.testing.assert_allclose(a, 2 * b, rtol=1e-5, atol=1e-7)
 
 
-def test_retained_hybridized_graph_sweeps_twice_alike():
-    net = _bn_net(2)
+@pytest.mark.parametrize("flags", SAVED, ids=SAVED_IDS)
+def test_retained_hybridized_graph_sweeps_twice_alike(flags):
+    net = _bn_net(2, **flags)
     loss = _bn_loss(net)
     loss.backward(retain_graph=True)
     first = _grads(net)
@@ -939,14 +955,124 @@ def test_retained_hybridized_graph_sweeps_twice_alike():
     assert autograd._tape() == []
 
 
-def test_backward_do_mirror_gives_the_same_gradients():
-    plain, mirror = _bn_net(2), _bn_net(2, backward_do_mirror=True)
+@pytest.mark.parametrize("n_bn", [1, 8])
+@pytest.mark.parametrize("case", ["unset", "flag", "full"])
+def test_backward_do_mirror_gives_the_same_gradients(monkeypatch, case,
+                                                     n_bn):
+    """Whatever the backward recomputes, the gradients are the
+    save-everything path's (up to the order of a float sum) and the
+    running statistics, which the forward alone writes, are its bits."""
+    if case == "full":
+        monkeypatch.setenv("MXNET_MIRROR_POLICY", "full")
+    flags = {"backward_do_mirror": True} if case == "flag" else {}
+    plain = _bn_net(n_bn, backward_do_mirror=False)
+    mirror = _bn_net(n_bn, **flags)
     _bn_loss(plain).backward()
     _bn_loss(mirror).backward()
     for a, b in zip(_grads(plain), _grads(mirror)):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+        assert np.abs(a).sum() > 0
+        # tests/test_mirror.py test_hybridize_mirror_flag_grads_match's
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-5)
     for a, b in zip(_stats(plain), _stats(mirror)):
-        np.testing.assert_allclose(a, b, rtol=1e-6)
+        np.testing.assert_array_equal(a, b)
+
+
+# ------------------- what a recorded call hands its backward (ISSUE 49) ---
+# By default the closure that crosses from the forward program to the
+# backward holds the MXU operations' results and the reductions' (a batch
+# norm's statistics) (executor.fwd_res_fn): no ReLU mask, no normalised
+# activation, and none of the forward's own inputs, which are bound again
+# on the host (executor.SavedForBackward).
+
+def _handed_over(net):
+    """What a recorded call of `net` returns for its backward, as
+    `jax.eval_shape` gives it (nothing runs): (the leaves the forward
+    program returns, all leaves of the closure the backward gets)."""
+    op, args, aux, diff = _cached_inputs(net)
+    call = ([args[n] for n in diff], args, aux, jax.random.PRNGKey(0))
+    _, saved = jax.eval_shape(op._get_fn(True, diff), *call)
+    return saved.saved, jax.tree.leaves(saved.bind(call))
+
+
+def _nbytes(leaves):
+    return sum(int(np.prod(v.shape)) * v.dtype.itemsize for v in leaves)
+
+
+@pytest.mark.parametrize("n_bn", [1, 8])
+def test_a_recorded_call_hands_over_mxu_results_and_statistics(n_bn):
+    saved, closure = _handed_over(_bn_net(n_bn))
+    kept, kept_closure = _handed_over(_bn_net(n_bn,
+                                              backward_do_mirror=False))
+    conv = ((4, 4, 8, 8), np.dtype("float32"))
+    # the convolutions' results, and no other activation: the classifier's
+    # result is the block's output, which no pullback reads
+    assert [(v.shape, v.dtype) for v in saved
+            if v.ndim == 4] == [conv] * n_bn
+    # beside them the reductions' results: a mean and a variance a batch
+    # norm, a vector a channel, and the pooled features
+    assert sorted(v.shape for v in saved if v.ndim != 4) \
+        == [(4,)] * (2 * n_bn) + [(4, 4)]
+    assert all(v.dtype == np.float32 for v in saved)
+    # save-everything keeps a mask a ReLU and the normalised activations
+    assert sum(v.dtype == np.bool_ for v in kept) == n_bn
+    assert len(kept) > 3 * n_bn and _nbytes(saved) < _nbytes(kept) / 2
+    # the rest of either closure are the forward's own inputs, which the
+    # program does not return: the batch, the classifier's weight, every
+    # convolution's but the first's (nothing differentiates its input)
+    # and, for the recomputation, each batch norm's scale, shift and the
+    # running mean it centres on
+    assert len(closure) - len(saved) == 4 * n_bn + 1
+    held = len(closure) - len(saved)
+    assert len(kept_closure) - len(kept) <= held
+
+
+def test_the_mirror_knobs_choose_what_is_handed_over(monkeypatch):
+    def handed(**flags):
+        return _nbytes(_handed_over(_bn_net(3, **flags))[0])
+    unset, everything = handed(), handed(backward_do_mirror=False)
+    assert handed(backward_do_mirror=True) == unset < everything
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "0")
+    assert handed() == everything
+    assert handed(backward_do_mirror=True) == unset       # the flag wins
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    assert handed() == unset
+    monkeypatch.setenv("MXNET_MIRROR_POLICY", "full")
+    assert handed() == 0 < unset
+
+
+def _cachedop_counts(core):
+    c = core.counters()
+    return tuple(int(c[k].total) if k in c else None
+                 for k in ("cachedop.recorded_calls",
+                           "cachedop.saved_buffers",
+                           "cachedop.saved_bytes"))
+
+
+@pytest.mark.parametrize("flags", SAVED, ids=SAVED_IDS)
+def test_a_recorded_call_counts_what_it_hands_over(telemetry, flags):
+    net = _bn_net(2, **flags)
+    saved, _ = _handed_over(net)
+    assert _cachedop_counts(telemetry) == (None, None, None)
+    x, _ = _bn_batch()
+    net(x)                                   # not recorded: not counted
+    assert _cachedop_counts(telemetry) == (None, None, None)
+    for _ in range(2):
+        _bn_loss(net).backward()
+    assert _cachedop_counts(telemetry) == (2, 2 * len(saved),
+                                           2 * _nbytes(saved))
+    if not flags:           # 2 results, 4 statistics, the pooled features
+        assert len(saved) == 7
+        assert _nbytes(saved) == (2 * 4 * 4 * 8 * 8 + 4 * 4 + 4 * 4) * 4
+
+
+def test_the_recorded_calls_counters_do_not_exist_with_telemetry_off(
+        monkeypatch):
+    from mxnet_tpu.observability import core
+    monkeypatch.delenv("MXNET_OBS", raising=False)
+    core.set_enabled(None)
+    core.reset()
+    _bn_loss(_bn_net(1)).backward()
+    assert not [n for n in core.counters() if n.startswith("cachedop.")]
 
 
 class _TwoHeads(gluon.HybridBlock):

@@ -55,15 +55,24 @@ def _bind_forward_backward(sym, env):
 
 def test_executor_mirror_shrinks_residuals_and_matches_grads():
     sym = _deep_sym()
-    ex_base, g_base = _bind_forward_backward(sym, {})
+    # the save-everything side is asked for: unset is the dots policy
+    ex_base, g_base = _bind_forward_backward(
+        sym, {"MXNET_BACKWARD_DO_MIRROR": "0"})
     ex_full, g_full = _bind_forward_backward(
         sym, {"MXNET_BACKWARD_DO_MIRROR": "1", "MXNET_MIRROR_POLICY": "full"})
     ex_dots, g_dots = _bind_forward_backward(
         sym, {"MXNET_BACKWARD_DO_MIRROR": "1", "MXNET_MIRROR_POLICY": "dots"})
 
+    ex_unset, g_unset = _bind_forward_backward(sym, {})
+
     b_base = _residual_bytes(ex_base)
     b_full = _residual_bytes(ex_full)
     b_dots = _residual_bytes(ex_dots)
+    # an Executor bound with nothing set latches the dots policy
+    assert _residual_bytes(ex_unset) == b_dots
+    for n in g_dots:
+        np.testing.assert_array_equal(g_unset[n].asnumpy(),
+                                      g_dots[n].asnumpy())
     # full mirroring keeps only inputs; dots keeps MXU outputs too;
     # both must be strictly smaller than the unmirrored residual set
     assert b_full < b_base, (b_full, b_base)
@@ -103,10 +112,9 @@ def _gluon_grads(mirror):
         net.add(gluon.nn.Dropout(0.3))
         net.add(gluon.nn.Dense(4))
     net.initialize(mx.init.Xavier())
-    if mirror:
-        net.hybridize(backward_do_mirror=True)
-    else:
-        net.hybridize()
+    # None: nothing said, the default
+    net.hybridize(**({} if mirror is None
+                     else {"backward_do_mirror": mirror}))
     x = mx.nd.array(rng.randn(8, 16))
     params = net.collect_params()
     with autograd.record():
@@ -117,12 +125,15 @@ def _gluon_grads(mirror):
             if p.grad_req != "null"}
 
 
-def test_hybridize_mirror_flag_grads_match():
-    """hybridize(backward_do_mirror=True) routes CachedOp through remat;
-    gradients (incl. through BatchNorm aux stats and Dropout rng) must be
-    identical to the unmirrored trace."""
+@pytest.mark.parametrize("mirror", [True, None], ids=["flag", "unset"])
+def test_hybridize_mirror_flag_grads_match(mirror):
+    """hybridize(backward_do_mirror=True), and since PR 49 hybridize()
+    alone, route CachedOp through remat; gradients (incl. through
+    BatchNorm aux stats and Dropout rng: the backward draws the mask
+    again from the same key) must be identical to the unmirrored
+    trace, hybridize(backward_do_mirror=False)."""
     base = _gluon_grads(False)
-    mirrored = _gluon_grads(True)
+    mirrored = _gluon_grads(mirror)
     assert len(base) == len(mirrored) and base
     # parameter names carry distinct auto name-scope prefixes
     # (hybridsequential0_ vs hybridsequential1_); compare by sorted order
@@ -132,6 +143,76 @@ def test_hybridize_mirror_flag_grads_match():
         # in backward), so equality is up to reassociation noise
         np.testing.assert_allclose(base[kb], mirrored[km], rtol=2e-3,
                                    atol=1e-5)
+
+
+class _CountedSquare(mx.operator.CustomOp):
+    entered = {"forward": 0, "backward": 0}
+
+    def forward(self, is_train, req, in_data, out_data, aux):
+        self.entered["forward"] += 1
+        self.assign(out_data[0], req[0], in_data[0] * in_data[0])
+
+    def backward(self, req, out_grad, in_data, out_data, in_grad, aux):
+        self.entered["backward"] += 1
+        self.assign(in_grad[0], req[0], 2 * in_data[0] * out_grad[0])
+
+
+@mx.operator.register("mirror_counted_square")
+class _CountedSquareProp(mx.operator.CustomOpProp):
+    def __init__(self):
+        super().__init__(need_top_grad=True)
+
+    def list_arguments(self):
+        return ["data"]
+
+    def list_outputs(self):
+        return ["output"]
+
+    def infer_shape(self, in_shape):
+        return in_shape, [in_shape[0]], []
+
+    def create_operator(self, ctx, shapes, dtypes):
+        return _CountedSquare()
+
+
+class _AroundCustom(gluon.HybridBlock):
+    def __init__(self):
+        super().__init__()
+        with self.name_scope():
+            self.first = gluon.nn.Dense(8, in_units=6)
+            self.last = gluon.nn.Dense(4, in_units=8)
+
+    def hybrid_forward(self, F, x):
+        h = F.Activation(self.first(x), act_type="tanh")
+        h = F.Custom(h, op_type="mirror_counted_square")
+        return self.last(F.Activation(h, act_type="tanh"))
+
+
+@pytest.mark.parametrize("env", [
+    {}, {"MXNET_MIRROR_POLICY": "full"}, {"MXNET_BACKWARD_DO_MIRROR": "0"}],
+    ids=["unset", "full", "off"])
+def test_a_custom_operators_python_forward_is_entered_once_a_step(
+        monkeypatch, env):
+    """What a host callback returned is saved under either policy: a
+    backward that recomputes the activations around a Custom operator
+    does not call its Python forward again."""
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    mx.random.seed(0)
+    net = _AroundCustom()
+    net.initialize(mx.init.Xavier())
+    net.hybridize()
+    x = mx.nd.array(np.random.RandomState(0).randn(4, 6))
+    _CountedSquare.entered.update(forward=0, backward=0)
+    grads = []
+    for _ in range(2):
+        with autograd.record():
+            loss = (net(x) ** 2).sum()
+        loss.backward()
+        grads.append(net.first.weight.grad().asnumpy().copy())
+    assert _CountedSquare.entered == {"forward": 2, "backward": 2}
+    assert np.abs(grads[0]).sum() > 0
+    np.testing.assert_array_equal(grads[0], grads[1])
 
 
 def test_transformer_remat_layers_matches_and_shrinks_memory():

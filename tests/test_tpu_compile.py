@@ -517,3 +517,84 @@ def test_smallthinker_admission_chunk(one_chip, smallthinker, monkeypatch,
           % (width, mem.temp_size_in_bytes))
     assert mem.temp_size_in_bytes < limit
     assert mem.temp_size_in_bytes + 11.69e9 + 0.24e9 < 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def resnet50_step(one_chip):
+    """The two programs of the cell resnet50-gluon-train-bs128's recorded
+    call, as `CachedOp` and the tape build them, compiled at batch 128
+    (the cell's own; both take ~40 s here): (what the forward hands over,
+    its closure's leaves, how many arrays the forward returns, the
+    compiled `jit_fwd_res`, the compiled `jit__apply_vjp`)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd, nd
+    from mxnet_tpu.gluon.model_zoo import vision
+    net = vision.resnet50_v1(classes=1000)
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    net.hybridize()
+    with autograd.pause():                   # builds the CachedOp, small
+        net(nd.NDArray(jnp.zeros((1, 3, 32, 32), jnp.bfloat16)))
+    op = net._cached_op
+    held = {p.name: p for p in net.collect_params().values()}
+
+    def aval(name):
+        if name in held:
+            data = held[name].data()._data
+            return _sds(one_chip, data.shape, data.dtype)
+        return _sds(one_chip, (128, 3, 224, 224))
+    args = {n: aval(n) for n in op._arg_names}
+    aux = {n: aval(n) for n in op._aux_names}
+    diff = tuple(n for n in op._arg_names
+                 if n in held and held[n].grad_req != "null")
+    call = ([args[n] for n in diff], args, aux,
+            _sds(one_chip, (2,), jnp.uint32))
+    fn = op._get_fn(True, diff)
+    returned = jax.eval_shape(fn, *call)
+    (outs, _), saved = returned
+    forward = fn.lower(*call).compile()
+    closure = _on(one_chip, saved.bind(call))
+    backward = autograd._apply_vjp.lower(
+        tuple((o.shape, o.dtype) for o in outs), closure,
+        tuple(_on(one_chip, outs))).compile()
+    return (saved, jax.tree.leaves(closure), len(jax.tree.leaves(returned)),
+            forward, backward)
+
+
+def test_resnet50_forward_hands_its_backward_the_mxu_results(
+        resnet50_step):
+    """ISSUE 49: the forward program of the Gluon cell returns 1 output,
+    106 running statistics, the 53 convolutions' results (2.71 GB) and
+    107 reductions' (a mean and a variance a batch norm, the pooled
+    features: 1.3 MB) where it returned 790 buffers with 9.03 GB of saved
+    leaves; no ReLU mask, no normalised activation, and no parameter as
+    a freshly made copy (the 213 a pullback reads are bound on the
+    host)."""
+    saved, closure, outputs, forward, _ = resnet50_step
+    big = [v for v in saved.saved if v.ndim == 4]
+    assert len(big) == 53 and all(v.dtype == jnp.bfloat16 for v in big)
+    assert len(saved.saved) == 53 + 107 and outputs == 1 + 106 + 160
+    assert sum(v.size * v.dtype.itemsize for v in saved.saved
+               if v.ndim != 4) < 2 * 2 ** 20
+    assert 2.7e9 < saved.nbytes() < 2.9e9
+    assert len(closure) - len(saved.saved) == 213
+    out = forward.memory_analysis().output_size_in_bytes
+    print("resnet50 jit_fwd_res: %d outputs, %d bytes" % (outputs, out))
+    assert saved.nbytes() <= out < saved.nbytes() + 2 ** 20
+
+
+def test_resnet50_two_steps_fit_the_chip_together(resnet50_step):
+    """What lets the next forward's buffers be made while this step's
+    backward runs: the backward's arguments and temporaries and one more
+    forward's outputs and temporaries lie under the chip's 16.9 GB with
+    room for the weights, the optimizer's state and the batch (0.4 GB)."""
+    *_, forward, backward = resnet50_step
+    fwd, bwd = forward.memory_analysis(), backward.memory_analysis()
+    together = (bwd.argument_size_in_bytes + bwd.temp_size_in_bytes
+                + bwd.output_size_in_bytes
+                + fwd.output_size_in_bytes + fwd.temp_size_in_bytes)
+    print("resnet50 step: backward arguments %d temporaries %d, forward "
+          "outputs %d temporaries %d" % (
+              bwd.argument_size_in_bytes, bwd.temp_size_in_bytes,
+              fwd.output_size_in_bytes, fwd.temp_size_in_bytes))
+    assert together + 0.4e9 < 16.9e9

@@ -104,11 +104,13 @@ def _batcher(**kw):
 # with K/V layers, as this one, its decode contractions by the path they
 # took, _count_kv_contractions; a model with routed experts adds moe.*,
 # one with latent layers mla.*, one with window layers kv.rows_*, one
-# with hyper-connections hc.rows)
+# with hyper-connections hc.rows), and since PR 49 what a recorded call
+# of a hybridized block hands its backward (cached_op.py)
 WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
                       "serving.fresh_rows", "serving.prefill_tokens",
                       "serving.prefill_rows", "kv.decode_kernel",
-                      "kv.decode_reference"}
+                      "kv.decode_reference", "cachedop.recorded_calls",
+                      "cachedop.saved_buffers", "cachedop.saved_bytes"}
 
 
 def _serve(srv, rounds):
