@@ -20,11 +20,13 @@ Design notes (all static-shape, XLA-friendly):
   positions beyond the prompt are overwritten by decode writes before
   they ever become attendable — the same self-healing argument the
   speculative decoder relies on.
-* HYBRID models (cfg.layer_kinds with "mamba" or "kda" layers) give a
-  lane two kinds of state: rows for the attention layers (K/V heads,
-  or an "mla" layer's one latent a position) and a fixed-size
-  recurrent state for the others (conv window + a float32 SSM state,
-  or a KDA layer's float32 matrix a head).
+* HYBRID models (cfg.layer_kinds with "mamba", "mamba2" or "kda"
+  layers) give a lane two kinds of state: rows for the attention layers
+  (K/V heads, or an "mla" layer's one latent a position) and a
+  fixed-size recurrent state for the others (conv window + a float32 SSM
+  state, a channel's in Mamba-1 and a head's in Mamba-2, or a KDA
+  layer's float32 matrix a head). An "ffn" block (a model whose blocks
+  hold one sub-layer each) keeps nothing: its state has no leaves.
   That state has no self-healing: a padded position folded into it
   stays. It is exact by construction instead — the bucketed prefill
   stops it at the last real token (prefill_chunk's logits_row), a
@@ -1044,20 +1046,22 @@ class ContinuousBatcher(object):
         self._slots = [None] * self.max_batch   # Request or None
         # what a live lane holds, for the serving.state_bytes /
         # serving.kv_bytes gauges: bytes of recurrent state a lane
-        # (whatever its length: a Mamba layer's, a KDA layer's matrices)
-        # and, for every layer that keeps rows (K/V heads, or a
-        # latent), the rows its leaf holds a lane (max_len, or a window
-        # layer's ring) with the bytes of one
+        # (whatever its length: a Mamba or Mamba-2 layer's, a KDA
+        # layer's matrices) and, for every layer that keeps rows (K/V
+        # heads, or a latent; an "ffn" block keeps nothing), the rows
+        # its leaf holds a lane (max_len, or a window layer's ring) with
+        # the bytes of one
         row = list(zip(tf._layer_kinds(cfg),
                        jax.eval_shape(lambda: tf.init_cache(cfg, 1))))
 
         def nbytes(layer):
             return sum(x.size * x.dtype.itemsize for x in layer.values())
         self._latent_layers = sum(kind == "mla" for kind, _ in row)
+        self._ssd_layers = sum(kind == "mamba2" for kind, _ in row)
         self._lane_state_bytes = sum(
             nbytes(l) for kind, l in row if kind in tf._RECURRENT)
         rowed = [(kind, layer) for kind, layer in row
-                 if kind not in tf._RECURRENT]
+                 if kind not in tf._RECURRENT and layer]
         held = [(next(iter(layer.values())).shape[1], nbytes(layer),
                  kind == "window") for kind, layer in rowed]
         # rows of every such leaf, beside the block decode's contraction
@@ -1564,12 +1568,22 @@ class ContinuousBatcher(object):
         """While spans record: one prefill_chunk call of an admission
         into the counters serving.prefill_tokens (the prompt's real
         tokens it took in) and serving.prefill_rows (the rows it
-        computed, the bucket's padding included)."""
+        computed, the bucket's padding included); for a model with
+        Mamba-2 layers also ssd.rows_live, those tokens a Mamba-2
+        layer, and ssd.rows_scanned, the rows its chunked form ran for
+        them: the call's width in whole chunks (ssd.mixer_seq pads a
+        width that is no multiple of cfg.ssd_chunk), a layer."""
         if _obs.active():
             _obs.counter("serving.prefill_tokens").add(tokens)
             _obs.counter("serving.prefill_rows").add(rows)
             self._count_frame_rows(rows)
             self._count_expert_matmuls(rows)
+            if self._ssd_layers:
+                _obs.counter("ssd.rows_live").add(
+                    self._ssd_layers * tokens)
+                _obs.counter("ssd.rows_scanned").add(
+                    self._ssd_layers
+                    * (rows + -rows % min(self.cfg.ssd_chunk, rows)))
 
     def _count_expert_matmuls(self, rows, passes=1):
         """While spans record, for a model with routed experts: the
@@ -2372,8 +2386,12 @@ class ContinuousBatcher(object):
         hc.rows (_count_frame_rows) for every lane; the
         expert layers' grouped matmuls (_count_expert_matmuls); the
         latent layers' stores of a step's `kr` rows (_count_row_stores);
-        and the K/V layers' decode contractions
-        (_count_kv_contractions)."""
+        the K/V layers' decode contractions (_count_kv_contractions);
+        and, for a model with Mamba-2 layers, ssd.lane_steps, the
+        states a one-row dispatch read and wrote (every one of the
+        max_batch lanes', a Mamba-2 layer a step), beside
+        ssd.lane_steps_live, those of the lanes that held a request
+        when it was issued."""
         self.dispatch_count += 1
         if _obs.active():
             _obs.counter("serving.dispatches").add(1)
@@ -2384,6 +2402,12 @@ class ContinuousBatcher(object):
             self._count_row_stores(steps)
             if window == 1:
                 self._count_kv_contractions(steps)
+                if self._ssd_layers:
+                    each = steps * self._ssd_layers
+                    _obs.counter("ssd.lane_steps").add(
+                        each * self.max_batch)
+                    _obs.counter("ssd.lane_steps_live").add(
+                        each * self.active_count)
 
     @staticmethod
     def _count_routing(routing):

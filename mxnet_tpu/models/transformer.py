@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import kda, ssm
+from . import kda, ssd, ssm
 from ..parallel.ring import ring_attention, ring_attention_sharded
 from ..parallel.pipeline import stack_stage_params, spmd_pipeline
 
@@ -36,7 +36,7 @@ __all__ = ["TransformerConfig", "YarnScaling", "init_params", "forward", "loss_f
            "quantize_weights_int8", "beam_search", "prefill_chunk",
            "speculative_generate", "save_checkpoint", "load_checkpoint",
            "restore_train_state", "init_paged_cache", "decode_step_paged",
-           "verify_chunk", "verify_chunk_paged"]
+           "verify_chunk", "verify_chunk_paged", "pad_expert_width"]
 
 
 class YarnScaling(NamedTuple):
@@ -76,17 +76,23 @@ class TransformerConfig:
     # cache kind), "window" (the same heads attending the last
     # `attn_window` positions only, its K/V rows a ring of that many),
     # "mamba" (models/ssm.py: fixed-size recurrent state instead of K/V
-    # rows), "kda" (models/kda.py: a matrix-valued state a head) or "mla"
+    # rows), "mamba2" (models/ssd.py: the scalar-decay form, a state a
+    # head), "kda" (models/kda.py: a matrix-valued state a head), "mla"
     # (latent attention: one latent row a position instead of K/V
-    # heads). None = every layer attention
+    # heads) or "ffn" (no mixer: the block is its feed-forward alone and
+    # keeps no state). None = every layer attention
     layer_kinds: tuple = None
+    # False = a block holds ONE sub-layer, x + f(norm x): a block with a
+    # mixer has no feed-forward (no "ln2", no "w1"..), and the
+    # feed-forwards are the "ffn" blocks of layer_kinds
+    mixer_ffn: bool = True
     # a "window" layer's span: a query at position i sees positions
     # i - attn_window + 1 .. i
     attn_window: int = None
     d_ff: int = 128
     # the feed-forward's form, dense or a routed expert's: "gelu" =
-    # w2 gelu(w1 x); "gated_silu" = w2 (silu(w1 x) * w3 x); "gated_relu"
-    # = w2 (relu(w1 x) * w3 x)
+    # w2 gelu(w1 x); "relu2" = w2 relu(w1 x)^2; "gated_silu" =
+    # w2 (silu(w1 x) * w3 x); "gated_relu" = w2 (relu(w1 x) * w3 x)
     ffn: str = "gelu"
     # routed experts: 0 = a dense FFN in every layer; E > 0 = the layers
     # from `first_dense_layers` on route each token over E experts of
@@ -149,6 +155,16 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = None
+    # Mamba-2 mixer sizes: heads (None = n_heads), a head's channels
+    # (None = 2 d_model / heads, the family's expansion), states a head,
+    # groups of heads that share one B and one C, conv taps, positions a
+    # chunk of the sequence form
+    ssd_heads: int = None
+    ssd_head_dim: int = None
+    ssd_state: int = 128
+    ssd_groups: int = 1
+    ssd_conv: int = 4
+    ssd_chunk: int = 128
     # KDA mixer sizes: heads (None = n_heads), the size of a head's keys
     # and values and of the gates' inner projection (None = d_model /
     # n_heads), conv taps
@@ -217,12 +233,14 @@ def _kvh(cfg):
 # Only dense lanes carry any of them: paged blocks, speculation, int8
 # and a mesh refuse these kinds by name (_refuse_dense_only)
 _DENSE_ONLY = {"mamba": "a state-space layer's recurrent state",
+               "mamba2": "a scalar-decay state-space layer's state a head",
                "kda": "a linear-attention layer's matrix state",
                "mla": "a latent-attention layer's latent rows",
-               "window": "a window layer's ring of K/V rows"}
+               "window": "a window layer's ring of K/V rows",
+               "ffn": "a feed-forward block's state without leaves"}
 # the kinds whose state is a recurrence: fixed-size, and not healed by
 # position as K/V or latent rows are
-_RECURRENT = ("mamba", "kda")
+_RECURRENT = ("mamba", "mamba2", "kda")
 
 
 def _layer_kinds(cfg):
@@ -232,7 +250,14 @@ def _layer_kinds(cfg):
             or set(kinds) - {"attention"} - set(_DENSE_ONLY):
         raise ValueError(
             "layer_kinds must name %d layers, each 'attention', 'window', "
-            "'mamba', 'kda' or 'mla'; got %r" % (cfg.n_layers, kinds))
+            "'mamba', 'mamba2', 'kda', 'mla' or 'ffn'; got %r"
+            % (cfg.n_layers, kinds))
+    if cfg.hc_mult is not None and (not cfg.mixer_ffn or "ffn" in kinds):
+        raise ValueError(
+            "a block of one sub-layer (mixer_ffn=False, or an 'ffn' block "
+            "in layer_kinds) cannot sit in a residual stream of hc_mult=%r "
+            "streams: a layer's two frames wrap a mixer and a feed-forward"
+            % (cfg.hc_mult,))
     return tuple(kinds)
 
 
@@ -310,6 +335,24 @@ def _mamba_state(cfg, batch):
                           cfg.ssm_conv, batch, cfg.dtype)
 
 
+def _ssd_sizes(cfg):
+    """(heads, a head's channels, states, groups, conv taps) of a Mamba-2
+    mixer, checked."""
+    h = cfg.ssd_heads or cfg.n_heads
+    sizes = (h, cfg.ssd_head_dim or 2 * cfg.d_model // h, cfg.ssd_state,
+             cfg.ssd_groups, cfg.ssd_conv)
+    if min(sizes) < 1 or h % cfg.ssd_groups or cfg.ssd_chunk < 1:
+        raise ValueError(
+            "a 'mamba2' layer's (heads, head size, states, groups, taps) "
+            "= %r with ssd_chunk=%r: each at least 1, and the groups "
+            "divide the heads" % (sizes, cfg.ssd_chunk))
+    return sizes
+
+
+def _mamba2_state(cfg, batch):
+    return ssd.init_state(*_ssd_sizes(cfg), batch, cfg.dtype)
+
+
 def _kda_sizes(cfg):
     """(heads, head size) of a KDA mixer."""
     return (cfg.kda_heads or cfg.n_heads,
@@ -359,13 +402,24 @@ def _experts(cfg):
     return e, k, first, held, cfg.d_expert or cfg.d_ff
 
 
+def _has_ffn(cfg, i):
+    """Whether block i holds a feed-forward: every one does, but the
+    blocks with a mixer under mixer_ffn=False."""
+    return bool(cfg.mixer_ffn) or _layer_kinds(cfg)[i] == "ffn"
+
+
 def _has_experts(cfg, i):
-    """Whether layer i routes over experts or has the dense FFN."""
-    return bool(cfg.n_experts) and i >= cfg.first_dense_layers
+    """Whether block i's feed-forward, where the plan gives it one,
+    routes over experts or is the dense FFN."""
+    return bool(cfg.n_experts) and i >= cfg.first_dense_layers \
+        and _has_ffn(cfg, i)
 
 
 # the gated forms of cfg.ffn, w2 (act(w1 x) * w3 x), and each one's act
 _GATED = {"gated_silu": jax.nn.silu, "gated_relu": jax.nn.relu}
+# the forms without a gate, w2 act(w1 x)
+_UNGATED = {"gelu": jax.nn.gelu,
+            "relu2": lambda h: jnp.square(jax.nn.relu(h))}
 
 
 def _yarn_mscale(factor, m):
@@ -447,7 +501,10 @@ def param_specs(cfg):
         ("in_proj", 2), ("conv_w", 2), ("conv_b", 1), ("x_proj", 2),
         ("dt_norm", 1), ("b_norm", 1), ("c_norm", 1), ("dt_proj", 2),
         ("dt_bias", 1), ("A_log", 2), ("D", 1), ("out_proj", 2))}
-    # so are a KDA and a latent-attention mixer
+    # so are a Mamba-2, a KDA and a latent-attention mixer
+    mamba2 = {k: P(*(None,) * n) for k, n in (
+        ("in_proj", 2), ("conv_w", 2), ("conv_b", 1), ("dt_bias", 1),
+        ("A_log", 1), ("D", 1), ("y_norm", 1), ("out_proj", 2))}
     kda_mixer = {k: P(*(None,) * n) for k, n in (
         ("wqkv", 2), ("conv_w", 2), ("f_a", 2), ("f_b", 2), ("dt_bias", 1),
         ("A_log", 1), ("b_proj", 2), ("g_a", 2), ("g_b", 2), ("o_norm", 1),
@@ -457,7 +514,7 @@ def param_specs(cfg):
         else (("wq", 3),))
         + (("wkva", 2), ("kv_norm", 1), ("wkvb", 3), ("wo", 3))}
     mixers = {"attention": attention, "window": attention, "mamba": mamba,
-              "kda": kda_mixer, "mla": mla}
+              "mamba2": mamba2, "kda": kda_mixer, "mla": mla}
     gated = cfg.ffn in _GATED
     dense = {"w1": P(None, tp), "w2": P(tp, None)}
     if gated:
@@ -477,11 +534,19 @@ def param_specs(cfg):
     frames = {} if cfg.hc_mult is None else {
         "%s_%s" % (name, k): P(*(None,) * rank) for name in HC_FRAMES
         for k, rank in HC_LEAVES}
+
+    def layer(i, kind):
+        ffn = experts if _has_experts(cfg, i) else dense
+        if kind == "ffn":       # a block of one sub-layer: its norm and
+            return dict(ffn, ln2=P(None))               # its leaves
+        if not cfg.mixer_ffn:
+            return dict(mixers[kind], ln1=P(None))
+        return dict(ffn, ln1=P(None), ln2=P(None), **frames, **mixers[kind])
+
     out = {
         "embed": P(None, None),
         "ln_f": P(None),
-        "layers": [dict(experts if _has_experts(cfg, i) else dense,
-                        ln1=P(None), ln2=P(None), **frames, **mixers[kind])
+        "layers": [layer(i, kind)
                    for i, kind in enumerate(_layer_kinds(cfg))],
     }
     if not cfg.tied_head:
@@ -543,6 +608,23 @@ def init_params(cfg, seed=0):
             "out_proj": dense(e, cfg.d_model),
         }
 
+    def mamba2():
+        # the family's initialisation: A = -U(1, 16) a head, a bias that
+        # puts softplus(dt) log-uniform in [1e-3, 1e-1], D = 1
+        h, hp, n, g, k = _ssd_sizes(cfg)
+        e, conv = h * hp, h * hp + 2 * g * n
+        dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), h))
+        return {
+            "in_proj": dense(cfg.d_model, e + conv + h),
+            "conv_w": dense(k, conv),
+            "conv_b": jnp.zeros((conv,), dt),
+            "dt_bias": jnp.asarray(dt0 + np.log(-np.expm1(-dt0)), dt),
+            "A_log": jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)), dt),
+            "D": jnp.ones((h,), dt),
+            "y_norm": jnp.ones((e,), dt),
+            "out_proj": dense(e, cfg.d_model),
+        }
+
     def kda_mixer():
         # the family's initialisation: A = -U(1, 16) a head, a bias that
         # puts softplus(dt) log-uniform in [1e-3, 1e-1]; the decay's two
@@ -589,7 +671,7 @@ def init_params(cfg, seed=0):
         }
 
     mixers = {"attention": attention, "window": attention, "mamba": mamba,
-              "kda": kda_mixer, "mla": mla}
+              "mamba2": mamba2, "kda": kda_mixer, "mla": mla}
     gated = cfg.ffn in _GATED
 
     def experts():
@@ -622,17 +704,21 @@ def init_params(cfg, seed=0):
                 "a": jnp.full((3,), HC_INIT_GAIN, jnp.float32)}
 
     def layer(i, kind):
-        p = {
-            "ln1": jnp.ones(_norm_shape(cfg), dt),
-            "ln2": jnp.ones(_norm_shape(cfg), dt),
-        }
+        # a block of one sub-layer has that sub-layer's norm and leaves
+        mixer, ffn = kind != "ffn", _has_ffn(cfg, i)
+        p = {}
+        if mixer:
+            p["ln1"] = jnp.ones(_norm_shape(cfg), dt)
+        if ffn:
+            p["ln2"] = jnp.ones(_norm_shape(cfg), dt)
         if cfg.hc_mult is not None:
             p.update({"%s_%s" % (name, k): v for name in HC_FRAMES
                       for k, v in frame().items()})
-        p.update(mixers[kind]())
+        if mixer:
+            p.update(mixers[kind]())
         if _has_experts(cfg, i):
             p.update(experts())
-        else:
+        elif ffn:
             p["w1"] = dense(cfg.d_model, cfg.d_ff)
             p["w2"] = dense(cfg.d_ff, cfg.d_model)
             if gated:
@@ -809,7 +895,7 @@ def _mlp(x, w1, w2, w3, cfg):
     if cfg.ffn in _GATED:
         h = _GATED[cfg.ffn](h) * jnp.einsum("btd,df->btf", x, w3)
     else:
-        h = jax.nn.gelu(h)
+        h = _UNGATED[cfg.ffn](h)
     return jnp.einsum("btf,fd->btd", h, w2)
 
 
@@ -817,9 +903,9 @@ def _ffn(x, p, cfg, loads=None, mesh=None, route_from=None):
     """A layer's feed-forward on x [B, T, d]: the dense form, or, for a
     layer with a router ("gate"), its routed experts (_expert_ffn;
     `route_from`: what the router scores where that is not x)."""
-    if cfg.ffn != "gelu" and cfg.ffn not in _GATED:
-        raise ValueError("ffn=%r: 'gelu', 'gated_silu' or 'gated_relu'"
-                         % (cfg.ffn,))
+    if cfg.ffn not in _UNGATED and cfg.ffn not in _GATED:
+        raise ValueError("ffn=%r: 'gelu', 'relu2', 'gated_silu' or "
+                         "'gated_relu'" % (cfg.ffn,))
     if "gate" in p:
         if mesh is None and route_from is None:
             return _expert_ffn(x, p, cfg, loads)
@@ -847,6 +933,42 @@ def moe_stats(loads, tokens, cfg):
         jnp.int32(tokens * _experts(cfg)[1] * load.shape[0]), jnp.sum(load),
         jnp.sum(load > 0), jnp.sum(jnp.max(load, axis=1)),
         jnp.int32(load.size), jnp.int32(load.shape[0])]).astype(jnp.int32)
+
+
+def pad_expert_width(params, cfg):
+    """(params, cfg) with every routed expert's hidden width padded by
+    zero units to a whole number of the chip's 128 lanes: zero columns
+    behind "w1" (and "w3"), zero rows behind "w2", cfg.d_expert the
+    padded width. A hidden unit whose "w1" column is zero reads
+    act(0) = 0 in every form of cfg.ffn (relu(0)^2, gelu(0),
+    act(0) * w3 x) and its zero "w2" row adds nothing: the same
+    function, at a width where kernels/grouped_matmul.py has a block and
+    the chip keeps a stack in the order a matmul reads it (1,856 is
+    14.5 x 128: XLA's ragged dot ran such a stack at a ninth of the HBM
+    peak behind a copy of it every round, PERF.md, PR 50; 1,920 costs
+    3.4% more bytes). For serving: weights are prepared once, as by
+    quantize_weights_int8. A width that 128 divides and a dense model
+    come back as they are. The layers are padded ONE AT A TIME, each
+    waited for, and the list `params["layers"]` is refilled in place, so
+    a caller that keeps no other reference to the old stacks never holds
+    both (0.64 GB a stack at 64 x 2,688 x 1,856; enqueued all at once the
+    ten pads kept every old stack alive until they ran: 15.5 GB in use
+    at the peak where 9.97 are held, PERF.md, PR 50)."""
+    if not cfg.n_experts:
+        return params, cfg
+    import dataclasses
+    f = _experts(cfg)[4]
+    pad = -f % 128
+    if pad:
+        grow = {"w1": 2, "w3": 2, "w2": 1}          # the hidden axis
+        for i, layer in enumerate(params["layers"]):
+            if "gate" in layer:
+                params["layers"][i] = jax.block_until_ready({
+                    name: jnp.pad(x, [(0, pad * (axis == grow[name]))
+                                      for axis in range(3)])
+                    if name in grow else x for name, x in layer.items()})
+                del layer
+    return params, dataclasses.replace(cfg, d_expert=f + pad)
 
 
 def expert_matmuls(params, cfg, rows):
@@ -919,7 +1041,7 @@ def _expert_ffn(x, p, cfg, loads, mesh=None, route_from=None):
         if cfg.ffn in _GATED:
             h = _GATED[cfg.ffn](h) * matmul(picked, p["w3"])
         else:
-            h = jax.nn.gelu(h)
+            h = _UNGATED[cfg.ffn](h)
         y = matmul(h, p["w2"])
         # back in pick order; rows in no group hold nothing to read
         y = jnp.where(here.reshape(-1, 1), y[jnp.argsort(order)], 0)
@@ -941,7 +1063,10 @@ def _pp_size(cfg, mesh):
 def _layer(x, p, kind, cfg, mix, state=None, loads=None, mesh=None,
            rotate=True):
     """One transformer block, the residual frame every entry point
-    runs: x + mix(ln1 x), then x + ffn(ln2 x). x is [B, C, d], or
+    runs: x + mix(ln1 x), then x + ffn(ln2 x), each where the block has
+    that sub-layer (an "ffn" block has no mixer, and under
+    cfg.mixer_ffn=False a block with a mixer has no feed-forward: its
+    parameters say which, "ln1" / "ln2"). x is [B, C, d], or
     [B, d] for decode's one row. With `cfg.hc_mult` the stream is n
     streams wide (x [n, B, C, d] / [n, B, d]) and each of the two
     sub-layers reads and writes it through _hyper_connect.
@@ -967,8 +1092,9 @@ def _layer(x, p, kind, cfg, mix, state=None, loads=None, mesh=None,
         return _ffn(h, p, cfg, loads, mesh, route_from)
 
     if cfg.hc_mult is None:
-        x = x + mixer(x)
-        return x + ffn(x), state
+        if "ln1" in p:
+            x = x + mixer(x)
+        return (x + ffn(x) if "ln2" in p else x), state
     x = _hyper_connect(x, p, "hc1", cfg, mixer)
     return _hyper_connect(x, p, "hc2", cfg, ffn), state
 
@@ -1090,10 +1216,11 @@ def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False,
     _layer_rope), and "mla" is `latent(h, p, state)`, its form of latent
     attention (_latent_attend). An entry point that has no form of a
     kind refuses it (_refuse_dense_only).
-    "mamba" and "kda" keep a recurrent state ({"conv", "ssm"} /
+    "mamba", "mamba2" and "kda" keep a recurrent state ({"conv", "ssm"} /
     {"conv", "kda"}) where an attention layer keeps K/V: the step form
     for decode's one row [B, d], the sequence form for [B, C, d], which
-    with `valid_len` stops after that many rows (ssm / kda .mixer_seq)
+    with `valid_len` stops after that many rows (ssm / ssd / kda
+    .mixer_seq)
     and with `from_zero` starts from a zero state whatever it was handed
     (training, and a prefill at position 0)."""
     def mix(kind, h, p, state, rotate=True):
@@ -1103,6 +1230,13 @@ def _mixer(cfg, attend, latent=None, valid_len=None, from_zero=False,
             if from_zero:
                 state = _mamba_state(cfg, h.shape[0])
             return ssm.mixer_seq(h, p, state, valid_len)
+        if kind == "mamba2":
+            if h.ndim == 2:
+                return ssd.mixer_step(h, p, state, cfg.norm_eps)
+            if from_zero:
+                state = _mamba2_state(cfg, h.shape[0])
+            return ssd.mixer_seq(h, p, state, valid_len, cfg.norm_eps,
+                                 cfg.ssd_chunk)
         if kind == "kda":
             if h.ndim == 2:
                 return kda.mixer_step(h, p, state, cfg.norm_eps)
@@ -1162,9 +1296,10 @@ def forward(params, tokens, cfg, mesh=None):
 
         if cfg.remat_layers:
             layer_fn = jax.checkpoint(layer_fn)
-        if cfg.rope_layers is not None:
+        if cfg.rope_layers is not None or not cfg.mixer_ffn:
             raise ValueError("pipeline stages stack one layer body: "
-                             "rope_layers cannot differ by layer there")
+                             "rope_layers cannot differ by layer there, "
+                             "nor blocks of one sub-layer (mixer_ffn)")
         stacked = stack_stage_params(params["layers"], n_stages)
         x = spmd_pipeline(
             layer_fn, stacked, x, mesh, axis_name=cfg.pp_axis,
@@ -1219,9 +1354,12 @@ def init_cache(cfg, batch):
     keep less than the headline half).
 
     A Mamba layer (cfg.layer_kinds) holds no rows but a fixed-size
-    state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}, and a KDA
-    layer {"conv": [B, K-1, 3*H*Dk], "kda": [B, H, Dk, Dv] float32}, a
-    matrix a head: batch first like the rows, so whatever moves a lane's
+    state, {"conv": [B, K-1, E], "ssm": [B, N, E] float32}, a Mamba-2
+    layer {"conv": [B, K-1, E + 2GN], "ssm": [B, H, P, N] float32}, and
+    a KDA layer {"conv": [B, K-1, 3*H*Dk], "kda": [B, H, Dk, Dv]
+    float32}, a matrix a head; an "ffn" block's state has no leaves
+    (states stay one a layer, and what moves a lane's rows moves nothing
+    there): batch first like the rows, so whatever moves a lane's
     rows (the batcher's lane write, beam search's re-gather) moves its
     state the same way. A latent-attention layer holds rows of ONE
     latent a position, {"c": [B, max_len, R], "kr": [B, max_len, E]}:
@@ -1232,7 +1370,8 @@ def init_cache(cfg, batch):
     min(attn_window, max_len) of them (_ring_rows)."""
     if cfg.kv_cache_int8:
         _refuse_dense_only(cfg, "kv_cache_int8")
-    states = {"mamba": _mamba_state, "kda": _kda_state}
+    states = {"mamba": _mamba_state, "mamba2": _mamba2_state,
+              "kda": _kda_state, "ffn": lambda cfg, batch: {}}
     return [states[kind](cfg, batch) if kind in states else
             _kv_leaves(cfg, batch, min(_window(cfg), cfg.max_len)
                        if kind == "window" else cfg.max_len, kind)
@@ -1454,6 +1593,7 @@ def quantize_weights_int8(params):
     latent-attention layers, whose up-projection decode absorbs into the
     query."""
     for leaf, kind, what in (
+            ("y_norm", "mamba2", "a scalar-decay state-space layer's"),
             ("D", "mamba", "a state-space layer's"),
             ("b_proj", "kda", "a linear-attention layer's"),
             ("wkva", "mla", "a latent-attention layer's"),

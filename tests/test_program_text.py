@@ -48,7 +48,16 @@ the rows' shape gives that kernel a block (kernels.kv_decode.kv_block).
 block would be the whole cache, and the XLA text stays, letter for
 letter (kv_decode_reference), as it does for SmallThinker's six rings.
 The other seventeen hashes stand too: no admission, prefill, forward or
-train step reaches that call, and latent and KDA mixers bypass it."""
+train step reaches that call, and latent and KDA mixers bypass it.
+
+PR 50 pinned three programs of the new configuration (Nemotron-3-Nano:
+blocks of one sub-layer, the Mamba-2 mixer of models/ssd.py, relu2
+experts at the width `pad_expert_width` serves them, 1,920, where the
+grouped matmuls are the kernel) at its own text. All twenty older hashes stand: `_layer` runs
+the mixer where the block has one and the feed-forward where its
+parameters hold "ln2", `_mlp` / `_expert_ffn` take the ungated form from
+a table, and a configuration that states neither `mixer_ffn=False`, an
+"ffn" or "mamba2" block nor `ffn="relu2"` lowers to the text it had."""
 
 import hashlib
 import importlib
@@ -71,7 +80,8 @@ def _shapes(specs, float32=()):
 # a configuration's runner -> its reference (others: Cerebras-GPT's pair)
 REFERENCES = {"serve_kimi_linear": "kimi_linear", "serve_kimi_k2": "kimi_k2",
               "serve_xing4": "xing4", "serve_jamba": "jamba",
-              "serve_smallthinker": "smallthinker"}
+              "serve_smallthinker": "smallthinker",
+              "serve_nemotron_h": "nemotron_h"}
 
 
 def _sides(cell):
@@ -91,6 +101,12 @@ def _sides(cell):
     if config["runner"] == "serve_xing4":    # a frame's leaves in three
         params = jax.eval_shape(
             lambda w: runner.program_params(w, config), shapes)
+    elif config["runner"] == "serve_nemotron_h":    # the experts padded
+        from mxnet_tpu.models import transformer as tf
+        params = jax.eval_shape(
+            lambda w: runner.program_sides(config, 0, w)[0], shapes)
+        return tf.pad_expert_width({"layers": []}, runner.program_config(
+            config))[1], params, lanes
     else:
         params = ref.as_tree(shapes, config)
     return runner.program_config(config), params, lanes
@@ -142,6 +158,7 @@ KL, JA, CE = ("kimi-linear-48b-serve-reason32", "jamba2-3b-serve-chat64",
               "cerebras-gpt-1.3b-serve-closed24")
 K2, XI = "kimi-k2.6-serve-agent32", "xing4.0-29b-a4b-serve-rag32"
 SM = "smallthinker-21b-serve-docchat32"
+NE = "nemotron3-nano-serve-subagent64"
 PROGRAMS = {
     "kimi-linear.decode": (_decode, KL),
     "kimi-linear.admission-1024": (_admission, KL, 1024),
@@ -163,6 +180,9 @@ PROGRAMS = {
     "smallthinker.decode": (_decode, SM),
     "smallthinker.admission-8192": (_admission, SM, 8192),
     "smallthinker.admission-256": (_admission, SM, 256),
+    "nemotron.decode": (_decode, NE),
+    "nemotron.admission-2048": (_admission, NE, 2048),
+    "nemotron.forward-256": (_forward, NE, 256),
 }
 # sha256 of the StableHLO text, first 16 hex digits, at the parent commit
 # (of PR 41; a line says where a later PR moved or first pinned it)
@@ -192,6 +212,10 @@ AT_THE_PARENT = {
     "smallthinker.admission-256": "5c3d4288cbea8573",
     "smallthinker.admission-8192": "5fff65ce8f15c9e9",
     "smallthinker.decode": "af1da804614b0d21",    # PR 48: kv_decode
+    # first pinned at PR 50, at its own text
+    "nemotron.admission-2048": "cad49bd4c57883bd",
+    "nemotron.decode": "40d112c918c1fe5f",
+    "nemotron.forward-256": "c3dda5037fbe78a0",
 }
 
 

@@ -303,6 +303,8 @@ EXPERT_SHAPES = {
     "xing4-chunk-2048": (8192, 64, 3584, 1024),
     "kimi-k2-decode": (256, 12, 7168, 2048),
     "kimi-k2-chunk-4096": (32768, 12, 7168, 2048),
+    "nemotron-decode": (384, 64, 2688, 1920),
+    "nemotron-chunk-8192": (49152, 64, 2688, 1920),
     "24-lanes-of-8-picks": (192, 64, 2304, 1024),
     "3-lanes-of-8-picks": (24, 12, 7168, 2048),
 }
@@ -517,6 +519,91 @@ def test_smallthinker_admission_chunk(one_chip, smallthinker, monkeypatch,
           % (width, mem.temp_size_in_bytes))
     assert mem.temp_size_in_bytes < limit
     assert mem.temp_size_in_bytes + 11.69e9 + 0.24e9 < 15.75 * 2 ** 30
+
+
+# ------------------------------------------- the Nemotron-3-Nano cell ---
+
+@pytest.fixture(scope="module")
+def nemotron(one_chip):
+    """The cell nemotron3-nano-serve-subagent64's sides as its runner
+    serves them: the experts' width of 1,856 padded to 1,920 by
+    `pad_expert_width`."""
+    import json
+    import os
+    from chipbench.reference import nemotron_h as ref
+    from chipbench.runners import serve_nemotron_h as runner
+    from mxnet_tpu.models import transformer as tf
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "nemotron3-nano-30b-a3b.json")) as f:
+        config = json.load(f)
+    flat = {leaf: _sds(one_chip, shape)
+            for leaf, shape, _ in ref.leaf_specs(config)}
+    params = jax.eval_shape(
+        lambda w: runner.program_sides(config, 0, w)[0], flat)
+    return tf.pad_expert_width({"layers": []},
+                               runner.program_config(config))[1], \
+        _on(one_chip, params)
+
+
+def test_nemotron_decode_round(one_chip, nemotron, monkeypatch):
+    """The batcher's one decode program at the cell's shape, 64 lanes of
+    6 Mamba-2 states [64, 64, 128] float32 and 2 K/V blocks x 8,192 rows,
+    the lanes donated: 9.97 GB of weights and lanes, the lanes (1.89 GB)
+    aliased to the result; a Mamba-2 block's update and its read are ONE
+    fusion over the state (134 MB for the 64 lanes), which is never
+    copied or transposed; the two attention blocks' contractions are the
+    kernel kv_decode and the five expert blocks' ten grouped matmuls the
+    kernel moe_gmm, no weight stack copied; 20 MB of temporaries when
+    written. At the published width of 1,856 (14.5 x 128) the same
+    program kept XLA's ragged dot behind a copy of every `w1` stack a
+    round, 0.68 GB of temporaries (PERF.md section 6, PR 50): why the
+    runner serves the stacks padded to 1,920."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = nemotron
+    compiled = _decode_round(one_chip, cfg, params, 64)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 10
+    assert "kv_decode" in text and "moe_gmm" in text \
+        and "ragged-dot" not in text
+    assert not _moved(text, ("f32[64,64,64,128]", "bf16[64,8192,2,128]",
+                             "bf16[64,2688,1920]", "bf16[64,1920,2688]"))
+    assert text.count("mx.ssd.step") > 0
+    mem = compiled.memory_analysis()
+    print("nemotron decode round: temporaries %d bytes, arguments %d"
+          % (mem.temp_size_in_bytes, mem.argument_size_in_bytes))
+    assert 9.9e9 < mem.argument_size_in_bytes < 10.0e9
+    assert mem.alias_size_in_bytes > 1.85e9         # the lanes, in place
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("width,limit", [(8192, 1.8e9), (256, 0.4e9)])
+def test_nemotron_admission_chunk(one_chip, nemotron, monkeypatch, width,
+                                  limit):
+    """An admission's one call at the cell's widest and narrowest buckets
+    against a one-lane row: the chunked form's [64 heads, 128, 128] decay
+    planes for all 64 chunks of an 8,192 bucket are 0.27 GB, the experts'
+    49,152 picked rows 0.26 GB; 1.62 GB of temporaries when written, 0.29
+    at 256. Both fit beside 9.97 GB of weights and lanes and the row
+    twice (0.06 GB) in the chip's 15.75 GB."""
+    from mxnet_tpu.models import transformer as tf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = nemotron
+    row = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, 1)))
+    compiled = jax.jit(
+        lambda p, c, t, s, r: tf.prefill_chunk(p, c, t, s, cfg,
+                                               logits_row=r)).lower(
+        params, row, _sds(one_chip, (1, width), jnp.int32),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "mx.ssd.chunk" in text and "mx.ssd.conv" in text \
+        and "moe_gmm" in text
+    mem = compiled.memory_analysis()
+    print("nemotron admission of %d: temporaries %d bytes"
+          % (width, mem.temp_size_in_bytes))
+    assert mem.temp_size_in_bytes < limit
+    assert mem.temp_size_in_bytes + 9.97e9 + 0.06e9 < 15.75 * 2 ** 30
 
 
 @pytest.fixture(scope="module")
