@@ -29,11 +29,16 @@ labelled with the device.
                                    (kernels interpreted off-TPU); the last
                                    line still reports the device truthfully
 
-With default settings NO Pallas kernel is on paths A/B (flash attention
-needs cfg.use_flash_kernel and T >= 8192, the paged kernel needs
-MXNET_PAGED_DECODE_PALLAS=1; latent_decode and latent_row_store are on
-every "mla" layer's decode path, and neither model here has one): kernel
-coverage is phase K alone.
+Kernels on paths A/B with default settings: the LM train step's causal
+attention is kernels/flash_attention.py's forward and fused backward
+wherever its shapes give them blocks (heads a multiple of 128 wide, T
+from 1,024 on and a multiple of 128: transformer.py
+causal_attention_blocks), and the LM's decode contraction over dense K/V
+rows is kernels/kv_decode.py by the same kind of rule (kv_decode_block).
+Not on them: the paged kernel (MXNET_PAGED_DECODE_PALLAS=1), flash_decode
+(cfg.use_flash_kernel), and latent_decode, latent_row_store and
+grouped_matmul, which sit on every "mla" and every expert layer's path
+(neither model here has one). Phase K runs every kernel alone.
 """
 
 import argparse

@@ -24,8 +24,10 @@ NEG_INF = -1e30
 # squeezed out of the block violates that, so [rows, 128] is the
 # lowerable layout (same choice as jax's reference TPU kernels). The
 # rule's "equal to the array dim" clause also admits [rows, 1] blocks
-# at 1/128th the stat HBM traffic (the dk/dv kernel re-streams lse and
-# delta once per q block) — env-overridable for an on-chip A/B (not measured).
+# at 1/128th the stat HBM traffic — env-overridable for an on-chip A/B
+# (not measured). The ring's carry, the decode kernels and the paged
+# kernel use it; the training forward and backward of
+# flash_attention.py keep their statistics as ROWS [1, rows] in HBM.
 STAT_LANES = int(os.environ.get("MXNET_FLASH_STAT_LANES", "128"))
 
 MIN_BLOCK = 8           # below this the grid is degenerate, not tiled

@@ -1345,6 +1345,12 @@ class ContinuousBatcher(object):
             # counted while spans record (_count_kv_contractions)
             for name in ("kv.decode_kernel", "kv.decode_reference"):
                 snap[name] = _obs.counter(name).value
+        if self._kv_layers or self._latent_layers:
+            # counted as a program is traced (tf._causal_attention): the
+            # whole-prompt prefill of generate() and the training forward
+            # in this process, which the batcher's own programs never run
+            for name in ("attn.causal_kernel", "attn.causal_reference"):
+                snap[name] = _obs.counter(name).value
         if self._rings:
             # counted while spans record (_count_kv_rows)
             for name in ("kv.rows_read", "kv.rows_live", "kv.rows_ring"):

@@ -195,10 +195,12 @@ class TransformerConfig:
     hc_clamp_min: float = -30.0
     hc_clamp_max: float = 30.0
     use_ring_attention: bool = True
-    # attention through the Pallas flash kernel (kernels/
-    # flash_attention.py): single-device dense path AND the per-shard
-    # block compute inside ring attention; sequences (or ring shards)
-    # must divide the kernel's blocks
+    # decode through kernels/flash_attention.py flash_decode, and the
+    # per-shard block compute inside ring attention through its carry
+    # kernel (ring shards must divide the kernel's blocks). The
+    # single-device causal attention of training and prefill does not
+    # ask: it takes the flash kernels by its shapes
+    # (causal_attention_blocks)
     use_flash_kernel: bool = False
     # activation recompute: checkpoint each transformer layer so backward
     # rematerializes its activations instead of storing them (the
@@ -790,26 +792,6 @@ def _qkv(x, p):
     return q, k, v
 
 
-def _flash_min_seq():
-    """Sequence-length crossover for the flash-vs-dense dispatch below.
-
-    The only flash-vs-dense chip A/B so far has DENSE winning at
-    T=4096 (PERF.md "Chip numbers of 2026-08-01", `flash_attention`:
-    fwd 16.51 ms dense vs 21.92 flash; fwd+bwd 37.17 vs 44.15 — a
-    claim until re-measured), so a config that requests the
-    flash kernel still routes short sequences to the dense softmax and
-    engages the streamed kernel only where the [T, T] score matrix
-    stops fitting the bandwidth budget. 8192 is the first unmeasured
-    length above that datapoint ("dense dies past 4k" is a claim, not
-    a number: T >= 8192 is not measured); MXNET_FLASH_MIN_SEQ re-pins
-    the crossover."""
-    from .. import _fastenv
-    try:
-        return int(_fastenv.get("MXNET_FLASH_MIN_SEQ", "8192"))
-    except (TypeError, ValueError):
-        return 8192
-
-
 def _paged_pallas_requested():
     """MXNET_PAGED_DECODE_PALLAS=1 routes decode_step_paged /
     verify_chunk_paged through the batched-lane Pallas megakernel
@@ -822,26 +804,45 @@ def _paged_pallas_requested():
         "0", "", "false", "False", None)
 
 
-def _causal_attention(q, k, v, cfg, out_dtype, norm=None, window=None):
-    """Single-device causal attention over [B, T, H, D] — flash kernel
-    (one block when T fits/divides 128, else gcd(T, 128)-sized blocks,
-    so ANY sequence length works) or the dense masked softmax. Shared
-    by training forward and prefill. use_flash_kernel is a REQUEST,
-    not a route: sequences below the measured crossover
-    (MXNET_FLASH_MIN_SEQ, _flash_min_seq above) still take the dense
-    path, which the chip A/B has winning there. `norm` is what the
-    scores are divided by (None = sqrt(D)); `window`: a "window" layer's
-    span, a query seeing that many positions up to its own (the dense
-    path: _window refuses the kernel)."""
-    if cfg.use_flash_kernel and q.shape[1] >= _flash_min_seq():
+def causal_attention_blocks(q, k, v, window=None, mesh=None):
+    """(block_q, block_k) where causal self-attention over q, k, v
+    [B, T, H, D] (arrays, or their shapes and dtypes) runs
+    kernels/flash_attention.py's kernels, forward and backward, by what
+    the call holds: None, and the XLA text stays, for a window layer, for
+    the mesh-sharded forward (GSPMD cannot partition a Pallas call),
+    for q, k, v of more than one shape or dtype (latent self-attention:
+    192-wide keys beside 128-wide values) and for the shapes
+    kernels.flash_attention.flash_blocks has no blocks for (heads no
+    multiple of 128 wide: toy widths; a T under its crossover, where the
+    score plane is small, or one no block divides). _causal_attention
+    counts its calls by this rule as it is traced (attn.causal_kernel /
+    attn.causal_reference)."""
+    if window is not None or mesh is not None \
+            or not q.shape == k.shape == v.shape \
+            or not q.dtype == k.dtype == v.dtype:
+        return None
+    from ..kernels.flash_attention import flash_blocks
+    return flash_blocks(q.shape[1], q.shape[3], q.dtype.itemsize)
+
+
+def _causal_attention(q, k, v, out_dtype, norm=None, window=None,
+                      mesh=None):
+    """Single-device causal attention over [B, T, H, D], shared by the
+    training forward and prefill: the flash kernels (no [B, H, T, T]
+    plane written, saved or read) where causal_attention_blocks has
+    blocks for the call, else the dense masked softmax. `norm` is what
+    the scores are divided by (None = sqrt(D)); `window`: a "window"
+    layer's span, a query seeing that many positions up to its own;
+    `mesh`: the mesh-sharded forward's."""
+    from ..observability import core as _obs
+    blocks = causal_attention_blocks(q, k, v, window, mesh)
+    _obs.counter("attn.causal_kernel" if blocks
+                 else "attn.causal_reference").add(1)
+    if blocks:
         from ..kernels import flash_attention
-        if norm is not None:       # the kernel divides by sqrt(D) itself
-            q = (q * (np.sqrt(q.shape[-1]) / norm)).astype(q.dtype)
-        # block sizing (128 default, MXNET_FLASH_BLOCK_Q/K override,
-        # clamp + gcd for short/odd sequences) lives in
-        # flash_attention itself — one source of truth
-        return flash_attention(q, k, v,
-                               causal=True).astype(out_dtype)
+        return flash_attention(
+            q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1],
+            scale=None if norm is None else 1.0 / norm).astype(out_dtype)
     T = q.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32)
@@ -885,7 +886,7 @@ def _attention(x, p, cfg, mesh, manual_sp=False, rotate=True, window=None):
                                    causal=True,
                                    use_flash_kernel=cfg.use_flash_kernel)
     else:
-        o = _causal_attention(q, k, v, cfg, x.dtype, window=window)
+        o = _causal_attention(q, k, v, x.dtype, window=window, mesh=mesh)
     return jnp.einsum("bthk,hkd->btd", o, p["wo"])
 
 
@@ -1974,7 +1975,7 @@ def _latent_self_attention(cfg):
     def contract(q, layer, rows, p):
         k, v = _latent_up(rows, p, cfg)
         return _causal_attention(
-            q, k, v, cfg, q.dtype, None if cfg.rope_scaling is None
+            q, k, v, q.dtype, None if cfg.rope_scaling is None
             else _latent_score_norm(cfg, q.shape[-1]))
     return contract
 
@@ -2093,11 +2094,12 @@ def prefill(params, cache, tokens, cfg):
     def read(q, layer, k, v):
         # self-attention over the fresh K/V: at position 0 the rows
         # just stored are all there is to read
-        if _attn_blocked(t_p, t_p, cfg) and not cfg.use_flash_kernel:
+        rk, rv = _repeat_kv(k, g), _repeat_kv(v, g)
+        if _attn_blocked(t_p, t_p, cfg) \
+                and causal_attention_blocks(q, rk, rv) is None:
             with _attn_scope(None):
                 return _blocked_attention(q, k, v, at)
-        return _causal_attention(q, _repeat_kv(k, g), _repeat_kv(v, g),
-                                 cfg, q.dtype)
+        return _causal_attention(q, rk, rv, q.dtype)
 
     def window_read(q, layer, k, v):
         with _attn_scope(_window(cfg)):

@@ -91,8 +91,6 @@ FINGERPRINT_ENVS = (
     "MXNET_KV_BLOCK_SIZE",
     "MXNET_KV_PAGED",
     "MXNET_SPEC_K",
-    "MXNET_FLASH_BLOCK_Q",
-    "MXNET_FLASH_BLOCK_K",
     "MXNET_FLASH_STAT_LANES",
 )
 

@@ -5,12 +5,17 @@ the reference keeps where codegen fell short; here the set is small and
 Pallas-based (kernels/).
 """
 
+import importlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
 from mxnet_tpu.kernels import flash_attention
+
+# the package re-exports the function under the module's own name
+_fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
 
 
 def _dense_attention(q, k, v, causal):
@@ -83,71 +88,202 @@ def test_flash_attention_gcd_adjusts_ragged_blocks():
         flash_attention(z, z, z, block_q=16, block_k=16)
 
 
-def test_transformer_flash_kernel_matches_dense_path(monkeypatch):
-    # pin the crossover to 0 so T=32 actually exercises the kernel
-    # (the shipped default routes short sequences dense — see
-    # test_flash_crossover_dispatch)
-    monkeypatch.setenv("MXNET_FLASH_MIN_SEQ", "0")
+def _wide_lm(**kw):
+    """A model whose heads are 128 wide, so that the route's rule
+    (transformer.causal_attention_blocks) has blocks for its attention
+    from kernels.flash_attention.MIN_SEQ positions on."""
     from mxnet_tpu.models import transformer as T
-    cfg_dense = T.TransformerConfig(
-        vocab_size=50, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=32,
-        dp_axis=None, tp_axis=None, sp_axis=None, ep_axis=None,
-        use_ring_attention=False, use_flash_kernel=False)
-    cfg_flash = T.TransformerConfig(
-        vocab_size=50, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=32,
-        dp_axis=None, tp_axis=None, sp_axis=None, ep_axis=None,
-        use_ring_attention=False, use_flash_kernel=True)
-    params = T.init_params(cfg_dense, seed=3)
-    toks = jnp.asarray(
-        np.random.RandomState(0).randint(0, 50, (2, 32)))
-    dense = T.forward(params, toks, cfg_dense)
-    flash = T.forward(params, toks, cfg_flash)
+    cfg = T.TransformerConfig(
+        vocab_size=50, d_model=256, n_heads=2, n_layers=1, d_ff=64,
+        max_len=2 * _fa.MIN_SEQ, dp_axis=None, tp_axis=None, sp_axis=None,
+        ep_axis=None, use_ring_attention=False, **kw)
+    return cfg, T.init_params(cfg, seed=3)
+
+
+def _tokens(t, batch=1):
+    return jnp.asarray(np.random.RandomState(0).randint(0, 50, (batch, t)))
+
+
+def test_transformer_flash_kernel_matches_dense_path(monkeypatch):
+    """The training forward at the crossover length takes the kernels
+    and reads what the XLA text reads (the rule switched off)."""
+    from mxnet_tpu.models import transformer as T
+    cfg, params = _wide_lm()
+    toks = _tokens(_fa.MIN_SEQ)
+    assert T.causal_attention_blocks(
+        *[jax.ShapeDtypeStruct((1, _fa.MIN_SEQ, 2, 128), jnp.float32)] * 3)
+    flash = T.forward(params, toks, cfg)
+    monkeypatch.setattr(T, "causal_attention_blocks",
+                        lambda *a, **kw: None)
+    dense = T.forward(params, toks, cfg)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_flash_crossover_dispatch(monkeypatch):
-    """use_flash_kernel is a request, not a route: sequences below
-    MXNET_FLASH_MIN_SEQ take the dense softmax (the chip A/B has dense
-    winning at T=4096), sequences at/above it take the kernel — and
-    BOTH route choices produce the same numbers as the dense config."""
+    """The route follows the shapes alone: under the crossover
+    (kernels.flash_attention.MIN_SEQ) the dense softmax, from it on the
+    kernels, a T the block does not divide the dense softmax again, and
+    a toy width the dense softmax whatever use_flash_kernel says (the
+    field stopped deciding this route)."""
     import mxnet_tpu.kernels as kernels
     from mxnet_tpu.models import transformer as T
     calls = []
     real = kernels.flash_attention
 
     def spy(*a, **kw):
-        calls.append(1)
+        calls.append((a[0].shape, kw))
         return real(*a, **kw)
 
     monkeypatch.setattr(kernels, "flash_attention", spy)
+    cfg, params = _wide_lm()
+    shapes = lambda t: jax.eval_shape(
+        lambda p, x: T.forward(p, x, cfg), params, _tokens(t))
+
+    shapes(_fa.MIN_SEQ // 2)
+    assert not calls
+    shapes(_fa.MIN_SEQ)
+    assert len(calls) == 1 and calls[0][0] == (1, _fa.MIN_SEQ, 2, 128)
+    assert (calls[0][1]["block_q"], calls[0][1]["block_k"]) \
+        == _fa.flash_blocks(_fa.MIN_SEQ, 128, 4)
+    shapes(_fa.MIN_SEQ + 64)           # 128 does not divide it
+    assert len(calls) == 1
+
+    # toy heads keep the XLA text with the field set, and its numbers
     kw = dict(vocab_size=50, d_model=32, n_heads=2, n_layers=1,
               d_ff=64, max_len=32, dp_axis=None, tp_axis=None,
               sp_axis=None, ep_axis=None, use_ring_attention=False)
     cfg_dense = T.TransformerConfig(use_flash_kernel=False, **kw)
     cfg_flash = T.TransformerConfig(use_flash_kernel=True, **kw)
-    params = T.init_params(cfg_dense, seed=3)
-    toks = jnp.asarray(np.random.RandomState(0).randint(0, 50, (2, 32)))
-    dense = np.asarray(T.forward(params, toks, cfg_dense))
+    toy = T.init_params(cfg_dense, seed=3)
+    toks = _tokens(32, batch=2)
+    np.testing.assert_array_equal(
+        np.asarray(T.forward(toy, toks, cfg_flash)),
+        np.asarray(T.forward(toy, toks, cfg_dense)))
+    assert len(calls) == 1
 
-    # default crossover (8192): T=32 must route DENSE despite the
-    # flash request — no kernel call, identical numbers
-    assert T._flash_min_seq() == 8192
-    below = T.forward(params, toks, cfg_flash)
-    assert not calls
-    np.testing.assert_allclose(np.asarray(below), dense, rtol=2e-4,
-                               atol=2e-4)
 
-    # crossover at/below T: the kernel engages, numerics still match
-    monkeypatch.setenv("MXNET_FLASH_MIN_SEQ", "32")
-    above = T.forward(params, toks, cfg_flash)
-    assert calls
-    np.testing.assert_allclose(np.asarray(above), dense, rtol=2e-4,
-                               atol=2e-4)
+def _causal_oracle(q, k, v):
+    """float32 causal attention over [B, T, H, D] as plain jax."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
-    # malformed env falls back to the default rather than crashing
-    monkeypatch.setenv("MXNET_FLASH_MIN_SEQ", "not-a-number")
-    assert T._flash_min_seq() == 8192
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_flash_kernels_match_the_dense_oracle(dtype, tol, blocks):
+    """The forward and all three gradients of the fused backward, heads
+    of 128, at 1, 2 and 4 blocks a side (1 + 3 + 10 live pairs; with 2
+    and 4 a pair under the diagonal skips the mask), against the float32
+    oracle on the same rounded inputs: bfloat16 operands into every dot,
+    the probabilities and dS cast to them, float32 accumulators. The
+    widest gap as a share of the largest entry: a few float32 roundings,
+    or a few of bfloat16's (2^-8 each)."""
+    rng = np.random.RandomState(5)
+    b, t, h, d = 1, 256, 2, 128
+    q, k, v = [jnp.asarray(rng.randn(b, t, h, d) * 0.5, dtype)
+               for _ in range(3)]
+    w = jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+
+    def loss(attend, cast):
+        def f(q, k, v):
+            o = attend(cast(q), cast(k), cast(v))
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    block = t // blocks
+    (_, o), grads = loss(
+        lambda *a: flash_attention(*a, causal=True, block_q=block,
+                                   block_k=block), lambda x: x)(q, k, v)
+    assert o.dtype == dtype and all(g.dtype == dtype for g in grads)
+    (_, want), want_grads = loss(
+        _causal_oracle, lambda x: x.astype(jnp.float32))(q, k, v)
+    for got, ref in zip((o,) + grads, (want,) + want_grads):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        gap = np.abs(got - ref).max() / np.abs(ref).max()
+        print("gap %.2e of the largest entry" % gap)
+        assert gap < tol
+
+
+def test_flash_kernels_take_unequal_blocks():
+    """1,024 x 512-style blocks: a live pair whose upper rows see no key
+    of the block, and a key block whose first query block is not the
+    one above it."""
+    rng = np.random.RandomState(6)
+    q, k, v = [jnp.asarray(rng.randn(1, 128, 1, 16), jnp.float32)
+               for _ in range(3)]
+
+    def grads(bq, bk):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(flash_attention(
+            *a, causal=True, block_q=bq, block_k=bk))), (0, 1, 2))(q, k, v)
+
+    for bq, bk in ((64, 32), (32, 64), (128, 16)):
+        for got, ref in zip(grads(bq, bk), grads(32, 32)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=2e-4, atol=2e-5)
+
+
+CELL = (4, 2048, 16, 128)        # cerebras-gpt-1.3b-train-8k's q, k, v
+
+
+@pytest.mark.parametrize("q,k,v,kw,kernel", [
+    (CELL, CELL, CELL, {}, True),
+    ((4, 256, 16, 128),) * 3 + ({}, False),
+    ((4, 2048, 32, 64),) * 3 + ({}, False),
+    ((4, 2048 + 64, 16, 128),) * 3 + ({}, False),
+    (CELL, CELL, CELL, {"window": 512}, False),
+    ((4, 2048, 16, 192), (4, 2048, 16, 192), CELL, {}, False),
+    (CELL, CELL, (jnp.float32,) + CELL, {}, False),
+    (CELL, CELL, CELL, {"mesh": "a mesh"}, False),
+], ids=["cell", "T256", "D64", "T-undivided", "window", "latent-widths",
+        "mixed-dtypes", "mesh"])
+def test_the_causal_route_follows_the_shapes(q, k, v, kw, kernel):
+    """transformer.causal_attention_blocks, the route's rule, and the
+    counters _causal_attention adds to by it as a program is traced."""
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.observability import core as obs
+
+    def sds(shape):
+        dtype = jnp.bfloat16
+        if not isinstance(shape[0], int):
+            dtype, shape = shape[0], shape[1:]
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    q, k, v = sds(q), sds(k), sds(v)
+    blocks = T.causal_attention_blocks(q, k, v, **kw)
+    assert (blocks is not None) == kernel
+    if kernel:
+        assert blocks == (_fa.BLOCK_Q, _fa.BLOCK_K) \
+            and q.shape[1] % blocks[0] == q.shape[1] % blocks[1] == 0
+    names = ("attn.causal_kernel", "attn.causal_reference")
+    before = [obs.counter(name).value for name in names]
+    out = jax.eval_shape(
+        lambda *a: T._causal_attention(*a, jnp.bfloat16, **kw),
+        q, k, v)
+    assert out.shape == q.shape[:3] + v.shape[3:]
+    after = [obs.counter(name).value for name in names]
+    assert [b - a for a, b in zip(before, after)] \
+        == [int(kernel), int(not kernel)]
+
+
+def test_prefill_takes_the_kernels_for_a_long_prompt():
+    """generate()'s whole-prompt prefill of a 128-wide model at a prompt
+    of MIN_SEQ tokens runs the kernel's forward (counted), and its last
+    logits are the training forward's."""
+    from mxnet_tpu.models import transformer as T
+    from mxnet_tpu.observability import core as obs
+    cfg, params = _wide_lm()
+    toks = _tokens(_fa.MIN_SEQ)
+    before = obs.counter("attn.causal_kernel").value
+    logits, _ = T.prefill(params, T.init_cache(cfg, 1), toks, cfg)
+    assert obs.counter("attn.causal_kernel").value == before + 1
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(T.forward(params, toks, cfg))[:, -1],
+        rtol=2e-3, atol=2e-3)
 
 
 def test_pallas_module_consumer():

@@ -57,7 +57,21 @@ grouped matmuls are the kernel) at its own text. All twenty older hashes stand: 
 the mixer where the block has one and the feed-forward where its
 parameters hold "ln2", `_mlp` / `_expert_ffn` take the ungated form from
 a table, and a configuration that states neither `mixer_ffn=False`, an
-"ffn" or "mamba2" block nor `ffn="relu2"` lowers to the text it had."""
+"ffn" or "mamba2" block nor `ffn="relu2"` lowers to the text it had.
+
+PR 51 re-pinned `cerebras.train-step` on purpose: the causal attention
+of its six layers, forward and backward, became the two kernels of
+kernels/flash_attention.py (flash_fwd, flash_bwd; interpreted in the
+text a CPU lowers) where it was XLA's float32 [4, 16, 2048, 2048] score
+plane, by the rule of shapes behind _causal_attention
+(transformer.causal_attention_blocks: heads a multiple of 128 wide, T a
+multiple of 128 from kernels.flash_attention.MIN_SEQ on, one shape and
+dtype, no window, no mesh). The other twenty-two hashes stand, the three
+`*.forward-256` and the three `*.prefill-*` among them: their T lies
+under the crossover (and Kimi-Linear's latent layers hold two head
+widths, Jamba2's and Nemotron's forwards 256 positions), so they lower
+to the XLA text, letter for letter; no decode or admission program
+reaches _causal_attention at all."""
 
 import hashlib
 import importlib
@@ -189,7 +203,7 @@ PROGRAMS = {
 AT_THE_PARENT = {
     "cerebras.admission-512": "aba915c111ccf1db",
     "cerebras.decode": "f8fe6738e9d6206d",        # PR 48: kv_decode
-    "cerebras.train-step": "42a91385a6f26e39",
+    "cerebras.train-step": "63f4be28e7c62c2c",    # PR 51: flash_fwd/bwd
     "jamba.admission-256": "1deb540c67da2cc8",
     "jamba.decode": "169f587ab80ff84e",
     # PR 44: the expert layers' grouped matmuls are the kernel moe_gmm

@@ -27,7 +27,7 @@ from mxnet_tpu.parallel import ring
 # the package re-exports the function under the module's own name
 fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
 
-B, T, H, D = 2, 8192, 16, 128          # flash: where transformer.py routes
+B, T, H, D = 4, 2048, 16, 128          # flash: the LM training cell's q, k, v
 DEC_B, DEC_T = 8, 4096                 # decode: bs 8 against a 4k cache
 NB, BS, KVH = 2048, 16, 4              # paged pool [NB, BS, KVH, D]
 
@@ -78,20 +78,82 @@ def _moved(text, shapes):
             and any(shape in line for shape in shapes)]
 
 
+def _prefetch(line):
+    """Whether a `copy-start` line only moves an array to another memory
+    space (XLA's own prefetch into the alternate memory, `S(n)` in the
+    layout) and keeps its order: no relayout, and off the critical path."""
+    import re
+    shapes = re.findall(r"[a-z0-9]+\[[0-9,]*\]\{[^}]*\}",
+                        line.split(" copy-start(")[0])
+    return " copy-start(" in line and len(shapes) >= 2 \
+        and re.sub(r"S\(\d\)", "", shapes[0]) \
+        == re.sub(r"S\(\d\)", "", shapes[1])
+
+
 def test_flash_attention_forward(one_chip):
     q = _sds(one_chip, (B, T, H, D))
     _compile(functools.partial(fa.flash_attention, causal=True,
                                interpret=False), q, q, q)
 
 
-def test_flash_attention_forward_backward(one_chip):
-    q = _sds(one_chip, (B, T, H, D))
+@pytest.mark.parametrize("batch,rows", [(B, T), (1, 16384), (8, None)],
+                         ids=["cell", "longest", "shortest"])
+def test_flash_attention_forward_backward(one_chip, batch, rows):
+    """At the training cell's shape and at the ends of the rule: the
+    longest sequence flash_blocks hands the kernels (a head's dQ
+    accumulator is 8 MB of the backward's VMEM) and the shortest."""
+    rows = rows or fa.MIN_SEQ
+    assert fa.flash_blocks(rows, D, 2) is not None
+    assert fa.flash_blocks(2 * 16384, D, 2) is None
+    q = _sds(one_chip, (batch, rows, H, D))
 
     def loss(q_, k_, v_):
         o = fa.flash_attention(q_, k_, v_, causal=True, interpret=False)
         return o.astype(jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_cerebras_train_step_holds_no_score_plane(one_chip, monkeypatch):
+    """The WHOLE training step of cerebras-gpt-1.3b-train-8k at its
+    [4, 2048] tokens: every layer's causal attention is the two flash
+    kernels (6 forward + 6 fused backward calls), no [B, H, T, T] array
+    exists anywhere in the compiled program, q, k, v, o and their
+    gradients pass between the projections' matmuls and the kernels in
+    the order the matmuls leave them (no copy or transpose of an array
+    of their size), and the temporaries are 5.41 GB where the XLA text's
+    six saved planes made them 11.87."""
+    import json
+    import os
+    from chipbench.reference import cerebras_gpt as ref
+    from chipbench.runners import lm_common
+    from mxnet_tpu.models import transformer as tf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "cerebras-gpt-1.3b-train.json")) as f:
+        config = json.load(f)
+    params = ref.as_tree({leaf: _sds(one_chip, shape) for leaf, shape, _
+                          in ref.leaf_specs(config)}, config)
+    mom = jax.tree.map(lambda x: _sds(one_chip, x.shape, jnp.float32),
+                       params)
+    step = tf.make_train_step(lm_common.program_config(config), lr=0.01)
+    compiled = step.lower(params, mom,
+                          _sds(one_chip, (B, T), jnp.int32)).compile()
+    text = compiled.as_text()
+    layers = config["n_layer"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * layers
+    assert text.count("flash_fwd/pallas_call") >= layers \
+        and text.count("flash_bwd/pallas_call") >= layers
+    assert "[%d,%d,%d,%d]" % (B, H, T, T) not in text
+    moved = [line for line in _moved(
+        text, ("[%d,%d,%d,%d]" % (B, T, H, D), "[%d,%d,%d,%d]" % (B, H, T, D)))
+        if not _prefetch(line)]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    print("cerebras train step: temporaries %d bytes, arguments %d"
+          % (mem.temp_size_in_bytes, mem.argument_size_in_bytes))
+    assert mem.temp_size_in_bytes < 6e9
 
 
 @pytest.mark.parametrize("kv_heads", [H, 2], ids=["mha", "gqa"])
@@ -104,7 +166,7 @@ def test_flash_decode(one_chip, kv_heads):
 
 
 def test_flash_carry_block(one_chip):
-    """The ring's per-round update at an sp=4 shard of T 8192."""
+    """The ring's per-round update at an sp=4 shard of T 2048."""
     bh, t_shard = B * H, T // 4
     q = _sds(one_chip, (bh, t_shard, D))
     o = _sds(one_chip, (bh, t_shard, D), jnp.float32)
