@@ -390,6 +390,11 @@ def _jitted_table_entry(cfg):
 # controller compares against MXNET_SPEC_ACCEPT_FLOOR
 _SPEC_EWMA_ALPHA = 0.3
 
+# the gap ledger's counters, in the order of a sync's sums
+# (ContinuousBatcher._note_progress, _count_gaps)
+_GAP_COUNTERS = ("serving.gaps", "serving.gap_ns",
+                 "serving.gaps_behind_admit", "serving.gap_admit_ns")
+
 
 def _ngram_propose(hist, tok, pos, keff, k, ng):
     """Prompt-lookup self-drafting (device-side, static-shape): for
@@ -774,7 +779,8 @@ class BlockAllocator(object):
 class Request(object):
     __slots__ = ("rid", "tokens", "n_new", "emitted", "stop_token",
                  "seed", "priority", "key", "t_enq_ns", "t_admit_ns",
-                 "t_first_ns", "t_last_ns", "slo_bad")
+                 "t_first_ns", "t_last_ns", "admit_clock", "admit_seq",
+                 "slo_bad")
 
     def __init__(self, rid, prompt, n_new, stop_token=None, seed=0,
                  priority=0, key=None):
@@ -786,12 +792,16 @@ class Request(object):
         self.seed = seed             # sampling seed (requeue needs it)
         self.priority = int(priority)  # larger = more important
         self.key = key               # idempotency key (dedup window)
-        # request-lifecycle clock (perf_counter_ns; None with obs off):
-        # enqueue -> admit -> first token -> last host-visible token
+        # request-lifecycle clock (perf_counter_ns; None while no span
+        # records): enqueue -> admit -> first token -> last host-visible
+        # token, and the batcher's admission ledger as it stood at that
+        # token (ContinuousBatcher._stamp)
         self.t_enq_ns = None
         self.t_admit_ns = None
         self.t_first_ns = None
         self.t_last_ns = None
+        self.admit_clock = None
+        self.admit_seq = None
         self.slo_bad = False         # any observation missed its SLO
 
     @property
@@ -1146,6 +1156,14 @@ class ContinuousBatcher(object):
         # first admission — feeds the serving.goodput_tok_s gauge
         self._completed_tokens = 0
         self._t_serve_start_ns = None
+        # the gap ledger, kept while spans record: the summed duration
+        # and the count of the admit() / admit_continuation() calls that
+        # admitted a request. Every request is stamped with both beside
+        # its last token's time, so a delivery knows whose admissions it
+        # waited behind (_note_admit, _note_progress)
+        self._admit_clock_ns = 0
+        self._admit_seq = 0
+        self._ledger_on = False
         # weight-version identity (integrity.tree_fingerprint over the
         # served params) — lazily computed once, cached: replicas of
         # one fleet must agree, and the router checks they do
@@ -1614,11 +1632,8 @@ class ContinuousBatcher(object):
 
     def _fresh_row(self, cfg=None):
         """A zeroed one-lane row of `cfg` (the target's, or the draft's)
-        in one launch (_jitted_fresh_row). While spans record, the
-        counter serving.fresh_rows counts them: one an admission that
-        found no cached prefix to start from."""
-        if _obs.active():
-            _obs.counter("serving.fresh_rows").add(1)
+        in one launch (_jitted_fresh_row): what an admission that found
+        no cached prefix starts from."""
         return _jitted_fresh_row(self.cfg if cfg is None else cfg)()
 
     def _lookup_prefix(self, prompt):
@@ -1765,8 +1780,8 @@ class ContinuousBatcher(object):
         # the sampling path below rebinds `key` to the PRNG chain —
         # keep the idempotency key under its own name past that point
         idem_key = key
-        obs_on = _obs.enabled()
-        t0_ns = time.perf_counter_ns() if obs_on else None
+        recording = self._ledger_gate()
+        t0_ns = time.perf_counter_ns() if recording else None
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         t_p = len(prompt)
         if t_p < 1:
@@ -1882,7 +1897,11 @@ class ContinuousBatcher(object):
                 self._patch_fn(self._dev_tok, self._dev_pos,
                                self._dev_keys, jnp.int32(slot),
                                first_dev, jnp.int32(t_p), key)
-        first = int(first_dev)
+        # the admission's launches are out: what is left is the host
+        # waiting for the device
+        with _obs.span("serving.first_token", cat="serving", rid=rid,
+                       lane=slot):
+            first = int(first_dev)
         self._pos[slot] = t_p          # next decode writes position t_p
         if self._spec_on:
             self._spec_admit(slot, prompt, t_p, first)
@@ -1903,7 +1922,7 @@ class ContinuousBatcher(object):
                 req.rid, req.tokens, n_new, seed=seed,
                 stop_token=stop_token, priority=priority,
                 key=idem_key, emitted=1)
-        if obs_on:
+        if recording:
             self._note_admit(req, slot, t0_ns, enqueued_ns)
         return req.rid
 
@@ -1933,7 +1952,8 @@ class ContinuousBatcher(object):
             raise ValueError(
                 "a continuation resumes a stream that emitted at "
                 "least its first token (emitted >= 1)")
-        obs_on = _obs.enabled()
+        recording = self._ledger_gate()
+        t0_ns = time.perf_counter_ns() if recording else None
         tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
         m = len(tokens) - 1
         if m < 1:
@@ -1994,17 +2014,18 @@ class ContinuousBatcher(object):
                 req.rid, req.tokens, req.n_new, seed=seed,
                 stop_token=stop_token, priority=priority, key=key,
                 emitted=emitted)
-        if obs_on:
-            t1 = time.perf_counter_ns()
-            req.t_admit_ns = req.t_first_ns = req.t_last_ns = t1
-            if preempted_ns is not None:
-                _obs.histogram("serving.preempt_stall_ms", "ms") \
-                    .observe((t1 - preempted_ns) / 1e6)
-            _obs.record_instant(
-                "serving.resumed", cat="serving",
-                args={"rid": rid, "lane": slot, "resume_pos": m,
-                      "priority": priority})
-            self._publish_occupancy()
+        if recording:
+            t1 = self._stamp_admitted(req, t0_ns)
+            req.t_admit_ns = t1
+            if _obs.enabled():
+                if preempted_ns is not None:
+                    _obs.histogram("serving.preempt_stall_ms", "ms") \
+                        .observe((t1 - preempted_ns) / 1e6)
+                _obs.record_instant(
+                    "serving.resumed", cat="serving",
+                    args={"rid": rid, "lane": slot, "resume_pos": m,
+                          "priority": priority})
+                self._publish_occupancy()
         return rid
 
     def _resume_key(self, seed, emitted):
@@ -2345,7 +2366,6 @@ class ContinuousBatcher(object):
         # the whole round: what lies beside dispatch and sync (retire
         # loop, coverage, lane bookkeeping) is this span's self time
         with _obs.span("serving.step", cat="serving"):
-            obs_on = _obs.enabled()
             finished = {}
             if self._pending_finished:
                 # re-delivery of deduped already-finished streams
@@ -2358,7 +2378,7 @@ class ContinuousBatcher(object):
             for i, req in enumerate(self._slots):
                 if req is not None and req.done:
                     finished[req.rid] = list(req.tokens)
-                    if obs_on:
+                    if _obs.enabled():
                         self._note_finish(req)
                     self._note_done(req)
                     self._free(i)
@@ -2593,8 +2613,10 @@ class ContinuousBatcher(object):
                 self._count_latent_rows(pos, live, toks.shape[0])
             if self._rings:
                 self._count_kv_rows(pos, live, toks.shape[0])
-        obs_on = _obs.enabled()
-        t_sync = time.perf_counter_ns() if obs_on else None
+        recording = self._ledger_gate()
+        obs_on = recording and _obs.enabled()
+        t_sync = time.perf_counter_ns() if recording else None
+        gaps = [0, 0, 0, 0] if recording else None
         finished = {}
         for i, rid in enumerate(lanes):
             if rid is None:
@@ -2612,14 +2634,17 @@ class ContinuousBatcher(object):
                 self._journal.append_emit(
                     req.rid, req.tokens[grew - req.emitted:],
                     req.emitted)
-            if t_sync is not None:
-                self._note_progress(req, i, req.emitted - grew, t_sync)
+            if recording:
+                self._note_progress(req, i, req.emitted - grew, t_sync,
+                                    gaps)
             if req.done:
                 finished[req.rid] = list(req.tokens)
-                if t_sync is not None:
+                if obs_on:
                     self._note_finish(req, t_sync)
                 self._note_done(req)
                 self._free(i)
+        if recording:
+            self._count_gaps(gaps)
         if obs_on:
             self._publish_occupancy()
         return finished
@@ -2719,8 +2744,10 @@ class ContinuousBatcher(object):
                        behind=len(self._inflight)):
             targets = np.asarray(targets_dev)      # [rounds, B, k+1]
             emits = np.asarray(emits_dev).astype(np.int64)  # [rounds, B]
-        obs_on = _obs.enabled()
-        t_sync = time.perf_counter_ns() if obs_on else None
+        recording = self._ledger_gate()
+        obs_on = recording and _obs.enabled()
+        t_sync = time.perf_counter_ns() if recording else None
+        gaps = [0, 0, 0, 0] if recording else None
         finished = {}
         rounds = emits.shape[0]
         for i, rid in enumerate(lanes):
@@ -2770,16 +2797,18 @@ class ContinuousBatcher(object):
                         "spec_k", lane=i, frm=k0,
                         to=int(self._keff[i]),
                         accept=round(float(self._accept_ewma[i]), 4))
-            if t_sync is not None:
+            if recording:
                 self._note_progress(req, i, req.emitted - grew0,
-                                    t_sync)
+                                    t_sync, gaps)
             if req.done:
                 finished[req.rid] = list(req.tokens)
-                if t_sync is not None:
+                if obs_on:
                     self._note_finish(req, t_sync)
                 self._note_done(req)
                 self._free(i)
         self._reconcile_pos(emits, lanes)
+        if recording:
+            self._count_gaps(gaps)
         if obs_on:
             _obs.gauge("serving.spec_draft_ratio").set(
                 self._spec_accepted / max(self._spec_drafted, 1))
@@ -3262,16 +3291,55 @@ class ContinuousBatcher(object):
             self._accept_ewma[i] = 1.0
 
     # ---- request-level observability ----
-    # Every caller guards on _obs.enabled(): with telemetry off none of
-    # these run and the batcher pays exactly the guarded branches.
+    # Every caller guards on _ledger_gate() (= _obs.active()): with
+    # neither gate open none of these run, no request is stamped and the
+    # batcher pays exactly the guarded branches. Under a profiler session
+    # alone _note_admit and _note_progress keep the request's stamps and
+    # the gap ledger's four counters; everything that writes a histogram,
+    # the ring or a gauge, _note_finish whole, stays behind
+    # _obs.enabled() (docs/OBSERVABILITY.md "Request lifecycle").
+
+    def _ledger_gate(self):
+        """Do the requests' stamps record (`_obs.active()`)? Where the
+        gate opens with requests in flight, their stamps are from before
+        it closed or were never taken: each is unstamped, stamped anew
+        at its first delivery, and that delivery is not counted."""
+        on = _obs.active()
+        if on != self._ledger_on:
+            self._ledger_on = on
+            if on:
+                for req in self._slots:
+                    if req is not None:
+                        req.t_last_ns = None
+        return on
+
+    def _stamp(self, req, t_ns):
+        """`req`'s newest token became host-visible at `t_ns`, with the
+        admission ledger as it stands."""
+        req.t_last_ns = t_ns
+        req.admit_clock = self._admit_clock_ns
+        req.admit_seq = self._admit_seq
+
+    def _stamp_admitted(self, req, t_admit_ns):
+        """The admitting call that began at `t_admit_ns` returns: the
+        admission ledger moves by its duration BEFORE the new request is
+        stamped, so a request never waits behind itself. -> now."""
+        t1 = time.perf_counter_ns()
+        self._admit_clock_ns += t1 - t_admit_ns
+        self._admit_seq += 1
+        req.t_first_ns = t1
+        self._stamp(req, t1)
+        return t1
 
     def _note_admit(self, req, lane, t_admit_ns, enqueued_ns):
-        """Admission bookkeeping: queue-wait span + histogram, TTFT
-        histogram, and the flow-chain start."""
-        t1 = time.perf_counter_ns()
+        """Admission bookkeeping: the request's stamps and the admission
+        ledger; under telemetry also the queue-wait span + histogram,
+        the TTFT histogram, and the flow-chain start."""
+        t1 = self._stamp_admitted(req, t_admit_ns)
         req.t_enq_ns = enqueued_ns
         req.t_admit_ns = t_admit_ns
-        req.t_first_ns = req.t_last_ns = t1
+        if not _obs.enabled():
+            return
         if self._t_serve_start_ns is None:
             self._t_serve_start_ns = t_admit_ns
         if enqueued_ns is not None:
@@ -3294,31 +3362,56 @@ class ContinuousBatcher(object):
                          args={"rid": req.rid, "lane": lane})
         self._publish_occupancy()
 
-    def _note_progress(self, req, lane, grew, t_ns):
+    def _note_progress(self, req, lane, grew, t_ns, gaps):
         """`grew` tokens of `req` became host-visible at `t_ns` (one
-        chunk sync): inter-token-latency samples — the chunk lands at
-        once, so the gap since the request's previous host-visible
-        token spreads evenly over the chunk — plus the flow step tying
-        this sync into the request's chain."""
+        chunk sync). The gap ledger, into this sync's sums `gaps` =
+        [gaps, gap ns, gaps behind an admission, admission ns]: a chunk
+        of k tokens is one gap of the chunk's time and k-1 of zero (the
+        rule of a client's own count), behind an admission if one
+        returned since the request's last stamp, by that many ns of
+        admitting calls. Under telemetry also the inter-token-latency
+        samples — the gap spread evenly over the chunk — plus the flow
+        step tying this sync into the request's chain."""
         if grew <= 0:
             return
+        t_last = req.t_last_ns
+        if t_last is not None:
+            gaps[0] += grew
+            gaps[1] += t_ns - t_last
+            if req.admit_seq != self._admit_seq:
+                gaps[2] += 1
+                gaps[3] += self._admit_clock_ns - req.admit_clock
+        self._stamp(req, t_ns)
+        if not _obs.enabled():
+            return
         h = _obs.histogram("serving.itl_ms", "ms")
-        gap_ms = ((t_ns - req.t_last_ns) / 1e6 / grew
-                  if req.t_last_ns is not None else 0.0)
+        gap_ms = ((t_ns - t_last) / 1e6 / grew
+                  if t_last is not None else 0.0)
         for _ in range(grew):
             h.observe(gap_ms)
             if _slo.check("itl_ms", gap_ms):
                 req.slo_bad = True
-        req.t_last_ns = t_ns
         _obs.record_flow("serving.request", req.rid, "t",
                          cat="serving",
                          args={"rid": req.rid, "lane": lane,
                                "tokens": grew})
 
+    @staticmethod
+    def _count_gaps(gaps):
+        """One sync's sums into the four counters serving.gaps,
+        serving.gap_ns, serving.gaps_behind_admit, serving.gap_admit_ns:
+        made together at the first counted delivery, so a window without
+        an admission reads 0 behind one and not nothing."""
+        if gaps[0]:
+            for name, n in zip(_GAP_COUNTERS, gaps):
+                _obs.counter(name).add(n)
+
     def _note_finish(self, req, t_ns=None, evicted=False):
         """Request left the pool (finished or evicted): e2e histogram,
         goodput gauge, the flow-chain finish, a finish/evict instant,
-        and the request's SLO verdict into the rolling attainment."""
+        and the request's SLO verdict into the rolling attainment.
+        Nothing of it is a profiler session's: its callers guard on
+        _obs.enabled()."""
         t_ns = time.perf_counter_ns() if t_ns is None else t_ns
         start = req.t_enq_ns if req.t_enq_ns is not None \
             else req.t_admit_ns

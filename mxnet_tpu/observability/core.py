@@ -15,9 +15,12 @@ Two gates, both derived (no knob of their own):
   histograms, instants, flows, the recompile detector.
 * ``active()`` — ``enabled()`` OR a live ``jax.profiler`` session, whoever
   started it. Gates spans alone: while a session is live a span is also a
-  ``TraceAnnotation("mx." + name)``, so it lies on ``/host:CPU`` of the same
-  ``.xplane.pb`` as the device's ``XLA Ops``, and its duration is added to
-  the per-name totals ``span_totals()`` returns.
+  ``TraceAnnotation("mx." + name)`` carrying the span's scalar arguments,
+  so it lies on ``/host:CPU`` of the same ``.xplane.pb`` as the device's
+  ``XLA Ops``, and its duration is added to the per-name totals
+  ``span_totals()`` returns. A collection of Python's collector is such a
+  span too, ``gc`` (one ``gc.callbacks`` hook, ``_on_gc``, which takes no
+  lock; ``_fold_gc`` adds the finished ones from where one may be taken).
 
 Design constraints (ISSUE 2 tentpole):
 
@@ -42,6 +45,7 @@ capacity (read when the ring is (re)built). ``set_enabled()`` overrides
 the env for the profiler state machine (profiler.set_state/pause).
 """
 
+import gc
 import threading
 import time
 
@@ -80,6 +84,9 @@ _cold = {}
 # epoch: where a benchmark's set-up ends (recompile.summary(before=...))
 _first_session_ns = None
 _local = threading.local()
+# what of a span's arguments goes into its annotation (rid, lane, behind,
+# kind): the trace's events carry scalars
+_SCALARS = (int, float, str, bool)
 
 # a live jax.profiler session, whoever started it (~20 ns)
 _session_live = _Annotation.is_enabled
@@ -200,10 +207,15 @@ class span(object):
             except AttributeError:
                 _local.stack = [self]
             if live:
-                self._ann = _Annotation("mx." + self.name)
+                self._ann = _Annotation(
+                    "mx." + self.name,
+                    **{k: v for k, v in self.args.items()
+                       if type(v) in _SCALARS})
                 self._ann.__enter__()
                 if _first_session_ns is None:
                     _first_session_ns = now_ns()
+                if "gc" not in _totals:
+                    _seed_gc()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -218,6 +230,8 @@ class span(object):
             return None
         t1 = time.perf_counter_ns()
         dur = t1 - t0
+        if _gc_done:
+            _fold_gc()
         if self._seq != _recompile.seq:
             _add_cold(self.name, dur)
         stack = getattr(_local, "stack", ())
@@ -256,12 +270,72 @@ def _add_total(name, dur, self_dur):
             t[3] = dur
 
 
+def _seed_gc():
+    """`gc` into the totals with zeros, by the first span that sees a live
+    session and finds none (after a reset too): a window without a
+    collection then reads 0 and not nothing."""
+    with _lock:
+        _totals.setdefault("gc", [0, 0, 0, 0])
+
+
+# The collector's hook takes no lock and opens no `span`: a collection can
+# start inside ANY allocation, one made while `_lock` is held among them,
+# and the hook runs on that thread. It keeps the collection in progress
+# here (the collector runs one at a time, "start" and "stop" on the thread
+# that triggered it) and leaves the finished ones for `_fold_gc`.
+_gc_open = []
+_gc_done = []
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` hook: a collection while spans record is the span
+    ``gc``, an annotation on the profiler's clock under a session, its
+    time charged as child time to whatever span its thread had open.
+    Off, one gate check a collection."""
+    if phase == "start":
+        if active():
+            ann = None
+            if _session_live():
+                ann = _Annotation("mx.gc", generation=info["generation"])
+                ann.__enter__()
+            _gc_open.append((ann, enabled(), info["generation"],
+                             time.perf_counter_ns()))
+    elif _gc_open:
+        ann, ring, generation, t0 = _gc_open.pop()
+        t1 = time.perf_counter_ns()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        stack = getattr(_local, "stack", None)
+        if stack:
+            stack[-1]._child_ns += t1 - t0
+        _gc_done.append((t0, t1, ann is not None, ring, generation,
+                         threading.get_ident()))
+
+
+def _fold_gc():
+    """The finished collections into the totals (those under a session)
+    and the ring (those under ``enabled()``), from where a lock may be
+    taken: a span's stop and the two readers."""
+    while True:
+        try:
+            t0, t1, live, ring, generation, tid = _gc_done.pop(0)
+        except IndexError:      # none left, or another thread folded it
+            return
+        if live:
+            _add_total("gc", t1 - t0, t1 - t0)
+        if ring:
+            _append(("X", "gc", "runtime", (t0 - _EPOCH_NS) // 1000,
+                     max((t1 - t0) // 1000, 0), tid,
+                     {"generation": generation}))
+
+
 def span_totals():
     """{name: {"count", "total_ns", "self_ns", "max_ns"}} of the spans
     that ran under a profiler session since the last reset, and of the
     ``startup.*`` spans (``record_startup``), which record whatever the
     gates. Self time is the total less the spans opened inside it on the
     same thread."""
+    _fold_gc()
     with _lock:
         return {name: {"count": t[0], "total_ns": t[1], "self_ns": t[2],
                        "max_ns": t[3]}
@@ -269,6 +343,7 @@ def span_totals():
 
 
 def reset_span_totals():
+    _fold_gc()
     with _lock:
         _totals.clear()
 
@@ -402,6 +477,7 @@ def histogram(name, unit=""):
 
 def records():
     """Snapshot of ring contents, oldest first."""
+    _fold_gc()
     with _lock:
         if not _ring:
             return []
@@ -438,6 +514,7 @@ def reset():
         _counters.clear()
         _totals.clear()
         _cold.clear()
+        del _gc_done[:]
     from . import histogram as _h
     _h.reset()
     from . import events as _ev
@@ -449,3 +526,5 @@ def reset():
 # the compile ledger's ``seq`` (span.start / stop). recompile imports
 # this module back; every name it uses is defined above.
 from . import recompile as _recompile      # noqa: E402
+
+gc.callbacks.append(_on_gc)

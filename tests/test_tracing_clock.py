@@ -6,6 +6,7 @@ else of the telemetry follows the session; `profiler.Task/Frame/Event` are
 the same span; and `dumps(aggregate=True)` lays the device's launches and
 idle time against the spans of the session's own `.xplane.pb`."""
 
+import gc
 import glob
 import os
 import threading
@@ -53,8 +54,9 @@ class session(object):
     def __exit__(self, *exc):
         jax.profiler.stop_trace()
 
-    def host_events(self, prefixes=("mx.", "bench.")):
-        """[(name, start_ns, end_ns, line)] of `/host:CPU`."""
+    def host_events(self, prefixes=("mx.", "bench."), args=False):
+        """[(name, start_ns, end_ns, line)] of `/host:CPU`; with `args`
+        each with the dict of its event's arguments behind."""
         path = sorted(glob.glob(os.path.join(
             self.dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
         out = []
@@ -64,7 +66,8 @@ class session(object):
                     for e in line.events:
                         if e.name.startswith(prefixes):
                             out.append((e.name, e.start_ns,
-                                        e.start_ns + e.duration_ns, i))
+                                        e.start_ns + e.duration_ns, i)
+                                       + ((dict(e.stats),) if args else ()))
         return out
 
 
@@ -96,21 +99,25 @@ def _batcher(**kw):
                                n_layers=1, d_ff=32, max_len=48,
                                dtype=jax.numpy.float32)
     return ContinuousBatcher(tf.init_params(cfg, seed=0), cfg,
-                             max_batch=2, **kw)
+                             **dict({"max_batch": 2}, **kw))
 
 
 # what the batcher counts while its spans record (models/serving.py
-# _count_dispatch, _fresh_row and _count_prefill; since PR 48 a model
-# with K/V layers, as this one, its decode contractions by the path they
-# took, _count_kv_contractions; a model with routed experts adds moe.*,
-# one with latent layers mla.*, one with window layers kv.rows_*, one
-# with hyper-connections hc.rows), and since PR 49 what a recorded call
-# of a hybridized block hands its backward (cached_op.py)
-WHILE_SPANS_RECORD = {"serving.dispatches", "serving.dispatch_ahead",
-                      "serving.fresh_rows", "serving.prefill_tokens",
-                      "serving.prefill_rows", "kv.decode_kernel",
-                      "kv.decode_reference", "cachedop.recorded_calls",
-                      "cachedop.saved_buffers", "cachedop.saved_bytes"}
+# _count_dispatch and _count_prefill; since PR 48 a model with K/V layers,
+# as this one, its decode contractions by the path they took,
+# _count_kv_contractions; a model with routed experts adds moe.*, one with
+# latent layers mla.*, one with window layers kv.rows_*, one with
+# hyper-connections hc.rows; since PR 52 the gap ledger's four,
+# _count_gaps, made together at the first counted delivery), and since
+# PR 49 what a recorded call of a hybridized block hands its backward
+# (cached_op.py)
+GAP_LEDGER = ("serving.gaps", "serving.gap_ns",
+              "serving.gaps_behind_admit", "serving.gap_admit_ns")
+WHILE_SPANS_RECORD = set(GAP_LEDGER) | {
+    "serving.dispatches", "serving.dispatch_ahead",
+    "serving.prefill_tokens", "serving.prefill_rows", "kv.decode_kernel",
+    "kv.decode_reference", "cachedop.recorded_calls",
+    "cachedop.saved_buffers", "cachedop.saved_bytes"}
 
 
 def _serve(srv, rounds):
@@ -126,10 +133,22 @@ STEPS = ROUNDS = 3
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """Span totals and host events of STEPS Gluon steps and ROUNDS rounds
-    of two requests, each under a bare profiler session (warmed first)."""
+    of two requests, each under a bare profiler session (warmed first).
+    The collector is held still meanwhile: a collection is a span of its
+    own since PR 52, and one inside a phase would take its time out of
+    the self times the cases below compare exactly."""
     os.environ.pop("MXNET_OBS", None)
     core.set_enabled(None)
     core.reset()
+    gc.disable()
+    try:
+        return _traced(tmp_path_factory)
+    finally:
+        gc.enable()
+        core.reset()
+
+
+def _traced(tmp_path_factory):
     out = {"records": []}
     step = _gluon_step()
     step()
@@ -149,7 +168,6 @@ def traced(tmp_path_factory):
             _serve(srv, ROUNDS)
         out[name] = (core.span_totals(), s.host_events())
         out["records"] += core.records()
-    core.reset()
     return out
 
 
@@ -162,12 +180,22 @@ def test_no_session_and_no_knob_records_nothing(dark):
     assert core.span("x").start().stop() is None
     step = _gluon_step()
     step()
-    _serve(_batcher(), 2)
+    srv = _batcher()
+    _serve(srv, 2)
+    gc.collect()
     assert core.records() == []
-    # but for the start-up spans, which record whatever the gates
+    # but for the start-up spans, which record whatever the gates (no
+    # `gc` among them: the collector's hook opened nothing)
     assert {"startup.batcher"} <= set(core.span_totals()) \
         <= {"startup.batcher", "startup.backend"}
     assert core.counters() == {}
+    # no request was stamped and the admission ledger never moved
+    live = [r for r in srv._slots if r is not None]
+    assert len(live) == 2 and all(r.emitted > 1 for r in live)
+    for r in live:
+        assert (r.t_admit_ns, r.t_first_ns, r.t_last_ns, r.admit_clock,
+                r.admit_seq) == (None,) * 5
+    assert (srv._admit_seq, srv._admit_clock_ns) == (0, 0)
 
 
 def test_a_session_switches_spans_on_and_nothing_else(dark, tmp_path):
@@ -517,3 +545,292 @@ def test_every_step_variant_is_one_serving_step_a_round(dark, tmp_path, kw):
         "total_ns"]
     assert t["serving.step"]["self_ns"] <= (
         t["serving.step"]["total_ns"] - t["serving.sync"]["total_ns"])
+
+
+# ------------------------------------ the gap ledger (PR 52), scripted ---
+
+ADMIT_NS, ROUND_NS = 7_000_000, 10_000_000
+
+
+class _Clock(object):
+    """serving.py's `time`, scripted: it stands still unless the test
+    moves it, and every admission moves it by ADMIT_NS (below)."""
+
+    def __init__(self):
+        self.t = 1_000_000_000
+
+    def perf_counter_ns(self):
+        return self.t
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from mxnet_tpu.models import serving
+    c = _Clock()
+    monkeypatch.setattr(serving, "time", c)
+    fresh_row = ContinuousBatcher._fresh_row
+
+    def slow_row(self, cfg=None):       # inside admit(), after its t0
+        c.t += ADMIT_NS
+        return fresh_row(self, cfg)
+    monkeypatch.setattr(ContinuousBatcher, "_fresh_row", slow_row)
+    return c
+
+
+def _round(srv, clock):
+    clock.t += ROUND_NS
+    return srv.step()
+
+
+def _ledger():
+    c = core.counters()
+    return tuple(int(c[name].value) if name in c else None
+                 for name in GAP_LEDGER)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_gap_behind_an_admission_is_counted_with_its_duration(
+        dark, tmp_path, clock, depth):
+    """Two lanes decoding, a third admitted between two syncs: both
+    wait behind it, by its admit()'s duration each; its own first gap
+    is not behind itself."""
+    srv = _batcher(max_batch=3, pipeline_depth=depth)
+    with session(tmp_path):
+        srv.admit([3, 4, 5, 6], 40)
+        srv.admit([7, 8, 9], 40)
+        _round(srv, clock)
+        _round(srv, clock)
+        gaps, ns, behind, admit_ns = _ledger()
+        # the first request waited behind the second's admission, once
+        assert (gaps, behind, admit_ns) == (4, 1, ADMIT_NS)
+        assert ns == 4 * ROUND_NS + ADMIT_NS
+        rid = srv.admit([1, 2, 3], 40)
+        new = next(r for r in srv._slots if r.rid == rid)
+        assert (new.admit_seq, new.admit_clock) == (3, 3 * ADMIT_NS)
+        assert new.t_last_ns == clock.t
+        for _ in range(3):
+            _round(srv, clock)
+    assert new.emitted > 1              # its first gaps are among them
+    gaps2, ns2, behind2, admit_ns2 = _ledger()
+    assert behind2 - behind == 2
+    assert admit_ns2 - admit_ns == 2 * ADMIT_NS
+    # 3 rounds of the two older lanes; the new one's tokens of the
+    # in-flight rounds dispatched before it was admitted belong to no one
+    assert gaps2 - gaps == 6 + new.emitted - 1
+    assert ns2 - ns == 2 * (3 * ROUND_NS + ADMIT_NS) \
+        + (clock.t - new.t_first_ns)
+    assert admit_ns2 <= ns2
+
+
+def test_a_chunk_of_k_tokens_is_k_gaps_and_one_behind(dark, tmp_path,
+                                                     clock):
+    srv = _batcher(max_batch=2, chunk_size=3, pipeline_depth=1)
+    with session(tmp_path):
+        srv.admit([3, 4, 5, 6], 40)
+        _round(srv, clock)
+        assert _ledger() == (3, ROUND_NS, 0, 0)
+        srv.admit([7, 8, 9], 40)
+        _round(srv, clock)
+    # the older lane: 3 more gaps, ONE of them behind the admission; the
+    # new lane: its first chunk, behind nothing
+    assert _ledger() == (9, 3 * ROUND_NS + ADMIT_NS, 1, ADMIT_NS)
+
+
+def test_a_session_that_opens_mid_request_skips_its_first_delivery(
+        dark, tmp_path, clock):
+    srv = _batcher(max_batch=2, pipeline_depth=1)
+    srv.admit([3, 4, 5, 6], 40)
+    _round(srv, clock)
+    req = srv._slots[0]
+    assert req.emitted == 2 and req.t_last_ns is None
+    with session(tmp_path):
+        _round(srv, clock)              # stamped here, and not counted
+        assert req.t_last_ns == clock.t and req.admit_seq == 0
+        assert not set(GAP_LEDGER) & set(core.counters())
+        _round(srv, clock)
+        assert _ledger() == (1, ROUND_NS, 0, 0)
+    # and a stamp from a session that has closed is not a gap's start
+    for _ in range(3):
+        _round(srv, clock)
+    assert req.t_last_ns == clock.t - 3 * ROUND_NS
+    with session(tmp_path / "again"):
+        _round(srv, clock)
+        _round(srv, clock)
+    assert _ledger() == (2, 2 * ROUND_NS, 0, 0)
+
+
+def test_a_continuation_moves_the_ledger_as_an_admission_does(
+        dark, tmp_path, clock):
+    srv = _batcher(max_batch=2, pipeline_depth=1)
+    with session(tmp_path):
+        srv.admit([3, 4, 5, 6], 40)
+        _round(srv, clock)
+        rid = srv.admit_continuation([7, 8, 9, 10], 20, emitted=1)
+        new = next(r for r in srv._slots if r.rid == rid)
+        assert (new.admit_seq, new.admit_clock) == (2, 2 * ADMIT_NS)
+        _round(srv, clock)
+    assert _ledger() == (3, 3 * ROUND_NS + ADMIT_NS, 1, ADMIT_NS)
+
+
+# ------------------ the fetch that ends an admission, and what a span's ---
+# ------------------------------------------ arguments look like in a trace
+
+def test_first_token_is_one_span_an_admission_with_its_rid(dark, tmp_path):
+    _serve(_batcher(), 1)                                   # warm
+    core.reset()
+    srv = _batcher()
+    with session(tmp_path) as s:
+        _serve(srv, ROUNDS)
+    t = core.span_totals()
+    assert t["serving.first_token"]["count"] == 2
+    assert t["serving.prefill"]["self_ns"] <= (
+        t["serving.prefill"]["total_ns"]
+        - t["serving.first_token"]["total_ns"])
+    ev = s.host_events(args=True)
+
+    def of(name):
+        return sorted((a, b, args) for n, a, b, _, args in ev
+                      if n == "mx." + name)
+    fetches = of("serving.first_token")
+    assert [args["rid"] for _, _, args in fetches] == [0, 1]
+    assert [args["lane"] for _, _, args in fetches] == [0, 1]
+    for (aa, ab, _), (pa, pb, pargs), (fa, fb, fargs) in zip(
+            of("serving.admit"), of("serving.prefill"), fetches):
+        assert aa <= pa <= fa < fb <= pb <= ab
+        # the spans of one request share its rid in the trace
+        assert pargs["rid"] == fargs["rid"]
+    # every scalar argument of a span is in its event
+    assert {args["behind"] for _, _, args in of("serving.sync")} == {1}
+    assert {args["kind"] for _, _, args in of("serving.patch")} == {"admit"}
+
+
+def test_a_continuation_fetches_nothing_and_has_no_first_token_span(
+        dark, tmp_path):
+    srv = _batcher()
+    with session(tmp_path):
+        srv.admit_continuation([7, 8, 9, 10], 5, emitted=1)
+        srv.step()
+    t = core.span_totals()
+    assert t["serving.prefill"]["count"] == 1
+    assert "serving.first_token" not in t
+
+
+# ---------------------------------------------- a collection is a span ---
+
+@pytest.fixture
+def still():
+    """The collector held still, so that the only collections are the
+    test's own."""
+    gc.collect()
+    gc.disable()
+    yield
+    gc.enable()
+
+
+def test_a_collection_under_a_session_is_one_gc_span(dark, tmp_path,
+                                                     still):
+    gc.collect()
+    assert "gc" not in core.span_totals()           # no session: nothing
+    with session(tmp_path) as s:
+        with core.span("serving.step", cat="serving"):
+            # seeded by the first span that saw the session
+            assert core.span_totals()["gc"] == {
+                "count": 0, "total_ns": 0, "self_ns": 0, "max_ns": 0}
+            gc.collect()
+    t = core.span_totals()
+    assert t["gc"]["count"] == 1 and t["gc"]["max_ns"] == t["gc"]["total_ns"]
+    # nested in the span that was open: its self time does not carry it
+    assert t["serving.step"]["self_ns"] \
+        == t["serving.step"]["total_ns"] - t["gc"]["total_ns"]
+    (name, a, b, _, args), = s.host_events(prefixes=("mx.gc",), args=True)
+    assert args["generation"] == 2
+    gc.collect()
+    assert core.span_totals()["gc"]["count"] == 1   # the session is over
+
+
+def test_a_collection_under_mxnet_obs_is_a_record(dark, monkeypatch, still):
+    monkeypatch.setenv("MXNET_OBS", "1")
+    gc.collect(0)
+    recs = [r for r in core.records() if r[0] == "X"]
+    assert [(r[1], r[2], r[6]["generation"]) for r in recs] \
+        == [("gc", "runtime", 0)]
+    assert core.span_totals() == {}
+
+
+def test_a_collection_that_starts_under_the_modules_lock_takes_none(
+        dark, monkeypatch, still):
+    """A collection can start inside any allocation, one made while
+    core's lock is held among them; its hook runs there and then."""
+    monkeypatch.setenv("MXNET_OBS", "1")
+    with core._lock:
+        gc.collect(0)
+    assert [r[1] for r in core.records() if r[0] == "X"] == ["gc"]
+
+
+def test_collections_on_many_threads_are_each_one_record(
+        dark, monkeypatch, still):
+    """The hook leaves finished collections in a list that spans' stops
+    and the readers fold from any thread: none is lost or counted twice,
+    and nobody waits for anybody."""
+    import sys
+    monkeypatch.setenv("MXNET_OBS", "1")
+    each, workers = 50, 8
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+
+    def work():
+        for _ in range(each):
+            with core.span("serving.step", cat="serving"):
+                gc.collect(0)
+            core.span_totals()
+
+    ran = []        # a collect() that finds one in progress runs none
+
+    def count(phase, info):
+        if phase == "stop":
+            ran.append(info["generation"])
+
+    threads = [threading.Thread(target=work) for _ in range(workers)]
+    gc.callbacks.append(count)
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        gc.callbacks.remove(count)
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    names = [r[1] for r in core.records() if r[0] == "X"]
+    assert names.count("serving.step") == each * workers
+    assert each <= names.count("gc") == len(ran)
+    assert core.dropped() == 0
+
+
+# -------------------- the inside count is the outside count (PR 52) ---
+
+def test_the_ledger_counts_the_gaps_a_closed_loop_client_counts(
+        dark, tmp_path):
+    import json
+    from chipbench import manifest
+    from chipbench.runners import serve_lm
+    from chipbench.traffic import ClosedLoop, request_stream
+    here = os.path.join(os.path.dirname(__file__), "bench_harness")
+    with open(os.path.join(here, "tiny", "lm.json")) as f:
+        lm = json.load(f)
+    traffic = dict(
+        manifest.load_traffic("closed24"), clients=3, pool=6, max_total=64,
+        prompt={"median": 16, "sigma": 0.8, "lo": 4, "hi": 40},
+        output={"median": 8, "sigma": 0.7, "lo": 2, "hi": 20})
+    sess = serve_lm.build(lm, traffic, 5)
+    loop = ClosedLoop(sess, traffic,
+                      request_stream(traffic, 5, lm["vocab_size"]))
+    t_open = time.perf_counter()
+    with session(tmp_path):
+        loop.run_until(turned_over=8)
+    m = loop.reduce(t_open, time.perf_counter())
+    c = core.counters()
+    assert m["gaps"] > 40 and len(m["finished"]) >= 8
+    assert c["serving.gaps"].value == m["gaps"]
+    assert 0 < c["serving.gaps_behind_admit"].value < m["gaps"]
+    assert 0 < c["serving.gap_admit_ns"].value <= c["serving.gap_ns"].value
