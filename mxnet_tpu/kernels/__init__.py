@@ -14,9 +14,10 @@ from .latent_decode import (latent_decode, latent_decode_reference,
                             latent_row_store)
 from .grouped_matmul import grouped_matmul, grouped_matmul_reference
 from .kv_decode import kv_decode, kv_decode_reference
+from .chunk_attention import chunk_attention
 
 __all__ = ["flash_attention", "flash_decode",
            "dense_decode_with_lse", "paged_attention",
            "latent_decode", "latent_decode_reference", "latent_row_store",
            "grouped_matmul", "grouped_matmul_reference",
-           "kv_decode", "kv_decode_reference"]
+           "kv_decode", "kv_decode_reference", "chunk_attention"]

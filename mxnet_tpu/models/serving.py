@@ -1061,8 +1061,8 @@ class ContinuousBatcher(object):
         # heads, or a latent; an "ffn" block keeps nothing), the rows
         # its leaf holds a lane (max_len, or a window layer's ring) with
         # the bytes of one
-        row = list(zip(tf._layer_kinds(cfg),
-                       jax.eval_shape(lambda: tf.init_cache(cfg, 1))))
+        self._lane_row = jax.eval_shape(lambda: tf.init_cache(cfg, 1))
+        row = list(zip(tf._layer_kinds(cfg), self._lane_row))
 
         def nbytes(layer):
             return sum(x.size * x.dtype.itemsize for x in layer.values())
@@ -1363,6 +1363,10 @@ class ContinuousBatcher(object):
             # counted while spans record (_count_kv_contractions)
             for name in ("kv.decode_kernel", "kv.decode_reference"):
                 snap[name] = _obs.counter(name).value
+        if self._kv_layers:
+            # counted while spans record (_count_prefill): one the whole
+            for name in ("attn.chunk_calls", "attn.chunk_kernel"):
+                snap[name] = _obs.counter(name).value
         if self._kv_layers or self._latent_layers:
             # counted as a program is traced (tf._causal_attention): the
             # whole-prompt prefill of generate() and the training forward
@@ -1596,12 +1600,21 @@ class ContinuousBatcher(object):
         Mamba-2 layers also ssd.rows_live, those tokens a Mamba-2
         layer, and ssd.rows_scanned, the rows its chunked form ran for
         them: the call's width in whole chunks (ssd.mixer_seq pads a
-        width that is no multiple of cfg.ssd_chunk), a layer."""
+        width that is no multiple of cfg.ssd_chunk), a layer; for a
+        model with K/V layers attn.chunk_calls, the call's chunk
+        contractions (a K/V layer each), and attn.chunk_kernel, those of
+        them that ran kernels/chunk_attention.py's kernel and wrote no
+        score plane, by the call's own rule (tf.chunk_contractions)."""
         if _obs.active():
             _obs.counter("serving.prefill_tokens").add(tokens)
             _obs.counter("serving.prefill_rows").add(rows)
             self._count_frame_rows(rows)
             self._count_expert_matmuls(rows)
+            if self._kv_layers:
+                calls, kernel = tf.chunk_contractions(
+                    self.params, self.cfg, self._lane_row, rows)
+                _obs.counter("attn.chunk_calls").add(calls)
+                _obs.counter("attn.chunk_kernel").add(kernel)
             if self._ssd_layers:
                 _obs.counter("ssd.rows_live").add(
                     self._ssd_layers * tokens)

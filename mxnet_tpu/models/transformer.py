@@ -1815,21 +1815,74 @@ def _attn_blocked(queries, rows, cfg):
     return queries * rows * cfg.n_heads > ATTN_PLANE_ELEMS
 
 
+def chunk_attention_blocks(q, view, positions, window=None):
+    """(block_q, block_k) where a chunk's contraction of q [B, C, H, D]
+    (an array, or its shape and dtype) against a layer's view {"k", "v"}
+    [B, T, KVH, D] runs kernels/chunk_attention.py's kernel, by what the
+    call holds: None, and the XLA text stays, for a window layer (its
+    view is the ring unrolled with the fresh rows behind it, from a
+    `first` position), int8 rows, positions a lane ([B, C]: both
+    verifiers, whose rows must stay bit-identical with decode's), a query
+    of another dtype than the rows, and the shapes
+    kernels.chunk_attention.chunk_blocks has no blocks for (heads no
+    multiple of 128 wide: toy widths; a chunk under its floor of 1,024
+    queries: the narrower buckets, whose planes fit VMEM, and a
+    speculative round's k + 1 rows; a chunk or rows 128 does not divide). serving.py counts an admission's
+    contractions by this rule (attn.chunk_kernel of attn.chunk_calls)."""
+    rows = view["k"]
+    if window is not None or "ks" in view or len(positions.shape) != 1 \
+            or q.dtype != rows.dtype:
+        return None
+    from ..kernels.chunk_attention import chunk_blocks
+    return chunk_blocks(q.shape[1], rows.shape[1], q.shape[2],
+                        rows.shape[2], q.shape[3], rows.dtype.itemsize)
+
+
+def chunk_contractions(params, cfg, row, rows):
+    """Of the chunk contractions ONE prefill_chunk call of `rows` token
+    rows makes against the one-lane row cache `row` (init_cache's
+    layers, arrays or their shapes and dtypes), a K/V layer each: (all of
+    them, those that run kernels/chunk_attention.py's kernel), by the
+    call's own rule (chunk_attention_blocks). serving.py adds them to
+    the counters attn.chunk_calls / attn.chunk_kernel."""
+    embed = params["embed"]
+    q = jax.ShapeDtypeStruct(
+        (1, rows, cfg.n_heads, _head_dim(cfg)),
+        (embed["dt"] if _is_q8(embed) else embed).dtype)
+    at = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    kernel = [chunk_attention_blocks(
+                  q, layer, at,
+                  _window(cfg) if kind == "window" else None) is not None
+              for kind, layer in zip(_layer_kinds(cfg), row)
+              if kind in ("attention", "window")]
+    return len(kernel), sum(kernel)
+
+
 def _cached_attention(q, view, positions, cfg, out_dtype, window=None,
                       first=0):
     """The one chunk contraction against cached K/V: q [B, C, H, D]
     against a layer's view [B, T, KVH, D], chunk row i attending
-    positions t <= positions[i] ([C], one window for the whole batch)
+    positions t <= positions[i] ([C], one window for the whole batch:
+    prefill_chunk's consecutive positions from its `start`)
     or t <= positions[b, i] ([B, C], a window a lane), so stale entries
     beyond the verified stream are never read. Grouped: the KVH-head
     cache is read once per GROUP of query heads (like _decode_attention,
     no materialized repeat on the hot path). Chunked prefill and both
     verifiers read through THIS function, which is what keeps
-    pool == solo and verify == decode bit-identical. A window layer's
-    view ([the ring unrolled, the fresh rows], row t at position
-    `first` + t: _ring_rows) and a plane past ATTN_PLANE_ELEMS contract
-    in blocks (_blocked_attention): the same sums in another order."""
+    pool == solo and verify == decode bit-identical. Where
+    chunk_attention_blocks has blocks for the call, one kernel that
+    writes no score plane and reads no row behind the chunk's last
+    position (kernels/chunk_attention.py). Elsewhere the XLA text: one
+    score plane (_cached_plane), or for a window layer's view ([the ring
+    unrolled, the fresh rows], row t at position `first` + t:
+    _ring_rows) and a plane past ATTN_PLANE_ELEMS the contraction in
+    blocks (_blocked_attention). All three are the same sums in another
+    order."""
     with _attn_scope(window):
+        if chunk_attention_blocks(q, view, positions, window):
+            from ..kernels.chunk_attention import chunk_attention
+            return chunk_attention(q, view["k"], view["v"],
+                                   positions[0]).astype(out_dtype)
         if window is not None or (not cfg.kv_cache_int8 and _attn_blocked(
                 q.shape[1], view["k"].shape[1], cfg)):
             return _blocked_attention(q, view["k"], view["v"], positions,

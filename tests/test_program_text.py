@@ -71,7 +71,31 @@ dtype, no window, no mesh). The other twenty-two hashes stand, the three
 under the crossover (and Kimi-Linear's latent layers hold two head
 widths, Jamba2's and Nemotron's forwards 256 positions), so they lower
 to the XLA text, letter for letter; no decode or admission program
-reaches _causal_attention at all."""
+reaches _causal_attention at all.
+
+PR 53 re-pinned on purpose the two admission programs of the K/V
+configurations whose width reaches kernels.chunk_attention.MIN_QUERIES
+(1,024): `smallthinker.admission-8192` and `nemotron.admission-2048`.
+The chunk contraction of their "attention" layers against the cached
+rows became the kernel of kernels/chunk_attention.py (chunk_attn;
+interpreted in the text a CPU lowers) where it was XLA's float32 score
+plane [1, C, KVH, G, T], or for SmallThinker's chunk of 8,192 the XLA
+blocks, by the rule of shapes behind _cached_attention
+(transformer.chunk_attention_blocks: a query of the rows' dtype, heads a
+multiple of 128 wide, 128 dividing chunk and rows, a chunk of 1,024
+queries or more, [C] positions, no window, no int8). It pinned four
+more: `cerebras.admission-1024` (the admission the cell's p95 gap waits
+behind) and `jamba.admission-1024` at their own text, with the kernel,
+and `cerebras.admission-128` and `jamba.admission-128` at the text of
+its parent (c1914b4). The other twenty-one hashes stand:
+`cerebras.admission-512`, `jamba.admission-256` and
+`smallthinker.admission-256` lie under the floor and keep the plane,
+letter for letter (the floor was 256 while this PR was measured and
+went to 1,024 by what the chip read: PERF.md section 6); every decode,
+prefill, forward, `*.forward-256` and train step; and the five
+admissions of the latent configurations, whose chunks contract through
+_latent_chunk_attention. SmallThinker's six window layers keep
+_blocked_attention inside its re-pinned program."""
 
 import hashlib
 import importlib
@@ -197,6 +221,10 @@ PROGRAMS = {
     "nemotron.decode": (_decode, NE),
     "nemotron.admission-2048": (_admission, NE, 2048),
     "nemotron.forward-256": (_forward, NE, 256),
+    "cerebras.admission-128": (_admission, CE, 128),
+    "cerebras.admission-1024": (_admission, CE, 1024),
+    "jamba.admission-128": (_admission, JA, 128),
+    "jamba.admission-1024": (_admission, JA, 1024),
 }
 # sha256 of the StableHLO text, first 16 hex digits, at the parent commit
 # (of PR 41; a line says where a later PR moved or first pinned it)
@@ -224,12 +252,18 @@ AT_THE_PARENT = {
     "jamba.prefill-256": "eb93d3a29c28a6da",
     # first pinned at PR 47, at its own text
     "smallthinker.admission-256": "5c3d4288cbea8573",
-    "smallthinker.admission-8192": "5fff65ce8f15c9e9",
+    "smallthinker.admission-8192": "537b9be32ba7e7a7",    # PR 53: chunk_attn
     "smallthinker.decode": "af1da804614b0d21",    # PR 48: kv_decode
     # first pinned at PR 50, at its own text
-    "nemotron.admission-2048": "cad49bd4c57883bd",
+    "nemotron.admission-2048": "72e4d63e20d07ed4",    # PR 53: chunk_attn
     "nemotron.decode": "40d112c918c1fe5f",
     "nemotron.forward-256": "c3dda5037fbe78a0",
+    # first pinned at PR 53: with the kernel, and under its floor at the
+    # text of its parent (c1914b4)
+    "jamba.admission-1024": "ba8e9c8b1c5f6eb0",
+    "cerebras.admission-1024": "5af26d04bc3a4509",
+    "cerebras.admission-128": "a958bd68df7474f9",
+    "jamba.admission-128": "2ed0d58d9aa7940b",
 }
 
 

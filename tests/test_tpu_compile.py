@@ -357,6 +357,74 @@ def test_kv_decode(one_chip, lanes, rows, kvh, group):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+CHUNK_SHAPES = {
+    # queries, rows, query heads, K/V heads
+    "cerebras-256": (256, 2048, 16, 16),
+    "cerebras-512": (512, 2048, 16, 16),
+    "cerebras-1024": (1024, 2048, 16, 16),
+    "cerebras-2048": (2048, 2048, 16, 16),
+    "smallthinker-full-layer": (8192, 16384, 28, 4),
+    "nemotron": (8192, 8192, 32, 2),
+    "jamba2": (2048, 4096, 20, 1),
+}
+
+
+def _relaid(text, lines):
+    """Of `lines` (_moved), those that change an array's order: not
+    XLA's prefetch into the alternate memory (_prefetch), and not a
+    `copy` whose result has its operand's layout but for the memory
+    space (`S(n)`)."""
+    import re
+
+    def layout(spelt):
+        return re.sub(r"S\(\d\)", "", spelt)
+
+    out = []
+    for line in lines:
+        if _prefetch(line):
+            continue
+        made = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) copy\(%([^),]+)\)", line)
+        source = made and re.search(
+            r"%%%s = (\S+) " % re.escape(made.group(2)), text)
+        if source and layout(made.group(1)) == layout(source.group(1)):
+            continue
+        out.append(line)
+    return out
+
+
+@pytest.mark.parametrize("queries,rows,heads,kvh", CHUNK_SHAPES.values(),
+                         ids=CHUNK_SHAPES.keys())
+def test_chunk_attention(one_chip, queries, rows, heads, kvh):
+    """A chunk's contraction against cached K/V rows at the Cerebras
+    cell's four bucket widths (the route hands the kernel the two wide
+    ones: kernels.chunk_attention.MIN_QUERIES; its own door takes all
+    four) and at the widest chunk of the three other K/V configurations: the rows are read from the 4-D leaf where it lies
+    (a block of up to 1,024 positions of all K/V heads; one head's rows are a
+    strided load inside the kernel; Jamba2's one head as the [T, D] the
+    chip keeps it as), so no copy or transpose of an array of a leaf's
+    size stands before the call, and nothing is a temporary: no score
+    plane, at any width. The queries go in and come out heads-first, the
+    order the kernel works in and XLA gives a projection's result
+    (test_cerebras_admission_holds_no_score_plane)."""
+    from mxnet_tpu.kernels.chunk_attention import chunk_attention
+    leaf = _sds(one_chip, (1, rows, kvh, 128))
+
+    def heads_first(q, k, v, start):
+        return chunk_attention(q.transpose(0, 2, 1, 3), k, v, start,
+                               interpret=False).transpose(0, 2, 1, 3)
+
+    compiled = _compile(
+        heads_first, _sds(one_chip, (1, heads, queries, 128)), leaf, leaf,
+        _sds(one_chip, (), jnp.int32))
+    text = compiled.as_text()
+    assert "chunk_attn" in text
+    moved = _relaid(text, _moved(text, (
+        "[1,%d,%d,128]" % (rows, kvh), "[1,%d,%d,128]" % (heads, queries),
+        "[1,%d,%d,128]" % (queries, heads))))
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 EXPERT_SHAPES = {
     # rows (lanes or a chunk's tokens x picks), experts held, d, width
     "kimi-linear-decode": (256, 64, 2304, 1024),
@@ -537,6 +605,42 @@ def test_cerebras_decode_round_reads_its_rows_in_place(one_chip, cerebras,
     assert mem.alias_size_in_bytes > 9.6e9          # the lanes, in place
 
 
+def test_cerebras_admission_holds_no_score_plane(one_chip, cerebras,
+                                                 monkeypatch):
+    """An admission of the bucket of 1,024 against a one-lane row, as
+    `_jitted_prefill_chunk_row` jits it: every layer's chunk contraction
+    is the kernel chunk_attn (24 calls), the float32 score plane
+    [1, 1024, 16, 1, 2048] that three fusions a layer wrote and read is
+    nowhere in the compiled program, the queries reach the kernel in the
+    order the projection's matmul leaves them and the rows as the store
+    leaves them (no copy or transpose of an array of their sizes but
+    XLA's own moves between memory spaces), and the temporaries fall
+    from 331 MB to 200."""
+    from mxnet_tpu.models import transformer as tf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = cerebras
+    width = 1024
+    row = _on(one_chip, jax.eval_shape(lambda: tf.init_cache(cfg, 1)))
+    compiled = jax.jit(
+        lambda p, c, t, s, r: tf.prefill_chunk(p, c, t, s, cfg,
+                                               logits_row=r)).lower(
+        params, row, _sds(one_chip, (1, width), jnp.int32),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 24
+    assert "chunk_attn" in text
+    assert "f32[1,%d,16,1,2048]" % width not in text
+    moved = _relaid(text, _moved(text, (
+        "[1,2048,16,128]", "[1,%d,16,128]" % width,
+        "[1,16,%d,128]" % width)))
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    print("cerebras admission of %d: temporaries %d bytes"
+          % (width, mem.temp_size_in_bytes))
+    assert mem.temp_size_in_bytes < 0.25e9
+
+
 def test_smallthinker_decode_round_reads_its_rows_in_place(
         smallthinker_round):
     """The two full layers' contractions are the kernel kv_decode, with
@@ -559,12 +663,14 @@ def test_smallthinker_decode_round_reads_its_rows_in_place(
 def test_smallthinker_admission_chunk(one_chip, smallthinker, monkeypatch,
                                       width, limit):
     """An admission's chunk at the cell's widest and narrowest widths
-    against a one-lane row: 8,192 queries against 16,384 rows of 28
-    heads would be 15 GB of scores in one plane and contract in blocks
-    (1.04 GB of temporaries when written, the experts' 49,152 picked
-    rows the largest of them); a chunk of 256 keeps the one plane (0.49
-    GB). Both fit beside 11.69 GB of weights and lanes and the row twice
-    (0.23 GB) in the chip's 15.75 GB."""
+    against a one-lane row: since PR 53 the two full layers' contraction
+    at 8,192 is the kernel chunk_attn (8,192 queries against 16,384 rows
+    of 28 heads would be 15 GB of scores in one plane; the six window
+    layers contract in XLA's blocks); 1.04 GB of temporaries when
+    written, the experts' 49,152 picked rows the largest of them; a
+    chunk of 256 lies under the kernel's floor and keeps the one plane,
+    0.49 GB. Both fit beside 11.69 GB of weights and lanes and the row
+    twice (0.23 GB) in the chip's 15.75 GB."""
     from mxnet_tpu.models import transformer as tf
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, params = smallthinker
@@ -575,7 +681,9 @@ def test_smallthinker_admission_chunk(one_chip, smallthinker, monkeypatch,
         params, row, _sds(one_chip, (1, width), jnp.int32),
         _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32)
     ).compile()
-    assert "moe_gmm" in compiled.as_text()
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    assert (text.count("chunk_attn/pallas_call") >= 2) == (width >= 1024)
     mem = compiled.memory_analysis()
     print("smallthinker admission of %d: temporaries %d bytes"
           % (width, mem.temp_size_in_bytes))
@@ -661,6 +769,9 @@ def test_nemotron_admission_chunk(one_chip, nemotron, monkeypatch, width,
     text = compiled.as_text()
     assert "mx.ssd.chunk" in text and "mx.ssd.conv" in text \
         and "moe_gmm" in text
+    # since PR 53 the two attention blocks' chunk contraction too, from
+    # its floor of 1,024 queries on
+    assert (text.count("chunk_attn/pallas_call") >= 2) == (width >= 1024)
     mem = compiled.memory_analysis()
     print("nemotron admission of %d: temporaries %d bytes"
           % (width, mem.temp_size_in_bytes))

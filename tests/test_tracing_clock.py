@@ -105,7 +105,8 @@ def _batcher(**kw):
 # what the batcher counts while its spans record (models/serving.py
 # _count_dispatch and _count_prefill; since PR 48 a model with K/V layers,
 # as this one, its decode contractions by the path they took,
-# _count_kv_contractions; a model with routed experts adds moe.*, one with
+# _count_kv_contractions, and since PR 53 an admission's chunk
+# contractions, attn.chunk_*; a model with routed experts adds moe.*, one with
 # latent layers mla.*, one with window layers kv.rows_*, one with
 # hyper-connections hc.rows; since PR 52 the gap ledger's four,
 # _count_gaps, made together at the first counted delivery), and since
@@ -116,7 +117,8 @@ GAP_LEDGER = ("serving.gaps", "serving.gap_ns",
 WHILE_SPANS_RECORD = set(GAP_LEDGER) | {
     "serving.dispatches", "serving.dispatch_ahead",
     "serving.prefill_tokens", "serving.prefill_rows", "kv.decode_kernel",
-    "kv.decode_reference", "cachedop.recorded_calls",
+    "kv.decode_reference", "attn.chunk_calls", "attn.chunk_kernel",
+    "cachedop.recorded_calls",
     "cachedop.saved_buffers", "cachedop.saved_bytes"}
 
 
